@@ -6,6 +6,8 @@ tables, trains on half the corpus (pull-train-push), and the merged global
 embeddings separate the corpus topics.
 
 Run:  python examples/distributed_word2vec_demo.py
+Each worker holds a device: on a TPU host the launcher gives each its own
+chip (multiverso_tpu/utils/chips.py); ``JAX_PLATFORMS=cpu`` runs anywhere.
 """
 
 import os
@@ -19,8 +21,6 @@ REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 
 WORKER = r"""
 import os, sys, time
-import jax
-jax.config.update("jax_platforms", "cpu")   # demo runs anywhere
 import numpy as np
 import multiverso_tpu as mv
 from multiverso_tpu.models.word2vec import Dictionary, Word2VecConfig
@@ -81,10 +81,15 @@ def main() -> int:
     script = os.path.join(workdir, "worker.py")
     with open(script, "w") as f:
         f.write(WORKER)
-    env = dict(os.environ)
-    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    procs = [subprocess.Popen([sys.executable, script, str(r), workdir],
-                              env=env) for r in range(2)]
+    sys.path.insert(0, REPO)
+    from multiverso_tpu.utils.chips import child_env
+
+    procs = []
+    for r in range(2):
+        env = child_env(r, 2, "distributed_word2vec_demo") or dict(os.environ)
+        env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
+        procs.append(subprocess.Popen(
+            [sys.executable, script, str(r), workdir], env=env))
     rc = 0
     for p in procs:
         p.wait(timeout=600)

@@ -2,6 +2,7 @@
 """Parameter-server table tour: every table type, sync/async, checkpointing.
 
 Run:  python examples/ps_tables_demo.py
+(on the devices jax finds; ``JAX_PLATFORMS=cpu`` for a CPU run)
 """
 
 import os
@@ -15,8 +16,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 
 def main() -> int:
-    from examples._backend import pin_backend
-    pin_backend()
     import multiverso_tpu as mv
     from multiverso_tpu.core import checkpoint as ckpt
     from multiverso_tpu.parallel.async_engine import (AsyncTableEngine,

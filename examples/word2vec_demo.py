@@ -2,8 +2,9 @@
 """End-to-end word2vec demo: generate a corpus, train, inspect neighbors.
 
 Run:  python examples/word2vec_demo.py
-(Choose the backend with jax's platform config; everything else is
-self-contained — the demo writes its corpus to a temp dir.)
+(Runs on the devices jax finds; ``JAX_PLATFORMS=cpu`` for a CPU run.
+Everything else is self-contained — the demo writes its corpus to a temp
+dir.)
 """
 
 import os
@@ -32,8 +33,6 @@ def make_corpus(path: str, n_sentences: int = 2000) -> None:
 
 
 def main() -> int:
-    from examples._backend import pin_backend
-    pin_backend()
     import multiverso_tpu as mv
     from multiverso_tpu.models.word2vec import (Dictionary, Word2Vec,
                                                 Word2VecConfig, read_corpus)

@@ -9,19 +9,37 @@ regression). See SURVEY.md for the structural map of the reference this
 framework re-implements TPU-first.
 """
 
+import os as _os
+
 import jax as _jax
 
-# Sharding-invariant PRNG: the legacy (non-partitionable) threefry lowering
-# produces DIFFERENT random bits inside a GSPMD-partitioned program than in
-# the single-device program (observed on jax 0.4.37: the in-graph window
-# draws of the dp x tp word2vec block step diverged from the unsharded step,
-# changing pair counts). Partitionable threefry computes each element from
-# its global index, so draws are identical under any mesh layout — required
-# for the "same keys -> same pairs" contract of build_sharded_block_step.
-try:
+
+def _configure_jax() -> None:
+    """Process-wide jax settings, applied once at import so CLIs, spawned
+    ranks, ``bench.py`` and ``chip_smoke.py`` all share them.
+
+    * Sharding-invariant PRNG: the legacy (non-partitionable) threefry
+      lowering produces DIFFERENT random bits inside a GSPMD-partitioned
+      program than in the single-device program (the in-graph window draws
+      of the dp x tp word2vec block step diverged from the unsharded step,
+      changing pair counts). Partitionable threefry computes each element
+      from its global index, so draws are identical under any mesh layout —
+      required for the "same keys -> same pairs" contract of
+      build_sharded_block_step.
+    * Persistent compilation cache: where ``JAX_COMPILATION_CACHE_DIR`` is
+      set jax already honors it and nothing is set here; otherwise the
+      cache lives at ``<checkout>/.jax_cache``. The path is part of the
+      cache key, so it is fixed: no temp dir, pid or timestamp.
+    """
     _jax.config.update("jax_threefry_partitionable", True)
-except AttributeError:  # pragma: no cover - future jax removes the flag
-    pass
+    if not _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        checkout = _os.path.dirname(_os.path.dirname(
+            _os.path.abspath(__file__)))
+        _jax.config.update("jax_compilation_cache_dir",
+                           _os.path.join(checkout, ".jax_cache"))
+
+
+_configure_jax()
 
 from multiverso_tpu.api import (aggregate, barrier, create_table,
                                 create_distributed_array_table,

@@ -35,11 +35,8 @@ TRACING_TRANSFORMS = {
     "jax.vmap", "jax.pmap", "jax.grad", "jax.value_and_grad",
     "jax.jacfwd", "jax.jacrev", "jax.hessian", "jax.linearize",
     "jax.checkpoint", "jax.remat", "jax.custom_jvp", "jax.custom_vjp",
-    "jax.experimental.shard_map.shard_map",
+    "jax.shard_map", "jax.experimental.shard_map.shard_map",
     "jax.experimental.pallas.pallas_call",
-    # repo-local transform wrappers (parallel/mesh.py re-exports shard_map
-    # with a version-compat shim; ops/ builders hand back jitted steps)
-    "multiverso_tpu.parallel.mesh.shard_map",
 }
 
 #: callables whose *function-valued arguments* run under the caller's trace

@@ -30,8 +30,7 @@ from multiverso_tpu.analysis.core import (FileContext, Finding, Rule,
 
 _JIT_NAMES = {"jax.jit", "jax.pjit", "jax.experimental.pjit.pjit"}
 _TRANSFORM_IN_LOOP = _JIT_NAMES | {
-    "jax.experimental.shard_map.shard_map",
-    "multiverso_tpu.parallel.mesh.shard_map",
+    "jax.shard_map", "jax.experimental.shard_map.shard_map",
     "jax.experimental.pallas.pallas_call",
     "jax.vmap", "jax.grad", "jax.value_and_grad",
 }

@@ -68,8 +68,6 @@ configure.define_int("dlrm_cache_staleness", 0,
                      "cache staleness bound (clock ticks)")
 configure.define_string("dlrm_summary_file", "",
                         "write the run summary JSON here")
-configure.define_string("dlrm_device", "",
-                        "jax platform override (cpu|default)")
 
 
 def _int_tuple(raw: str, flag: str) -> tuple:
@@ -165,11 +163,9 @@ def _body(argv: List[str]) -> int:
 
 
 def main(argv=None) -> int:
-    from multiverso_tpu.apps._runner import pin_device_if_requested, run_app
+    from multiverso_tpu.apps._runner import run_app
 
-    args = argv if argv is not None else sys.argv[1:]
-    pin_device_if_requested(args, device_flag="dlrm_device")
-    return run_app(_body, args)
+    return run_app(_body, argv if argv is not None else sys.argv[1:])
 
 
 if __name__ == "__main__":

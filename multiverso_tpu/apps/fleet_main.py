@@ -11,9 +11,10 @@ Three roles (``-fleet_role``):
   (``-fleet_synthetic=ROWSxCOLS@SEED`` — benches/smokes), warms every
   bucket executable, then joins the router and heartbeats.
 * ``local``   — dev/bench topology in one command: an in-process router
-  plus ``-fleet_replicas`` spawned replica processes (each pinned to CPU
-  unless ``-serve_device=default`` — N local replicas must not fight
-  over one chip). With ``-fleet_supervise`` the spawned fleet is
+  plus ``-fleet_replicas`` spawned replica processes. On a TPU host each
+  replica is handed its own chip (utils/chips.py; more replicas than
+  chips is an error) and this process stays off the device. With
+  ``-fleet_supervise`` the spawned fleet is
   SELF-HEALING (docs/DURABILITY.md): a dead or heartbeat-lost replica
   is respawned through the same spawn path, firing SLO-burn /
   queue-saturation alerts grow the fleet (to ``-fleet_max_replicas``),
@@ -46,9 +47,9 @@ import sys
 import time
 from typing import List
 
-from multiverso_tpu.apps._runner import (fleet_config,
-                                         pin_device_if_requested, run_app,
-                                         serve_config)
+from multiverso_tpu.apps._runner import (fleet_config, raw_flag_value,
+                                         run_app, serve_config)
+from multiverso_tpu.utils.chips import child_env, sigterm_as_interrupt
 from multiverso_tpu.utils.configure import define_string, get_flag
 from multiverso_tpu.utils.log import check, log
 
@@ -57,8 +58,6 @@ define_string("checkpoint_dir", "", "checkpoint directory to serve from "
               "(latest complete ckpt_* is loaded; drains hot-swap to it)")
 define_string("serve_table", "", "table name to serve rows from (empty = "
               "the checkpoint's first table)")
-define_string("serve_device", "default", "default|cpu: cpu pins jax off "
-              "the chip (serving a replica needs no accelerator)")
 
 
 def _write_addr_file(path: str, address) -> None:
@@ -85,19 +84,17 @@ def _build_synthetic_runner(rows: int, cols: int, seed: int):
     """Seeded synthetic lookup table: every replica spawned with the same
     -fleet_synthetic value serves bitwise-identical rows (what the bench
     parity check and the smoke's get_rows comparison rely on)."""
-    import jax
     import numpy as np
-    from jax.sharding import Mesh
 
     from multiverso_tpu.core.table import ServerStore
     from multiverso_tpu.core.updater import get_updater
+    from multiverso_tpu.core.zoo import Zoo
     from multiverso_tpu.serving import SparseLookupRunner
 
     rng = np.random.default_rng(seed)
-    mesh = Mesh(np.asarray(jax.devices()[:1]).reshape(1), ("server",))
     store = ServerStore(
         "fleet_synthetic", (rows, cols), np.float32,
-        get_updater(np.float32, "default"), mesh, num_workers=1,
+        get_updater(np.float32, "default"), Zoo.get().mesh, num_workers=1,
         init_array=rng.normal(size=(rows, cols)).astype(np.float32))
     from multiverso_tpu.serving.cache import cache_from_flags
     # The synthetic table is immutable: a constant clock is its honest
@@ -278,10 +275,10 @@ def _router_body(cfg: dict) -> int:
 
 def _spawn_replicas(cfg: dict, router_addr, args: List[str],
                     count: int, first_slot: int = 0) -> List:
-    """Re-exec this module once per replica, pointed at the router. Each
-    child defaults to CPU pinning (N local replicas would otherwise fight
-    for one accelerator). ``first_slot`` numbers the member ids — the
-    supervisor respawns/scales individual slots through the same path."""
+    """Re-exec this module once per replica, pointed at the router. On a
+    TPU host slot ``r`` runs on chip ``r``, whether first spawned or
+    respawned. ``first_slot`` numbers the member ids — the supervisor
+    respawns/scales individual slots through the same path."""
     import subprocess
 
     base = [a for a in args
@@ -292,15 +289,15 @@ def _spawn_replicas(cfg: dict, router_addr, args: List[str],
                                              "fleet_supervise=",
                                              "serve_addr_file=",
                                              "serve_port="))]
-    if not any(a.lstrip("-").startswith("serve_device=") for a in base):
-        base.append("-serve_device=cpu")
+    holders = max(cfg["replicas"], first_slot + count)
     procs = []
     for r in range(first_slot, first_slot + count):
         cmd = [sys.executable, "-m", "multiverso_tpu.apps.fleet_main",
                "-fleet_role=replica",
                f"-fleet_router={router_addr[0]}:{router_addr[1]}",
                f"-fleet_member_id=replica-{r}", *base]
-        procs.append(subprocess.Popen(cmd))
+        procs.append(subprocess.Popen(
+            cmd, env=child_env(r, holders, "fleet_main -fleet_role=local")))
     return procs
 
 
@@ -377,7 +374,6 @@ def main(argv=None) -> int:
     # and inflates request p50 toward the switch interval on small hosts.
     sys.setswitchinterval(5e-4)
     args = list(argv if argv is not None else sys.argv[1:])
-    pin_device_if_requested(args, "serve_device")
     raw_args = list(args)
 
     def _body(remaining: List[str]) -> int:
@@ -397,7 +393,13 @@ def main(argv=None) -> int:
               f"got '{role}'")
         return _local_body(cfg, raw_args)
 
-    return run_app(_body, args)
+    # Only a replica holds a device; every other role starts or talks to
+    # processes that do, and must leave the chips to them.
+    role = raw_flag_value(args, "fleet_role") or "local"
+    if role == "replica":
+        return run_app(_body, args)
+    with sigterm_as_interrupt():    # the children stop with the launcher
+        return run_app(_body, args, launcher=True)
 
 
 if __name__ == "__main__":

@@ -28,8 +28,6 @@ configure.define_int("world_size", 1, "number of distributed worker ranks")
 configure.define_int("lr_rank", -1, "this rank (set by the launcher)")
 configure.define_string("rendezvous_dir", "",
                         "shared dir for address exchange")
-configure.define_string("lr_device", "cpu",
-                        "distributed ranks: jax platform (cpu|default)")
 
 _DIST_TABLE_ID = 60
 
@@ -150,9 +148,7 @@ def _body(argv: List[str]) -> int:
 
 
 def main(argv=None) -> int:
-    from multiverso_tpu.apps._runner import (pin_cpu_for_local_rank,
-                                             pin_device_if_requested,
-                                             run_app, spawn_ranks)
+    from multiverso_tpu.apps._runner import run_app, spawn_ranks
 
     args = argv if argv is not None else sys.argv[1:]
     world = next((int(a.split("=", 1)[1]) for a in args
@@ -162,10 +158,6 @@ def main(argv=None) -> int:
     if world > 1 and not has_rank:
         return spawn_ranks("multiverso_tpu.apps.logreg_main", args, world,
                            rank_flag="lr_rank")
-    if has_rank:
-        pin_cpu_for_local_rank(args, device_flag="lr_device")
-    else:
-        pin_device_if_requested(args, device_flag="lr_device")
     return run_app(_body, args)
 
 

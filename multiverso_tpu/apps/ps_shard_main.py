@@ -43,8 +43,6 @@ define_double("ps_checkpoint_every_s", 0.0, "checkpoint this rank's "
               "shard (and truncate the WAL) every N seconds; 0 = never")
 define_string("checkpoint_dir", "", "shard checkpoint directory "
               "(restored on start when a shard file exists)")
-define_string("serve_device", "default", "default|cpu: cpu pins jax off "
-              "the chip (a PS seat needs no accelerator for the drill)")
 
 
 def _shard_uri(ckpt_dir: str, rank: int) -> str:
@@ -151,10 +149,7 @@ def _body(remaining: List[str]) -> int:
 
 
 def main(argv=None) -> int:
-    from multiverso_tpu.apps._runner import pin_device_if_requested
-    args = list(argv if argv is not None else sys.argv[1:])
-    pin_device_if_requested(args, "serve_device")
-    return run_app(_body, args)
+    return run_app(_body, list(argv if argv is not None else sys.argv[1:]))
 
 
 if __name__ == "__main__":

@@ -23,8 +23,7 @@ import sys
 import time
 from typing import List
 
-from multiverso_tpu.apps._runner import (pin_device_if_requested, run_app,
-                                         serve_config)
+from multiverso_tpu.apps._runner import run_app, serve_config
 from multiverso_tpu.utils.configure import (define_double, define_string,
                                             get_flag)
 from multiverso_tpu.utils.log import check, log
@@ -33,8 +32,6 @@ define_string("checkpoint_dir", "", "checkpoint directory to serve from "
               "(latest complete ckpt_* is loaded and followed)")
 define_string("serve_table", "", "table name to serve rows from (empty = "
               "the checkpoint's first table)")
-define_string("serve_device", "default", "default|cpu: cpu pins jax off "
-              "the chip (serving a replica needs no accelerator)")
 define_double("serve_refresh_s", 5.0, "seconds between checkpoint "
               "refresh polls (hot-swap cadence)")
 
@@ -97,9 +94,7 @@ def main(argv=None) -> int:
     # See fleet_main: serving processes convoy on the default 5ms GIL
     # switch interval; 0.5ms keeps request latency off that floor.
     sys.setswitchinterval(5e-4)
-    args = list(argv if argv is not None else sys.argv[1:])
-    pin_device_if_requested(args, "serve_device")
-    return run_app(_body, args)
+    return run_app(_body, list(argv if argv is not None else sys.argv[1:]))
 
 
 if __name__ == "__main__":

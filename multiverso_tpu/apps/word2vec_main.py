@@ -47,8 +47,8 @@ configure.define_int("pad_sentence_length", 512,
 configure.define_string("dispatch_mode", "auto",
                         "chunk-loop execution: auto|in_graph|"
                         "pipelined_host|pallas_grid (sg-ns device "
-                        "pipeline; auto probes launch latency + VMEM fit"
-                        " — docs/MIGRATION.md decision table)")
+                        "pipeline; auto probes launch latency — "
+                        "docs/MIGRATION.md decision table)")
 configure.define_int("dispatch_depth", 8,
                      "pipelined_host: chunk dispatches in flight before "
                      "the host waits on the oldest")
@@ -170,17 +170,8 @@ def _body(argv: List[str]) -> int:
     return 0
 
 
-configure.define_string("w2v_device", "cpu",
-                        "distributed ranks: jax platform (cpu|default). "
-                        "N local ranks must not contend for one TPU chip; "
-                        "'default' keeps the platform auto-selection for "
-                        "one-rank-per-host deployments")
-
-
 def main(argv=None) -> int:
-    from multiverso_tpu.apps._runner import (pin_cpu_for_local_rank,
-                                             pin_device_if_requested,
-                                             run_app, spawn_ranks)
+    from multiverso_tpu.apps._runner import run_app, spawn_ranks
 
     args = argv if argv is not None else sys.argv[1:]
     # Launcher path runs BEFORE run_app: it must not start the runtime (or
@@ -192,10 +183,6 @@ def main(argv=None) -> int:
     if world > 1 and not has_rank:
         return spawn_ranks("multiverso_tpu.apps.word2vec_main", args, world,
                            rank_flag="w2v_rank")
-    if has_rank:
-        pin_cpu_for_local_rank(args, device_flag="w2v_device")
-    else:
-        pin_device_if_requested(args, device_flag="w2v_device")
     return run_app(_body, args)
 
 

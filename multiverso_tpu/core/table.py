@@ -168,12 +168,16 @@ class ServerStore:
         # 2-byte types two rows per sublane in HBM ((8,128)(2,1) tiling),
         # so the kernels' single-row DMA slices fail to compile on real
         # chips ("Slice shape along dimension 0 must be aligned to
-        # tiling"). Multi-shard stays XLA: the row kernels would need
+        # tiling"). So are column counts that are not a multiple of the
+        # 128-lane tile (the reference's 1M x 50 matrix: "Slice shape
+        # along dimension 1 must be aligned to tiling (128), but is 50",
+        # v5e, PR 21). Multi-shard stays XLA: the row kernels would need
         # per-shard offset remapping under shard_map, and XLA's sharded
         # scatter already overlaps the collective with the update.
         self._pallas_cap = None
         if (use_pallas_rows and len(self.padded_shape) == 2
                 and np.dtype(self.dtype) == np.dtype(np.float32)
+                and self.padded_shape[1] % 128 == 0
                 and num_servers == 1):
             cap = pallas_row_capability(updater)
             if cap in ("scatter_add", "scatter_sub") or (
@@ -193,6 +197,13 @@ class ServerStore:
         # graftlint: disable=unbounded-metric-name
         self._g_state_bytes = gauge(f"ps.state_bytes.{name}")
         self._publish_memory_gauges()
+
+    @property
+    def row_plane(self) -> str:
+        """Which data plane serves this store's row ops: ``"xla"``, or the
+        Pallas capability selected at construction (``"scatter_add"``,
+        ``"scatter_sub"``, ``"fused_stateful"``)."""
+        return self._pallas_cap or "xla"
 
     @contextlib.contextmanager
     def _dispatch_scope(self):
@@ -299,12 +310,12 @@ class ServerStore:
 
         self._dense_update = jax.jit(dense, donate_argnums=(0, 1))
         if self._pallas_rows:
+            from multiverso_tpu.ops import pallas_interpret
             from multiverso_tpu.ops.pallas_rows import (fused_stateful_rows,
                                                         gather_rows,
                                                         scatter_add_rows)
 
-            # Mosaic kernels need the interpreter on CPU backends (tests).
-            interpret = jax.default_backend() == "cpu"
+            interpret = pallas_interpret(self.sharding.device_set)
 
             if self._pallas_cap == "fused_stateful":
                 from multiverso_tpu.core.updater import combine_duplicate_rows

@@ -24,6 +24,7 @@ import functools
 from typing import Any, Callable, Dict, Tuple
 
 import jax
+import jax.extend
 import jax.numpy as jnp
 import numpy as np
 
@@ -69,7 +70,7 @@ def _eval_jaxpr_contraction_proof(jaxpr, consts, guard, *args):
     one = jnp.where(guard, np.float32(1.0), np.float32(2.0))
 
     def read(v):
-        return v.val if isinstance(v, jax.core.Literal) else env[v]
+        return v.val if isinstance(v, jax.extend.core.Literal) else env[v]
 
     for var, val in zip(jaxpr.constvars, consts):
         env[var] = val

@@ -31,7 +31,10 @@ Used by ``fleet_main -fleet_role=ps_fleet`` (operator topology),
 ``serve_bench --chaos-drill`` (the kill-any-subset drill), and the
 fleet smoke tests. The owning process must have the multiverso runtime
 initialized (``mv.init``) before :meth:`PSShardFleet.start` builds the
-client seat.
+client seat — and, on a TPU host, must not have touched a jax device
+yet: every seat holds a shard on its own chip (utils/chips.py), the
+owner takes chip 0 for the rank-0 seat, and the seats are spawned before
+the owner's backend comes up.
 """
 
 from __future__ import annotations
@@ -44,6 +47,7 @@ import tempfile
 import time
 from typing import Dict, List, Optional, Tuple
 
+from multiverso_tpu.utils.chips import child_env, take_chip
 from multiverso_tpu.utils.log import check, log
 
 _REPO = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -79,7 +83,8 @@ class PSShardFleet:
     seat that survives any subset of shard deaths (park-and-retry
     through the replicated directory)."""
 
-    def __init__(self, shards: int = 4, *, table_id: int = 912,
+    def __init__(self, shards: int = 4, *, first_chip: int = 0,
+                 table_id: int = 912,
                  table_size: int = 256, table_kind: str = "array",
                  table_cols: int = 8, workdir: Optional[str] = None,
                  sync_acks: bool = True, wal_flush_ms: float = 25.0,
@@ -92,6 +97,10 @@ class PSShardFleet:
         check(table_kind in ("array", "matrix"),
               f"table_kind={table_kind!r} (want array|matrix)")
         self.shards = int(shards)
+        #: on a TPU host the owner runs on chip ``first_chip`` and seat
+        #: ``rank`` on chip ``first_chip + rank`` (a caller that already
+        #: gave chips to other children starts the fleet after them).
+        self.first_chip = int(first_chip)
         self.table_id = int(table_id)
         self.table_size = int(table_size)
         self.table_kind = table_kind
@@ -164,12 +173,20 @@ class PSShardFleet:
                f"-ps_checkpoint_every_s={self.checkpoint_every_s}",
                f"-ps_addr_file={self.addr_file(rank)}",
                f"-serve_duration={self.serve_duration}",
-               "-serve_device=cpu", "-telemetry_alerts=false",
-               "-telemetry_flight=false",
+               "-telemetry_alerts=false", "-telemetry_flight=false",
                *self.extra_seat_args.get(rank, [])]
-        proc = subprocess.Popen(cmd, cwd=_REPO)
+        proc = subprocess.Popen(
+            cmd, cwd=_REPO,
+            env=child_env(self.first_chip + rank, self._holders(),
+                          self._what()))
         self._handles[rank] = proc
         return proc
+
+    def _what(self) -> str:
+        return f"ps fleet (owner + {self.shards} shard seats)"
+
+    def _holders(self) -> int:
+        return self.first_chip + self.shards + 1
 
     def seat_alive(self, rank: int) -> bool:
         h = self._handles.get(rank)
@@ -191,6 +208,7 @@ class PSShardFleet:
             DistributedArrayTable, DistributedMatrixTable, PSService)
 
         check(self._svc is None, "fleet already started")
+        take_chip(self.first_chip, self._holders(), self._what())
         self._svc = PSService()
         self.peers = [self._svc.address] \
             + [("127.0.0.1", 1)] * self.shards

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from multiverso_tpu.ops import pallas_interpret
 from multiverso_tpu.parallel.sequence import (ring_attention,
                                               ulysses_attention)
 from multiverso_tpu.utils.log import check, log
@@ -167,10 +168,11 @@ def _BLOCK_SHAPES(cfg: LMConfig):
             (cfg.dim, 4 * cfg.dim), (4 * cfg.dim, cfg.dim))
 
 
-def _pipeline_stage_fn(cfg: LMConfig, sp: int):
+def _pipeline_stage_fn(cfg: LMConfig, sp: int, interpret: bool):
     """One pipeline stage = blocks_per_stage transformer blocks. ``x`` is
     this device's [mb, S/sp, D] sequence block; attention runs the ring
-    body over the enclosing shard_map's "seq" axis."""
+    body over the enclosing shard_map's "seq" axis (``interpret``: that
+    mesh's ``ops.pallas_interpret`` verdict, for the flash kernel)."""
     from multiverso_tpu.parallel.sequence import ring_attention_block
 
     H, D = cfg.heads, cfg.dim
@@ -187,7 +189,7 @@ def _pipeline_stage_fn(cfg: LMConfig, sp: int):
             return t.reshape(mb, Sb, H, dh).transpose(0, 2, 1, 3)
 
         o = ring_attention_block(heads(q), heads(k), heads(v), "seq", sp,
-                                 causal=True)
+                                 causal=True, interpret=interpret)
         o = o.transpose(0, 2, 1, 3).reshape(mb, Sb, D)
         x = x + o @ bp["attn_out"]
         h = _ln(x)
@@ -305,7 +307,8 @@ class AttentionLM:
         self._opt_state = self._opt.init(self.params)
         self._token_sharding = NamedSharding(
             self.mesh, P(None, None, "seq"))
-        stage_fn = _pipeline_stage_fn(cfg, sp)
+        stage_fn = _pipeline_stage_fn(
+            cfg, sp, pallas_interpret(self.mesh.devices.flat))
         loss_fn = _pipeline_loss_fn(cfg.seq)
         stage_keys = ("qkv", "attn_out", "mlp_in", "mlp_out")
 
@@ -342,6 +345,12 @@ class AttentionLM:
 
     def fit(self, batches: Iterable[np.ndarray]) -> List[float]:
         """batches of int tokens [B, S]; returns per-batch losses."""
+        from multiverso_tpu.utils.configure import get_flag
+        check(not get_flag("flash_attention"),
+              "-flash_attention is forward-only (loss/eval): "
+              "flash_block_attn has no backward pass — jax's pallas_call "
+              "JVP rule rejects its scratch operands with a bare "
+              "AssertionError — so train without the flag (ROADMAP A8)")
         losses = []
         for tokens in batches:
             tokens = np.asarray(tokens, dtype=np.int32)

@@ -199,7 +199,7 @@ class DLRMModel:
         self._cp = cp
         lr = cfg.learning_rate
         loss_fn = _make_loss(cfg)
-        barrier = getattr(jax.lax, "optimization_barrier", lambda x: x)
+        barrier = jax.lax.optimization_barrier
 
         def delta_step(params, emb, dense_x, y):
             (loss, scores), (gp, gemb) = jax.value_and_grad(

@@ -295,7 +295,6 @@ class AllreduceModel:
     def __init__(self, cfg: LogRegConfig, table=None, dp_mesh=None,
                  dp_axis: Optional[str] = None):
         from multiverso_tpu.parallel import comm_policy as cp
-        from multiverso_tpu.parallel.mesh import shard_map
         from multiverso_tpu.utils.log import check
         from jax.sharding import PartitionSpec as P
 
@@ -311,7 +310,7 @@ class AllreduceModel:
         lr = cfg.learning_rate
         n_axis = (dp_mesh.shape.get(dp_axis, 1)
                   if dp_mesh is not None and dp_axis else 1)
-        barrier = getattr(jax.lax, "optimization_barrier", lambda x: x)
+        barrier = jax.lax.optimization_barrier
 
         # Bitwise parity with the PS path needs its exact rounding
         # points: there grad is a jit OUTPUT, lr*grad rounds as its own
@@ -334,9 +333,9 @@ class AllreduceModel:
                 loss = jax.lax.psum(loss, axis) / n_axis
                 return lr * barrier(grad), loss
 
-            fn = shard_map(delta_step, mesh=dp_mesh,
-                           in_specs=(P(), P(axis), P(axis)),
-                           out_specs=(P(), P()), check_vma=False)
+            fn = jax.shard_map(delta_step, mesh=dp_mesh,
+                               in_specs=(P(), P(axis), P(axis)),
+                               out_specs=(P(), P()), check_vma=False)
             # No donation by design: w must SURVIVE this program for the
             # separate donated apply kernel (the bitwise-parity split
             # above); grad/loss don't alias any input shape worth reusing.

@@ -4,11 +4,11 @@ from multiverso_tpu.models.word2vec.data import (BatchGenerator, BlockStream,
 from multiverso_tpu.models.word2vec.dictionary import (Dictionary,
                                                        HuffmanEncoder,
                                                        Sampler)
-from multiverso_tpu.models.word2vec.model import (DISPATCH_MODES, Word2Vec,
-                                                  Word2VecConfig,
-                                                  resolve_dispatch_mode)
+from multiverso_tpu.models.word2vec.model import (
+    DISPATCH_MODES, Word2Vec, Word2VecConfig, measured_dispatch_latency_ms,
+    resolve_dispatch_mode)
 
 __all__ = ["Word2Vec", "Word2VecConfig", "Dictionary", "HuffmanEncoder",
            "Sampler", "BatchGenerator", "BlockStream", "SkipGramBatch",
            "CbowBatch", "read_corpus", "DISPATCH_MODES",
-           "resolve_dispatch_mode"]
+           "measured_dispatch_latency_ms", "resolve_dispatch_mode"]

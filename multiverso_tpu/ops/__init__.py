@@ -1,15 +1,24 @@
 """TPU data-plane kernels (Pallas) and device-side table ops."""
 
+
+def pallas_interpret(devices) -> bool:
+    """The ONE place ``interpret=`` is decided for every Pallas kernel in
+    the package: from the platform of the devices the kernel's arrays live
+    on. True exactly when those are not TPUs (tier-1 runs the kernels under
+    the Pallas interpreter on CPU); never true on a TPU, where a kernel
+    Mosaic refuses fails with the compiler's message instead of quietly
+    running interpreted or giving way to XLA."""
+    return any(d.platform != "tpu" for d in devices)
+
+
 from multiverso_tpu.ops.pallas_rows import (gather_rows, scatter_add_rows,
                                             scatter_add_sorted_rows,
                                             tiled_scatter_add_rows,
                                             tiled_scatter_add_sorted_rows,
                                             tiled_scatter_eligible)
-from multiverso_tpu.ops.pallas_sgns import (build_sgns_grid_step,
-                                            sgns_grid_bytes,
-                                            sgns_grid_eligible)
+from multiverso_tpu.ops.pallas_sgns import build_sgns_grid_step
 
-__all__ = ["gather_rows", "scatter_add_rows", "scatter_add_sorted_rows",
-           "tiled_scatter_add_rows", "tiled_scatter_add_sorted_rows",
-           "tiled_scatter_eligible", "build_sgns_grid_step",
-           "sgns_grid_bytes", "sgns_grid_eligible"]
+__all__ = ["pallas_interpret", "gather_rows", "scatter_add_rows",
+           "scatter_add_sorted_rows", "tiled_scatter_add_rows",
+           "tiled_scatter_add_sorted_rows", "tiled_scatter_eligible",
+           "build_sgns_grid_step"]

@@ -22,7 +22,6 @@ ROADMAP perf #3).
 from __future__ import annotations
 
 import functools
-import inspect
 
 import jax
 import jax.numpy as jnp
@@ -32,10 +31,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _scratch(shape, dtype):
-    return pltpu.VMEM(shape, dtype)
-
 NEG_INF = -1e30
+_LANES = 128    # stats live lane-replicated (TPU tiling wants a 128 lane dim)
 
 
 def _kernel(offs_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, m_ref, l_ref,
@@ -87,8 +84,8 @@ def _kernel(offs_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, m_ref, l_ref,
     @pl.when(j == n_k - 1)
     def _flush():
         o_ref[0] = acc_s[...]
-        m_ref[0] = m_s[:, 0]
-        l_ref[0] = l_s[:, 0]
+        m_ref[0] = m_s[...]
+        l_ref[0] = l_s[...]
 
 
 @functools.partial(jax.jit,
@@ -148,33 +145,31 @@ def flash_block_attn(q: jax.Array, k: jax.Array, v: jax.Array,
             lambda offs, qr, kr, vr, *rest, **kws: _kernel(
                 offs, qr, kr, vr, None, *rest, **kws), **kw)
 
-    # Pre-VMA jax has no ``vma=`` kwarg on ShapeDtypeStruct — and nothing
-    # to declare either (mesh.shard_map disables the replication check
-    # there), so the annotation is simply dropped.
-    sds_kw = {}
-    if vma and "vma" in inspect.signature(jax.ShapeDtypeStruct).parameters:
-        sds_kw["vma"] = frozenset(vma)
+    sds_kw = {"vma": frozenset(vma)} if vma else {}
+    # The stats leave the kernel lane-replicated, [bh, Sq, 128], exactly as
+    # the scratch holds them: a (1, block_q) block of a [bh, Sq] array has a
+    # second-minor dim of 1, which Mosaic's (8, 128) block rule refuses.
     out_shape = [
         jax.ShapeDtypeStruct((bh, Sq, D), jnp.float32, **sds_kw),
-        jax.ShapeDtypeStruct((bh, Sq), jnp.float32, **sds_kw),
-        jax.ShapeDtypeStruct((bh, Sq), jnp.float32, **sds_kw),
+        jax.ShapeDtypeStruct((bh, Sq, _LANES), jnp.float32, **sds_kw),
+        jax.ShapeDtypeStruct((bh, Sq, _LANES), jnp.float32, **sds_kw),
     ]
     out_specs = [
         pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-        pl.BlockSpec((1, block_q), lambda b, i, j: (b, i)),
-        pl.BlockSpec((1, block_q), lambda b, i, j: (b, i)),
+        pl.BlockSpec((1, block_q, _LANES), lambda b, i, j: (b, i, 0)),
+        pl.BlockSpec((1, block_q, _LANES), lambda b, i, j: (b, i, 0)),
     ]
     scratch = [
-        _scratch((block_q, D), jnp.float32),
-        _scratch((block_q, 128), jnp.float32),
-        _scratch((block_q, 128), jnp.float32),
+        pltpu.VMEM((block_q, D), jnp.float32),
+        pltpu.VMEM((block_q, _LANES), jnp.float32),
+        pltpu.VMEM((block_q, _LANES), jnp.float32),
     ]
     o, m, l = pl.pallas_call(
         kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
         out_shape=out_shape, scratch_shapes=scratch,
         interpret=interpret)(*operands)
-    return (o.reshape(B, H, Sq, D), m.reshape(B, H, Sq, 1),
-            l.reshape(B, H, Sq, 1))
+    return (o.reshape(B, H, Sq, D), m[:, :, :1].reshape(B, H, Sq, 1),
+            l[:, :, :1].reshape(B, H, Sq, 1))
 
 
 def supported(q: jax.Array, k: jax.Array,
@@ -214,37 +209,41 @@ def _paged_kernel(ptab_ref, len_ref, t_ref, q_ref, k_ref, v_ref, o_ref,
         m_s[...] = jnp.full_like(m_s, NEG_INF)
         l_s[...] = jnp.zeros_like(l_s)
 
-    q = q_ref[0].astype(jnp.float32)                       # [H, dh]
+    # Every operand keeps a unit query dim ([H, 1, ...]): a batched
+    # dot_general whose lhs has NO free dim ([H, dh] x [H, P, dh]) reaches
+    # Mosaic with an empty lhs_non_contracting_dims list, which its
+    # attribute parser rejects.
+    q = q_ref[0].astype(jnp.float32)                       # [H, 1, dh]
     k = k_ref[0].astype(jnp.float32)                       # [H, P, dh]
     v = v_ref[0].astype(jnp.float32)                       # [H, P, dh]
-    # s[h, p] = q[h] . k[h, p]  (batched over heads)
-    s = jax.lax.dot_general(q, k, (((1,), (2,)), ((0,), (0,))),
+    # s[h, 0, p] = q[h] . k[h, p]  (batched over heads)
+    s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
                             preferred_element_type=jnp.float32) * scale
     # Slot/position mask computed IN the kernel from the prefetched
     # scalars — the drain path's formula verbatim: a key at logical
     # position r is valid iff r < len (real prompt) or bucket <= r <=
     # bucket + t (generated so far).
-    pos = j * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    pos = j * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
     length = len_ref[b]
     t = t_ref[b]
     valid = (pos < length) | ((pos >= bucket) & (pos <= bucket + t))
     s = s + jnp.where(valid, 0.0, NEG_INF)
 
-    m_prev = m_s[:, :1]                                    # [H, 1]
-    m_cur = jnp.max(s, axis=1, keepdims=True)
+    m_prev = m_s[:, :, :1]                                 # [H, 1, 1]
+    m_cur = jnp.max(s, axis=2, keepdims=True)
     m_new = jnp.maximum(m_prev, m_cur)
     alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)                                 # [H, P]
-    l_new = alpha * l_s[:, :1] + jnp.sum(p, axis=1, keepdims=True)
+    p = jnp.exp(s - m_new)                                 # [H, 1, P]
+    l_new = alpha * l_s[:, :, :1] + jnp.sum(p, axis=2, keepdims=True)
     acc_s[...] = acc_s[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (1,)), ((0,), (0,))),
+        p, v, (((2,), (1,)), ((0,), (0,))),
         preferred_element_type=jnp.float32)
     m_s[...] = jnp.broadcast_to(m_new, m_s.shape)
     l_s[...] = jnp.broadcast_to(l_new, l_s.shape)
 
     @pl.when(j == n_pages - 1)
     def _flush():
-        o_ref[0] = acc_s[...] / l_s[:, :1]
+        o_ref[0] = acc_s[...] / l_s[:, :, :1]
 
 
 @functools.partial(jax.jit,
@@ -274,8 +273,8 @@ def paged_decode_attn(q: jax.Array, kp: jax.Array, vp: jax.Array,
         num_scalar_prefetch=3,
         grid=(B, G),
         in_specs=[
-            pl.BlockSpec((1, H, dh),
-                         lambda b, j, ptab_r, len_r, t_r: (b, 0, 0)),
+            pl.BlockSpec((1, H, 1, dh),
+                         lambda b, j, ptab_r, len_r, t_r: (b, 0, 0, 0)),
             pl.BlockSpec((1, H, page, dh),
                          lambda b, j, ptab_r, len_r, t_r:
                          (ptab_r[b, j], 0, 0, 0)),
@@ -284,18 +283,19 @@ def paged_decode_attn(q: jax.Array, kp: jax.Array, vp: jax.Array,
                          (ptab_r[b, j], 0, 0, 0)),
         ],
         out_specs=pl.BlockSpec(
-            (1, H, dh), lambda b, j, ptab_r, len_r, t_r: (b, 0, 0)),
+            (1, H, 1, dh), lambda b, j, ptab_r, len_r, t_r: (b, 0, 0, 0)),
         scratch_shapes=[
-            _scratch((H, dh), jnp.float32),
-            _scratch((H, 128), jnp.float32),
-            _scratch((H, 128), jnp.float32),
+            pltpu.VMEM((H, 1, dh), jnp.float32),
+            pltpu.VMEM((H, 1, _LANES), jnp.float32),
+            pltpu.VMEM((H, 1, _LANES), jnp.float32),
         ],
     )
     kernel = functools.partial(_paged_kernel, scale=scale, n_pages=G,
                                page=page, bucket=bucket)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, dh), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((B, H, 1, dh), jnp.float32),
         interpret=interpret,
     )(jnp.asarray(ptab, jnp.int32), jnp.asarray(lengths, jnp.int32),
-      jnp.asarray(t, jnp.int32), q, kp, vp)
+      jnp.asarray(t, jnp.int32), q[:, :, None, :], kp, vp)
+    return out[:, :, 0, :]

@@ -28,7 +28,6 @@ exercised in interpret mode on CPU plus numerically on the real chip.
 
 from __future__ import annotations
 
-import dataclasses
 import functools
 from typing import Tuple
 
@@ -37,32 +36,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-# jax renamed TPUCompilerParams -> CompilerParams across 0.4.x/0.5.x and
-# grew fields (has_side_effects) along the way; support every baked-in
-# toolchain by resolving the class AND dropping a known-safe subset of
-# kwargs the local version lacks. Only has_side_effects may be dropped
-# (it just guards against DCE, and every caller consumes the aliased
-# table output); semantics-bearing fields like dimension_semantics must
-# never be silently stripped — a sequential grid treated as parallel
-# corrupts donated table state with no error.
-_COMPILER_PARAMS_CLS = getattr(pltpu, "CompilerParams", None) or \
-    getattr(pltpu, "TPUCompilerParams")
-_COMPILER_PARAMS_FIELDS = {
-    f.name for f in dataclasses.fields(_COMPILER_PARAMS_CLS)}
-_DROPPABLE_PARAMS = {"has_side_effects"}
-
-
-def CompilerParams(**kwargs):
-    missing = set(kwargs) - _COMPILER_PARAMS_FIELDS
-    if missing - _DROPPABLE_PARAMS:
-        raise TypeError(
-            f"{_COMPILER_PARAMS_CLS.__name__} on this jax version lacks "
-            f"required field(s) {sorted(missing - _DROPPABLE_PARAMS)}; "
-            "refusing to drop them silently")
-    return _COMPILER_PARAMS_CLS(**{k: v for k, v in kwargs.items()
-                                   if k in _COMPILER_PARAMS_FIELDS})
-
 
 def group_for_dtype(dtype) -> int:
     """Rows per grid step: the sublane tile is 8 for 4-byte types and 16
@@ -212,7 +185,7 @@ def scatter_add_sorted_rows(table: jax.Array, sorted_ids: jax.Array,
         out_shape=jax.ShapeDtypeStruct(table.shape, table.dtype),
         grid_spec=grid_spec,
         input_output_aliases={2: 0},   # table (after ids, deltas) -> out
-        compiler_params=CompilerParams(has_side_effects=True),
+        compiler_params=pltpu.CompilerParams(has_side_effects=True),
         interpret=interpret,
     )(sorted_ids.astype(jnp.int32), sorted_deltas, table)
 
@@ -379,7 +352,7 @@ def fused_stateful_rows(table: jax.Array, state: dict, ids: jax.Array,
         grid_spec=grid_spec,
         # inputs: ids(0) meta(1) opts(2) deltas(3) table(4) leaves(5..)
         input_output_aliases={4 + i: i for i in range(1 + n_state)},
-        compiler_params=CompilerParams(has_side_effects=True),
+        compiler_params=pltpu.CompilerParams(has_side_effects=True),
         interpret=interpret,
     )(ids.astype(jnp.int32), meta, opts,
       deltas.astype(jnp.float32), table, *leaves)

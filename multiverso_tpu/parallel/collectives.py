@@ -22,7 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from multiverso_tpu.parallel.mesh import SERVER_AXIS, shard_map
+from multiverso_tpu.parallel.mesh import SERVER_AXIS
 
 
 def device_allreduce(x: jax.Array, mesh: Mesh,
@@ -33,9 +33,9 @@ def device_allreduce(x: jax.Array, mesh: Mesh,
     def _sum(v):
         return jax.lax.psum(v, axis)
 
-    fn = shard_map(_sum, mesh=mesh,
-                   in_specs=P(*([axis] + [None] * (x.ndim - 1))),
-                   out_specs=P(*([None] * x.ndim)))
+    fn = jax.shard_map(_sum, mesh=mesh,
+                       in_specs=P(*([axis] + [None] * (x.ndim - 1))),
+                       out_specs=P(*([None] * x.ndim)))
     return fn(x)
 
 
@@ -47,7 +47,7 @@ def device_allgather(x: jax.Array, mesh: Mesh,
     def _gather(v):
         return jax.lax.all_gather(v, axis, tiled=True)
 
-    fn = shard_map(_gather, mesh=mesh,
+    fn = jax.shard_map(_gather, mesh=mesh,
                        in_specs=P(*([axis] + [None] * (x.ndim - 1))),
                        out_specs=P(*([None] * x.ndim)),
                        check_vma=False)
@@ -65,7 +65,7 @@ def device_reduce_scatter(x: jax.Array, mesh: Mesh,
         return jax.lax.psum_scatter(v, axis, scatter_dimension=0,
                                     tiled=True)
 
-    fn = shard_map(_rs, mesh=mesh,
+    fn = jax.shard_map(_rs, mesh=mesh,
                        in_specs=P(*([None] * x.ndim)),
                        out_specs=P(*([axis] + [None] * (x.ndim - 1))))
     return fn(x)

@@ -108,7 +108,7 @@ def measured_policy_latency_ms(nbytes: int, mesh=None, world: int = 1,
     if hit is not None:
         return hit
 
-    from multiverso_tpu.parallel.mesh import SERVER_AXIS, shard_map
+    from multiverso_tpu.parallel.mesh import SERVER_AXIS
     from jax.sharding import PartitionSpec as P
 
     data = jnp.zeros((n,), jnp.float32)
@@ -134,8 +134,8 @@ def measured_policy_latency_ms(nbytes: int, mesh=None, world: int = 1,
         def _psum(v):
             return jax.lax.psum(v, axis) / n_axis
 
-        fn = jax.jit(shard_map(_psum, mesh=mesh, in_specs=P(),
-                               out_specs=P(), check_vma=False),
+        fn = jax.jit(jax.shard_map(_psum, mesh=mesh, in_specs=P(),
+                                   out_specs=P(), check_vma=False),
                      donate_argnums=0)
     else:
         fn = jax.jit(lambda v: v + 0.0, donate_argnums=0)
@@ -306,7 +306,7 @@ def build_dense_sync(mesh, axis: Optional[str] = None):
 
     Build ONCE per model (compiles one executable); dispatch per block.
     """
-    from multiverso_tpu.parallel.mesh import SERVER_AXIS, shard_map
+    from multiverso_tpu.parallel.mesh import SERVER_AXIS
     from jax.sharding import PartitionSpec as P
 
     axis = axis or SERVER_AXIS
@@ -317,8 +317,8 @@ def build_dense_sync(mesh, axis: Optional[str] = None):
     def _sync(v):
         return jax.lax.psum(v, axis) / n_axis
 
-    return jax.jit(shard_map(_sync, mesh=mesh, in_specs=P(),
-                             out_specs=P(), check_vma=False))
+    return jax.jit(jax.shard_map(_sync, mesh=mesh, in_specs=P(),
+                                 out_specs=P(), check_vma=False))
 
 
 def model_average_arrays(arrays: Sequence[np.ndarray]) -> List[np.ndarray]:

@@ -10,8 +10,7 @@ sharding) can be requested via the ``mesh_shape`` flag.
 
 from __future__ import annotations
 
-import functools
-import inspect
+import os
 from typing import Dict, Optional, Sequence, Tuple
 
 import numpy as np
@@ -19,44 +18,10 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from multiverso_tpu.utils.configure import get_flag
+from multiverso_tpu.utils.log import log
 
 SERVER_AXIS = "server"
 WORKER_AXIS = "worker"
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None):
-    """``jax.shard_map`` across jax versions. jax >= 0.6 exposes it
-    top-level with the replication check named ``check_vma``; older jax
-    only has ``jax.experimental.shard_map.shard_map`` with the same flag
-    named ``check_rep``. All framework shard_maps route through here so a
-    container's jax pin can't take out every multi-device code path."""
-    sm, rep_kwarg = _resolve_shard_map()
-    if not hasattr(jax.lax, "pvary") and not hasattr(jax.lax, "pcast"):
-        # Pre-VMA jax: our bodies can't annotate varying-ness (pvary does
-        # not exist), so check_rep would reject correct programs — e.g.
-        # a scan whose carry becomes varying mid-loop. The check is a
-        # debugging aid, not semantics; disable it outright here.
-        check_vma = False
-    if check_vma is None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              **{rep_kwarg: check_vma})
-
-
-@functools.lru_cache(maxsize=1)
-def _resolve_shard_map():
-    """Resolve the shard_map callable and the name of its replication-check
-    kwarg (``check_vma`` on jax >= 0.6, ``check_rep`` before) by probing
-    the signature once, so genuine TypeErrors from bad specs propagate
-    instead of being retried under the other spelling."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    try:
-        params = inspect.signature(sm).parameters
-    except (TypeError, ValueError):  # C-accelerated / unsigned callable
-        params = {}
-    return sm, ("check_vma" if "check_vma" in params else "check_rep")
 
 
 def parse_mesh_spec(spec: str) -> Dict[str, int]:
@@ -93,6 +58,20 @@ def build_mesh(devices: Optional[Sequence[jax.Device]] = None,
         dev_array = np.asarray(devices[:total]).reshape(tuple(axes.values()))
         return Mesh(dev_array, tuple(axes.keys()))
     return Mesh(np.asarray(devices), (SERVER_AXIS,))
+
+
+def log_backend(devices: Sequence[jax.Device]) -> None:
+    """One line naming what this process holds: platform, device kind and
+    device ids. Every process that initializes a backend logs it, so a log
+    shows at a glance whether work sat on the chip and on which one. A
+    process a launcher restricted to one chip sees it as device 0, so the
+    line also carries the host chip it was given (utils/chips.py)."""
+    devices = list(devices)
+    chip = os.environ.get("TPU_VISIBLE_CHIPS")
+    log.info("jax backend: platform=%s device_kind=%s devices=%s%s",
+             devices[0].platform, devices[0].device_kind,
+             [d.id for d in devices],
+             f" host_chip={chip}" if chip is not None else "")
 
 
 def table_sharding(mesh: Mesh, ndim: int, axis: int = 0,

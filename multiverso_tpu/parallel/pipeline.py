@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from multiverso_tpu.parallel.mesh import shard_map
 from multiverso_tpu.utils.log import check
 
 STAGE_AXIS = "stage"
@@ -120,7 +119,7 @@ def pipeline_apply(stage_fn: Callable[[Any, jax.Array], jax.Array],
         # only the last stage wrote outputs; sum-replicate across stages
         return jax.lax.psum(ys, axis)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: P(axis), stage_params),
                   P(axis)),
@@ -294,7 +293,7 @@ def pipeline_train_1f1b(stage_fn: Callable[[Any, jax.Array], jax.Array],
         return (loss, jax.tree.map(lambda g: g[None], grads), hgrads, dxs)
 
     head_in = head_params if with_head else ()
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: P(axis), stage_params),
                   jax.tree.map(lambda _: P(), head_in),

@@ -28,20 +28,15 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from multiverso_tpu.parallel.mesh import shard_map
+from multiverso_tpu.ops import pallas_interpret
 
 SEQ_AXIS = "seq"
 
 
 def _pvary(x, axis):
-    """Mark ``x`` as varying over ``axis`` (jax>=0.9 renamed pvary to
-    pcast(..., to='varying'); pre-VMA jax has neither and needs no mark —
-    the old check_rep system tracks replication without annotations)."""
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, axis, to="varying")
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(x, axis)
-    return x
+    """Mark ``x`` as varying over ``axis`` (fresh accumulators are unvarying
+    until marked; a scan carry must match the ppermute outputs' type)."""
+    return jax.lax.pcast(x, axis, to="varying")
 
 
 def _resolve_flash(use_flash, sq: int, sk: int, d: int) -> bool:
@@ -72,7 +67,8 @@ def _block_attn(q, k, v, scale, mask=None):
 def ring_attention_block(q_blk: jax.Array, k_blk: jax.Array,
                          v_blk: jax.Array, axis: str, n: int,
                          causal: bool = False,
-                         use_flash: Optional[bool] = None) -> jax.Array:
+                         use_flash: Optional[bool] = None,
+                         interpret: bool = False) -> jax.Array:
     """The per-device ring-attention body, for use INSIDE a shard_map.
 
     ``q_blk/k_blk/v_blk``: this device's [B, H, S/n, D] sequence block on a
@@ -87,6 +83,8 @@ def ring_attention_block(q_blk: jax.Array, k_blk: jax.Array,
     VMEM instead of materializing the [Sq, Sk] score block in HBM);
     ``None`` reads the ``-flash_attention`` flag (default off until
     on-chip timing adopts it, same protocol as the scatter kernels).
+    ``interpret`` is the enclosing mesh's ``ops.pallas_interpret`` verdict
+    (the blocks here are tracers and carry no devices).
     """
     use_flash = _resolve_flash(use_flash, q_blk.shape[2], k_blk.shape[2],
                                q_blk.shape[3])
@@ -107,8 +105,7 @@ def ring_attention_block(q_blk: jax.Array, k_blk: jax.Array,
                 .astype(jnp.int32)
             o, m, l = flash_block_attn(
                 q_blk, k_cur, v_cur, scale=float(scale), causal=causal,
-                offsets=offsets,
-                interpret=jax.default_backend() == "cpu", vma=(axis,))
+                offsets=offsets, interpret=interpret, vma=(axis,))
             o = o.astype(q_blk.dtype)
             m = m.astype(q_blk.dtype)
             l = l.astype(q_blk.dtype)
@@ -161,16 +158,19 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array, mesh: Mesh,
     blk = q.shape[2] // n
     use_flash = _resolve_flash(None, blk, blk, q.shape[3])
 
+    interpret = pallas_interpret(mesh.devices.flat)
+
     def local(q_blk, k_blk, v_blk):
         return ring_attention_block(q_blk, k_blk, v_blk, axis, n,
-                                    causal=causal, use_flash=use_flash)
+                                    causal=causal, use_flash=use_flash,
+                                    interpret=interpret)
 
     spec = P(None, None, axis, None)
     # check_vma off on the flash path: jax's interpret/lowering of a
     # pallas_call inside shard_map mixes varying and unvarying internals
     # (jax suggests exactly this workaround in the error it raises).
-    fn = shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
-                   out_specs=spec, check_vma=not use_flash)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
+                       out_specs=spec, check_vma=not use_flash)
     return fn(q, k, v)
 
 
@@ -190,6 +190,7 @@ def ulysses_attention(q: jax.Array, k: jax.Array, v: jax.Array, mesh: Mesh,
     scale = 1.0 / np.sqrt(q.shape[-1])
     # After the layout swap every device holds the FULL sequence.
     use_flash = _resolve_flash(None, q.shape[2], q.shape[2], q.shape[3])
+    interpret = pallas_interpret(mesh.devices.flat)
 
     def local(q_blk, k_blk, v_blk):
         # [B, H, S/n, D] -> [B, H/n, S, D]
@@ -208,7 +209,7 @@ def ulysses_attention(q: jax.Array, k: jax.Array, v: jax.Array, mesh: Mesh,
             # Causal mask computed in-kernel (offsets zero: full sequence).
             o, _, l = flash_block_attn(
                 qh, kh, vh, scale=float(scale), causal=causal,
-                interpret=jax.default_backend() == "cpu", vma=(axis,))
+                interpret=interpret, vma=(axis,))
             o = (o / jnp.maximum(l, 1e-20)).astype(qh.dtype)
         else:
             s = jnp.einsum("bhqd,bhkd->bhqk", qh, kh) * scale
@@ -220,8 +221,8 @@ def ulysses_attention(q: jax.Array, k: jax.Array, v: jax.Array, mesh: Mesh,
         return head_to_seq(o)
 
     spec = P(None, None, axis, None)
-    fn = shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
-                   out_specs=spec, check_vma=not use_flash)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
+                       out_specs=spec, check_vma=not use_flash)
     return fn(q, k, v)
 
 

@@ -32,12 +32,23 @@ class NativeRuntimeUnavailable(RuntimeError):
 def _build() -> None:
     # No -ffast-math: it links crtfastmath.o, which flips FTZ/DAZ for the
     # whole process at dlopen and silently changes numpy/JAX numerics.
+    # Compile to a name of our own beside the target, then rename over it:
+    # _lib_lock is per PROCESS, and N freshly spawned ranks on a clean
+    # checkout all find the .so missing at once — none of them may dlopen
+    # a file another is still writing. The rename is atomic; a rank that
+    # loses the race loads the winner's complete file.
+    tmp = f"{_SO}.{os.getpid()}.tmp"
     cmd = ["g++", "-O3", "-std=c++17", "-fPIC", "-Wall", "-pthread",
-           "-fno-math-errno", "-shared", "-o", _SO, *_SRCS]
-    result = subprocess.run(cmd, capture_output=True, text=True)
-    if result.returncode != 0:
-        raise NativeRuntimeUnavailable(
-            f"native runtime build failed:\n{result.stderr}")
+           "-fno-math-errno", "-shared", "-o", tmp, *_SRCS]
+    try:
+        result = subprocess.run(cmd, capture_output=True, text=True)
+        if result.returncode != 0:
+            raise NativeRuntimeUnavailable(
+                f"native runtime build failed:\n{result.stderr}")
+        os.replace(tmp, _SO)
+    finally:
+        if os.path.exists(tmp):
+            os.remove(tmp)
 
 
 def load() -> ctypes.CDLL:

@@ -72,6 +72,17 @@ from multiverso_tpu.telemetry import child_of, counter, emit_span, gauge
 from multiverso_tpu.utils.log import check, log
 
 
+def _snapshot(host: np.ndarray):
+    """Device copy of a host array the worker keeps writing in place (slot
+    lengths, step counters, page tables). jax may alias a host buffer
+    (CPU) or read it after the dispatch returns (async host-to-device
+    copy), so handing it the live array lets a later ``eng.t[i] += 1``
+    change a step already in flight. The copy is never written again."""
+    import jax.numpy as jnp
+
+    return jnp.asarray(host.copy())
+
+
 class _SlotEngine:
     """Per-bucket decode state: B cache slots sharing one KV-cache of
     shape ``[layers, B, heads, bucket+max_new, dh]`` plus the device-side
@@ -151,10 +162,8 @@ class _PagedEngine:
         return sum(1 for r in self.reqs if r is not None)
 
     def device_ptab(self):
-        import jax.numpy as jnp
-
         if self.ptab_dirty or self.ptab_dev is None:
-            self.ptab_dev = jnp.asarray(self.ptab)
+            self.ptab_dev = _snapshot(self.ptab)
             self.ptab_dirty = False
         return self.ptab_dev
 
@@ -559,8 +568,8 @@ class ContinuousBatcher(DynamicBatcher):
                         vs, eng.out, eng.tok)
                 kp, vp, ks, vs, eng.out, eng.tok = \
                     self._step_paged_for(bucket)(
-                        params, jnp.asarray(eng.lengths),
-                        jnp.asarray(eng.t), eng.device_ptab(), kp, vp,
+                        params, _snapshot(eng.lengths),
+                        _snapshot(eng.t), eng.device_ptab(), kp, vp,
                         ks, vs, eng.out, eng.tok)
                 self.pool.update(kp, vp, ks, vs)
             else:
@@ -568,7 +577,7 @@ class ContinuousBatcher(DynamicBatcher):
                     params, zeros, one, slot0, eng.ck, eng.cv, eng.out,
                     eng.tok)
                 eng.ck, eng.cv, eng.out, eng.tok = self._step(
-                    params, jnp.asarray(eng.lengths), jnp.asarray(eng.t),
+                    params, _snapshot(eng.lengths), _snapshot(eng.t),
                     eng.ck, eng.cv, eng.out, eng.tok)
             warmed += 2
         return warmed
@@ -878,8 +887,6 @@ class ContinuousBatcher(DynamicBatcher):
         return sum(e.n_active() for e in self._engines.values())
 
     def _step_engines(self) -> None:
-        import jax.numpy as jnp
-
         params = None
         for eng in self._engines.values():
             if eng.n_active() == 0:
@@ -891,14 +898,14 @@ class ContinuousBatcher(DynamicBatcher):
                     kp, vp, ks, vs = self.pool.arrays()
                     kp, vp, ks, vs, eng.out, eng.tok = \
                         self._step_paged_for(eng.bucket)(
-                            params, jnp.asarray(eng.lengths),
-                            jnp.asarray(eng.t), eng.device_ptab(), kp,
+                            params, _snapshot(eng.lengths),
+                            _snapshot(eng.t), eng.device_ptab(), kp,
                             vp, ks, vs, eng.out, eng.tok)
                     self.pool.update(kp, vp, ks, vs)
                 else:
                     eng.ck, eng.cv, eng.out, eng.tok = self._step(
-                        params, jnp.asarray(eng.lengths),
-                        jnp.asarray(eng.t), eng.ck, eng.cv, eng.out,
+                        params, _snapshot(eng.lengths),
+                        _snapshot(eng.t), eng.ck, eng.cv, eng.out,
                         eng.tok)
             except Exception as e:  # noqa: BLE001 - shed this engine's
                 log.error("continuous decode: step failed: %s", e)  # slots

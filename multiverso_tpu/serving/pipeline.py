@@ -15,9 +15,9 @@ an unbounded buffer chain over a slow link.
 
 Depth AUTO follows the ``resolve_dispatch_mode`` decision-table move:
 probe the host's jitted dispatch+sync latency once and pick the shallowest
-window that still hides it (a co-located chip launches in ~10-100us and
-double-buffering suffices; a tunneled chip at ~40ms needs a deeper window
-to keep the device fed). The roofline framing is the concurrency-limits
+window that still hides it (cheap launches need only a double buffer;
+high launch latency, ~40ms, needs a deeper window to keep the device
+fed). The roofline framing is the concurrency-limits
 study (PAPERS.md 2011.03641): in-flight depth ~ service time / inter-
 arrival gap, clamped to a small constant so a stall never hides more than
 ``depth`` batches of latency.
@@ -44,8 +44,9 @@ from multiverso_tpu.utils.locks import make_condition
 # Depth decision table (AUTO): measured one-dispatch round-trip latency
 # -> in-flight window. Below DISPATCH_FAST_MS a double buffer already
 # hides the launch; between the thresholds one extra slot absorbs jitter;
-# above DISPATCH_SLOW_MS (tunneled links) the window deepens so the host
-# keeps dispatching while early batches ride out the link latency.
+# above DISPATCH_SLOW_MS (high launch latency) the window deepens so the
+# host keeps dispatching while early batches ride out the latency. The
+# v5e host measures 0.5-0.8ms (PR 21); ROADMAP A7 re-measures the table.
 DISPATCH_FAST_MS = 1.0
 DISPATCH_SLOW_MS = 10.0
 MAX_AUTO_DEPTH = 4
@@ -91,8 +92,8 @@ def resolve_pipeline_depth(value) -> int:
     * an int (or int string) >= 2 — use it verbatim;
     * ``1`` or ``0`` — serialized dispatch (the pre-pipeline path);
     * ``"auto"`` — probe the dispatch latency and apply the decision
-      table (docs/SERVING.md "Dispatch pipeline"): fast co-located
-      launches -> 2, mid -> 3, slow tunneled -> 4.
+      table (docs/SERVING.md "Dispatch pipeline"): fast launches -> 2,
+      mid -> 3, high launch latency -> 4.
     """
     if isinstance(value, str):
         v = value.strip().lower()
@@ -230,7 +231,7 @@ class DispatchPipeline:
         # Wedge watchdog: a wedged device sync in collect() is EXACTLY
         # the stall this loop can hide — the window fills, the producer
         # backpressures, and the service looks "busy" forever. The 60s
-        # timeout rides out any legitimate tunneled sync.
+        # timeout rides out any legitimate slow sync.
         with watchdog_scope("serve-collector", timeout_s=60.0) as wd:
             self._run_collect(wd)
 

@@ -28,7 +28,7 @@ from __future__ import annotations
 
 import functools
 import threading
-from typing import Callable, Dict, Optional, Tuple
+from typing import Callable, Dict, Optional, Protocol, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -42,12 +42,6 @@ from multiverso_tpu.serving.quant import (decode_rows, encode_rows,
 from multiverso_tpu.telemetry.sketch import record_keys
 from multiverso_tpu.utils.log import check
 from multiverso_tpu.utils.locks import make_lock
-
-try:                     # 3.8+ typing.Protocol
-    from typing import Protocol
-except ImportError:      # pragma: no cover - ancient interpreter
-    Protocol = object
-
 
 class ServingRunner(Protocol):
     """What the batcher needs from a model runner."""

@@ -231,8 +231,6 @@ define_string("coordinator", "", "host:port of the jax.distributed "
               "coordinator; empty = single-process")
 define_int("world_size", 1, "number of processes (ranks)")
 define_int("rank", 0, "this process's rank")
-define_string("platform", "", "force the jax platform (e.g. 'cpu') before "
-              "first device use — lets CLIs run when the TPU is unreachable")
 # Serving plane (multiverso_tpu/serving; docs/SERVING.md).
 define_string("serve_host", "127.0.0.1", "serving listener bind address "
               "(0.0.0.0 to accept remote clients; the advertised address "

@@ -17,20 +17,17 @@ import numpy as np
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
-if os.environ.get("JAX_PLATFORMS") == "cpu":
-    import jax
-    jax.config.update("jax_platforms", "cpu")
-
 
 def main() -> None:
     import jax
     import jax.numpy as jnp
 
+    from multiverso_tpu.ops import pallas_interpret
     from multiverso_tpu.ops.pallas_attention import flash_block_attn
     from multiverso_tpu.parallel.sequence import _block_attn
 
     backend = jax.devices()[0].platform
-    interpret = backend == "cpu"
+    interpret = pallas_interpret(jax.devices())
     print(f"backend: {backend} (interpret={interpret})")
     rng = np.random.default_rng(0)
     # Ring-step shapes: per-device S/n blocks at long-context scale.

@@ -69,9 +69,27 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, _REPO)
 
 
+from multiverso_tpu.utils.chips import child_env, take_chip  # noqa: E402
+
+
 # ---------------------------------------------------------------------------
 # Shared plumbing
 # ---------------------------------------------------------------------------
+def _chaos_shards(args) -> int:
+    return 2 if args.dry_run else 4
+
+
+def _chip_holders(args) -> int:
+    """Processes of this run that hold a device at the same time: the
+    replicas, this process, and the drills' PS shard seats."""
+    seats = 0
+    if args.recovery_drill:
+        seats = 1
+    if args.chaos_drill:
+        seats = max(seats, _chaos_shards(args))
+    return args.replicas + 1 + seats
+
+
 def _percentiles(lat_ms) -> dict:
     lat = np.asarray(lat_ms, dtype=np.float64)
     if not lat.size:
@@ -759,7 +777,8 @@ def run_single(args) -> dict:
     from multiverso_tpu.core.updater import get_updater
     from multiverso_tpu.utils.configure import set_flag
     import jax
-    from jax.sharding import Mesh
+
+    from multiverso_tpu.parallel.mesh import build_mesh, log_backend
 
     set_flag("serve_wire_dtype", args.wire_dtype)
     if args.overload:
@@ -767,7 +786,8 @@ def run_single(args) -> dict:
         args.deadline_ms = min(args.deadline_ms, 20.0)
 
     rng = np.random.default_rng(0)
-    mesh = Mesh(np.asarray(jax.devices()[:1]).reshape(1), ("server",))
+    mesh = build_mesh(jax.devices(), spec="")
+    log_backend(mesh.devices.flat)
     store = ServerStore(
         "serve_bench", (args.rows, args.cols), np.float32,
         get_updater(np.float32, "default"), mesh, num_workers=1,
@@ -1176,8 +1196,7 @@ def _spawn_router(args, tdir: str, addr_file: str,
            # Fast alert windows: the fault drill asserts the router's
            # heartbeat-loss alert within a 4s dry-run drill window.
            "-telemetry_alerts=true", "-telemetry_flight=true",
-           "-telemetry_ts_interval=0.25",
-           "-serve_device=cpu"]
+           "-telemetry_ts_interval=0.25"]
     if port:
         # The router-kill round respawns on the SAME port so replicas
         # and clients reconnect through connect_with_backoff unchanged.
@@ -1224,11 +1243,12 @@ def _spawn_replica(args, router_addr, idx: int,
            # Attribution plane (ISSUE 18): the replica's continuous
            # profiler feeds its serve-plane roofline verdict, which
            # ships on the heartbeat into Fleet_Stats.
-           "-telemetry_profile=true",
-           "-serve_device=cpu"]
+           "-telemetry_profile=true"]
     if slo_ms is not None:
         cmd.append(f"-serve_slo_ms={slo_ms}")
-    return subprocess.Popen(cmd, cwd=_REPO)
+    return subprocess.Popen(
+        cmd, cwd=_REPO,
+        env=child_env(idx, _chip_holders(args), "serve_bench"))
 
 
 def _wait_addr_file(path: str, procs, timeout_s: float = 120.0):
@@ -1854,7 +1874,7 @@ class _FileMembershipView:
         return False                        # one seat: never scaled down
 
 
-def _spawn_ps_shard(parent_addr, tmp: str, addr_file: str,
+def _spawn_ps_shard(args, parent_addr, tmp: str, addr_file: str,
                     size: int) -> subprocess.Popen:
     if os.path.exists(addr_file):
         os.remove(addr_file)                # stale announce must not
@@ -1866,9 +1886,10 @@ def _spawn_ps_shard(parent_addr, tmp: str, addr_file: str,
            "-wal=true", f"-wal_dir={tmp}/wal", "-wal_sync_acks=true",
            f"-checkpoint_dir={tmp}/ckpt", "-ps_checkpoint_every_s=1.0",
            f"-ps_addr_file={addr_file}", "-serve_duration=600",
-           "-serve_device=cpu", "-telemetry_alerts=false",
-           "-telemetry_flight=false"]
-    return subprocess.Popen(cmd, cwd=_REPO)
+           "-telemetry_alerts=false", "-telemetry_flight=false"]
+    return subprocess.Popen(
+        cmd, cwd=_REPO,
+        env=child_env(args.replicas + 1, _chip_holders(args), "serve_bench"))
 
 
 def _lockwitness_leg(args) -> dict:
@@ -1944,7 +1965,7 @@ def _wal_recovery_leg(args) -> dict:
     sup = None
     result: dict = {"size": size}
     try:
-        child = _spawn_ps_shard(svc0.address, tmp, addr_file, size)
+        child = _spawn_ps_shard(args, svc0.address, tmp, addr_file, size)
         deadline = time.monotonic() + 120
         while not os.path.exists(addr_file):
             if child.poll() is not None:
@@ -1958,7 +1979,7 @@ def _wal_recovery_leg(args) -> dict:
 
         sup = ReplicaSupervisor(
             _FileMembershipView(addr_file, "ps-1"),
-            lambda slot: _spawn_ps_shard(svc0.address, tmp, addr_file,
+            lambda slot: _spawn_ps_shard(args, svc0.address, tmp, addr_file,
                                          size),
             member_prefix="ps-", min_replicas=1, max_replicas=1,
             cooldown_s=0.5, poll_s=0.1, join_grace_s=60.0)
@@ -2317,7 +2338,7 @@ def _chaos_drill(args, router_addr, procs, tdir, fleet,
 
     _ensure_mv_runtime()
     seed = args.chaos_seed
-    shards = 2 if args.dry_run else 4
+    shards = _chaos_shards(args)
     rounds = args.chaos_rounds or (2 if args.dry_run else 3)
     size = 128
     srng = np.random.default_rng(seed)
@@ -2325,7 +2346,8 @@ def _chaos_drill(args, router_addr, procs, tdir, fleet,
         np.arange(1, shards + 1), size=max(1, shards // 2),
         replace=False))
     psf = PSShardFleet(
-        shards=shards, table_id=916, table_size=size, sync_acks=True,
+        shards=shards, first_chip=args.replicas,
+        table_id=916, table_size=size, sync_acks=True,
         checkpoint_every_s=1.0, join_grace_s=120.0,
         extra_seat_args={r: ["-wal_fsync_delay_ms=10"] for r in slow})
     psf.start()
@@ -3207,6 +3229,10 @@ def main() -> int:
                 # parity + supervisor replacement witnesses.
                 args.recovery_drill = True
 
+    # One process per chip: before anything here touches jax, this process
+    # takes the chip after the replicas' (it is the server in single mode
+    # and the PS client seat in the drills) and leaves the rest to them.
+    take_chip(args.replicas, _chip_holders(args), "serve_bench")
     record = run_fleet(args) if args.replicas >= 1 else run_single(args)
     _emit(record, args.out)
     return 0

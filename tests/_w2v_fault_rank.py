@@ -21,8 +21,6 @@ import time
 def _pin_cpu(repo):
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     sys.path.insert(0, repo)
-    from multiverso_tpu.apps._runner import _pin_jax_cpu
-    _pin_jax_cpu()
 
 
 def _build(args):

@@ -246,7 +246,6 @@ _SPARSE_WORKER = r"""
 import os, sys, time
 os.environ["JAX_PLATFORMS"] = "cpu"
 import jax
-jax.config.update("jax_platforms", "cpu")
 import numpy as np
 import multiverso_tpu as mv
 from multiverso_tpu.core.options import AddOption, GetOption

@@ -19,7 +19,6 @@ _WORKER = r"""
 import os, sys
 os.environ["JAX_PLATFORMS"] = "cpu"
 import jax
-jax.config.update("jax_platforms", "cpu")
 import numpy as np
 import multiverso_tpu as mv
 

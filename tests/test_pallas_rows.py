@@ -198,6 +198,10 @@ def test_table_pallas_eligibility_widened():
                             get_updater(np.float32, "dcasgd"), mesh, 1,
                             use_pallas_rows=True)
         assert not st_dc._pallas_rows   # not in the capability registry
+        st_50 = ServerStore("p5", (32, 50), np.float32,
+                            get_updater(np.float32, "default"), mesh, 1,
+                            use_pallas_rows=True)
+        assert not st_50._pallas_rows   # 50 cols: Mosaic lane misaligned
         # behavior: sgd table applies data -= delta through the kernel
         ids = jnp.asarray([1, 1, 3], dtype=jnp.int32)
         st.apply_rows(ids, jnp.ones((3, 128), jnp.float32), AddOption())
@@ -267,10 +271,10 @@ def test_fused_stateful_bitwise_vs_xla(updater):
 
     mv.init([], devices=jax.devices()[:1])
     try:
-        t_xla = mv.create_table(mv.MatrixTableOption(33, 16,
+        t_xla = mv.create_table(mv.MatrixTableOption(33, 128,
                                                      updater=updater,
                                                      name="fx"))
-        t_pal = mv.create_table(mv.MatrixTableOption(33, 16,
+        t_pal = mv.create_table(mv.MatrixTableOption(33, 128,
                                                      updater=updater,
                                                      name="fp",
                                                      use_pallas=True))
@@ -281,7 +285,7 @@ def test_fused_stateful_bitwise_vs_xla(updater):
         for step in range(5):
             n = int(rng.integers(1, 24))
             ids = rng.integers(0, 33, size=n).astype(np.int32)
-            d = rng.normal(size=(n, 16)).astype(np.float32)
+            d = rng.normal(size=(n, 128)).astype(np.float32)
             t_xla.add_rows(ids, d, opt)
             t_pal.add_rows(ids, d, opt)
         assert np.array_equal(t_xla.get(), t_pal.get()), updater
@@ -301,20 +305,21 @@ def test_fused_stateful_duplicates_and_empty():
 
     mv.init([], devices=jax.devices()[:1])
     try:
-        t_xla = mv.create_table(mv.MatrixTableOption(8, 4,
+        t_xla = mv.create_table(mv.MatrixTableOption(8, 128,
                                                      updater="adagrad",
                                                      name="dx"))
-        t_pal = mv.create_table(mv.MatrixTableOption(8, 4,
+        t_pal = mv.create_table(mv.MatrixTableOption(8, 128,
                                                      updater="adagrad",
                                                      name="dp",
                                                      use_pallas=True))
+        assert t_pal.store._pallas_cap == "fused_stateful"
         opt = mv.AddOption(learning_rate=0.1, rho=0.1)
         # 11 ids over 3 rows: duplicates straddle the 8-lane group
         ids = np.array([2, 2, 2, 6, 6, 1, 1, 1, 1, 2, 6], dtype=np.int32)
-        d = np.ones((11, 4), dtype=np.float32)
+        d = np.ones((11, 128), dtype=np.float32)
         t_xla.add_rows(ids, d, opt)
         t_pal.add_rows(ids, d, opt)
-        t_pal.add_rows([], np.zeros((0, 4), np.float32), opt)  # no-op
+        t_pal.add_rows([], np.zeros((0, 128), np.float32), opt)  # no-op
         assert np.array_equal(t_xla.get(), t_pal.get())
         assert np.array_equal(np.asarray(t_xla.store.state["g2"]),
                               np.asarray(t_pal.store.state["g2"]))
@@ -327,21 +332,22 @@ def test_fused_stateful_per_worker_state_indexing():
     accumulator plane, not worker 0's."""
     import multiverso_tpu as mv
 
-    mv.init([], num_local_workers=2)
+    mv.init([], num_local_workers=2, devices=jax.devices()[:1])
     try:
-        t_xla = mv.create_table(mv.MatrixTableOption(16, 8,
+        t_xla = mv.create_table(mv.MatrixTableOption(16, 128,
                                                      updater="adagrad",
                                                      name="wx"))
-        t_pal = mv.create_table(mv.MatrixTableOption(16, 8,
+        t_pal = mv.create_table(mv.MatrixTableOption(16, 128,
                                                      updater="adagrad",
                                                      name="wp",
                                                      use_pallas=True))
+        assert t_pal.store._pallas_cap == "fused_stateful"
         rng = np.random.default_rng(5)
         for step in range(4):
             w = step % 2
             opt = mv.AddOption(worker_id=w, learning_rate=0.1, rho=0.1)
             ids = rng.integers(0, 16, size=6).astype(np.int32)
-            d = rng.normal(size=(6, 8)).astype(np.float32)
+            d = rng.normal(size=(6, 128)).astype(np.float32)
             t_xla.add_rows(ids, d, opt)
             t_pal.add_rows(ids, d, opt)
         assert np.array_equal(t_xla.get(), t_pal.get())

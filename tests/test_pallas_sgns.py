@@ -14,9 +14,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from multiverso_tpu.ops.pallas_sgns import (build_sgns_grid_step,
-                                            sgns_grid_bytes,
-                                            sgns_grid_eligible)
+from multiverso_tpu.ops.pallas_sgns import build_sgns_grid_step
 
 
 def _tables(V, D, dtype=jnp.float32, seed=1):
@@ -117,13 +115,3 @@ def test_grid_step_bfloat16_tables():
             else np.asarray(r),
             np.asarray(g).view(np.uint16) if g.dtype == jnp.bfloat16
             else np.asarray(g))
-
-
-def test_vmem_eligibility_model():
-    """The AUTO gate: small vocabs fit, the 50K-vocab bench shape does
-    not (that is exactly why pipelined_host/in_graph still exist)."""
-    assert sgns_grid_eligible(2048, 2048, 128, 8192, 5, np.float32)
-    assert not sgns_grid_eligible(50_000, 50_000, 128, 8192, 5, np.float32)
-    # bf16 embeddings shrink the resident bytes but accumulators stay f32
-    assert (sgns_grid_bytes(4096, 4096, 128, 8192, 5, np.dtype("bfloat16"))
-            < sgns_grid_bytes(4096, 4096, 128, 8192, 5, np.float32))

@@ -4,8 +4,7 @@
 loop de-optimization (docs/BENCHMARK.md Round 4) and runs for real only
 inside a live-chip window — without an off-chip smoke it can bit-rot
 between windows (and HAD never executed before one). ``--dry-run``
-shrinks every leg to seconds on CPU, including the Pallas grid leg in
-interpret mode."""
+shrinks every leg to seconds on CPU."""
 
 import glob
 import json
@@ -29,10 +28,9 @@ def test_perf_attrib_dry_run_cpu(tmp_path):
     # every formulation leg reported a number (E legitimately skips when
     # the dry-run vocab is already sub-table-sized)
     for leg in ("A standalone", "B fori-full", "C fori-gather",
-                "D fori-scatter", "F fori-sub", "G pallas-grid",
-                "H fori @ Vg"):
+                "D fori-scatter", "F fori-sub"):
         assert leg in out, f"missing leg {leg!r}:\n{out}"
-    assert out.count("ms/chunk") >= 7
+    assert out.count("ms/chunk") >= 5
     # telemetry snapshots + Chrome trace are emitted alongside the numbers
     from multiverso_tpu.telemetry import (validate_chrome_trace,
                                           validate_snapshot)

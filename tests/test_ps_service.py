@@ -87,7 +87,6 @@ _WORKER = r"""
 import os, sys, time
 os.environ["JAX_PLATFORMS"] = "cpu"
 import jax
-jax.config.update("jax_platforms", "cpu")
 import numpy as np
 import multiverso_tpu as mv
 from multiverso_tpu.parallel.ps_service import DistributedArrayTable, PSService
@@ -158,7 +157,6 @@ _CKPT_WORKER = r"""
 import os, sys, time
 os.environ["JAX_PLATFORMS"] = "cpu"
 import jax
-jax.config.update("jax_platforms", "cpu")
 import numpy as np
 import multiverso_tpu as mv
 from multiverso_tpu.core.checkpoint import CheckpointManager

@@ -156,7 +156,6 @@ _SYNC_WORKER = r"""
 import os, sys, threading, time
 os.environ["JAX_PLATFORMS"] = "cpu"
 import jax
-jax.config.update("jax_platforms", "cpu")
 import numpy as np
 import multiverso_tpu as mv
 from multiverso_tpu.core.options import AddOption, GetOption

@@ -191,8 +191,7 @@ mv.shutdown()
         [sys.executable, "-m", "multiverso_tpu.apps.serve_main",
          f"-checkpoint_dir={ckpt_dir}", "-serve_table=served",
          "-serve_buckets=4,8", "-serve_max_wait_ms=1",
-         f"-serve_addr_file={addr_file}", "-serve_duration=45",
-         "-serve_device=cpu"],
+         f"-serve_addr_file={addr_file}", "-serve_duration=45"],
         cwd=_REPO, env=env, stdout=subprocess.PIPE,
         stderr=subprocess.STDOUT, text=True)
     try:

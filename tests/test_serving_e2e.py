@@ -247,12 +247,20 @@ def test_attention_lm_decode_served_matches_full_forward(mv_env):
     mesh = Mesh(np.asarray(jax.devices()[:1]).reshape(1, 1),
                 ("data", "seq"))
 
+    # One compile for every length: the forward is causal, so position i
+    # of a zero-padded sequence sees exactly the tokens before it (an
+    # eager call per length costs ~40 s of tier-1 in per-op compiles).
+    pad_to = 12     # longest prompt (7) + 5 generated
+    fwd = jax.jit(lambda t: forward(params, t, cfg, mesh)[0])
+
     def ref_decode(prompt, n):
         toks = list(prompt)
         out = []
         for _ in range(n):
-            logits, _ = forward(params, jnp.asarray([toks]), cfg, mesh)
-            nxt = int(jnp.argmax(logits[0, -1]))
+            padded = np.zeros((1, pad_to), np.int32)
+            padded[0, :len(toks)] = toks
+            logits = fwd(jnp.asarray(padded))
+            nxt = int(jnp.argmax(logits[0, len(toks) - 1]))
             out.append(nxt)
             toks.append(nxt)
         return out

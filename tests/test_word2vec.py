@@ -437,28 +437,25 @@ def test_dispatch_mode_auto_decision_table(monkeypatch, mv_env):
 
     cfg = Word2VecConfig(sg=True, hs=False, device_pipeline=True)
     monkeypatch.setattr(m, "measured_dispatch_latency_ms", lambda: 0.05)
-    assert m.resolve_dispatch_mode(cfg, 1000, 1000) == "pipelined_host"
+    assert m.resolve_dispatch_mode(cfg) == "pipelined_host"
     monkeypatch.setattr(m, "measured_dispatch_latency_ms", lambda: 40.0)
-    assert m.resolve_dispatch_mode(cfg, 1000, 1000) == "in_graph"
+    assert m.resolve_dispatch_mode(cfg) == "in_graph"
     # non-sg-ns variants and meshes always use the fused block step
     for variant in (dataclasses.replace(cfg, hs=True),
                     dataclasses.replace(cfg, sg=False),
                     dataclasses.replace(cfg, mesh_data=2)):
-        assert m.resolve_dispatch_mode(variant, 1000, 1000) == "in_graph"
+        assert m.resolve_dispatch_mode(variant) == "in_graph"
     # legacy bool maps onto the new modes
     assert m.resolve_dispatch_mode(
-        dataclasses.replace(cfg, chunk_dispatch=True),
-        1000, 1000) == "pipelined_host"
+        dataclasses.replace(cfg, chunk_dispatch=True)) == "pipelined_host"
     assert m.resolve_dispatch_mode(
-        dataclasses.replace(cfg, chunk_dispatch=False),
-        1000, 1000) == "in_graph"
+        dataclasses.replace(cfg, chunk_dispatch=False)) == "in_graph"
     # explicit mode wins over the probe; unknown names are rejected
     assert m.resolve_dispatch_mode(
-        dataclasses.replace(cfg, dispatch_mode="pallas_grid"),
-        1000, 1000) == "pallas_grid"
+        dataclasses.replace(cfg, dispatch_mode="pallas_grid")) == "pallas_grid"
     with pytest.raises(FatalError):
         m.resolve_dispatch_mode(
-            dataclasses.replace(cfg, dispatch_mode="bogus"), 1000, 1000)
+            dataclasses.replace(cfg, dispatch_mode="bogus"))
 
 
 @pytest.mark.parametrize("mode", ["pipelined_host", "pallas_grid"])
