@@ -37,7 +37,7 @@ import numpy as np
 from multiverso_tpu.core.options import AddOption, GetOption
 from multiverso_tpu.core.updater import Updater, pallas_row_capability
 from multiverso_tpu.parallel import mesh as mesh_lib
-from multiverso_tpu.telemetry import gauge
+from multiverso_tpu.telemetry import gauge, span
 from multiverso_tpu.utils.configure import get_flag
 from multiverso_tpu.utils.log import check
 from multiverso_tpu.utils.locks import make_lock
@@ -103,14 +103,20 @@ class ServerStore:
         self._pad = self.padded_shape[shard_axis] - self.logical_shape[shard_axis]
 
         self.sharding = mesh_lib.table_sharding(mesh, len(padded), shard_axis)
-        if init_array is None:
+        # Start-up phases, read from their span.<name> histograms (they
+        # run before any traced window): the table made on the host, and
+        # the CALL that starts its transfer (asynchronous: nothing here
+        # waits for it to land).
+        with span("table.host_init", table=name):
             host = np.zeros(self.padded_shape, dtype=self.dtype)
-        else:
-            check(tuple(init_array.shape) == self.logical_shape,
-                  f"init shape {init_array.shape} != {self.logical_shape}")
-            host = np.zeros(self.padded_shape, dtype=self.dtype)
-            host[tuple(slice(0, s) for s in self.logical_shape)] = init_array
-        self.data = jax.device_put(host, self.sharding)
+            if init_array is not None:
+                check(tuple(init_array.shape) == self.logical_shape,
+                      f"init shape {init_array.shape} != "
+                      f"{self.logical_shape}")
+                host[tuple(slice(0, s) for s in self.logical_shape)] = \
+                    init_array
+        with span("table.device_put", table=name):
+            self.data = jax.device_put(host, self.sharding)
 
         # Updater state: shard each leaf along the same logical axis, shifted
         # by any leading worker axis (AdaGrad's [num_workers, ...] g2).
@@ -132,8 +138,9 @@ class ServerStore:
         replicas = mesh.shape.get(mesh_lib.WORKER_AXIS, 1)
         self.state_replicas = replicas
         want_sharded = mode != "off" and replicas > 1
-        state_host = updater.init_state(self.padded_shape, self.dtype,
-                                        num_workers)
+        with span("table.host_init", table=name, leaves="state"):
+            state_host = updater.init_state(self.padded_shape, self.dtype,
+                                            num_workers)
         check(not (mode == "on" and replicas < 2 and state_host),
               f"state_sharding=on: table '{name}' carries updater state "
               "but the mesh has no replica ('worker') axis to shard it "
@@ -157,7 +164,8 @@ class ServerStore:
             leaf_sharding = mesh_lib.table_sharding(mesh, leaf.ndim,
                                                     leaf_axis,
                                                     mesh_axis=axes)
-            self.state[key] = jax.device_put(leaf, leaf_sharding)
+            with span("table.device_put", table=name, leaf=key):
+                self.state[key] = jax.device_put(leaf, leaf_sharding)
 
         # Opt-in Pallas row data plane (DMA gather / sorted scatter-add /
         # fused stateful gather-update-scatter, ops/pallas_rows.py),

@@ -21,6 +21,7 @@ from typing import Any, List, Optional
 import jax
 
 from multiverso_tpu.parallel import mesh as mesh_lib
+from multiverso_tpu.telemetry import span
 from multiverso_tpu.utils import configure
 from multiverso_tpu.utils.log import log, check
 from multiverso_tpu.utils.locks import make_lock
@@ -101,6 +102,10 @@ class Zoo:
               devices: Optional[List[jax.Device]] = None,
               num_local_workers: int = 1) -> List[str]:
         check(not self.started, "Zoo already started")
+        with span("zoo.start"):
+            return self._start(argv, devices, num_local_workers)
+
+    def _start(self, argv, devices, num_local_workers) -> List[str]:
         remaining = configure.parse_cmd_flags(argv)
         self.role = Role.parse(configure.get_flag("ps_role"))
         self.ma_mode = configure.get_flag("ma")

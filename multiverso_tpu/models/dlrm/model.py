@@ -297,21 +297,24 @@ class DLRMModel:
         with span("recsys.pull", fields=self.cfg.fields):
             emb = self.gather_emb(ids)
         with span("recsys.compute", batch=len(labels)):
-            deltas, demb, loss, scores = self._delta(
-                self.dense_params, jnp.asarray(emb), jnp.asarray(dense_x),
-                jnp.asarray(labels))
-            merged = jax.tree_util.tree_map(self._dense_sync, deltas)
-            self.dense_params = self._apply(self.dense_params, merged)
-            self._cp.record(self._cp.ALLREDUCE, self._grad_bytes)
-            demb = np.asarray(demb)
+            with span("recsys.compute.dispatch"):
+                deltas, demb, loss, scores = self._delta(
+                    self.dense_params, jnp.asarray(emb),
+                    jnp.asarray(dense_x), jnp.asarray(labels))
+                merged = jax.tree_util.tree_map(self._dense_sync, deltas)
+                self.dense_params = self._apply(self.dense_params, merged)
+                self._cp.record(self._cp.ALLREDUCE, self._grad_bytes)
+            with span("recsys.compute.sync"):
+                demb = np.asarray(demb)
         with span("recsys.push", fields=self.cfg.fields):
             for f in range(self.cfg.fields):
                 # Duplicate ids within the batch are exact: the updater's
                 # combine_duplicate_rows sums co-keyed deltas before the
                 # row math, identically on both planes.
                 self._push_rows(f, ids[:, f], demb[:, f, :])
-        self.steps += 1
-        return float(loss), np.asarray(scores)
+        with span("recsys.finish"):
+            self.steps += 1
+            return float(loss), np.asarray(scores)
 
     # -- inference ---------------------------------------------------------
     def predict(self, ids: np.ndarray, dense_x: np.ndarray) -> np.ndarray:

@@ -1260,15 +1260,12 @@ class Word2Vec:
             mode = self._dispatch_mode if not sharded else "in_graph"
             W, chunk = self.cfg.window, self.cfg.batch_size
             inflight = _DispatchQueue(self.cfg.dispatch_depth)
-            # Per-mode chunk-dispatch latency: the monitor name carries the
-            # dispatch_mode so runs under different modes diff cleanly in
-            # telemetry_report (AUTO selector introspection, PR 2 follow-up).
-            dispatch_mon = f"W2V_DISPATCH_{mode.upper()}"
             try:
                 for mat, lens, words in source:
-                    with span("w2v.device_block", mode=mode), \
-                            monitor("W2V_DEVICE_BLOCK"), \
-                            monitor(dispatch_mon):
+                    # The one timer of a block's host-side dispatch; the
+                    # mode rides as its attribute. (The block's DEVICE
+                    # time is the jit_block_step program's, in the trace.)
+                    with span("w2v.device_block", mode=mode):
                         self._key, sub = jax.random.split(self._key)
                         lr = np.float32(self._current_lr() *
                                         self._push_scale)

@@ -45,8 +45,8 @@ from typing import Callable, List, Optional, Sequence, Tuple
 import numpy as np
 
 from multiverso_tpu.telemetry import (child_of, counter, current_context,
-                                      emit_span, gauge, histogram, span,
-                                      watchdog_scope)
+                                      emit_span, gauge, histogram, phase,
+                                      span, watchdog_scope)
 from multiverso_tpu.telemetry.context import TraceContext
 from multiverso_tpu.utils.log import check, log
 from multiverso_tpu.utils.locks import make_condition, make_lock
@@ -438,17 +438,22 @@ class DynamicBatcher:
         on shutdown with an empty queue."""
         with self._cv:
             while self._running and not self._queue:
-                self._cv.wait(0.2)
+                # One phase a wake-up, not one an idle spell: a profiler
+                # sees only what begins and ends inside its session, so
+                # a capture of an idle server loses at most 0.2 s at
+                # either edge, however long the spell.
+                with phase("serve.batcher.idle"):
+                    self._cv.wait(0.2)
                 self._wd.beat()     # idle is progress, not a wedge
             if not self._queue:
                 return None         # shutdown
             head = self._queue[0]
             flush_at = head.t_submit + self.max_wait_s
-            while (self._running and len(self._queue) < self.max_batch
-                   and time.monotonic() < flush_at):
-                if self._pipeline is not None and not self._pipeline.full():
-                    break           # free dispatch slot: go now
-                self._cv.wait(max(flush_at - time.monotonic(), 1e-4))
+            if self._wants_company(flush_at):
+                with phase("serve.batcher.coalesce"):
+                    while self._wants_company(flush_at):
+                        self._cv.wait(max(flush_at - time.monotonic(),
+                                          1e-4))
             batch = [self._queue.popleft()
                      for _ in range(min(self.max_batch, len(self._queue)))]
             if batch:
@@ -476,7 +481,15 @@ class DynamicBatcher:
                 live.append(r)
         return live
 
-    def _form_batch(self, batch: List[ServeRequest], t0: float):
+    def _wants_company(self, flush_at: float) -> bool:
+        """Keep coalescing (cv held)? Not past the head's flush time, not
+        with a full batch, and in pipelined mode not with a free dispatch
+        slot: go now."""
+        return (self._running and len(self._queue) < self.max_batch
+                and time.monotonic() < flush_at
+                and (self._pipeline is None or self._pipeline.full()))
+
+    def _form_batch(self, batch: List[ServeRequest]):
         """Pad the batch into its bucket-shaped matrix — the ONE
         formation path shared by the serialized and pipelined loops
         (padding/dtype/bucket fixes must never diverge between them)."""
@@ -489,7 +502,6 @@ class DynamicBatcher:
             n = r.payload.shape[0]
             mat[i, :n] = r.payload
             lengths[i] = n
-        self._h_batch.observe((time.monotonic() - t0) * 1e3)
         return mat, lengths, bucket
 
     def _run_batch(self, batch: List[ServeRequest]) -> None:
@@ -498,17 +510,16 @@ class DynamicBatcher:
         batch (none delivered yet), and a per-request delivery/slice
         error is contained to that request (already-answered siblings
         must never see a second, contradictory callback)."""
-        t0 = time.monotonic()
         try:
             # Formation is inside the guard too: admission validates
             # payload rank, but a dtype a runner can't cast must shed the
             # batch, never kill the worker thread (one hostile client
             # would otherwise wedge the service for everyone).
-            mat, lengths, bucket = self._form_batch(batch, t0)
-            t1 = time.monotonic()
+            with phase("serve.batcher.form") as form:
+                mat, lengths, bucket = self._form_batch(batch)
             with span("serve.batch",
                       runner=getattr(self.runner, "name", "?"),
-                      bucket=bucket, size=len(batch)):
+                      bucket=bucket, size=len(batch)) as run:
                 out = self.runner.run(mat, lengths)
         except Exception as e:  # noqa: BLE001 - a poisoned batch must not
             log.error("serve batcher: batch failed: %s", e)   # kill the
@@ -517,7 +528,10 @@ class DynamicBatcher:
                                              f"runner error: {e}"))
             return
         self._c_batches.inc()
-        t2 = time.monotonic()
+        # The thread's phases and the requests' stages share each
+        # boundary's one clock reading.
+        t0, t1, t2 = form.t0, form.t1, run.t1
+        self._h_batch.observe((t1 - t0) * 1e3)
         self._h_device.observe((t2 - t1) * 1e3)
         for r in batch:
             # Per-request stage spans for sampled traces: where did THIS
@@ -550,20 +564,28 @@ class DynamicBatcher:
         as the serialized path."""
         from multiverso_tpu.serving.pipeline import InflightBatch
 
-        t0 = time.monotonic()
         # Reserve the window slot BEFORE launching: the bound is on
         # device in-flight work, so dispatching first would let depth+1
         # batches ride the device while the producer blocks. Formation
         # below still overlaps the device (the wait is the backpressure).
-        if not self._pipeline.wait_for_slot():
+        t_slot = 0.0
+        if self._pipeline.full():
+            with phase("serve.batcher.coalesce", waits="slot") as slot:
+                open_ = self._pipeline.wait_for_slot()
+            t_slot = slot.t0
+        else:
+            open_ = self._pipeline.wait_for_slot()
+        if not open_:
             for r in batch:
                 self._safe_done(r, ShedError("closed",
                                              "batcher is closed"))
             return
         try:
-            mat, lengths, bucket = self._form_batch(batch, t0)
-            t1 = time.monotonic()
-            handle = self.runner.dispatch(mat, lengths)
+            with phase("serve.batcher.form") as form:
+                mat, lengths, bucket = self._form_batch(batch)
+            with phase("serve.batcher.dispatch", bucket=bucket,
+                      size=len(batch)) as launch:
+                handle = self.runner.dispatch(mat, lengths)
         except Exception as e:  # noqa: BLE001 - a poisoned batch must not
             log.error("serve batcher: dispatch failed: %s", e)  # kill the
             for r in batch:                                     # worker
@@ -572,7 +594,9 @@ class DynamicBatcher:
             return
         # Phase ledger: dispatch (the async launch call) ends here; the
         # stretch to the collector's pickup is device-window residency.
-        t_d = time.monotonic()
+        # The batch-form stage starts where the slot wait did, as before.
+        t0, t1, t_d = t_slot or form.t0, form.t1, launch.t1
+        self._h_batch.observe((t1 - t0) * 1e3)
         self._h_dispatch.observe((t_d - t1) * 1e3)
         item = InflightBatch(handle, self.runner.collect,
                              self._deliver_collected, len(batch),
@@ -589,11 +613,11 @@ class DynamicBatcher:
         is the synced batch output, or the exception that killed
         collection (shed the whole batch — none delivered yet)."""
         batch, lengths, bucket, t0, t1, t_d = item.meta
-        t2 = time.monotonic()
-        # Collector pickup stamp (serving/pipeline.py sets it right
-        # before calling collect): splits window residency (device) from
-        # the host-side sync (collect). Absent stamp -> zero-width
+        # The collector's stamps (serving/pipeline.py: the edges of its
+        # serve.collector.collect phase) split window residency (device)
+        # from the host-side sync (collect). Absent stamps -> zero-width
         # collect, never a negative device phase.
+        t2 = getattr(item, "t_collect1", 0.0) or time.monotonic()
         t_c0 = getattr(item, "t_collect0", 0.0) or t2
         if isinstance(result, BaseException):
             for r in batch:

@@ -37,7 +37,7 @@ from typing import Callable, List, Optional
 
 import numpy as np
 
-from multiverso_tpu.telemetry import counter, gauge, watchdog_scope
+from multiverso_tpu.telemetry import counter, gauge, phase, watchdog_scope
 from multiverso_tpu.utils.log import check, log
 from multiverso_tpu.utils.locks import make_condition
 
@@ -129,7 +129,7 @@ class InflightBatch:
     spans/histograms the batcher emits at delivery."""
 
     __slots__ = ("handle", "collect", "deliver", "n_requests",
-                 "t_dispatch", "t_collect0", "meta")
+                 "t_collect0", "t_collect1", "meta")
 
     def __init__(self, handle, collect: Callable[[object], object],
                  deliver: Callable[["InflightBatch", object], None],
@@ -138,11 +138,12 @@ class InflightBatch:
         self.collect = collect
         self.deliver = deliver
         self.n_requests = max(0, int(n_requests))
-        self.t_dispatch = time.monotonic()
-        # Collector pickup stamp (set by _run_collect just before
-        # collect()): the phase-ledger boundary between device-window
-        # residency and the host-side sync (critical_path.py).
+        # Collector stamps (set by _run_collect: the edges of its
+        # serve.collector.collect phase): the phase-ledger boundaries
+        # between device-window residency, the host-side sync and
+        # delivery (critical_path.py).
         self.t_collect0 = 0.0
+        self.t_collect1 = 0.0
         self.meta = meta
 
 
@@ -240,9 +241,11 @@ class DispatchPipeline:
             with self._cv:
                 while self._running and not self._fifo:
                     # collector idle (no batch in flight): a present
-                    # batch is collected at once under serve.collect
-                    # graftlint: disable=unattributed-wait
-                    self._cv.wait(0.2)
+                    # batch is collected at once under serve.collect.
+                    # One phase a wake-up (see serve.batcher.idle).
+                    with phase("serve.collector.wait"):
+                        # graftlint: disable=unattributed-wait
+                        self._cv.wait(0.2)
                     wd.beat()       # idle is progress, not a wedge
                 if not self._fifo:
                     return          # closed and drained
@@ -254,17 +257,20 @@ class DispatchPipeline:
                 self._g_inflight.set(len(self._fifo) + 1)
                 self._cv.notify_all()
             wd.beat()
-            item.t_collect0 = time.monotonic()
-            try:
-                result: object = item.collect(item.handle)
-            except Exception as e:  # noqa: BLE001 - a poisoned batch must
-                log.error("serve pipeline: collect failed: %s", e)  # not
-                result = e                                # kill the thread
-            try:
-                item.deliver(item, result)
-            except Exception as e:  # noqa: BLE001 - delivery guards its
-                log.error("serve pipeline: deliver failed: %s", e)  # own
-            self._c_batches.inc()                    # per-request errors
+            with phase("serve.collector.collect") as sync:
+                try:
+                    result: object = item.collect(item.handle)
+                except Exception as e:  # noqa: BLE001 - a poisoned batch
+                    log.error("serve pipeline: collect failed: %s", e)
+                    result = e          # must not kill the thread
+            item.t_collect0, item.t_collect1 = sync.t0, sync.t1
+            with phase("serve.collector.deliver"):
+                try:
+                    item.deliver(item, result)
+                except Exception as e:  # noqa: BLE001 - delivery guards
+                    # its own per-request errors
+                    log.error("serve pipeline: deliver failed: %s", e)
+            self._c_batches.inc()
             with self._cv:
                 self._collecting = False
                 self._inflight_reqs -= item.n_requests

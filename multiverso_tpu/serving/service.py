@@ -32,7 +32,7 @@ from multiverso_tpu.parallel.net import (pack_serve_payload, recv_message,
                                          send_message, unpack_trace_ctx)
 from multiverso_tpu.serving.batcher import DynamicBatcher, ShedError
 from multiverso_tpu.telemetry import (activate, child_of, counter, emit_span,
-                                      gauge, histogram)
+                                      gauge, histogram, phase)
 from multiverso_tpu.utils.locks import make_lock
 from multiverso_tpu.utils.log import check, log
 
@@ -256,7 +256,13 @@ class ServingService:
             self._drop(conn)
 
     def _handle(self, conn: socket.socket, msg: Message) -> None:
-        t0 = time.monotonic()
+        # The connection thread's phase, up to the enqueue; the request's
+        # residency (serve.request) starts at the same clock reading.
+        with phase("serve.conn.submit") as submit:
+            self._submit(conn, msg, submit.t0)
+
+    def _submit(self, conn: socket.socket, msg: Message,
+                t0: float) -> None:
         batcher = self._batchers.get(msg.table_id)
         if batcher is None:
             self._reply_error(conn, msg, f"no runner {msg.table_id}")

@@ -83,7 +83,7 @@ from multiverso_tpu.telemetry.metrics import (Counter, Gauge, Histogram,
                                               gauge, get_registry, histogram)
 from multiverso_tpu.telemetry.spans import (TraceBuffer, current_identity,
                                             emit_span, get_trace_buffer,
-                                            span)
+                                            phase, span)
 
 __all__ = [
     "SNAPSHOT_SCHEMA", "TelemetryExporter", "build_chrome_trace",
@@ -94,7 +94,7 @@ __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "counter", "gauge",
     "get_registry", "histogram",
     "TraceBuffer", "current_identity", "emit_span", "get_trace_buffer",
-    "span",
+    "phase", "span",
     "TraceContext", "activate", "child_of", "current_context",
     "maybe_new_root", "new_root",
     "AlertEngine", "AlertManager", "AlertRule", "BurnRateRule",

@@ -9,11 +9,15 @@ whose :meth:`MetricsRegistry.snapshot` is the JSON the exporter ships.
 
 Design constraints:
 
-* hot-path cheap — ``Histogram.observe`` is a couple of float ops and one
-  list increment under a lock (host-side code paths only; nothing here
-  ever runs inside a jitted region);
-* fixed memory — histograms use FIXED log-2 buckets (no per-sample
-  storage), so a week-long run costs the same RAM as a unit test;
+* hot-path cheap — ``Histogram.observe`` is ONE list append: the caller
+  is usually on a request's or a step's critical path with cold caches,
+  where bucketing under a lock cost several times what it does in a loop
+  (PERF.md §6, PR 24); the values are bucketed by whoever READS the
+  histogram, and at every ``PENDING_MAX``-th append (host-side code paths
+  only; nothing here ever runs inside a jitted region);
+* fixed memory — histograms use FIXED log-2 buckets (at most
+  ``PENDING_MAX`` samples held), so a week-long run costs the same RAM as
+  a unit test;
 * stdlib only — this module must import nothing from the framework so
   every layer (utils, core, parallel, models) can depend on it without
   cycles.
@@ -21,6 +25,7 @@ Design constraints:
 
 from __future__ import annotations
 
+import bisect
 import math
 import threading
 from typing import Dict, List, Optional
@@ -52,39 +57,64 @@ class Histogram:
     N_BOUNDS = _HIST_N_BOUNDS
     BOUNDS: List[float] = _HIST_BOUNDS
 
-    __slots__ = ("name", "_lock", "_counts", "count", "sum", "_min", "_max")
+    #: Observations held before they are bucketed.
+    PENDING_MAX = 256
+
+    __slots__ = ("name", "_lock", "_counts", "_count", "_sum", "_min",
+                 "_max", "_pending")
 
     def __init__(self, name: str):
         self.name = name
         self._lock = threading.Lock()
         self._counts = [0] * (self.N_BOUNDS + 1)   # +1 = overflow
-        self.count = 0
-        self.sum = 0.0
+        self._count = 0
+        self._sum = 0.0
         self._min = math.inf
         self._max = 0.0
+        self._pending: List[float] = []
 
     @classmethod
     def bucket_index(cls, value_ms: float) -> int:
-        if value_ms <= cls.LO_MS:
-            return 0
-        idx = int(math.ceil(math.log(value_ms / cls.LO_MS, cls.BASE)))
-        # Float round-off at an exact boundary may land one bucket high.
-        if idx > 0 and value_ms <= cls.BOUNDS[min(idx - 1,
-                                                  cls.N_BOUNDS - 1)]:
-            idx -= 1
-        return min(idx, cls.N_BOUNDS)
+        # First bound at or above the value: exact at the boundaries.
+        return bisect.bisect_left(cls.BOUNDS, value_ms)
 
     def observe(self, value_ms: float) -> None:
-        value_ms = max(float(value_ms), 0.0)
-        idx = self.bucket_index(value_ms)
-        with self._lock:
-            self._counts[idx] += 1
-            self.count += 1
-            self.sum += value_ms
+        """Hold the value; every reader (and the ``PENDING_MAX``-th
+        append) buckets what is held first, so nothing read from a
+        histogram ever lacks an observation."""
+        pending = self._pending
+        pending.append(value_ms)
+        if len(pending) >= self.PENDING_MAX:
+            with self._lock:
+                self._fold_locked()
+
+    def _fold_locked(self) -> None:
+        pending = self._pending
+        while pending:
+            try:
+                value_ms = pending.pop()    # GIL-atomic: a racing append
+            except IndexError:              # waits for the next fold
+                break
+            value_ms = max(float(value_ms), 0.0)
+            self._counts[self.bucket_index(value_ms)] += 1
+            self._count += 1
+            self._sum += value_ms
             if value_ms < self._min:
                 self._min = value_ms
             if value_ms > self._max:
                 self._max = value_ms
+
+    @property
+    def count(self) -> int:
+        with self._lock:
+            self._fold_locked()
+            return self._count
+
+    @property
+    def sum(self) -> float:
+        with self._lock:
+            self._fold_locked()
+            return self._sum
 
     # -- quantiles ---------------------------------------------------------
     @classmethod
@@ -141,11 +171,12 @@ class Histogram:
 
     def _percentile_locked(self, q: float) -> float:
         return self.percentile_from_counts(
-            self._counts, self.count, q,
+            self._counts, self._count, q,
             value_min=self._min, value_max=self._max)
 
     def percentile(self, q: float) -> float:
         with self._lock:
+            self._fold_locked()
             return self._percentile_locked(q)
 
     def raw_counts(self) -> tuple:
@@ -153,18 +184,20 @@ class Histogram:
         sampler's entry point (windowed percentiles come from DELTAS of
         these, so the full snapshot would be wasted work per tick)."""
         with self._lock:
-            return self.count, list(self._counts)
+            self._fold_locked()
+            return self._count, list(self._counts)
 
     def snapshot(self) -> Dict:
         """Consistent point-in-time view (single lock acquisition)."""
         with self._lock:
-            count = self.count
+            self._fold_locked()
+            count = self._count
             return {
                 "count": count,
-                "sum_ms": self.sum,
+                "sum_ms": self._sum,
                 "min_ms": self._min if count else 0.0,
                 "max_ms": self._max,
-                "mean_ms": self.sum / count if count else 0.0,
+                "mean_ms": self._sum / count if count else 0.0,
                 "p50": self._percentile_locked(0.50),
                 "p95": self._percentile_locked(0.95),
                 "p99": self._percentile_locked(0.99),
@@ -240,6 +273,11 @@ class MetricsRegistry:
         self._gauges: Dict[str, Gauge] = {}
 
     def histogram(self, name: str) -> Histogram:
+        # One dict read finds a histogram that exists (every span exit
+        # asks for one); only making it takes the lock.
+        h = self._histograms.get(name)
+        if h is not None:
+            return h
         with self._lock:
             h = self._histograms.get(name)
             if h is None:

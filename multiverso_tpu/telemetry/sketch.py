@@ -50,6 +50,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from multiverso_tpu.telemetry.metrics import counter, gauge
+from multiverso_tpu.telemetry.spans import span
 from multiverso_tpu.utils.locks import make_lock
 
 __all__ = ["CountMinSketch", "SpaceSaving", "TrafficSketch", "SketchHub",
@@ -402,6 +403,7 @@ class SketchHub:
         #: exactly on the next tick.
         self._published: Dict[str, Tuple[int, int]] = {}
         self._dropped = counter("telemetry.sketch.surfaces_dropped")
+        self._folds_on_caller = counter("telemetry.sketch.folds_on_caller")
 
     # -- hot path ------------------------------------------------------------
     def record(self, surface: str, keys, nbytes: int = 0) -> None:
@@ -471,14 +473,17 @@ class SketchHub:
         advisor — so memory stays bounded in unticked processes while
         the overflow cost is hashing the thread's OWN pending keys, not
         a full hub flush on a request path."""
-        pending: Dict[str, Tuple[list, int]] = {}
-        self._drain_buffer(buf, pending)
-        if not pending:
-            return
-        with self._lock:
-            dropped = self._fold_locked(pending)
-        if dropped:
-            self._dropped.inc(dropped)
+        with span("telemetry.sketch_fold", records=len(buf)) as fold:
+            pending: Dict[str, Tuple[list, int]] = {}
+            self._drain_buffer(buf, pending)
+            fold.attrs["keys"] = sum(a.size for arrs, _ in pending.values()
+                                     for a in arrs)
+            if pending:
+                with self._lock:
+                    dropped = self._fold_locked(pending)
+                if dropped:
+                    self._dropped.inc(dropped)
+        self._folds_on_caller.inc()
 
     def flush(self) -> None:
         """Fold pending key arrays into the sketches and publish the

@@ -17,18 +17,17 @@ disagree about what was measured.
 
 from __future__ import annotations
 
-import contextlib
 import os
 import threading
 import time
-from typing import Dict, Iterator, List, Optional
+from typing import Dict, List, Optional
 
 from multiverso_tpu.telemetry import context as trace_context
 from multiverso_tpu.telemetry.context import TraceContext
 from multiverso_tpu.telemetry.metrics import get_registry
 
-__all__ = ["span", "emit_span", "TraceBuffer", "get_trace_buffer",
-           "current_identity"]
+__all__ = ["span", "phase", "emit_span", "TraceBuffer",
+           "get_trace_buffer", "current_identity"]
 
 
 class TraceBuffer:
@@ -138,14 +137,21 @@ def _reset_identity_cache() -> None:
     _identity_cache = None
 
 
-def _trace_annotation(name: str):
-    """``jax.profiler.TraceAnnotation`` when jax is importable; identity
-    otherwise (telemetry stays usable without an accelerator runtime)."""
-    try:
-        import jax
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:  # noqa: BLE001 - profiling sugar must never break
-        return contextlib.nullcontext()
+# ``jax.profiler.TraceAnnotation``, resolved ONCE per process (not by an
+# ``import jax`` in a ``try`` on every call); None when jax is not
+# importable: telemetry stays usable without an accelerator runtime.
+try:
+    from jax.profiler import TraceAnnotation as _Annotation
+except Exception:  # noqa: BLE001 - profiling sugar must never break
+    _Annotation = None
+
+# A region's entry and exit run with COLD caches (the region's own work,
+# or another thread's, evicted them), where every object touched and
+# every frame entered costs several times what it does in a loop: so
+# the process's one registry and the threads' context stacks are bound
+# once, here.
+_REGISTRY = get_registry()
+_ctx_tls = trace_context._tls
 
 
 def _clean_attrs(attrs: Dict) -> Dict:
@@ -165,10 +171,41 @@ def _trace_args(args: Dict, ctx: TraceContext) -> Dict:
     return args
 
 
-@contextlib.contextmanager
-def span(name: str, **attrs) -> Iterator[None]:
-    """Named host-side region: Chrome trace event + ``span.<name>``
-    latency histogram + nested device-trace annotation.
+def _event(name: str, ts_us: float, dur_ms: float, attrs: Dict,
+           ctx: Optional[TraceContext]) -> Dict:
+    """One Chrome "complete" event, wall-clock stamped (Unix epoch
+    microseconds, so processes merge on one axis)."""
+    ident = current_identity()
+    args = _clean_attrs(attrs)
+    args["rank"] = ident.get("rank", 0)
+    if ctx is not None:
+        _trace_args(args, ctx)
+    return {
+        "name": name,
+        "ph": "X",
+        "ts": int(ts_us),
+        "dur": max(int(dur_ms * 1e3), 0),
+        "pid": ident["pid"],
+        "tid": threading.get_ident() % (1 << 31),
+        "cat": "multiverso_tpu",
+        "args": args,
+    }
+
+
+class span:  # noqa: N801 - used as ``with span("name"):``, a verb
+    """Named host-side region ON THE THREAD THAT RUNS IT: a
+    ``jax.profiler.TraceAnnotation`` (the profiler's clock: in a traced
+    run the region sits on its thread's own line, beside the device's
+    lines) + one observation of the ``span.<name>`` latency histogram +
+    a Chrome trace event in the ring.
+
+    The one way to time a region. A REQUEST's stages, whose begin and end
+    straddle threads, are :func:`emit_span` events built from the same
+    clock readings: the handle exposes ``t0`` / ``t1``
+    (``time.monotonic()`` at entry / exit) so a call site reads each
+    boundary once. ``attrs`` may be added to inside the body
+    (``with span("x") as s: s.attrs["n"] = n``). No region sits under two
+    whole-region timers.
 
     When a :class:`~multiverso_tpu.telemetry.context.TraceContext` is
     active on this thread, the region becomes a CHILD span of it (and the
@@ -176,37 +213,65 @@ def span(name: str, **attrs) -> Iterator[None]:
     wire-propagated requests parent correctly); an UNSAMPLED context
     still times the histogram but skips the trace buffer — head-based
     sampling keeps the request hot path cheap. With no active context the
-    behavior is exactly the pre-tracing one (recorded unconditionally,
-    no trace fields)."""
-    ident = current_identity()
-    parent = trace_context.current_context()
-    ctx = trace_context.child_of(parent) if parent is not None else None
-    ts_us = time.time() * 1e6
-    t0 = time.perf_counter()
-    try:
-        with trace_context.activate(ctx), _trace_annotation(name):
-            yield
-    finally:
-        dur_ms = (time.perf_counter() - t0) * 1e3
-        if ctx is None or ctx.sampled:
-            args = _clean_attrs(attrs)
-            args["rank"] = ident.get("rank", 0)
-            if ctx is not None:
-                _trace_args(args, ctx)
-            get_trace_buffer().record({
-                "name": name,
-                "ph": "X",
-                "ts": int(ts_us),
-                "dur": max(int(dur_ms * 1e3), 0),
-                "pid": ident["pid"],
-                "tid": threading.get_ident() % (1 << 31),
-                "cat": "multiverso_tpu",
-                "args": args,
-            })
+    event is recorded unconditionally, with no trace fields (but see
+    :class:`phase`).
+
+    Metrics read the HISTOGRAMS, never the ring: the ring holds the last
+    ``TraceBuffer.DEFAULT_CAPACITY`` events (one traced lookup window at
+    sample rate 1 emits more), widened only by the exporter."""
+
+    __slots__ = ("name", "attrs", "t0", "t1", "_ctx", "_ann")
+
+    #: Record the ring event when NO context is active on the thread.
+    RING_WITHOUT_CONTEXT = True
+
+    def __init__(self, name: str, **attrs):
+        self.name = name
+        self.attrs = attrs
+        self.t0 = self.t1 = 0.0
+        self._ctx: Optional[TraceContext] = None
+        self._ann = None
+
+    def __enter__(self) -> "span":
+        stack = _ctx_tls.stack
+        if stack:
+            self._ctx = trace_context.child_of(stack[-1])
+            stack.append(self._ctx)
+        if _Annotation is not None:
+            self._ann = _Annotation(self.name)
+            self._ann.__enter__()
+        self.t0 = time.monotonic()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        t1 = self.t1 = time.monotonic()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        ctx = self._ctx
+        if ctx is not None:
+            _ctx_tls.stack.pop()
+        dur_ms = (t1 - self.t0) * 1e3
+        if ctx.sampled if ctx is not None else self.RING_WITHOUT_CONTEXT:
+            get_trace_buffer().record(_event(
+                self.name, (time.time() - t1 + self.t0) * 1e6, dur_ms,
+                self.attrs, ctx))
         # Span names are literal at every call site (the documented
         # component.operation convention — cardinality lives in attrs).
         # graftlint: disable=unbounded-metric-name
-        get_registry().histogram(f"span.{name}").observe(dur_ms)
+        _REGISTRY.histogram("span." + self.name).observe(dur_ms)
+
+
+class phase(span):  # noqa: N801 - ``with phase("thread.step"):``
+    """A :class:`span` of a thread's steady cycle (a batch's form /
+    dispatch / collect, a table call's dispatch / sync, an idle wait:
+    hundreds to thousands a second): its ring event is recorded only
+    under a SAMPLED context, never unconditionally. The profiler and the
+    ``span.<name>`` histograms carry the phases; the ring keeps the
+    sampled request stages and tail exemplars it exists for, and an idle
+    server does not fill it."""
+
+    __slots__ = ()
+    RING_WITHOUT_CONTEXT = False
 
 
 def emit_span(name: str, ctx: Optional[TraceContext], t0_mono: float,
@@ -223,24 +288,12 @@ def emit_span(name: str, ctx: Optional[TraceContext], t0_mono: float,
     span-derived percentiles always describe the events in the trace."""
     if ctx is None or not (ctx.sampled or force):
         return
-    ident = current_identity()
-    epoch_minus_mono = time.time() - time.monotonic()
-    args = _clean_attrs(attrs)
-    args["rank"] = ident.get("rank", 0)
-    _trace_args(args, ctx)
     if force and not ctx.sampled:
-        args["tail"] = 1
+        attrs["tail"] = 1
     dur_ms = max(float(dur_ms), 0.0)
-    get_trace_buffer().record({
-        "name": name,
-        "ph": "X",
-        "ts": int((epoch_minus_mono + t0_mono) * 1e6),
-        "dur": max(int(dur_ms * 1e3), 0),
-        "pid": ident["pid"],
-        "tid": threading.get_ident() % (1 << 31),
-        "cat": "multiverso_tpu",
-        "args": args,
-    })
+    get_trace_buffer().record(_event(
+        name, (time.time() - time.monotonic() + t0_mono) * 1e6, dur_ms,
+        attrs, ctx))
     # Same convention as span(): literal names, cardinality in attrs.
     # graftlint: disable=unbounded-metric-name
-    get_registry().histogram(f"span.{name}").observe(dur_ms)
+    _REGISTRY.histogram("span." + name).observe(dur_ms)
