@@ -42,6 +42,7 @@ def _configure_jax() -> None:
 _configure_jax()
 
 from multiverso_tpu.api import (aggregate, barrier, create_table,
+                                create_table_group,
                                 create_distributed_array_table,
                                 create_distributed_kv_table,
                                 create_distributed_matrix_table,
@@ -60,7 +61,8 @@ __version__ = "0.1.0"
 __all__ = [
     "init", "shutdown", "barrier", "rank", "size", "num_workers",
     "num_servers", "worker_id", "server_id", "is_master_worker",
-    "set_flag", "get_flag", "create_table", "aggregate", "finish_train",
+    "set_flag", "get_flag", "create_table", "create_table_group", "aggregate",
+    "finish_train",
     "net_bind", "net_connect", "create_distributed_array_table",
     "create_distributed_matrix_table", "create_distributed_kv_table",
     "create_distributed_sparse_matrix_table",

@@ -118,6 +118,15 @@ def create_table(option: TableOption):
     return table
 
 
+def create_table_group(tables):
+    """A :class:`~multiverso_tpu.tables.table_group.TableGroup` over
+    already-created matrix tables: row get/add for all of them in one
+    launch and one copy each way (docs/DESIGN.md "Grouped row
+    operations")."""
+    from multiverso_tpu.tables.table_group import TableGroup
+    return TableGroup(tables)
+
+
 def aggregate(data):
     """``MV_Aggregate`` analog: allreduce-SUM across processes."""
     return collectives.aggregate(data)

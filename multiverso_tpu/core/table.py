@@ -40,7 +40,7 @@ from multiverso_tpu.parallel import mesh as mesh_lib
 from multiverso_tpu.telemetry import gauge, span
 from multiverso_tpu.utils.configure import get_flag
 from multiverso_tpu.utils.log import check
-from multiverso_tpu.utils.locks import make_lock
+from multiverso_tpu.utils.locks import make_lock, set_lock_order
 
 # XLA's CPU collectives deadlock under concurrent dispatch: a sharded
 # store kernel expands to one participant per virtual device, all of which
@@ -353,13 +353,18 @@ class ServerStore:
             def pallas_access_rows(data, row_ids):
                 return gather_rows(data, row_ids, interpret=interpret)
 
-            self._row_update = jax.jit(pallas_rows_update,
-                                       donate_argnums=(0, 1))
-            self._access_rows = pallas_access_rows  # inner fns already jit
+            rows, access_rows = pallas_rows_update, pallas_access_rows
+            self._row_update = jax.jit(rows, donate_argnums=(0, 1))
+            self._access_rows = access_rows  # inner fns already jit
         else:
             self._row_update = jax.jit(rows, donate_argnums=(0, 1))
             self._access_rows = jax.jit(access_rows)
         self._access = jax.jit(access)
+        # The un-jitted row functions of this store's row plane: a
+        # TableGroup (tables/table_group.py) traces them into its one
+        # program over all members, so the row math has one definition.
+        self.row_update_fn = rows
+        self.access_rows_fn = access_rows
 
     # -- server ops (ref ServerTable::ProcessAdd/ProcessGet) ---------------
     # Every dispatch happens under the store lock: the update kernels DONATE
@@ -523,6 +528,9 @@ class WorkerTable:
         from multiverso_tpu.core.zoo import Zoo
         zoo = Zoo.get()
         self.table_id = zoo.register_table(self)
+        # The store locks form an ordered family keyed by table id: a
+        # TableGroup holds several at once, in that order.
+        set_lock_order(store._lock, self.table_id)
         # BSP gating (SyncServer semantics) when multiple workers share the
         # host-driven path (ref src/server.cpp:68-222). Sized by LOCAL
         # workers only: this store is per-process state, and remote

@@ -9,7 +9,11 @@ the planes this stack already grew:
   ``adagrad`` updater whose per-worker ``g2`` state shards under
   ``-state_sharding``. Clients push lr-prescaled row deltas
   (``AddOption.learning_rate`` reconstructs the raw gradient server-side
-  — the PSModel contract from models/logreg).
+  — the PSModel contract from models/logreg). A step pulls and pushes
+  ALL fields through one :class:`~multiverso_tpu.tables.table_group.
+  TableGroup`: one launch and one copy for the pull (rows arrive as
+  ``[B, fields, D]``), one donated update for the push, which returns
+  once the device has executed it (docs/RECSYS.md).
 * **Dense bottom/top MLP** — device-resident, trained by the CommPolicy
   hybrid step: gradients merge IN-GRAPH through
   :func:`~multiverso_tpu.parallel.comm_policy.build_dense_sync` (a real
@@ -20,10 +24,11 @@ the planes this stack already grew:
   program pins ``lr * grad`` behind ``optimization_barrier`` so XLA:CPU
   cannot contract the scale into the subtract as an fma, and the donated
   apply is its own ``w - d`` kernel. The LOCAL twin (``mode='local'``)
-  drives the *identical* jitted programs and applies embedding deltas
-  through the *same* ``AdaGradUpdater.update_rows`` row-plane math the
-  server runs — so PS-vs-local parity is bitwise, not approximate
-  (tests/test_dlrm.py pins it).
+  drives the *identical* jitted programs — the grouped gather and the
+  grouped row update are built by the group's own builders over the
+  *same* ``AdaGradUpdater.update_rows`` row-plane math the server runs —
+  so PS-vs-local parity is bitwise, not approximate (tests/test_dlrm.py
+  pins it).
 
 Model shape (DLRM): bottom MLP embeds the dense features into the
 embedding space, the interaction layer takes all pairwise dot products
@@ -43,6 +48,9 @@ import numpy as np
 import multiverso_tpu as mv
 from multiverso_tpu.core.options import AddOption, MatrixTableOption
 from multiverso_tpu.core.updater import get_updater
+from multiverso_tpu.tables.table_group import (build_group_access,
+                                               build_group_update,
+                                               group_scalars)
 from multiverso_tpu.telemetry import span
 
 __all__ = ["DLRMConfig", "DLRMModel", "SnapshotScorer", "dense_param_count",
@@ -235,6 +243,9 @@ class DLRMModel:
                     updater="adagrad", name=cfg.table_name(f),
                     comm_policy=cfg.comm_policy or "ps"))
                 for f in range(cfg.fields)]
+            # One group over the field tables: a step pulls and pushes
+            # every field in one launch and one copy each way.
+            self.group = mv.create_table_group(self.tables)
             # Dense params ride the allreduce plane's publish surface so
             # checkpoints (and serving snapshots) carry the whole model.
             self.dense_table = mv.create_table(MatrixTableOption(
@@ -243,10 +254,10 @@ class DLRMModel:
                 comm_policy="allreduce"))
             self.sync()
         else:
-            self._opt_scalars = AddOption(
+            self._opt_scalars = group_scalars([AddOption(
                 worker_id=0, learning_rate=lr,
-                rho=cfg.adagrad_step).scalars()
-            self._updater = get_updater(np.float32, "adagrad")
+                rho=cfg.adagrad_step)] * cfg.fields)
+            updater = get_updater(np.float32, "adagrad")
             self._emb: List[jax.Array] = []
             self._emb_state: List[dict] = []
             for f in range(cfg.fields):
@@ -256,13 +267,23 @@ class DLRMModel:
                 self._emb.append(jnp.asarray(
                     rng.uniform(-0.5, 0.5, size=(cfg.vocab, cfg.embed_dim)
                                 ).astype(np.float32)))
-                self._emb_state.append(self._updater.init_state(
+                self._emb_state.append(updater.init_state(
                     (cfg.vocab, cfg.embed_dim), jnp.float32,
                     max(1, num_workers)))
-            self._update_rows = jax.jit(self._updater.update_rows,
-                                        donate_argnums=(0, 1))
-            self._take = jax.jit(
-                lambda d, i: jnp.take(d, i, axis=0, mode="clip"))
+
+            # The row functions a single-device ServerStore on the XLA
+            # row plane hands its group (core/table._build_kernels), in
+            # the group's own program builders: the twin's programs are
+            # the PS model's programs.
+            def take(data, ids):
+                return jnp.take(data, ids, axis=0, mode="clip")
+
+            def rows(data, state, ids, delta, *opt):
+                return updater.update_rows(data, state, ids, delta, opt)
+
+            self._take = jax.jit(take)
+            self._group_access = build_group_access([take] * cfg.fields)
+            self._group_update = build_group_update([rows] * cfg.fields)
 
     # -- embedding plane ---------------------------------------------------
     def pull_rows(self, field: int, ids: np.ndarray) -> np.ndarray:
@@ -273,26 +294,38 @@ class DLRMModel:
         return np.asarray(self._take(self._emb[field],
                                      np.asarray(ids, np.int32)))
 
-    def _push_rows(self, field: int, ids: np.ndarray,
+    def _push_rows(self, field: None, ids: np.ndarray,
                    delta: np.ndarray) -> None:
+        """Every field's row deltas (``ids`` [B, fields], ``delta``
+        [B, fields, D]) in one donated update. ``field`` is always None:
+        the name and the signature are what the benchmark's dropped-push
+        control patches (benchmark/tests/test_controls.py). Duplicate
+        ids within the batch are exact: the updater's
+        combine_duplicate_rows sums co-keyed deltas before the row math,
+        identically on both planes."""
+        if field is not None:
+            raise ValueError("a step pushes every field at once")
         if self.mode == "ps":
-            self.tables[field].add_rows(ids, delta, self._add_option)
+            self.group.add_rows(ids, delta, self._add_option)
             return
-        self._emb[field], self._emb_state[field] = self._update_rows(
-            self._emb[field], self._emb_state[field],
-            jnp.asarray(ids, jnp.int32), jnp.asarray(delta),
-            self._opt_scalars)
+        emb, state, _ = self._group_update(
+            tuple(self._emb), tuple(self._emb_state),
+            np.asarray(ids, np.int32), delta, *self._opt_scalars)
+        self._emb, self._emb_state = list(emb), list(state)
 
     def gather_emb(self, ids: np.ndarray) -> np.ndarray:
-        """[B, fields, embed_dim] rows for one batch's id matrix."""
-        return np.stack([self.pull_rows(f, ids[:, f])
-                         for f in range(self.cfg.fields)], axis=1)
+        """[B, fields, embed_dim] rows for one batch's id matrix: one
+        launch and one copy for all fields."""
+        ids = np.asarray(ids, np.int32)
+        if self.mode == "ps":
+            return self.group.get_rows(ids)
+        return np.asarray(self._group_access(tuple(self._emb), ids))
 
     # -- training ----------------------------------------------------------
     def step(self, ids: np.ndarray, dense_x: np.ndarray,
              labels: np.ndarray) -> Tuple[float, np.ndarray]:
         """One minibatch: pull touched rows, run the hybrid step, push
-        per-field row deltas. Returns (loss, predicted scores) — the
+        the fields' row deltas. Returns (loss, predicted scores) — the
         scores feed the streaming train AUC for free."""
         with span("recsys.pull", fields=self.cfg.fields):
             emb = self.gather_emb(ids)
@@ -307,11 +340,7 @@ class DLRMModel:
             with span("recsys.compute.sync"):
                 demb = np.asarray(demb)
         with span("recsys.push", fields=self.cfg.fields):
-            for f in range(self.cfg.fields):
-                # Duplicate ids within the batch are exact: the updater's
-                # combine_duplicate_rows sums co-keyed deltas before the
-                # row math, identically on both planes.
-                self._push_rows(f, ids[:, f], demb[:, f, :])
+            self._push_rows(None, ids, demb)
         with span("recsys.finish"):
             self.steps += 1
             return float(loss), np.asarray(scores)

@@ -87,7 +87,8 @@ def _hist(name: str):
     return h
 
 
-def _note_acquired(name: str, waited_s: float, reentrant: bool) -> None:
+def _note_acquired(name: str, waited_s: float, reentrant: bool,
+                   order: Optional[int] = None) -> None:
     held = _held_stack()
     if reentrant:
         for entry in held:
@@ -106,13 +107,21 @@ def _note_acquired(name: str, waited_s: float, reentrant: bool) -> None:
         tname = threading.current_thread().name
         with _state_lock:
             for entry in held:
+                # An ordered family: instances of ONE name taken in
+                # strictly ascending order keys cannot form a cycle among
+                # themselves (the classic total-order discipline), so the
+                # nesting records no self-edge. Any other same-name
+                # nesting (no keys, or not ascending) still does.
+                if (entry[0] == name and order is not None
+                        and entry[3] is not None and entry[3] < order):
+                    continue
                 rec = _edges.get((entry[0], name))
                 if rec is None:
                     rec = _edges[(entry[0], name)] = {
                         "count": 0, "threads": set()}
                 rec["count"] += 1
                 rec["threads"].add(tname)
-    held.append([name, time.monotonic(), 1])
+    held.append([name, time.monotonic(), 1, order])
 
 
 def _note_released(name: str, full: bool = False) -> None:
@@ -131,9 +140,12 @@ def _note_released(name: str, full: bool = False) -> None:
 
 # -- instrumented primitives -------------------------------------------------
 class WitnessLock:
-    """Named non-reentrant mutex: acquisition edges + hold times."""
+    """Named non-reentrant mutex: acquisition edges + hold times.
+    ``order`` (``utils.locks.set_lock_order``) is this instance's key in
+    an ordered family of same-named locks."""
 
     _reentrant = False
+    order: Optional[int] = None
 
     def __init__(self, name: str, inner=None):
         self.name = str(name)
@@ -145,7 +157,7 @@ class WitnessLock:
         ok = self._inner.acquire(blocking, timeout)
         if ok:
             _note_acquired(self.name, time.monotonic() - t0,
-                           self._reentrant)
+                           self._reentrant, self.order)
         return ok
 
     def release(self) -> None:

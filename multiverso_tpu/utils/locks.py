@@ -40,7 +40,7 @@ import os
 import threading
 from typing import Optional
 
-__all__ = ["make_lock", "make_rlock", "make_condition",
+__all__ = ["make_lock", "make_rlock", "make_condition", "set_lock_order",
            "witness_enabled", "set_witness_enabled"]
 
 #: Tri-state override: None = follow env/flag; True/False = forced by a
@@ -79,6 +79,17 @@ def make_lock(name: str) -> threading.Lock:
         return threading.Lock()
     from multiverso_tpu.telemetry.lockwitness import wrap_lock
     return wrap_lock(name)
+
+
+def set_lock_order(lock, key: int) -> None:
+    """Declare ``lock``'s key in an ORDERED FAMILY: same-named locks that
+    code may hold together, always acquired in ascending key order (a
+    ``TableGroup`` takes its members' ``core.store`` locks in table-id
+    order). The witness then checks the discipline instead of reporting
+    every such nesting as a self-loop: ascending keys record no edge,
+    anything else does. Witness off: the bare lock carries nothing."""
+    if hasattr(lock, "order"):
+        lock.order = key
 
 
 def make_rlock(name: str) -> threading.RLock:

@@ -275,9 +275,10 @@ def test_dlrm_step_phase_is_observed(mv_env, name):
         b = stream.batch(16)
         model.step(b.ids, b.dense, b.labels)
     assert _count(name) - before == steps
-    # 3 fields: a step is 3 pulls and 3 pushes through the table phases
-    assert _count("table.get_rows.sync") - gets == 3 * steps
-    assert _count("table.add_rows.sync") - adds == 3 * steps
+    # 3 fields: a step is ONE grouped pull and ONE grouped push through the
+    # table phases (tables/table_group.py), whatever the number of fields
+    assert _count("table.get_rows.sync") - gets == steps
+    assert _count("table.add_rows.sync") - adds == steps
 
 
 def test_no_part_of_a_dlrm_step_is_outside_a_program_span(mv_env):
