@@ -1,0 +1,19 @@
+"""A layer-typed LM (state-space, attention and expert layers chosen per
+layer from a pattern) trained through the parameter-server plane: see
+docs/HYBRID_LM.md."""
+
+from multiverso_tpu.models.hybrid_lm.config import (ATTENTION, EXPERTS,
+                                                    MAMBA, HybridLMConfig)
+from multiverso_tpu.models.hybrid_lm.model import (APPLY_PROGRAM,
+                                                   DELTA_PROGRAM, HybridLM,
+                                                   dense_param_count,
+                                                   forward_hidden,
+                                                   init_buffers, init_params,
+                                                   layer_forward, make_loss,
+                                                   pack_batch, param_shapes,
+                                                   rmsnorm)
+
+__all__ = ["HybridLMConfig", "HybridLM", "MAMBA", "EXPERTS", "ATTENTION",
+           "DELTA_PROGRAM", "APPLY_PROGRAM", "dense_param_count",
+           "forward_hidden", "init_buffers", "init_params", "layer_forward",
+           "make_loss", "pack_batch", "param_shapes", "rmsnorm"]

@@ -1,0 +1,155 @@
+"""Configuration of the layer-typed LM, read from a file with the published
+``config.json``'s own keys (docs/HYBRID_LM.md)."""
+
+from __future__ import annotations
+
+import dataclasses
+import json
+from typing import Any, Dict, Tuple
+
+__all__ = ["HybridLMConfig", "MAMBA", "EXPERTS", "ATTENTION"]
+
+MAMBA, EXPERTS, ATTENTION = "M", "E", "*"
+
+#: Keys a file gives under the published ``config.json``'s own names.
+_PUBLISHED_KEYS = (
+    "hidden_size", "vocab_size", "mamba_num_heads", "mamba_head_dim",
+    "ssm_state_size", "n_groups", "conv_kernel", "chunk_size",
+    "time_step_min", "time_step_max", "time_step_floor",
+    "num_attention_heads", "num_key_value_heads", "head_dim",
+    "num_experts_per_tok", "moe_intermediate_size",
+    "moe_shared_expert_intermediate_size", "routed_scaling_factor",
+    "norm_topk_prob")
+#: Keys of this repo, optional in a file.
+_OWN_KEYS = ("learning_rate", "adagrad_step", "init_std", "attn_block",
+             "moe_block", "loss_block", "row_bucket", "comm_policy")
+
+
+@dataclasses.dataclass
+class HybridLMConfig:
+    """Shape + optimizer of one chip's share of a hybrid state-space /
+    expert / attention LM. Widths are the published ones; ``pattern``,
+    ``held`` and ``vocab_size`` say what of the model lives here."""
+    hidden_size: int = 64
+    vocab_size: int = 64
+    #: One mixer per layer: ``M`` Mamba-2, ``E`` expert layer, ``*`` attention.
+    pattern: str = "MEM*E"
+    norm_eps: float = 1e-5
+    # -- Mamba-2 ------------------------------------------------------------
+    mamba_num_heads: int = 2
+    mamba_head_dim: int = 16
+    ssm_state_size: int = 16
+    n_groups: int = 1
+    conv_kernel: int = 4
+    chunk_size: int = 8
+    time_step_min: float = 0.001
+    time_step_max: float = 0.1
+    time_step_floor: float = 1e-4
+    # -- attention ------------------------------------------------------------
+    num_attention_heads: int = 4
+    num_key_value_heads: int = 2
+    head_dim: int = 16
+    # -- expert layer ---------------------------------------------------------
+    #: Width of the router: every expert of the model, held here or not.
+    router_experts: int = 8
+    #: The experts this chip holds (ids among ``router_experts``).
+    held: Tuple[int, ...] = (0, 1)
+    num_experts_per_tok: int = 2
+    moe_intermediate_size: int = 32
+    moe_shared_expert_intermediate_size: int = 64
+    routed_scaling_factor: float = 2.5
+    norm_topk_prob: bool = True
+    # -- optimizer: the server plane's AdaGrad on every parameter -------------
+    #: Client-side prescale of every pushed delta (the PSModel contract:
+    #: the server reconstructs ``grad = delta / learning_rate``).
+    learning_rate: float = 0.05
+    #: ``AddOption.rho``: the step is ``rho / sqrt(G + eps) * grad``.
+    adagrad_step: float = 0.002
+    init_std: float = 0.02
+    seed: int = 0
+    # -- how the step program blocks its work ---------------------------------
+    attn_block: int = 512
+    moe_block: int = 512
+    loss_block: int = 2048
+    #: Pulled-row counts are rounded up to a multiple of this, so that a
+    #: step's distinct-id count picks one of few compiled shapes.
+    row_bucket: int = 1024
+    table_name: str = "hybrid_lm_emb"
+    comm_policy: str = "ps"
+    # -- provenance (carried, not interpreted) --------------------------------
+    source: str = ""
+    reduced: Tuple[str, ...] = ()
+    assumed: Dict[str, Any] = dataclasses.field(default_factory=dict)
+
+    # -- derived widths -------------------------------------------------------
+    @property
+    def d_inner(self) -> int:
+        """``mamba_num_heads * mamba_head_dim`` (NOT ``expand * hidden``)."""
+        return self.mamba_num_heads * self.mamba_head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        return self.d_inner + 2 * self.n_groups * self.ssm_state_size
+
+    @property
+    def in_proj_dim(self) -> int:
+        """``[z | xBC | dt]``."""
+        return self.d_inner + self.conv_dim + self.mamba_num_heads
+
+    @property
+    def q_dim(self) -> int:
+        return self.num_attention_heads * self.head_dim
+
+    @property
+    def kv_dim(self) -> int:
+        return self.num_key_value_heads * self.head_dim
+
+    def expert_layers(self) -> Tuple[int, ...]:
+        return tuple(i for i, k in enumerate(self.pattern) if k == EXPERTS)
+
+    def validate(self) -> None:
+        from multiverso_tpu.utils.log import check
+        check(set(self.pattern) <= {MAMBA, EXPERTS, ATTENTION} and
+              self.pattern, f"bad layer pattern {self.pattern!r}")
+        check(self.mamba_num_heads % self.n_groups == 0,
+              "mamba heads must divide into n_groups")
+        check(self.d_inner % self.n_groups == 0, "d_inner % n_groups")
+        check(self.num_attention_heads % self.num_key_value_heads == 0,
+              "query heads must be a multiple of key-value heads")
+        check(len(set(self.held)) == len(self.held) and all(
+            0 <= e < self.router_experts for e in self.held),
+            f"held experts {self.held} not among {self.router_experts}")
+        check(self.num_experts_per_tok <= self.router_experts,
+              "more experts a token than experts")
+
+    # -- files ------------------------------------------------------------
+    @classmethod
+    def from_dict(cls, d: Dict[str, Any], **overrides) -> "HybridLMConfig":
+        """From the published keys. ``n_routed_experts`` counts the experts
+        HELD (``held_experts`` names them, default the first ones) and
+        ``published.n_routed_experts`` the router's width; the pattern is
+        the first ``num_hidden_layers`` of ``hybrid_override_pattern``."""
+        published = d.get("published", {})
+        n_held = int(d["n_routed_experts"])
+        router = int(published.get("n_routed_experts", n_held))
+        held = tuple(d.get("held_experts", range(n_held)))
+        if len(held) != n_held:
+            raise ValueError(f"held_experts names {len(held)} experts, "
+                             f"n_routed_experts says {n_held}")
+        kw = {key: d[key] for key in _PUBLISHED_KEYS}
+        kw.update(
+            pattern=d["hybrid_override_pattern"][:d["num_hidden_layers"]],
+            norm_eps=d.get("layer_norm_epsilon", d.get("norm_eps", 1e-5)),
+            router_experts=router, held=held, source=d.get("source", ""),
+            reduced=tuple(d.get("reduced", ())),
+            assumed=dict(d.get("assumed", {})))
+        kw.update({key: d[key] for key in _OWN_KEYS if key in d})
+        kw.update(overrides)
+        cfg = cls(**kw)
+        cfg.validate()
+        return cfg
+
+    @classmethod
+    def from_file(cls, path: str, **overrides) -> "HybridLMConfig":
+        with open(path) as f:
+            return cls.from_dict(json.load(f), **overrides)

@@ -1,0 +1,130 @@
+"""Mamba-2 mixer: the state-space recurrence computed in chunks (SSD).
+
+Per head (``h`` is ``head_dim x state``)::
+
+    h_t = exp(dt_t A) h_{t-1} + dt_t x_t B_t^T        y_t = h_t C_t + D x_t
+
+The program never steps through tokens. A sequence is cut into chunks of
+``chunk`` tokens; inside a chunk the outputs are one masked product
+``(C B^T o L) x`` with ``L[t, s] = exp(sum_{s < r <= t} dt_r A)``; across
+chunks a scan carries the ``head_dim x state`` state, each chunk adding
+what its own tokens leave behind. The reference
+(``benchmark/reference/nemotron3-nano-30b-a3b-ep16.py``) runs the
+recurrence as written.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+__all__ = ["mamba2_mixer", "ssd_chunked", "causal_conv1d",
+           "gated_group_rmsnorm"]
+
+
+def causal_conv1d(x: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
+    """Depthwise causal convolution along the sequence: ``x`` [B, S, C],
+    ``w`` [C, K], ``out[t] = sum_j w[:, j] x[t - (K-1) + j] + b``."""
+    k = w.shape[1]
+    s = x.shape[1]
+    xp = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
+    out = b
+    for j in range(k):
+        out = out + xp[:, j:j + s, :] * w[:, j]
+    return out
+
+
+def gated_group_rmsnorm(y: jax.Array, z: jax.Array, w: jax.Array,
+                        groups: int, eps: float) -> jax.Array:
+    """``RMSNorm_w`` over ``groups`` equal groups of ``y * silu(z)``."""
+    y = y * jax.nn.silu(z)
+    shape = y.shape
+    g = y.reshape(shape[:-1] + (groups, shape[-1] // groups))
+    g = g * jax.lax.rsqrt(jnp.mean(jnp.square(g), axis=-1, keepdims=True)
+                          + eps)
+    return g.reshape(shape) * w
+
+
+def ssd_chunked(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
+                c: jax.Array, chunk: int, group: int = 8) -> jax.Array:
+    """``x`` [B, S, H, P], ``dt`` [B, S, H] (positive), ``a`` [H]
+    (negative), ``b``/``c`` [B, S, G, N] (a group serves H/G heads) ->
+    ``y`` [B, S, H, P], without the ``D x`` term. Any S: the tail is
+    padded with ``dt = 0`` tokens, which neither decay nor feed the
+    state. The products inside the chunks run ``group`` chunks at a time
+    (each group rematerialised in the backward pass), so the ``chunk x
+    chunk`` decay planes of a whole sequence never exist at once."""
+    bsz, s, h, p = x.shape
+    g, n = b.shape[2], b.shape[3]
+    r = h // g
+    k = min(group, -(-s // chunk))
+    pad = (-s) % (chunk * k)
+    if pad:
+        x, dt, b, c = (jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) *
+                               (t.ndim - 2)) for t in (x, dt, b, c))
+    nc = (s + pad) // chunk
+    x = x.reshape(bsz, nc, chunk, g, r, p)
+    dt = dt.reshape(bsz, nc, chunk, g, r)
+    b = b.reshape(bsz, nc, chunk, g, n)
+    c = c.reshape(bsz, nc, chunk, g, n)
+    # heads before positions, so that the chunk x chunk planes are minor
+    cum = jnp.cumsum(jnp.moveaxis(dt * a.reshape(g, r), 2, -1), axis=-1)
+    xdt = x * dt[..., None]                            # cum: [B, nc, G, R, L]
+    causal = jnp.tril(jnp.ones((chunk, chunk), bool))
+
+    @jax.checkpoint
+    def inside(chunks):
+        """(C B^T o L) x for ``k`` chunks."""
+        c_k, b_k, cum_k, xdt_k = chunks
+        decay = jnp.exp(jnp.where(
+            causal, cum_k[..., :, None] - cum_k[..., None, :], -jnp.inf))
+        cb = jnp.einsum("bctgn,bcsgn->bcgts", c_k, b_k)
+        return jnp.einsum("bcgrts,bcsgrp->bctgrp",
+                          decay * cb[:, :, :, None], xdt_k)
+
+    def grouped(t):
+        return jnp.moveaxis(t.reshape((bsz, nc // k, k) + t.shape[2:]), 1, 0)
+
+    y = jax.lax.map(inside, (grouped(c), grouped(b), grouped(cum),
+                             grouped(xdt)))
+    y = jnp.moveaxis(y, 0, 1).reshape(x.shape)
+
+    # what each chunk leaves behind, and the scan that carries it on
+    last = cum[..., -1]                                # [B, nc, G, R]
+    to_end = jnp.moveaxis(jnp.exp(last[..., None] - cum), -1, 2)
+    left = jnp.einsum("bcsgn,bcsgrp->bcgrpn", b, xdt * to_end[..., None])
+
+    def carry_on(state, chunk_in):
+        decay_c, left_c = chunk_in
+        return decay_c[..., None, None] * state + left_c, state
+
+    _, before = jax.lax.scan(
+        carry_on, jnp.zeros((bsz, g, r, p, n), x.dtype),
+        (jnp.moveaxis(jnp.exp(last), 1, 0), jnp.moveaxis(left, 1, 0)))
+    before = jnp.moveaxis(before, 0, 1)                # state at chunk start
+    y = y + jnp.einsum("bctgn,bcgrpn->bctgrp", c, before) \
+        * jnp.moveaxis(jnp.exp(cum), -1, 2)[..., None]
+    return y.reshape(bsz, nc * chunk, h, p)[:, :s]
+
+
+def mamba2_mixer(p: dict, n: jax.Array, cfg) -> jax.Array:
+    """``n`` [B, S, hidden] (already normed) -> the mixer's output."""
+    bsz, s, _ = n.shape
+    h, hp = cfg.mamba_num_heads, cfg.mamba_head_dim
+    g, st = cfg.n_groups, cfg.ssm_state_size
+    d_inner = cfg.d_inner
+    zxbcdt = n @ p["in_proj"]
+    z = zxbcdt[..., :d_inner]
+    xbc = zxbcdt[..., d_inner:d_inner + cfg.conv_dim]
+    dt = zxbcdt[..., d_inner + cfg.conv_dim:]
+    xbc = jax.nn.silu(causal_conv1d(xbc, p["conv_w"], p["conv_b"]))
+    x = xbc[..., :d_inner].reshape(bsz, s, h, hp)
+    b = xbc[..., d_inner:d_inner + g * st].reshape(bsz, s, g, st)
+    c = xbc[..., d_inner + g * st:].reshape(bsz, s, g, st)
+    dt = jax.nn.softplus(dt + p["dt_bias"])
+    a = -jnp.exp(p["A_log"])
+    y = ssd_chunked(x, dt, a, b, c, cfg.chunk_size)
+    y = y + x * p["D"][:, None]
+    y = gated_group_rmsnorm(y.reshape(bsz, s, d_inner), z, p["gnorm"], g,
+                            cfg.norm_eps)
+    return y @ p["out_proj"]

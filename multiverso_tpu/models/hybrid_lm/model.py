@@ -1,0 +1,445 @@
+"""A layer-typed LM trained through the parameter-server plane.
+
+Every layer is ``u <- u + mixer(RMSNorm_w(u))`` with the mixer chosen per
+layer from a pattern: ``M`` a Mamba-2 state-space mixer
+(:mod:`.mamba2`), ``*`` causal grouped-query attention without positions
+(:mod:`.attention`), ``E`` this chip's share of a sigmoid top-k expert
+layer with a shared expert (:func:`~multiverso_tpu.parallel.expert.
+held_topk_moe`). Then a final RMSNorm and an untied head; the loss is
+next-token cross-entropy over the vocabulary slice, taken in blocks of
+tokens so that the logits of a step never exist at once.
+
+Trained as DLRM is (models/dlrm/model.py), by the same entry points:
+
+* the **input embedding** is a ``MatrixTable`` with the server-side
+  ``adagrad`` updater on the ``ps`` plane. A step pulls the rows of its
+  DISTINCT token ids and pushes their lr-prescaled deltas through a
+  :class:`~multiverso_tpu.tables.table_group.TableGroup` (a group of one);
+* the **layer stack, final norm and head** are device-resident and take
+  the CommPolicy hybrid step: a non-donated delta program
+  (``value_and_grad``, ``lr * barrier(g)``), ``build_dense_sync`` leaf by
+  leaf, then a donated apply that runs
+  ``AdaGradUpdater.update_dense`` on (weight, accumulator) — the server
+  plane's own arithmetic and its own state layout;
+* ``mode='local'`` drives the identical programs with the embedding in the
+  model's own arrays, updated by the group's program builders over the same
+  ``update_rows``.
+
+There is NO second resident copy of the dense parameters (DLRM's flattened
+``dense_table`` would be 2.5 GB on the device and as much on the host
+here): :meth:`HybridLM.dense_leaves` hands the live leaves out on demand.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Dict, List, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+import multiverso_tpu as mv
+from multiverso_tpu.core.options import AddOption, MatrixTableOption
+from multiverso_tpu.core.updater import get_updater
+from multiverso_tpu.models.hybrid_lm.attention import attention_mixer
+from multiverso_tpu.models.hybrid_lm.config import (ATTENTION, EXPERTS,
+                                                    MAMBA, HybridLMConfig)
+from multiverso_tpu.models.hybrid_lm.mamba2 import mamba2_mixer
+from multiverso_tpu.parallel.expert import held_topk_moe
+from multiverso_tpu.tables.table_group import (build_group_access,
+                                               build_group_update,
+                                               group_scalars)
+from multiverso_tpu.telemetry import counter, span
+
+__all__ = ["HybridLM", "init_params", "init_buffers", "rmsnorm",
+           "forward_hidden", "make_loss", "dense_param_count",
+           "pack_batch", "DELTA_PROGRAM", "APPLY_PROGRAM"]
+
+#: Names of the two programs of a step as the profiler shows them
+#: (``jit_<name>``): the benchmark's readers find them by these.
+DELTA_PROGRAM = "lm_delta_step"
+APPLY_PROGRAM = "lm_apply"
+
+
+# -- parameters ---------------------------------------------------------------
+def _layer_shapes(cfg: HybridLMConfig, kind: str) -> Dict[str, tuple]:
+    d = cfg.hidden_size
+    if kind == MAMBA:
+        h = cfg.mamba_num_heads
+        return {"norm": (d,), "in_proj": (d, cfg.in_proj_dim),
+                "conv_w": (cfg.conv_dim, cfg.conv_kernel),
+                "conv_b": (cfg.conv_dim,), "dt_bias": (h,), "A_log": (h,),
+                "D": (h,), "gnorm": (cfg.d_inner,),
+                "out_proj": (cfg.d_inner, d)}
+    if kind == ATTENTION:
+        return {"norm": (d,), "wq": (d, cfg.q_dim), "wk": (d, cfg.kv_dim),
+                "wv": (d, cfg.kv_dim), "wo": (cfg.q_dim, d)}
+    f, fs, e = (cfg.moe_intermediate_size,
+                cfg.moe_shared_expert_intermediate_size, len(cfg.held))
+    return {"norm": (d,), "router": (d, cfg.router_experts),
+            "w_up": (e, d, f), "w_down": (e, f, d), "s_up": (d, fs),
+            "s_down": (fs, d)}
+
+
+def param_shapes(cfg: HybridLMConfig) -> dict:
+    return {"layers": [_layer_shapes(cfg, k) for k in cfg.pattern],
+            "final_norm": (cfg.hidden_size,),
+            "head": (cfg.hidden_size, cfg.vocab_size)}
+
+
+def dense_param_count(cfg: HybridLMConfig) -> int:
+    return sum(int(np.prod(s)) for s in jax.tree_util.tree_leaves(
+        param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple)))
+
+
+#: Leaves that project back into the residual stream: scaled down by
+#: ``sqrt(layers)`` (``rescale_prenorm_residual``).
+_OUT_PROJECTIONS = ("out_proj", "wo", "w_down", "s_down")
+
+
+def init_params(cfg: HybridLMConfig) -> dict:
+    """Deterministic from ``cfg.seed`` (so ``ps`` and ``local`` start
+    bitwise alike): matrices normal ``init_std``, projections back into
+    the stream divided by ``sqrt(layers)``, norms and ``D`` one, ``A`` in
+    [1, 16], ``dt`` log-uniform in ``[time_step_min, time_step_max]``
+    through the inverse softplus, as Mamba-2 draws them."""
+    rng = np.random.default_rng(cfg.seed)
+    depth = math.sqrt(len(cfg.pattern))
+
+    def leaf(name, shape):
+        if name in ("norm", "gnorm", "D", "final_norm"):
+            return np.ones(shape, np.float32)
+        if name == "A_log":
+            return np.log(rng.uniform(1.0, 16.0, shape)).astype(np.float32)
+        if name == "dt_bias":
+            dt = np.exp(rng.uniform(math.log(cfg.time_step_min),
+                                    math.log(cfg.time_step_max), shape))
+            dt = np.maximum(dt, cfg.time_step_floor)
+            return (dt + np.log(-np.expm1(-dt))).astype(np.float32)
+        if name == "conv_w":
+            return rng.uniform(-0.5, 0.5, shape).astype(np.float32)
+        if name == "conv_b":
+            return np.zeros(shape, np.float32)
+        w = rng.standard_normal(shape, dtype=np.float32) * cfg.init_std
+        return w / depth if name in _OUT_PROJECTIONS else w
+
+    shapes = param_shapes(cfg)
+    return {"layers": [{k: jnp.asarray(leaf(k, s)) for k, s in layer.items()}
+                       for layer in shapes["layers"]],
+            "final_norm": jnp.asarray(leaf("final_norm",
+                                           shapes["final_norm"])),
+            "head": jnp.asarray(leaf("head", shapes["head"]))}
+
+
+def init_buffers(cfg: HybridLMConfig) -> list:
+    """Per layer what is carried but not trained: an expert layer's
+    ``e_score_correction_bias`` (seeded, small; the published scheme moves
+    it outside the gradient, here it stays fixed)."""
+    rng = np.random.default_rng(cfg.seed + 7)
+    return [jnp.asarray(rng.uniform(-0.01, 0.01, cfg.router_experts)
+                        .astype(np.float32)) if k == EXPERTS else None
+            for k in cfg.pattern]
+
+
+# -- the forward pass ---------------------------------------------------------
+def rmsnorm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
+    return x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+                             + eps) * w
+
+
+def layer_forward(kind: str, p: dict, bias, u: jax.Array,
+                  cfg: HybridLMConfig, remat: bool = False):
+    """One layer: (``u + mixer(RMSNorm_w(u))``, assignments per held expert
+    or None). With ``remat`` the layer is rematerialised in the backward
+    pass: what a step keeps of its forward is the [B, S, hidden] input. A
+    Mamba-2 or attention layer mixes inside a sequence only, so it runs
+    (and is rematerialised) one sequence at a time, and its working set is
+    a sequence's and not the batch's."""
+    keep = jax.checkpoint if remat else (lambda fn: fn)
+    if kind in (MAMBA, ATTENTION):
+        mixer = mamba2_mixer if kind == MAMBA else attention_mixer
+
+        def one_sequence(seq):
+            n = rmsnorm(seq[None], p["norm"], cfg.norm_eps)
+            return seq + mixer(p, n, cfg)[0]
+
+        with jax.named_scope("lm_mamba2" if kind == MAMBA
+                             else "lm_attention"):
+            return jax.lax.map(keep(one_sequence), u), None
+
+    def tokens(p, u):
+        bsz, s, d = u.shape
+        n = rmsnorm(u, p["norm"], cfg.norm_eps).reshape(bsz * s, d)
+        y, counts = held_topk_moe(
+            n, p["router"], bias, p["w_up"], p["w_down"], p["s_up"],
+            p["s_down"], cfg.held, cfg.num_experts_per_tok,
+            cfg.routed_scaling_factor, cfg.norm_topk_prob, cfg.moe_block)
+        return u + y.reshape(u.shape), counts
+
+    with jax.named_scope("lm_experts"):
+        return keep(tokens)(p, u)
+
+
+def forward_hidden(params: dict, buffers: list, u: jax.Array,
+                   cfg: HybridLMConfig, remat: bool = True):
+    """The layer stack over ``u`` [B, S, hidden] -> (hidden states before
+    the final norm, [expert layers, held] assignment counts)."""
+    counts = []
+    for i, kind in enumerate(cfg.pattern):
+        u, c = layer_forward(kind, params["layers"][i], buffers[i], u, cfg,
+                             remat)
+        if c is not None:
+            counts.append(c)
+    return u, (jnp.stack(counts) if counts
+               else jnp.zeros((0, len(cfg.held)), jnp.int32))
+
+
+def blocked_cross_entropy(u: jax.Array, norm_w: jax.Array, head: jax.Array,
+                          targets: jax.Array, mask: jax.Array, eps: float,
+                          block: int) -> jax.Array:
+    """Mean over the unmasked positions of ``-log softmax(RMSNorm_w(u)
+    W_head)[target]``: ``u`` [T, hidden], in blocks of ``block`` tokens,
+    each block's logits recomputed in the backward pass."""
+    t = u.shape[0]
+    blk = min(block, t)
+    pad = (-t) % blk
+    if pad:
+        u = jnp.pad(u, ((0, pad), (0, 0)))
+        targets, mask = jnp.pad(targets, (0, pad)), jnp.pad(mask, (0, pad))
+    nb = (t + pad) // blk
+
+    @jax.checkpoint
+    def block_loss(ub, tb, mb):
+        logits = (rmsnorm(ub, norm_w, eps) @ head).astype(jnp.float32)
+        picked = jnp.take_along_axis(logits, tb[:, None], axis=-1)[:, 0]
+        return jnp.sum((jax.nn.logsumexp(logits, axis=-1) - picked) * mb)
+
+    def add(total, xs):
+        return total + block_loss(*xs), None
+
+    total, _ = jax.lax.scan(add, jnp.float32(0.0), (
+        u.reshape(nb, blk, -1), targets.reshape(nb, blk),
+        mask.reshape(nb, blk)))
+    return total / jnp.maximum(jnp.sum(mask), 1.0)
+
+
+def make_loss(cfg: HybridLMConfig, remat: bool = True):
+    """``(params, rows [n, hidden], buffers, where [B, S], targets [B, S],
+    mask [B, S]) -> (loss, counts)``: ``rows[where]`` is the embedded
+    input (``rows`` the pulled rows of the step's distinct ids)."""
+    def loss_fn(params, rows, buffers, where, targets, mask):
+        u = jnp.take(rows, where, axis=0)
+        u, counts = forward_hidden(params, buffers, u, cfg, remat)
+        loss = blocked_cross_entropy(
+            u.reshape(-1, cfg.hidden_size), params["final_norm"],
+            params["head"], targets.reshape(-1), mask.reshape(-1),
+            cfg.norm_eps, cfg.loss_block)
+        return loss, counts
+
+    return loss_fn
+
+
+def pack_batch(tokens: np.ndarray, bucket: int, min_rows: int = 0):
+    """Host side of a step: ``tokens`` [B, S] -> (ids [n] the distinct
+    token ids padded to a multiple of ``bucket`` with repeats of the first,
+    whose deltas are zero; distinct count; where [B, S] each position's row
+    among ``ids``; targets [B, S] the next token; mask [B, S] 0 at each
+    sequence's last position)."""
+    tokens = np.asarray(tokens, np.int32)
+    ids, where = np.unique(tokens, return_inverse=True)
+    n = len(ids)
+    cap = max(-(-n // bucket) * bucket, min_rows)
+    ids = np.concatenate([ids, np.full(cap - n, ids[0], ids.dtype)])
+    targets = np.concatenate([tokens[:, 1:], tokens[:, :1]], axis=1)
+    mask = np.ones(tokens.shape, np.float32)
+    mask[:, -1] = 0.0
+    return (ids.astype(np.int32), n,
+            where.reshape(tokens.shape).astype(np.int32), targets, mask)
+
+
+class HybridLM:
+    """The train-side model. ``mode='ps'`` keeps the input embedding in a PS
+    table (requires ``mv.init``); ``mode='local'`` is the single-worker
+    twin: the same step programs, the embedding in the model's own arrays
+    updated through the server's own AdaGrad row math."""
+
+    def __init__(self, cfg: HybridLMConfig, mode: str = "ps", dp_mesh=None,
+                 dp_axis: Optional[str] = None,
+                 params: Optional[dict] = None,
+                 buffers: Optional[list] = None):
+        from multiverso_tpu.parallel import comm_policy as cp
+        from multiverso_tpu.utils.log import check
+
+        check(mode in ("ps", "local"), f"bad HybridLM mode {mode!r}")
+        cfg.validate()
+        self.cfg, self.mode = cfg, mode
+        self._cp = cp
+        # A caller that brings its own weights (a checkpoint, a benchmark's
+        # seeded leaves) spares the host the drawing of these.
+        self.params = init_params(cfg) if params is None else params
+        self.buffers = init_buffers(cfg) if buffers is None else buffers
+        self._updater = get_updater(np.float32, "adagrad")
+        self.state = self.fresh_state()
+        lr = cfg.learning_rate
+        self._option = AddOption(
+            worker_id=max(mv.worker_id(), 0) if mode == "ps" else 0,
+            learning_rate=lr, rho=cfg.adagrad_step)
+        loss_fn = make_loss(cfg)
+        barrier = jax.lax.optimization_barrier
+
+        def lm_delta_step(params, rows, buffers, where, targets, mask):
+            (loss, counts), (gp, grows) = jax.value_and_grad(
+                loss_fn, argnums=(0, 1), has_aux=True)(
+                    params, rows, buffers, where, targets, mask)
+            deltas = jax.tree_util.tree_map(lambda g: lr * barrier(g), gp)
+            return deltas, lr * barrier(grows), loss, counts
+
+        updater = self._updater
+
+        def lm_apply(params, state, deltas, *opt):
+            leaves, treedef = jax.tree_util.tree_flatten(params)
+            out = [updater.update_dense(w, s, d, opt) for w, s, d in zip(
+                leaves, treedef.flatten_up_to(state),
+                treedef.flatten_up_to(deltas))]
+            return (treedef.unflatten([w for w, _ in out]),
+                    treedef.unflatten([s for _, s in out]))
+
+        # Not donated (the DLRM / AllreduceModel discipline): the parameters
+        # outlive the program for the separate donated apply, and keeping
+        # lr * grad an OUTPUT pins its rounding point.
+        self._delta = jax.jit(lm_delta_step)  # graftlint: disable=missing-donation
+        self._apply = jax.jit(lm_apply, donate_argnums=(0, 1))
+        self._dense_sync = cp.build_dense_sync(dp_mesh, dp_axis)
+        self._grad_bytes = dense_param_count(cfg) * 4
+        self.steps = 0
+        #: Floor of a step's padded row count: a caller that knows its
+        #: batches sets it so that every step takes ONE compiled shape.
+        self.min_rows = 0
+        self.last_counts = np.zeros((len(cfg.expert_layers()),
+                                     len(cfg.held)), np.int64)
+
+        # Uniform of the parameters' standard deviation: the table's own
+        # random_init draws uniformly.
+        bound = cfg.init_std * math.sqrt(3.0)
+        if mode == "ps":
+            self.table = mv.create_table(MatrixTableOption(
+                num_row=cfg.vocab_size, num_col=cfg.hidden_size,
+                random_init=True, init_low=-bound, init_high=bound,
+                seed=cfg.seed + 101, updater="adagrad",
+                name=cfg.table_name, comm_policy=cfg.comm_policy or "ps"))
+            self.group = mv.create_table_group([self.table])
+        else:
+            # Bitwise what the table's random_init draws
+            # (tables/matrix_table.py): same rng, bounds, dtype.
+            rng = np.random.default_rng(cfg.seed + 101)
+            self._emb = jnp.asarray(rng.uniform(
+                -bound, bound, size=(cfg.vocab_size, cfg.hidden_size)
+            ).astype(np.float32))
+            self._emb_state = self._updater.init_state(
+                (cfg.vocab_size, cfg.hidden_size), jnp.float32, 1)
+
+            def take(data, ids):
+                return jnp.take(data, ids, axis=0, mode="clip")
+
+            def rows(data, state, ids, delta, *opt):
+                return updater.update_rows(data, state, ids, delta, opt)
+
+            self._group_access = build_group_access([take])
+            self._group_update = build_group_update([rows])
+
+    def fresh_state(self) -> dict:
+        """The dense plane's optimizer state as a new model has it: per
+        leaf what ``AdaGradUpdater.init_state`` gives a one-worker
+        server."""
+        return jax.tree_util.tree_map(
+            lambda w: self._updater.init_state(w.shape, w.dtype, 1),
+            self.params)
+
+    # -- embedding plane ---------------------------------------------------
+    def pull_rows(self, ids: np.ndarray) -> np.ndarray:
+        """[n, hidden] current embedding rows of ``ids``."""
+        ids = np.asarray(ids, np.int32)[:, None]
+        if self.mode == "ps":
+            return self.group.get_rows(ids)[:, 0]
+        return np.asarray(self._group_access((self._emb,), ids))[:, 0]
+
+    def _push_rows(self, ids: np.ndarray, delta: np.ndarray) -> None:
+        """lr-prescaled row deltas in one donated update; repeated ids are
+        summed exactly before the row math, on both planes. (The name is
+        what the benchmark's dropped-push control patches.)"""
+        ids = np.asarray(ids, np.int32)[:, None]
+        if self.mode == "ps":
+            self.group.add_rows(ids, delta[:, None, :], self._option)
+            return
+        emb, state, _ = self._group_update(
+            (self._emb,), (self._emb_state,), ids, delta[:, None, :],
+            *group_scalars([self._option]))
+        self._emb, self._emb_state = emb[0], state[0]
+
+    # -- training ----------------------------------------------------------
+    def step(self, tokens: np.ndarray) -> float:
+        """One batch of packed sequences ``tokens`` [B, S]: pull the rows
+        of its distinct ids, run the hybrid step, push the row deltas.
+        Returns the loss once the device has finished the step."""
+        cfg = self.cfg
+        with span("lm.step", tokens=int(tokens.size)):
+            ids, distinct, where, targets, mask = pack_batch(
+                tokens, cfg.row_bucket, self.min_rows)
+            with span("lm.pull", rows=distinct):
+                rows = self.pull_rows(ids)
+            with span("lm.compute"):
+                with span("lm.compute.dispatch"):
+                    deltas, drows, loss, counts = self._delta(
+                        self.params, jnp.asarray(rows), self.buffers,
+                        jnp.asarray(where), jnp.asarray(targets),
+                        jnp.asarray(mask))
+                    # Leaf by leaf, the unmerged delta dropped as soon as
+                    # its merge is launched: at most one leaf is held twice.
+                    leaves, treedef = jax.tree_util.tree_flatten(deltas)
+                    del deltas
+                    merged = []
+                    while leaves:
+                        merged.append(self._dense_sync(leaves.pop(0)))
+                    merged = treedef.unflatten(merged)
+                    self.params, self.state = self._apply(
+                        self.params, self.state, merged,
+                        *self._option.scalars())
+                    del merged
+                    self._cp.record(self._cp.ALLREDUCE, self._grad_bytes)
+                with span("lm.compute.sync"):
+                    drows = np.asarray(drows)
+            with span("lm.push", rows=distinct):
+                self._push_rows(ids, drows)
+            loss = float(loss)
+            self.last_counts = np.asarray(counts, np.int64)
+        self.steps += 1
+        self._count(tokens.size, distinct)
+        return loss
+
+    def _count(self, tokens: int, distinct: int) -> None:
+        counter("lm.tokens").inc(int(tokens))
+        counter("lm.rows_pulled").inc(int(distinct))
+        for layer, per_expert in zip(self.cfg.expert_layers(),
+                                     self.last_counts):
+            # One pair per expert layer of the pattern: bounded.
+            # graftlint: disable=unbounded-metric-name
+            counter(f"lm.moe.assignments_held.l{layer}").inc(
+                int(per_expert.sum()))
+            # graftlint: disable=unbounded-metric-name
+            counter(f"lm.moe.max_expert_load.l{layer}").inc(
+                int(per_expert.max()))
+
+    # -- publish surface -----------------------------------------------------
+    def dense_leaves(self) -> List[Tuple[str, jax.Array]]:
+        """(path, live device array) of every dense parameter, on demand:
+        what a checkpoint or a publish walks, one leaf at a time. There is
+        no flattened second copy."""
+        flat, _ = jax.tree_util.tree_flatten_with_path(self.params)
+        return [(jax.tree_util.keystr(path), leaf) for path, leaf in flat]
+
+    def local_rows(self) -> np.ndarray:
+        """The whole embedding of the local twin (parity tests)."""
+        if self.mode != "local":
+            raise ValueError("local_rows is the local twin's surface")
+        return np.asarray(self._emb)

@@ -1,4 +1,5 @@
-"""Expert parallelism: top-1 routed MoE with expert-sharded weights.
+"""Expert parallelism: top-1 routed MoE with expert-sharded weights, and the
+held-share expert layer of a published sigmoid top-k router.
 
 The reference predates MoE entirely; this module supplies the
 expert-parallel building block the same way ``parallel/sequence.py``
@@ -11,12 +12,24 @@ routed top-1, each expert takes at most ``capacity`` tokens (overflow drops,
 standard MoE semantics), dispatch/combine are one-hot einsums. Dense
 dispatch trades FLOPs for compiler-friendliness: everything is static-shape
 einsums the TPU runs well, versus gather/sort plumbing.
+
+:func:`held_topk_moe` is the other formulation, for layers whose experts
+outnumber the chips: the layer is TOLD which experts it holds, routes over
+all of them, and computes its own experts' terms for every assignment that
+lands here — no capacity, nothing dropped. The assignments are sorted by
+expert into blocks of one expert each, and a loop over the blocks IN USE
+gathers a block's tokens, runs the two products against that expert's
+weights and adds the weighted result back; the backward is the same loop
+with the products transposed, so nothing of a block outlives it. What the
+absent experts would add is left out: on one chip the layer runs without
+its exchange (docs/HYBRID_LM.md).
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Tuple
+import functools
+from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -114,3 +127,142 @@ def reference_top1_moe(params: MoEParams, x: jax.Array,
         h = gelu(xt[t] @ w1[e])
         out[t] = (h @ w2[e]) * gate[t]
     return out.reshape(B, S, D)
+
+
+# -- the held share of a sigmoid top-k expert layer ---------------------------
+def sigmoid_topk_route(n: jax.Array, router: jax.Array, bias: jax.Array,
+                       top_k: int, scaling: float, normalize: bool = True
+                       ) -> Tuple[jax.Array, jax.Array]:
+    """``n`` [T, D] -> (experts [T, k], weights [T, k]): scores
+    ``sigmoid(n W_r)`` in float32 at ``highest`` (as the published code
+    computes them, so that routing does not turn on a product's rounding),
+    the ``k`` largest of ``score + bias`` chosen, their own scores
+    normalised over the chosen (``+ 1e-20``) and scaled."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        n.astype(jnp.float32), router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, chosen = jax.lax.top_k(scores + bias, top_k)
+    w = jnp.take_along_axis(scores, chosen, axis=-1)
+    if normalize:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return chosen, w * scaling
+
+
+def group_held_assignments(chosen: jax.Array, weights: jax.Array,
+                           held: Sequence[int], num_experts: int,
+                           block: int):
+    """Sort the assignments that land on held experts into blocks of one
+    expert each. Returns ``(tokens [L], gates [L], block_expert [L/block],
+    blocks_in_use, counts [len(held)])``: slot ``i`` of the layout serves
+    token ``tokens[i]`` (``T`` marks an empty slot, gate 0) with weight
+    ``gates[i]``; every expert's run is padded to whole blocks; ``L`` is
+    the layout's static worst case (every assignment held)."""
+    t, k = chosen.shape
+    n_held = len(held)
+    local_of = np.full(num_experts, n_held, np.int32)
+    local_of[np.asarray(held)] = np.arange(n_held, dtype=np.int32)
+    local = jnp.asarray(local_of)[chosen.reshape(-1)]
+    order = jnp.argsort(local, stable=True)       # held first, by expert
+    sorted_local = local[order]
+    counts = jnp.sum(local[:, None] == jnp.arange(n_held)[None, :],
+                     axis=0, dtype=jnp.int32)
+    padded = (counts + block - 1) // block * block
+    ends = jnp.cumsum(padded)
+    here = jnp.minimum(sorted_local, n_held - 1)
+    rank = jnp.arange(t * k, dtype=jnp.int32) \
+        - (jnp.cumsum(counts) - counts)[here]
+    length = -(-t * k // block) * block + n_held * block
+    dest = jnp.where(sorted_local < n_held,
+                     (ends - padded)[here] + rank, length)
+    tokens = jnp.full((length,), t, jnp.int32).at[dest].set(
+        (order // k).astype(jnp.int32), mode="drop")
+    gates = jnp.zeros((length,), weights.dtype).at[dest].set(
+        weights.reshape(-1)[order], mode="drop")
+    starts = jnp.arange(length // block, dtype=jnp.int32) * block
+    block_expert = jnp.minimum(
+        jnp.searchsorted(ends, starts, side="right"),
+        n_held - 1).astype(jnp.int32)
+    return tokens, gates, block_expert, ends[-1] // block, counts
+
+
+def _block_of(tokens, gates, block_expert, b, block):
+    idx = jax.lax.dynamic_slice_in_dim(tokens, b * block, block)
+    gate = jax.lax.dynamic_slice_in_dim(gates, b * block, block)
+    return idx, gate, block_expert[b]
+
+
+def _rows(x, idx):
+    return jnp.take(x, idx, axis=0, mode="fill", fill_value=0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
+def grouped_relu2_experts(n, gates, w_up, w_down, tokens, block_expert,
+                          blocks_in_use, block):
+    """``sum_e gate_e relu(n W_up,e)^2 W_down,e`` over the grouped
+    assignments: ``n`` [T, D], ``w_up`` [E_held, D, F], ``w_down``
+    [E_held, F, D] -> [T, D]."""
+    def body(b, out):
+        idx, gate, e = _block_of(tokens, gates, block_expert, b, block)
+        h = jnp.square(jax.nn.relu(_rows(n, idx) @ w_up[e]))
+        return out.at[idx].add((h @ w_down[e]) * gate[:, None], mode="drop")
+
+    return jax.lax.fori_loop(0, blocks_in_use, body, jnp.zeros_like(n))
+
+
+def _grouped_fwd(n, gates, w_up, w_down, tokens, block_expert, blocks_in_use,
+                 block):
+    out = grouped_relu2_experts(n, gates, w_up, w_down, tokens, block_expert,
+                                blocks_in_use, block)
+    return out, (n, gates, w_up, w_down, tokens, block_expert, blocks_in_use)
+
+
+def _grouped_bwd(block, saved, dout):
+    n, gates, w_up, w_down, tokens, block_expert, blocks_in_use = saved
+
+    def body(b, carry):
+        dn, dgates, dup, ddown = carry
+        idx, gate, e = _block_of(tokens, gates, block_expert, b, block)
+        x = _rows(n, idx)
+        r = jax.nn.relu(x @ w_up[e])
+        h = jnp.square(r)
+        dy = _rows(dout, idx)
+        dgates = jax.lax.dynamic_update_slice_in_dim(
+            dgates, jnp.sum(dy * (h @ w_down[e]), axis=-1), b * block, 0)
+        dy = dy * gate[:, None]
+        da = (dy @ w_down[e].T) * (2.0 * r)
+        ddown = ddown.at[e].add(h.T @ dy)
+        dup = dup.at[e].add(x.T @ da)
+        return (dn.at[idx].add(da @ w_up[e].T, mode="drop"), dgates, dup,
+                ddown)
+
+    dn, dgates, dup, ddown = jax.lax.fori_loop(
+        0, blocks_in_use, body,
+        (jnp.zeros_like(n), jnp.zeros_like(gates), jnp.zeros_like(w_up),
+         jnp.zeros_like(w_down)))
+    no_grad = lambda x: np.zeros(x.shape, jax.dtypes.float0)  # noqa: E731
+    return (dn, dgates, dup, ddown, no_grad(tokens), no_grad(block_expert),
+            no_grad(blocks_in_use))
+
+
+grouped_relu2_experts.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+def held_topk_moe(n: jax.Array, router: jax.Array, bias: jax.Array,
+                  w_up: jax.Array, w_down: jax.Array, s_up: jax.Array,
+                  s_down: jax.Array, held: Sequence[int], top_k: int,
+                  scaling: float, normalize: bool = True, block: int = 512,
+                  shared: bool = True) -> Tuple[jax.Array, jax.Array]:
+    """One chip's share of a sigmoid top-k expert layer: ``n`` [T, D] ->
+    (y [T, D], assignments per held expert [len(held)]). ``router`` is
+    [D, E] over ALL experts, ``w_up`` / ``w_down`` hold the experts
+    ``held`` names, in that order. ``shared=False`` leaves the shared
+    expert to another share (a deployment computes it once)."""
+    chosen, weights = sigmoid_topk_route(n, router, bias, top_k, scaling,
+                                         normalize)
+    tokens, gates, block_expert, in_use, counts = group_held_assignments(
+        chosen, weights, held, router.shape[1], block)
+    y = grouped_relu2_experts(n, gates, w_up, w_down, tokens, block_expert,
+                              in_use, block)
+    if shared:
+        y = y + jnp.square(jax.nn.relu(n @ s_up)) @ s_down
+    return y, counts
