@@ -11,9 +11,11 @@ the planes this stack already grew:
   (``AddOption.learning_rate`` reconstructs the raw gradient server-side
   — the PSModel contract from models/logreg). A step pulls and pushes
   ALL fields through one :class:`~multiverso_tpu.tables.table_group.
-  TableGroup`: one launch and one copy for the pull (rows arrive as
+  TableGroup`: one launch for the pull (rows arrive as
   ``[B, fields, D]``), one donated update for the push, which returns
-  once the device has executed it (docs/RECSYS.md).
+  once the device has executed it (docs/RECSYS.md). Where the tables
+  live on the one device the dense programs run on, the pulled rows and
+  their gradient never leave it: only the ids cross the host link.
 * **Dense bottom/top MLP** — device-resident, trained by the CommPolicy
   hybrid step: gradients merge IN-GRAPH through
   :func:`~multiverso_tpu.parallel.comm_policy.build_dense_sync` (a real
@@ -244,8 +246,12 @@ class DLRMModel:
                     comm_policy=cfg.comm_policy or "ps"))
                 for f in range(cfg.fields)]
             # One group over the field tables: a step pulls and pushes
-            # every field in one launch and one copy each way.
+            # every field in one launch each way.
             self.group = mv.create_table_group(self.tables)
+            # Decided once, from where the arrays live: a group spread
+            # over a mesh hands the step host rows.
+            self._rows_on_device = self.group.lives_with(
+                self.dense_params[0][0])
             # Dense params ride the allreduce plane's publish surface so
             # checkpoints (and serving snapshots) carry the whole model.
             self.dense_table = mv.create_table(MatrixTableOption(
@@ -254,6 +260,7 @@ class DLRMModel:
                 comm_policy="allreduce"))
             self.sync()
         else:
+            self._rows_on_device = True     # the twin's arrays: one device
             self._opt_scalars = group_scalars([AddOption(
                 worker_id=0, learning_rate=lr,
                 rho=cfg.adagrad_step)] * cfg.fields)
@@ -294,10 +301,10 @@ class DLRMModel:
         return np.asarray(self._take(self._emb[field],
                                      np.asarray(ids, np.int32)))
 
-    def _push_rows(self, field: None, ids: np.ndarray,
-                   delta: np.ndarray) -> None:
+    def _push_rows(self, field: None, ids: np.ndarray, delta) -> None:
         """Every field's row deltas (``ids`` [B, fields], ``delta``
-        [B, fields, D]) in one donated update. ``field`` is always None:
+        [B, fields, D], on the host or on the device) in one donated
+        update. ``field`` is always None:
         the name and the signature are what the benchmark's dropped-push
         control patches (benchmark/tests/test_controls.py). Duplicate
         ids within the batch are exact: the updater's
@@ -313,13 +320,22 @@ class DLRMModel:
             np.asarray(ids, np.int32), delta, *self._opt_scalars)
         self._emb, self._emb_state = list(emb), list(state)
 
-    def gather_emb(self, ids: np.ndarray) -> np.ndarray:
-        """[B, fields, embed_dim] rows for one batch's id matrix: one
-        launch and one copy for all fields."""
+    def _gather(self, ids: np.ndarray, device: bool):
+        """[B, fields, embed_dim] rows for one batch's id matrix, one launch
+        for all fields: on the device as the gather program left them, or
+        (one copy) on the host."""
         ids = np.asarray(ids, np.int32)
         if self.mode == "ps":
-            return self.group.get_rows(ids)
-        return np.asarray(self._group_access(tuple(self._emb), ids))
+            pull = self.group.get_rows_device if device \
+                else self.group.get_rows
+            return pull(ids)
+        rows = self._group_access(tuple(self._emb), ids)
+        return rows if device else np.asarray(rows)
+
+    def gather_emb(self, ids: np.ndarray) -> np.ndarray:
+        """[B, fields, embed_dim] rows for one batch's id matrix, on the
+        host (serving lanes, checks)."""
+        return self._gather(ids, device=False)
 
     # -- training ----------------------------------------------------------
     def step(self, ids: np.ndarray, dense_x: np.ndarray,
@@ -328,17 +344,31 @@ class DLRMModel:
         the fields' row deltas. Returns (loss, predicted scores) — the
         scores feed the streaming train AUC for free."""
         with span("recsys.pull", fields=self.cfg.fields):
-            emb = self.gather_emb(ids)
+            emb = self._gather(ids, self._rows_on_device)
         with span("recsys.compute", batch=len(labels)):
             with span("recsys.compute.dispatch"):
+                if (self._rows_on_device and emb.committed
+                        and not self.dense_params[0][0].committed):
+                    # Fresh leaves (init, a checkpoint, a benchmark's seed)
+                    # beside committed rows: committed too (the same
+                    # buffers), or the step's programs compile once for
+                    # them and again for their own committed outputs.
+                    self.dense_params = jax.device_put(
+                        self.dense_params, next(iter(emb.devices())))
                 deltas, demb, loss, scores = self._delta(
-                    self.dense_params, jnp.asarray(emb),
+                    self.dense_params, emb,
                     jnp.asarray(dense_x), jnp.asarray(labels))
+                # The program holds its input: without this name the pulled
+                # rows go when it ends, not when the step does.
+                del emb
                 merged = jax.tree_util.tree_map(self._dense_sync, deltas)
                 self.dense_params = self._apply(self.dense_params, merged)
                 self._cp.record(self._cp.ALLREDUCE, self._grad_bytes)
             with span("recsys.compute.sync"):
-                demb = np.asarray(demb)
+                # The phase ends when the row gradient exists: on the
+                # device, or copied to the host for a group over a mesh.
+                demb = jax.block_until_ready(demb) if self._rows_on_device \
+                    else np.asarray(demb)
         with span("recsys.push", fields=self.cfg.fields):
             self._push_rows(None, ids, demb)
         with span("recsys.finish"):

@@ -329,7 +329,12 @@ class HybridLM:
                 seed=cfg.seed + 101, updater="adagrad",
                 name=cfg.table_name, comm_policy=cfg.comm_policy or "ps"))
             self.group = mv.create_table_group([self.table])
+            # Decided once, from where the arrays live: a table spread
+            # over a mesh hands the step host rows.
+            self._rows_on_device = self.group.lives_with(
+                self.params["head"])
         else:
+            self._rows_on_device = True     # the twin's arrays: one device
             # Bitwise what the table's random_init draws
             # (tables/matrix_table.py): same rng, bounds, dtype.
             rng = np.random.default_rng(cfg.seed + 101)
@@ -357,24 +362,37 @@ class HybridLM:
             self.params)
 
     # -- embedding plane ---------------------------------------------------
-    def pull_rows(self, ids: np.ndarray) -> np.ndarray:
-        """[n, hidden] current embedding rows of ``ids``."""
-        ids = np.asarray(ids, np.int32)[:, None]
+    # A group of ONE table: its calls take the id vector and the row block
+    # as they are (the one-vector-a-table layout), so no [n, 1, hidden]
+    # array is made on either side.
+    def _pull(self, ids: np.ndarray, device: bool):
+        """[n, hidden] current rows of ``ids``: on the device as the gather
+        program left them, or on the host."""
+        ids = np.asarray(ids, np.int32)
         if self.mode == "ps":
-            return self.group.get_rows(ids)[:, 0]
-        return np.asarray(self._group_access((self._emb,), ids))[:, 0]
+            pull = self.group.get_rows_device if device \
+                else self.group.get_rows
+            return pull([ids])[0]
+        rows = self._group_access((self._emb,), ids, lengths=(len(ids),),
+                                  blocks=True)[0]
+        return rows if device else np.asarray(rows)
 
-    def _push_rows(self, ids: np.ndarray, delta: np.ndarray) -> None:
-        """lr-prescaled row deltas in one donated update; repeated ids are
-        summed exactly before the row math, on both planes. (The name is
-        what the benchmark's dropped-push control patches.)"""
-        ids = np.asarray(ids, np.int32)[:, None]
+    def pull_rows(self, ids: np.ndarray) -> np.ndarray:
+        """[n, hidden] current embedding rows of ``ids``, on the host."""
+        return self._pull(ids, device=False)
+
+    def _push_rows(self, ids: np.ndarray, delta) -> None:
+        """lr-prescaled row deltas ``[n, hidden]`` (on the host or on the
+        device) in one donated update; repeated ids are summed exactly
+        before the row math, on both planes. (The name is what the
+        benchmark's dropped-push control patches.)"""
+        ids = np.asarray(ids, np.int32)
         if self.mode == "ps":
-            self.group.add_rows(ids, delta[:, None, :], self._option)
+            self.group.add_rows([ids], [delta], self._option)
             return
         emb, state, _ = self._group_update(
-            (self._emb,), (self._emb_state,), ids, delta[:, None, :],
-            *group_scalars([self._option]))
+            (self._emb,), (self._emb_state,), ids, (delta,),
+            *group_scalars([self._option]), lengths=(len(ids),))
         self._emb, self._emb_state = emb[0], state[0]
 
     # -- training ----------------------------------------------------------
@@ -387,13 +405,24 @@ class HybridLM:
             ids, distinct, where, targets, mask = pack_batch(
                 tokens, cfg.row_bucket, self.min_rows)
             with span("lm.pull", rows=distinct):
-                rows = self.pull_rows(ids)
+                rows = self._pull(ids, self._rows_on_device)
             with span("lm.compute"):
                 with span("lm.compute.dispatch"):
+                    if (self._rows_on_device and rows.committed
+                            and not self.params["head"].committed):
+                        # Fresh leaves (init, a checkpoint, a seed) beside
+                        # committed rows: committed too (the same buffers),
+                        # or the step's programs compile once for them and
+                        # again for their own committed outputs.
+                        self.params, self.state = jax.device_put(
+                            (self.params, self.state), next(iter(rows.devices())))
                     deltas, drows, loss, counts = self._delta(
-                        self.params, jnp.asarray(rows), self.buffers,
+                        self.params, rows, self.buffers,
                         jnp.asarray(where), jnp.asarray(targets),
                         jnp.asarray(mask))
+                    # The program holds its input: without this name the
+                    # pulled rows go when it ends, not when the step does.
+                    del rows
                     # Leaf by leaf, the unmerged delta dropped as soon as
                     # its merge is launched: at most one leaf is held twice.
                     leaves, treedef = jax.tree_util.tree_flatten(deltas)
@@ -408,7 +437,10 @@ class HybridLM:
                     del merged
                     self._cp.record(self._cp.ALLREDUCE, self._grad_bytes)
                 with span("lm.compute.sync"):
-                    drows = np.asarray(drows)
+                    # The phase ends when the row deltas exist: on the
+                    # device, or copied to the host for a table over a mesh.
+                    drows = jax.block_until_ready(drows) \
+                        if self._rows_on_device else np.asarray(drows)
             with span("lm.push", rows=distinct):
                 self._push_rows(ids, drows)
             loss = float(loss)

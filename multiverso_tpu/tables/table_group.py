@@ -19,6 +19,11 @@ same calls for all its members at once:
   executed the update, waiting on an output of that very program: no
   program is launched for the sake of waiting and no table is copied.
 
+* ``get_rows_device`` — the same pull with the rows LEFT on the device
+  (the gather program's output as it is), and ``add_rows`` takes device
+  arrays as they are: a step that pulls, computes and pushes on the
+  tables' own device moves only the ids over the host link.
+
 The program is over a TUPLE of arrays, so members may differ in rows and
 width. Nothing is cached across calls: every call reads ``store.data`` /
 ``store.state`` afresh under the stores' locks and writes the new buffers
@@ -75,26 +80,32 @@ def _member_ids(ids: jax.Array, lengths: Lengths) -> List[jax.Array]:
 
 
 def build_group_access(access_fns: Sequence[Callable]) -> Callable:
-    """``(datas, ids, lengths=None) -> rows``: every member's row gather in
-    one program. Matrix layout (``ids`` ``[B, n]``, equal widths) returns
-    ``[B, n, D]``; flat layout (``ids`` the members' id vectors
-    concatenated, ``lengths`` static) returns the members' row blocks
-    raveled and concatenated."""
-    def group_access_rows(datas, ids, lengths=None):
+    """``(datas, ids, lengths=None, blocks=False) -> rows``: every member's
+    row gather in one program. Matrix layout (``ids`` ``[B, n]``, equal
+    widths) returns ``[B, n, D]``; flat layout (``ids`` the members' id
+    vectors concatenated, ``lengths`` static) returns the members' row
+    blocks raveled and concatenated (ONE array for one copy to the host)
+    or, with ``blocks``, as they are (a tuple, for a caller that leaves
+    them on the device)."""
+    def group_access_rows(datas, ids, lengths=None, blocks=False):
         rows = [fn(data, member) for fn, data, member in
                 zip(access_fns, datas, _member_ids(ids, lengths))]
         if lengths is None:
             return jnp.stack(rows, axis=1)
+        if blocks:
+            return tuple(rows)
         return jnp.concatenate([r.reshape(-1) for r in rows])
 
-    return jax.jit(group_access_rows, static_argnames="lengths")
+    return jax.jit(group_access_rows, static_argnames=("lengths", "blocks"))
 
 
 def build_group_update(update_fns: Sequence[Callable]) -> Callable:
     """``(datas, states, ids, deltas, *group_scalars, lengths=None) ->
     (datas, states, done)``: every member's row update in one program that
     donates all data and state. ``update_fns[i]`` has the per-store
-    signature ``(data, state, row_ids, delta, *opt)``. ``done`` reads one
+    signature ``(data, state, row_ids, delta, *opt)``. In the flat layout
+    ``deltas`` is the members' blocks raveled and concatenated, or a tuple
+    of the blocks themselves. ``done`` reads one
     element of each UPDATED table: the output a caller waits on (it is
     never donated, so no later update can delete it under the waiter)."""
     def group_rows(datas, states, ids, deltas, worker_id, momentum, lr, rho,
@@ -102,6 +113,8 @@ def build_group_update(update_fns: Sequence[Callable]) -> Callable:
         member_ids = _member_ids(ids, lengths)
         if lengths is None:
             member_deltas = [deltas[:, i] for i in range(deltas.shape[1])]
+        elif isinstance(deltas, tuple):
+            member_deltas = deltas
         else:
             member_deltas = _blocks(deltas, lengths,
                                     [d.shape[1] for d in datas])
@@ -129,8 +142,10 @@ def group_scalars(options: Sequence[AddOption]) -> tuple:
 
 class TableGroup:
     """Row get/add for a list of :class:`MatrixTable`\\ s in one launch and
-    one copy each way. Columns of a call follow the order the tables were
-    given in; locks and BSP gates are taken in ascending table id.
+    one copy each way, or no copy where the caller's rows stay on the
+    device (``get_rows_device``, device deltas). Columns of a call follow
+    the order the tables were given in; locks and BSP gates are taken in
+    ascending table id.
 
     Covered, decided from what the members show: plain ``MatrixTable``\\ s
     (a ``SparseMatrixTable`` keeps per-worker staleness bitmaps the group
@@ -212,54 +227,100 @@ class TableGroup:
         return np.concatenate(vectors), tuple(len(v) for v in vectors)
 
     # -- row ops -------------------------------------------------------------
+    def lives_with(self, array: jax.Array) -> bool:
+        """Whether the members live on ONE device and ``array`` on the same:
+        a program over ``array`` then takes rows pulled by
+        :meth:`get_rows_device` as they are. (Rows of a group that spans a
+        mesh carry the mesh's sharding, and a multi-device CPU program
+        belongs under the collective lock: such a caller pulls to the
+        host.)"""
+        devices = self._stores[0].sharding.device_set
+        return len(devices) == 1 and devices == array.devices()
+
+    def _pull(self, ids, option: Optional[GetOption], device: bool):
+        """The launch of a pull: ``(the gather program's output, lengths)``.
+        The output is a fresh buffer, never an alias of a ``store.data``:
+        no later donated update can change or delete it (the contract of
+        ``ServerStore.read_rows_with``)."""
+        with phase("table.get_rows.dispatch"):
+            ids, lengths = self._layout(ids)
+            t0 = time.perf_counter()
+            with contextlib.ExitStack() as gates:
+                for t in self._by_id:
+                    gates.enter_context(t._bsp_get(option))
+                with self._dispatch_scope():
+                    out = self._finish(self._access(
+                        tuple(s.data for s in self._stores), ids,
+                        lengths=lengths,
+                        blocks=device and lengths is not None))
+            counts = lengths or (len(ids),) * len(self.tables)
+            self._record([n * w * self.dtype.itemsize
+                          for n, w in zip(counts, self.widths)],
+                         (time.perf_counter() - t0) * 1e3)
+        return out, lengths
+
     def get_rows(self, ids, option: Optional[GetOption] = None):
-        """Rows of every member: ``ids`` is ``[B, n_tables]`` (returns
-        ``[B, n_tables, D]`` where the widths agree) or one id vector per
-        table (returns one ``[len_i, D_i]`` array per table)."""
+        """Rows of every member, on the host: ``ids`` is ``[B, n_tables]``
+        (returns ``[B, n_tables, D]`` where the widths agree) or one id
+        vector per table (returns one ``[len_i, D_i]`` array per table)."""
         with monitor("WORKER_TABLE_SYNC_GET"):
-            with phase("table.get_rows.dispatch"):
-                ids, lengths = self._layout(ids)
-                t0 = time.perf_counter()
-                with contextlib.ExitStack() as gates:
-                    for t in self._by_id:
-                        gates.enter_context(t._bsp_get(option))
-                    with self._dispatch_scope():
-                        out = self._finish(self._access(
-                            tuple(s.data for s in self._stores), ids,
-                            lengths=lengths))
-                counts = lengths or (len(ids),) * len(self.tables)
-                self._record([n * w * self.dtype.itemsize
-                              for n, w in zip(counts, self.widths)],
-                             (time.perf_counter() - t0) * 1e3)
+            out, lengths = self._pull(ids, option, device=False)
             with phase("table.get_rows.sync"):
                 host = np.asarray(out)
         return host if lengths is None else _blocks(host, lengths,
                                                     self.widths)
+
+    def get_rows_device(self, ids, option: Optional[GetOption] = None):
+        """:meth:`get_rows` with the rows left where they are: the gather
+        program's output, a ``jax.Array`` (or one per table) that no copy
+        and no wait has touched. A snapshot like the host form's: a push
+        after the pull does not show in it. The rows are COMMITTED to their
+        device, and so is every output of a program over them; a caller's
+        fresh leaves (``jnp.asarray``) are not, and jit compiles a program
+        once for each: commit such leaves first (``jax.device_put`` to
+        the rows' device: the same buffers) and the program compiles once."""
+        with monitor("WORKER_TABLE_SYNC_GET"):
+            out, lengths = self._pull(ids, option, device=True)
+            # Nothing to wait for: the phase brackets the hand-over, so the
+            # readers of the host form's copy find it (and read ~0).
+            with phase("table.get_rows.sync"):
+                counter("table.group.device_pulls").inc()
+        return out if lengths is None else list(out)
+
+    def _on_device(self, delta) -> bool:
+        return isinstance(delta, jax.Array) and delta.dtype == self.dtype
 
     def add_rows(self, ids, deltas,
                  option: Optional[AddOption] = None) -> None:
         """Apply every member's row deltas through its updater; returns
         once the device has executed the update. ``deltas`` is
         ``[B, n_tables, D]`` beside an id matrix, else one
-        ``[len_i, D_i]`` array per table."""
+        ``[len_i, D_i]`` array per table. Device arrays of the group's
+        dtype go to the program as they are; anything else is copied up
+        from the host."""
         with monitor("WORKER_TABLE_SYNC_ADD"):
             with phase("table.add_rows.dispatch"):
                 ids, lengths = self._layout(ids)
                 if lengths is None:
-                    deltas = np.asarray(deltas, self.dtype)
+                    device = self._on_device(deltas)
+                    if not device:
+                        deltas = np.asarray(deltas, self.dtype)
                     check(deltas.shape == ids.shape + self.widths[:1],
                           f"row delta shape {deltas.shape} != "
                           f"{ids.shape + self.widths[:1]}")
                     nbytes = [deltas.nbytes // len(self.tables)] * \
                         len(self.tables)
                 else:
-                    blocks = [np.asarray(d, self.dtype) for d in deltas]
+                    device = all(self._on_device(d) for d in deltas)
+                    blocks = list(deltas) if device else [
+                        np.asarray(d, self.dtype) for d in deltas]
                     want = list(zip(lengths, self.widths))
                     check([b.shape for b in blocks] == want,
                           f"row delta shapes {[b.shape for b in blocks]} "
                           f"!= {want}")
                     nbytes = [b.nbytes for b in blocks]
-                    deltas = np.concatenate([b.reshape(-1) for b in blocks])
+                    deltas = tuple(blocks) if device else np.concatenate(
+                        [b.reshape(-1) for b in blocks])
                 t0 = time.perf_counter()
                 with contextlib.ExitStack() as gates:
                     opts = {t.table_id: gates.enter_context(
@@ -276,5 +337,7 @@ class TableGroup:
                             s.data, s.state = data, state
                         self._finish((datas, states, done))
                 self._record(nbytes, (time.perf_counter() - t0) * 1e3)
+                if device:
+                    counter("table.group.device_pushes").inc()
             with phase("table.add_rows.sync"):
                 jax.block_until_ready(done)

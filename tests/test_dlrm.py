@@ -45,9 +45,16 @@ _SMALL = dict(fields=3, vocab=64, embed_dim=8, dense_dim=4,
               bottom_mlp=(8,), top_mlp=(8,))
 
 
-def _run_parity(steps: int = 4, batch: int = 16) -> None:
+def _run_parity(steps: int = 4, batch: int = 16,
+                rows_on_device: bool = False) -> None:
     """Drive PS model and local twin on identical impression sequences;
-    every observable must match bitwise."""
+    every observable must match bitwise. ``rows_on_device``: which form
+    of pull and push the PS model's step takes on this mesh (the twin's
+    rows never leave its device)."""
+    from multiverso_tpu.telemetry.metrics import get_registry
+    device_calls = [get_registry().counter(f"table.group.device_{kind}")
+                    for kind in ("pulls", "pushes")]
+    before = [c.value for c in device_calls]
     cfg = DLRMConfig(**_SMALL)
     scfg = StreamConfig(fields=cfg.fields, vocab=cfg.vocab,
                         dense_dim=cfg.dense_dim, zipf=1.3, seed=1,
@@ -74,12 +81,20 @@ def _run_parity(steps: int = 4, batch: int = 16) -> None:
                                             local.dense_params):
         assert np.array_equal(np.asarray(w_ps), np.asarray(w_lo))
         assert np.array_equal(np.asarray(b_ps_), np.asarray(b_lo_))
+    assert [c.value - b for c, b in zip(device_calls, before)] == \
+        [steps * rows_on_device] * 2
 
 
-def test_ps_local_bitwise_parity():
-    mv.init([])
+@pytest.mark.parametrize("devices", ["mesh_of_8", "one_device"])
+def test_ps_local_bitwise_parity(devices):
+    """Over the default mesh the step pulls to the host and pushes from it;
+    with the tables on the dense programs' one device the rows stay
+    there. The same bytes as the twin's either way."""
+    import jax
+    one = devices == "one_device"
+    mv.init([], devices=jax.devices()[:1] if one else None)
     try:
-        _run_parity()
+        _run_parity(rows_on_device=one)
     finally:
         mv.shutdown()
 

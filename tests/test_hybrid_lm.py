@@ -303,11 +303,53 @@ def test_reference_expert_layer_in_token_blocks_is_the_whole(monkeypatch):
 
 
 # -- the planes ------------------------------------------------------------
-def test_ps_plane_matches_local_twin_bitwise(mv_env):
+@pytest.fixture(params=["mesh_of_8", "one_device"])
+def table_devices(request):
+    import multiverso_tpu as mv
+    one = request.param == "one_device"
+    mv.init([], devices=jax.devices()[:1] if one else None)
+    yield one
+    mv.shutdown()
+
+
+def test_ps_plane_matches_local_twin_bitwise(table_devices, monkeypatch):
+    """With the table on the dense programs' one device a step is one
+    device pull and one device push and no row block visits the host;
+    over a mesh it pulls to the host and pushes from it. The twin's bytes
+    either way."""
+    from multiverso_tpu.tables.table_group import TableGroup
     cfg = small(pattern="ME*")
     local, ps = HybridLM(cfg, mode="local"), HybridLM(cfg, mode="ps")
     batches = [batch(cfg, seed=7), batch(cfg, seed=8), batch(cfg, seed=7)]
+    pushed, host_pulls, add_rows, get_rows = [], [], TableGroup.add_rows, \
+        TableGroup.get_rows
+
+    def spy_add(self, ids, deltas, option=None):
+        pushed.extend(type(d) for d in deltas)
+        return add_rows(self, ids, deltas, option)
+
+    def spy_get(self, ids, option=None):
+        host_pulls.append(len(ids[0]))
+        return get_rows(self, ids, option)
+    monkeypatch.setattr(TableGroup, "add_rows", spy_add)
+    monkeypatch.setattr(TableGroup, "get_rows", spy_get)
+    device_calls = [get_registry().counter(f"table.group.device_{kind}")
+                    for kind in ("pulls", "pushes")]
+    before = [c.value for c in device_calls]
     assert [local.step(b) for b in batches] == [ps.step(b) for b in batches]
+    assert [c.value - b for c, b in zip(device_calls, before)] == \
+        [len(batches) * table_devices] * 2
+    assert len(host_pulls) == (0 if table_devices else len(batches))
+    assert len(pushed) == len(batches) and all(
+        issubclass(t, jax.Array) == table_devices for t in pushed)
+    # the first step compiles what every later one runs (as the twin, whose
+    # arrays are all uncommitted: one program a padded shape)
+    assert ps._delta._cache_size() == local._delta._cache_size()
+    assert ps._apply._cache_size() == local._apply._cache_size()
+    monkeypatch.undo()
+    ids = np.unique(batches[0])
+    np.testing.assert_array_equal(ps.pull_rows(ids), local.pull_rows(ids))
+    assert isinstance(ps.pull_rows(ids), np.ndarray)
     for (name, a), (_, b) in zip(local.dense_leaves(), ps.dense_leaves()):
         np.testing.assert_array_equal(a, b, err_msg=name)
     np.testing.assert_array_equal(

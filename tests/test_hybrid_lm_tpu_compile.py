@@ -1,4 +1,5 @@
-"""The layer-typed LM's mixers compile for a v5e at the published widths and
+"""The layer-typed LM's mixers, and the grouped row programs of the device
+form, compile for a v5e at the published widths and
 the benchmark's sequence length (no chip: the TPU compiler is installed and
 compiles for a described device; docs/HYBRID_LM.md). What it guards: a slice
 the tiling refuses, a loop the compiler cannot lower, a working set that does
@@ -8,11 +9,17 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from multiverso_tpu.core.options import AddOption
+from multiverso_tpu.core.updater import get_updater
 from multiverso_tpu.models.hybrid_lm import (HybridLMConfig, layer_forward,
                                              param_shapes)
+from multiverso_tpu.tables.table_group import (build_group_access,
+                                               build_group_update,
+                                               group_scalars)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SEQ = 8192
@@ -58,3 +65,44 @@ def test_mixer_compiles_for_v5e_at_published_widths(one_chip, cfg, kind,
     compiled = jax.jit(run).lower(p, bias, u).compile()
     stats = compiled.memory_analysis()
     assert stats.temp_size_in_bytes < temp_gb * 1e9, stats
+
+
+# (tables, rows a table, width, ids a table, one [B, n] id matrix?): the two
+# cells whose steps keep their pulled rows on the device (ISSUE 27).
+@pytest.mark.parametrize("tables,rows,width,ids,matrix", [
+    (26, 262144, 128, 2048, True), (1, 16384, 2688, 7168, False)],
+    ids=["dlrm_train", "nemotron_train"])
+def test_device_form_group_programs_compile_for_v5e(one_chip, tables, rows,
+                                                    width, ids, matrix):
+    """The grouped gather that hands out device rows and the grouped AdaGrad
+    update that takes device deltas, at the cells' sizes: the [B, n, D]
+    matrix layout (DLRM) and the one-block-a-table layout (the LM), whose
+    programs take and return the members' blocks as a tuple."""
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    updater = get_updater(np.float32, "adagrad")
+    state = jax.eval_shape(
+        lambda: updater.init_state((rows, width), jnp.float32, 1))
+    datas = (spec((rows, width)),) * tables
+    states = (jax.tree_util.tree_map(lambda s: spec(s.shape, s.dtype),
+                                     state),) * tables
+    lengths = None if matrix else (ids,) * tables
+    id_spec = spec((ids, tables) if matrix else (ids * tables,), jnp.int32)
+    deltas = spec((ids, tables, width)) if matrix else \
+        (spec((ids, width)),) * tables
+    access = build_group_access(
+        [lambda data, i: jnp.take(data, i, axis=0, mode="clip")] * tables)
+    update = build_group_update(
+        [lambda data, st, i, d, *opt: updater.update_rows(data, st, i, d,
+                                                          opt)] * tables)
+    pulled = access.lower(datas, id_spec, lengths=lengths,
+                          blocks=not matrix).compile()
+    assert pulled.memory_analysis().output_size_in_bytes >= \
+        ids * tables * width * 4
+    pushed = update.lower(datas, states, id_spec, deltas,
+                          *group_scalars([AddOption()] * tables),
+                          lengths=lengths).compile()
+    # donated: the tables and their accumulators are updated in place
+    assert pushed.memory_analysis().alias_size_in_bytes >= \
+        2 * tables * rows * width * 4

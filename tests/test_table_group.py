@@ -1,10 +1,12 @@
 """Grouped row operations (ISSUE 25, tables/table_group.py): a
 ``TableGroup`` answers ``get_rows`` and ``add_rows`` for all its members in
 one launch and one copy each way, and is bit for bit the per-table calls.
-CPU: bytes and counts only."""
+The device form (ISSUE 27) leaves the pulled rows on the device and takes
+device deltas: the same bytes, no copy. CPU: bytes and counts only."""
 import threading
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -57,18 +59,43 @@ def _state_np(table):
     return {k: np.asarray(v) for k, v in table.store.state.items()}
 
 
+FORMS = ["host", "device"]
+DEVICE_COUNTERS = ("table.group.device_pulls", "table.group.device_pushes")
+
+
+def _device_calls():
+    reg = get_registry()
+    return [reg.counter(n).value for n in DEVICE_COUNTERS]
+
+
+def _deltas(blocks, kind, form):
+    """One push's deltas as the layout takes them, from the host or from
+    the device."""
+    if kind == "equal":
+        deltas = np.stack(blocks, axis=1)
+        return deltas if form == "host" else jnp.asarray(deltas)
+    return blocks if form == "host" else [jnp.asarray(b) for b in blocks]
+
+
+@pytest.mark.parametrize("form", FORMS)
 @pytest.mark.parametrize("kind", sorted(SHAPES))
-def test_grouped_get_equals_the_per_table_gets(mesh_env, kind):
+def test_grouped_get_equals_the_per_table_gets(mesh_env, kind, form):
+    """Both forms of the pull, bit for bit; the device form hands out
+    ``jax.Array``\\ s and counts itself."""
     shapes = SHAPES[kind]
     tables = _tables(shapes, "adagrad", "g")
     group = mv.create_table_group(tables)
     ids = _ids(shapes, kind, np.random.default_rng(0))
-    got = group.get_rows(ids)
+    before = _device_calls()
+    got = group.get_rows(ids) if form == "host" else \
+        group.get_rows_device(ids)
+    assert _device_calls() == [before[0] + (form == "device"), before[1]]
     want = [t.get_rows(c) for t, c in zip(tables, _columns(ids, len(tables)))]
     if kind == "equal":
         assert got.shape == (24, 3, 8)
         got = [got[:, i] for i in range(3)]
     for g, w in zip(got, want):
+        assert isinstance(g, jax.Array if form == "device" else np.ndarray)
         assert g.dtype == w.dtype and np.array_equal(g, w)
 
 
@@ -82,15 +109,19 @@ def test_id_matrix_over_unequal_widths_returns_one_block_a_table(mv_env):
         assert np.array_equal(g, t.get_rows(col))
 
 
+@pytest.mark.parametrize("form", FORMS)
 @pytest.mark.parametrize("kind", sorted(SHAPES))
 @pytest.mark.parametrize("updater", ["adagrad", "sgd", "momentum_sgd"])
-def test_grouped_add_equals_the_per_table_adds(mesh_env, updater, kind):
-    """Data AND state, after three adds with duplicate ids in every batch."""
+def test_grouped_add_equals_the_per_table_adds(mesh_env, updater, kind,
+                                               form):
+    """Data AND state, after three adds with duplicate ids in every batch,
+    the deltas handed over from the host or from the device."""
     shapes = SHAPES[kind]
     solo = _tables(shapes, updater, "solo")
     grouped = _tables(shapes, updater, "grp")
     group = mv.create_table_group(grouped)
     rng = np.random.default_rng(2)
+    before = _device_calls()
     for _ in range(3):
         ids = _ids(shapes, kind, rng)
         cols = _columns(ids, len(shapes))
@@ -98,8 +129,9 @@ def test_grouped_add_equals_the_per_table_adds(mesh_env, updater, kind):
                   for c, (_, w) in zip(cols, shapes)]
         for t, c, d in zip(solo, cols, deltas):
             t.add_rows(c, d, OPTION)
-        group.add_rows(ids, np.stack(deltas, axis=1) if kind == "equal"
-                       else deltas, OPTION)
+        group.add_rows(ids, _deltas(deltas, kind, form), OPTION)
+    assert _device_calls() == [before[0],
+                               before[1] + 3 * (form == "device")]
     for a, b in zip(solo, grouped):
         assert np.array_equal(a.get(), b.get())
         sa, sb = _state_np(a), _state_np(b)
@@ -122,6 +154,29 @@ def test_a_get_after_an_add_sees_it(mesh_env):
     # and the per-table client reads the same bytes
     assert np.array_equal(tables[1].get_rows(ids[:, 1]),
                           (before + delta)[:, 1])
+
+
+@pytest.mark.parametrize("kind", sorted(SHAPES))
+def test_rows_pulled_on_the_device_are_a_snapshot(mesh_env, kind):
+    """The snapshot contract against donation: rows pulled on the device
+    BEFORE a push hold the pre-push values after it (the push donates every
+    member's table), and pushed themselves they are what their host copy
+    is."""
+    shapes = SHAPES[kind]
+    tables, twins = (_tables(shapes, "default", p) for p in ("snap", "twin"))
+    group, twin = mv.create_table_group(tables), mv.create_table_group(twins)
+    ids = _ids(shapes, kind, np.random.default_rng(4))
+    pulled = group.get_rows_device(ids)
+    before = group.get_rows(ids)
+    group.add_rows(ids, pulled)
+    twin.add_rows(ids, before)
+    blocks = (lambda rows: [rows]) if kind == "equal" else list
+    for kept, was, now in zip(blocks(pulled), blocks(before),
+                              blocks(group.get_rows(ids))):
+        assert np.array_equal(kept, was)
+        assert not np.array_equal(now, was)
+    for a, b in zip(tables, twins):
+        assert np.array_equal(a.get(), b.get())
 
 
 @pytest.mark.parametrize("swap", ["load_state", "write_dense"])
@@ -261,50 +316,87 @@ def test_what_the_group_does_not_cover_is_refused_at_construction(mv_env,
         mv.create_table_group(members)
 
 
-def test_wrong_shapes_are_refused_before_anything_is_donated(mv_env):
+@pytest.mark.parametrize("form", FORMS)
+def test_wrong_shapes_are_refused_before_anything_is_donated(mv_env, form):
     tables = _tables(SHAPES["equal"], "default", "bad")
     group = mv.create_table_group(tables)
     ids = np.zeros((4, 3), np.int32)
+    ones = np.ones if form == "host" else jnp.ones
+    pull = group.get_rows if form == "host" else group.get_rows_device
+    before = _device_calls()
     with pytest.raises(FatalError):
-        group.add_rows(ids, np.ones((4, 3, 9), np.float32))
+        group.add_rows(ids, ones((4, 3, 9), np.float32))
     with pytest.raises(FatalError):
-        group.get_rows(np.zeros((4, 2), np.int32))
+        group.add_rows(list(ids.T), [ones((4, 8), np.float32)] * 2 +
+                       [ones((5, 8), np.float32)])
+    with pytest.raises(FatalError):
+        pull(np.zeros((4, 2), np.int32))
+    assert _device_calls() == before
     assert np.array_equal(tables[0].get_rows([0]), tables[0].get()[:1])
 
 
+@pytest.mark.parametrize("devices", ["one_device", "mesh_of_8"])
 @pytest.mark.parametrize("fields", [3, 5])
-def test_a_dlrm_step_is_two_grouped_calls_and_copies_no_table(mv_env,
-                                                              fields):
+def test_a_dlrm_step_is_two_grouped_calls_and_copies_no_table(
+        fields, devices, monkeypatch):
     """``table.group.calls`` / ``table.group.member_ops`` count 2 and
     2 x fields a step; the ``comm.ps.*`` totals stay per member; and no
     member's whole-table ``jit_access`` program is ever launched (the
-    per-table add's wait, ``ServerStore.block``, runs one per call)."""
+    per-table add's wait, ``ServerStore.block``, runs one per call).
+    With the tables on the one device of the dense programs the step is one
+    device pull and one device push, no row block visits the host and its
+    programs compile once; over a mesh it is the host form."""
     from multiverso_tpu.models.dlrm import (DLRMConfig, DLRMModel,
                                             ImpressionStream, StreamConfig)
-    cfg = DLRMConfig(fields=fields, vocab=64, embed_dim=8, dense_dim=4,
-                     bottom_mlp=(8,), top_mlp=(8,))
-    stream = ImpressionStream(StreamConfig(
-        fields=fields, vocab=64, dense_dim=4, zipf=1.3, seed=1,
-        drift_every=0))
-    model = DLRMModel(cfg, mode="ps")
-    reg = get_registry()
-    names = ("table.group.calls", "table.group.member_ops", "comm.ps.ops",
-             "comm.ps.bytes")
-    before = {n: reg.counter(n).value for n in names}
-    steps, batch = 4, 16
-    for _ in range(steps):
-        b = stream.batch(batch)
-        model.step(b.ids, b.dense, b.labels)
-    moved = {n: reg.counter(n).value - before[n] for n in names}
-    assert moved["table.group.calls"] == 2 * steps
-    assert moved["table.group.member_ops"] == 2 * fields * steps
-    assert moved["comm.ps.ops"] == 2 * fields * steps
-    assert moved["comm.ps.bytes"] == 2 * fields * steps * batch * 8 * 4
-    assert all(t.store._access._cache_size() == 0 for t in model.tables)
-    # the per-table add DOES run one, which is what the group leaves out
-    model.tables[0].add_rows([1], np.zeros((1, 8), np.float32),
-                             model._add_option)
-    assert model.tables[0].store._access._cache_size() == 1
+    from multiverso_tpu.tables.table_group import TableGroup
+    one = devices == "one_device"
+    mv.init([], devices=jax.devices()[:1] if one else None)
+    try:
+        cfg = DLRMConfig(fields=fields, vocab=64, embed_dim=8, dense_dim=4,
+                         bottom_mlp=(8,), top_mlp=(8,))
+        stream = ImpressionStream(StreamConfig(
+            fields=fields, vocab=64, dense_dim=4, zipf=1.3, seed=1,
+            drift_every=0))
+        model = DLRMModel(cfg, mode="ps")
+        pushed, host_pulls, add_rows, get_rows = [], [], \
+            TableGroup.add_rows, TableGroup.get_rows
+
+        def spy_add(self, ids, deltas, option=None):
+            pushed.append(type(deltas))
+            return add_rows(self, ids, deltas, option)
+
+        def spy_get(self, ids, option=None):
+            host_pulls.append(ids.shape)
+            return get_rows(self, ids, option)
+        monkeypatch.setattr(TableGroup, "add_rows", spy_add)
+        monkeypatch.setattr(TableGroup, "get_rows", spy_get)
+        reg = get_registry()
+        names = ("table.group.calls", "table.group.member_ops",
+                 "comm.ps.ops", "comm.ps.bytes") + DEVICE_COUNTERS
+        before = {n: reg.counter(n).value for n in names}
+        steps, batch = 4, 16
+        for _ in range(steps):
+            b = stream.batch(batch)
+            model.step(b.ids, b.dense, b.labels)
+        moved = {n: reg.counter(n).value - before[n] for n in names}
+        assert moved["table.group.calls"] == 2 * steps
+        assert moved["table.group.member_ops"] == 2 * fields * steps
+        assert moved["comm.ps.ops"] == 2 * fields * steps
+        assert moved["comm.ps.bytes"] == 2 * fields * steps * batch * 8 * 4
+        assert [moved[n] for n in DEVICE_COUNTERS] == [one * steps] * 2
+        assert len(host_pulls) == (0 if one else steps)
+        assert all(issubclass(t, jax.Array) == one for t in pushed)
+        # the first step compiles what every later one runs: the fresh
+        # dense leaves are placed beside the (committed) pulled rows
+        assert model._delta._cache_size() == 1
+        assert model._apply._cache_size() == 1
+        assert all(t.store._access._cache_size() == 0 for t in model.tables)
+        # the per-table add DOES run one, which is what the group leaves out
+        model.tables[0].add_rows([1], np.zeros((1, 8), np.float32),
+                                 model._add_option)
+        assert model.tables[0].store._access._cache_size() == 1
+    finally:
+        mv.shutdown()
 
 
 @pytest.fixture
