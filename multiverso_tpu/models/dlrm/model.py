@@ -10,27 +10,18 @@ the planes this stack already grew:
   ``-state_sharding``. Clients push lr-prescaled row deltas
   (``AddOption.learning_rate`` reconstructs the raw gradient server-side
   — the PSModel contract from models/logreg). A step pulls and pushes
-  ALL fields through one :class:`~multiverso_tpu.tables.table_group.
-  TableGroup`: one launch for the pull (rows arrive as
-  ``[B, fields, D]``), one donated update for the push, which returns
-  once the device has executed it (docs/RECSYS.md). Where the tables
-  live on the one device the dense programs run on, the pulled rows and
-  their gradient never leave it: only the ids cross the host link.
-* **Dense bottom/top MLP** — device-resident, trained by the CommPolicy
-  hybrid step: gradients merge IN-GRAPH through
-  :func:`~multiverso_tpu.parallel.comm_policy.build_dense_sync` (a real
-  ``psum`` on a data-parallel mesh, an identity-preserving jitted
-  barrier on one device), then apply in a separate donated dispatch.
-* **Bitwise-parity discipline** — same two-dispatch split as
-  ``AllreduceModel`` (models/logreg/model.py): the non-donated delta
-  program pins ``lr * grad`` behind ``optimization_barrier`` so XLA:CPU
-  cannot contract the scale into the subtract as an fma, and the donated
-  apply is its own ``w - d`` kernel. The LOCAL twin (``mode='local'``)
-  drives the *identical* jitted programs — the grouped gather and the
-  grouped row update are built by the group's own builders over the
-  *same* ``AdaGradUpdater.update_rows`` row-plane math the server runs —
-  so PS-vs-local parity is bitwise, not approximate (tests/test_dlrm.py
-  pins it).
+  ALL fields through one table group (tables/table_group.py): one launch
+  for the pull (rows arrive as ``[B, fields, D]``), one donated update
+  for the push (docs/RECSYS.md).
+* **Dense bottom/top MLP** — device-resident, trained by the hybrid step
+  (:class:`~multiverso_tpu.parallel.hybrid_step.HybridStep`, which owns
+  the pull-compute-merge-apply-push cycle and where the rows live
+  meanwhile). This module states the delta function, the apply function
+  and the tables.
+* **The local twin** (``mode='local'``) is the same model over a
+  :class:`~multiverso_tpu.tables.table_group.LocalTableGroup` of the
+  same table options: the same programs over the same initial bytes, so
+  PS-vs-local parity is bitwise (tests/test_dlrm.py pins it).
 
 Model shape (DLRM): bottom MLP embeds the dense features into the
 embedding space, the interaction layer takes all pairwise dot products
@@ -49,11 +40,10 @@ import numpy as np
 
 import multiverso_tpu as mv
 from multiverso_tpu.core.options import AddOption, MatrixTableOption
-from multiverso_tpu.core.updater import get_updater
-from multiverso_tpu.tables.table_group import (build_group_access,
-                                               build_group_update,
-                                               group_scalars)
+from multiverso_tpu.parallel.hybrid_step import HybridStep
+from multiverso_tpu.tables.table_group import LocalTableGroup
 from multiverso_tpu.telemetry import span
+from multiverso_tpu.utils.log import check
 
 __all__ = ["DLRMConfig", "DLRMModel", "SnapshotScorer", "dense_param_count",
            "flatten_dense", "unflatten_dense", "init_dense_params",
@@ -198,15 +188,10 @@ class DLRMModel:
     """
 
     def __init__(self, cfg: DLRMConfig, mode: str = "ps", dp_mesh=None,
-                 dp_axis: Optional[str] = None, num_workers: int = 1):
-        from multiverso_tpu.parallel import comm_policy as cp
-        from multiverso_tpu.utils.log import check
-
+                 dp_axis: Optional[str] = None):
         check(mode in ("ps", "local"), f"bad DLRM mode {mode!r}")
-        self.cfg = cfg
-        self.mode = mode
+        self.cfg, self.mode = cfg, mode
         self.dense_params = init_dense_params(cfg)
-        self._cp = cp
         lr = cfg.learning_rate
         loss_fn = _make_loss(cfg)
         barrier = jax.lax.optimization_barrier
@@ -215,43 +200,24 @@ class DLRMModel:
             (loss, scores), (gp, gemb) = jax.value_and_grad(
                 loss_fn, argnums=(0, 1), has_aux=True)(params, emb,
                                                        dense_x, y)
-            deltas = jax.tree_util.tree_map(
-                lambda g: lr * barrier(g), gp)
+            deltas = jax.tree_util.tree_map(lambda g: lr * barrier(g), gp)
             return deltas, lr * barrier(gemb), loss, scores
 
-        # Deliberately non-donated (the AllreduceModel discipline): the
-        # params must survive for the separate donated apply kernel, and
-        # keeping lr*grad a program OUTPUT pins its rounding point.
-        self._delta = jax.jit(delta_step)  # graftlint: disable=missing-donation
-        self._apply = jax.jit(
-            lambda p, d: jax.tree_util.tree_map(lambda w, g: w - g, p, d),
-            donate_argnums=0)
-        # The hybrid step's dense-plane merge: real psum over a dp axis,
-        # identity-preserving jitted barrier on one device. Dispatched
-        # per leaf between the delta and apply programs.
-        self._dense_sync = cp.build_dense_sync(dp_mesh, dp_axis)
-        self._grad_bytes = dense_param_count(cfg) * 4
         self.steps = 0
-
+        self._add_option = AddOption(
+            worker_id=max(mv.worker_id(), 0) if mode == "ps" else 0,
+            learning_rate=lr, rho=cfg.adagrad_step)
+        options = [MatrixTableOption(
+            num_row=cfg.vocab, num_col=cfg.embed_dim,
+            random_init=True, seed=cfg.seed + 101 + f,
+            updater="adagrad", name=cfg.table_name(f),
+            comm_policy=cfg.comm_policy or "ps")
+            for f in range(cfg.fields)]
+        # One group over the field tables: a step pulls and pushes every
+        # field in one launch each way.
         if mode == "ps":
-            wid = max(mv.worker_id(), 0)
-            self._add_option = AddOption(worker_id=wid,
-                                         learning_rate=lr,
-                                         rho=cfg.adagrad_step)
-            self.tables = [
-                mv.create_table(MatrixTableOption(
-                    num_row=cfg.vocab, num_col=cfg.embed_dim,
-                    random_init=True, seed=cfg.seed + 101 + f,
-                    updater="adagrad", name=cfg.table_name(f),
-                    comm_policy=cfg.comm_policy or "ps"))
-                for f in range(cfg.fields)]
-            # One group over the field tables: a step pulls and pushes
-            # every field in one launch each way.
+            self.tables = [mv.create_table(o) for o in options]
             self.group = mv.create_table_group(self.tables)
-            # Decided once, from where the arrays live: a group spread
-            # over a mesh hands the step host rows.
-            self._rows_on_device = self.group.lives_with(
-                self.dense_params[0][0])
             # Dense params ride the allreduce plane's publish surface so
             # checkpoints (and serving snapshots) carry the whole model.
             self.dense_table = mv.create_table(MatrixTableOption(
@@ -260,82 +226,40 @@ class DLRMModel:
                 comm_policy="allreduce"))
             self.sync()
         else:
-            self._rows_on_device = True     # the twin's arrays: one device
-            self._opt_scalars = group_scalars([AddOption(
-                worker_id=0, learning_rate=lr,
-                rho=cfg.adagrad_step)] * cfg.fields)
-            updater = get_updater(np.float32, "adagrad")
-            self._emb: List[jax.Array] = []
-            self._emb_state: List[dict] = []
-            for f in range(cfg.fields):
-                # Bitwise-identical to the PS table's random_init path
-                # (tables/matrix_table.py): same rng, bounds, dtype.
-                rng = np.random.default_rng(cfg.seed + 101 + f)
-                self._emb.append(jnp.asarray(
-                    rng.uniform(-0.5, 0.5, size=(cfg.vocab, cfg.embed_dim)
-                                ).astype(np.float32)))
-                self._emb_state.append(updater.init_state(
-                    (cfg.vocab, cfg.embed_dim), jnp.float32,
-                    max(1, num_workers)))
-
-            # The row functions a single-device ServerStore on the XLA
-            # row plane hands its group (core/table._build_kernels), in
-            # the group's own program builders: the twin's programs are
-            # the PS model's programs.
-            def take(data, ids):
-                return jnp.take(data, ids, axis=0, mode="clip")
-
-            def rows(data, state, ids, delta, *opt):
-                return updater.update_rows(data, state, ids, delta, opt)
-
-            self._take = jax.jit(take)
-            self._group_access = build_group_access([take] * cfg.fields)
-            self._group_update = build_group_update([rows] * cfg.fields)
+            self.group = LocalTableGroup(options)
+        self._hybrid = HybridStep(
+            self, delta_step,
+            lambda p, d: jax.tree_util.tree_map(lambda w, g: w - g, p, d),
+            dense=("dense_params",), group=self.group, pull=self._pull,
+            push=lambda ids, delta: self._push_rows(None, ids, delta),
+            prefix="recsys", grad_bytes=dense_param_count(cfg) * 4,
+            dp_mesh=dp_mesh, dp_axis=dp_axis)
 
     # -- embedding plane ---------------------------------------------------
-    def pull_rows(self, field: int, ids: np.ndarray) -> np.ndarray:
-        """Current embedding rows for ``ids`` of one field — the train
-        path's pull; serving lanes use runners/snapshots instead."""
-        if self.mode == "ps":
-            return self.tables[field].get_rows(ids)
-        return np.asarray(self._take(self._emb[field],
-                                     np.asarray(ids, np.int32)))
-
     def _push_rows(self, field: None, ids: np.ndarray, delta) -> None:
         """Every field's row deltas (``ids`` [B, fields], ``delta``
         [B, fields, D], on the host or on the device) in one donated
-        update. ``field`` is always None:
-        the name and the signature are what the benchmark's dropped-push
-        control patches (benchmark/tests/test_controls.py). Duplicate
-        ids within the batch are exact: the updater's
-        combine_duplicate_rows sums co-keyed deltas before the row math,
-        identically on both planes."""
+        update. ``field`` is always None: the name and the signature are
+        what the benchmark's dropped-push control patches
+        (benchmark/tests/test_controls.py). Duplicate ids within the batch
+        are exact: the updater's combine_duplicate_rows sums co-keyed
+        deltas before the row math, identically on both planes."""
         if field is not None:
             raise ValueError("a step pushes every field at once")
-        if self.mode == "ps":
-            self.group.add_rows(ids, delta, self._add_option)
-            return
-        emb, state, _ = self._group_update(
-            tuple(self._emb), tuple(self._emb_state),
-            np.asarray(ids, np.int32), delta, *self._opt_scalars)
-        self._emb, self._emb_state = list(emb), list(state)
+        self.group.add_rows(np.asarray(ids, np.int32), delta,
+                            self._add_option)
 
-    def _gather(self, ids: np.ndarray, device: bool):
+    def _pull(self, ids: np.ndarray, device: bool):
         """[B, fields, embed_dim] rows for one batch's id matrix, one launch
         for all fields: on the device as the gather program left them, or
         (one copy) on the host."""
-        ids = np.asarray(ids, np.int32)
-        if self.mode == "ps":
-            pull = self.group.get_rows_device if device \
-                else self.group.get_rows
-            return pull(ids)
-        rows = self._group_access(tuple(self._emb), ids)
-        return rows if device else np.asarray(rows)
+        pull = self.group.get_rows_device if device else self.group.get_rows
+        return pull(np.asarray(ids, np.int32))
 
     def gather_emb(self, ids: np.ndarray) -> np.ndarray:
         """[B, fields, embed_dim] rows for one batch's id matrix, on the
         host (serving lanes, checks)."""
-        return self._gather(ids, device=False)
+        return self._pull(ids, device=False)
 
     # -- training ----------------------------------------------------------
     def step(self, ids: np.ndarray, dense_x: np.ndarray,
@@ -343,34 +267,8 @@ class DLRMModel:
         """One minibatch: pull touched rows, run the hybrid step, push
         the fields' row deltas. Returns (loss, predicted scores) — the
         scores feed the streaming train AUC for free."""
-        with span("recsys.pull", fields=self.cfg.fields):
-            emb = self._gather(ids, self._rows_on_device)
-        with span("recsys.compute", batch=len(labels)):
-            with span("recsys.compute.dispatch"):
-                if (self._rows_on_device and emb.committed
-                        and not self.dense_params[0][0].committed):
-                    # Fresh leaves (init, a checkpoint, a benchmark's seed)
-                    # beside committed rows: committed too (the same
-                    # buffers), or the step's programs compile once for
-                    # them and again for their own committed outputs.
-                    self.dense_params = jax.device_put(
-                        self.dense_params, next(iter(emb.devices())))
-                deltas, demb, loss, scores = self._delta(
-                    self.dense_params, emb,
-                    jnp.asarray(dense_x), jnp.asarray(labels))
-                # The program holds its input: without this name the pulled
-                # rows go when it ends, not when the step does.
-                del emb
-                merged = jax.tree_util.tree_map(self._dense_sync, deltas)
-                self.dense_params = self._apply(self.dense_params, merged)
-                self._cp.record(self._cp.ALLREDUCE, self._grad_bytes)
-            with span("recsys.compute.sync"):
-                # The phase ends when the row gradient exists: on the
-                # device, or copied to the host for a group over a mesh.
-                demb = jax.block_until_ready(demb) if self._rows_on_device \
-                    else np.asarray(demb)
-        with span("recsys.push", fields=self.cfg.fields):
-            self._push_rows(None, ids, demb)
+        loss, scores = self._hybrid(ids, dense_x, labels,
+                                    fields=self.cfg.fields, batch=len(labels))
         with span("recsys.finish"):
             self.steps += 1
             return float(loss), np.asarray(scores)
@@ -378,13 +276,12 @@ class DLRMModel:
     # -- inference ---------------------------------------------------------
     def predict(self, ids: np.ndarray, dense_x: np.ndarray) -> np.ndarray:
         """Fresh-table scores (the staleness-0 lane)."""
-        emb = self.gather_emb(ids)
-        return self.scores(emb, dense_x)
+        return self.scores(self.gather_emb(ids), dense_x)
 
     def scores(self, emb: np.ndarray, dense_x: np.ndarray) -> np.ndarray:
         """Scores from pre-gathered rows — the serving lanes feed rows
         from whatever plane (live runner, frozen replica) they own."""
-        _, _, _, scores = self._delta(
+        _, _, _, scores = self._hybrid.delta(
             self.dense_params, jnp.asarray(emb), jnp.asarray(dense_x),
             jnp.zeros(len(dense_x), jnp.float32))
         return np.asarray(scores)
@@ -400,9 +297,7 @@ class DLRMModel:
 
     def local_rows(self, field: int) -> np.ndarray:
         """Whole-table snapshot of one local-twin field (parity tests)."""
-        if self.mode != "local":
-            raise ValueError("local_rows is the local twin's surface")
-        return np.asarray(self._emb[field])
+        return self.group.local_rows(field)
 
 
 class SnapshotScorer:

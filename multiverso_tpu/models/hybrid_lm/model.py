@@ -9,21 +9,20 @@ held_topk_moe`). Then a final RMSNorm and an untied head; the loss is
 next-token cross-entropy over the vocabulary slice, taken in blocks of
 tokens so that the logits of a step never exist at once.
 
-Trained as DLRM is (models/dlrm/model.py), by the same entry points:
+Trained as DLRM is (models/dlrm/model.py), by the same hybrid step
+(:class:`~multiverso_tpu.parallel.hybrid_step.HybridStep`; docs/DESIGN.md
+"Hybrid step"):
 
 * the **input embedding** is a ``MatrixTable`` with the server-side
-  ``adagrad`` updater on the ``ps`` plane. A step pulls the rows of its
-  DISTINCT token ids and pushes their lr-prescaled deltas through a
-  :class:`~multiverso_tpu.tables.table_group.TableGroup` (a group of one);
-* the **layer stack, final norm and head** are device-resident and take
-  the CommPolicy hybrid step: a non-donated delta program
-  (``value_and_grad``, ``lr * barrier(g)``), ``build_dense_sync`` leaf by
-  leaf, then a donated apply that runs
-  ``AdaGradUpdater.update_dense`` on (weight, accumulator) — the server
-  plane's own arithmetic and its own state layout;
-* ``mode='local'`` drives the identical programs with the embedding in the
-  model's own arrays, updated by the group's program builders over the same
-  ``update_rows``.
+  ``adagrad`` updater on the ``ps`` plane, in a table group of one
+  (tables/table_group.py). A step pulls the rows of its DISTINCT token ids
+  and pushes their lr-prescaled deltas;
+* the **layer stack, final norm and head** are device-resident: the delta
+  program is ``value_and_grad`` with ``lr * barrier(g)`` outputs, the donated
+  apply runs ``AdaGradUpdater.update_dense`` on (weight, accumulator) — the
+  server plane's own arithmetic and its own state layout;
+* ``mode='local'`` is the same model over a ``LocalTableGroup`` of the same
+  table option.
 
 There is NO second resident copy of the dense parameters (DLRM's flattened
 ``dense_table`` would be 2.5 GB on the device and as much on the host
@@ -47,10 +46,10 @@ from multiverso_tpu.models.hybrid_lm.config import (ATTENTION, EXPERTS,
                                                     MAMBA, HybridLMConfig)
 from multiverso_tpu.models.hybrid_lm.mamba2 import mamba2_mixer
 from multiverso_tpu.parallel.expert import held_topk_moe
-from multiverso_tpu.tables.table_group import (build_group_access,
-                                               build_group_update,
-                                               group_scalars)
+from multiverso_tpu.parallel.hybrid_step import HybridStep
+from multiverso_tpu.tables.table_group import LocalTableGroup
 from multiverso_tpu.telemetry import counter, span
+from multiverso_tpu.utils.log import check
 
 __all__ = ["HybridLM", "init_params", "init_buffers", "rmsnorm",
            "forward_hidden", "make_loss", "dense_param_count",
@@ -268,18 +267,14 @@ class HybridLM:
                  dp_axis: Optional[str] = None,
                  params: Optional[dict] = None,
                  buffers: Optional[list] = None):
-        from multiverso_tpu.parallel import comm_policy as cp
-        from multiverso_tpu.utils.log import check
-
         check(mode in ("ps", "local"), f"bad HybridLM mode {mode!r}")
         cfg.validate()
         self.cfg, self.mode = cfg, mode
-        self._cp = cp
         # A caller that brings its own weights (a checkpoint, a benchmark's
         # seeded leaves) spares the host the drawing of these.
         self.params = init_params(cfg) if params is None else params
         self.buffers = init_buffers(cfg) if buffers is None else buffers
-        self._updater = get_updater(np.float32, "adagrad")
+        updater = self._updater = get_updater(np.float32, "adagrad")
         self.state = self.fresh_state()
         lr = cfg.learning_rate
         self._option = AddOption(
@@ -295,8 +290,6 @@ class HybridLM:
             deltas = jax.tree_util.tree_map(lambda g: lr * barrier(g), gp)
             return deltas, lr * barrier(grows), loss, counts
 
-        updater = self._updater
-
         def lm_apply(params, state, deltas, *opt):
             leaves, treedef = jax.tree_util.tree_flatten(params)
             out = [updater.update_dense(w, s, d, opt) for w, s, d in zip(
@@ -305,13 +298,6 @@ class HybridLM:
             return (treedef.unflatten([w for w, _ in out]),
                     treedef.unflatten([s for _, s in out]))
 
-        # Not donated (the DLRM / AllreduceModel discipline): the parameters
-        # outlive the program for the separate donated apply, and keeping
-        # lr * grad an OUTPUT pins its rounding point.
-        self._delta = jax.jit(lm_delta_step)  # graftlint: disable=missing-donation
-        self._apply = jax.jit(lm_apply, donate_argnums=(0, 1))
-        self._dense_sync = cp.build_dense_sync(dp_mesh, dp_axis)
-        self._grad_bytes = dense_param_count(cfg) * 4
         self.steps = 0
         #: Floor of a step's padded row count: a caller that knows its
         #: batches sets it so that every step takes ONE compiled shape.
@@ -322,36 +308,23 @@ class HybridLM:
         # Uniform of the parameters' standard deviation: the table's own
         # random_init draws uniformly.
         bound = cfg.init_std * math.sqrt(3.0)
+        option = MatrixTableOption(
+            num_row=cfg.vocab_size, num_col=cfg.hidden_size,
+            random_init=True, init_low=-bound, init_high=bound,
+            seed=cfg.seed + 101, updater="adagrad",
+            name=cfg.table_name, comm_policy=cfg.comm_policy or "ps")
         if mode == "ps":
-            self.table = mv.create_table(MatrixTableOption(
-                num_row=cfg.vocab_size, num_col=cfg.hidden_size,
-                random_init=True, init_low=-bound, init_high=bound,
-                seed=cfg.seed + 101, updater="adagrad",
-                name=cfg.table_name, comm_policy=cfg.comm_policy or "ps"))
+            self.table = mv.create_table(option)
             self.group = mv.create_table_group([self.table])
-            # Decided once, from where the arrays live: a table spread
-            # over a mesh hands the step host rows.
-            self._rows_on_device = self.group.lives_with(
-                self.params["head"])
         else:
-            self._rows_on_device = True     # the twin's arrays: one device
-            # Bitwise what the table's random_init draws
-            # (tables/matrix_table.py): same rng, bounds, dtype.
-            rng = np.random.default_rng(cfg.seed + 101)
-            self._emb = jnp.asarray(rng.uniform(
-                -bound, bound, size=(cfg.vocab_size, cfg.hidden_size)
-            ).astype(np.float32))
-            self._emb_state = self._updater.init_state(
-                (cfg.vocab_size, cfg.hidden_size), jnp.float32, 1)
-
-            def take(data, ids):
-                return jnp.take(data, ids, axis=0, mode="clip")
-
-            def rows(data, state, ids, delta, *opt):
-                return updater.update_rows(data, state, ids, delta, opt)
-
-            self._group_access = build_group_access([take])
-            self._group_update = build_group_update([rows])
+            self.group = LocalTableGroup([option])
+        self._hybrid = HybridStep(
+            self, lm_delta_step, lm_apply, dense=("params", "state"),
+            group=self.group, pull=self._pull,
+            push=lambda ids, delta: self._push_rows(ids, delta),
+            prefix="lm", grad_bytes=dense_param_count(cfg) * 4,
+            apply_args=self._option.scalars(), dp_mesh=dp_mesh,
+            dp_axis=dp_axis)
 
     def fresh_state(self) -> dict:
         """The dense plane's optimizer state as a new model has it: per
@@ -368,14 +341,8 @@ class HybridLM:
     def _pull(self, ids: np.ndarray, device: bool):
         """[n, hidden] current rows of ``ids``: on the device as the gather
         program left them, or on the host."""
-        ids = np.asarray(ids, np.int32)
-        if self.mode == "ps":
-            pull = self.group.get_rows_device if device \
-                else self.group.get_rows
-            return pull([ids])[0]
-        rows = self._group_access((self._emb,), ids, lengths=(len(ids),),
-                                  blocks=True)[0]
-        return rows if device else np.asarray(rows)
+        pull = self.group.get_rows_device if device else self.group.get_rows
+        return pull([np.asarray(ids, np.int32)])[0]
 
     def pull_rows(self, ids: np.ndarray) -> np.ndarray:
         """[n, hidden] current embedding rows of ``ids``, on the host."""
@@ -386,63 +353,19 @@ class HybridLM:
         device) in one donated update; repeated ids are summed exactly
         before the row math, on both planes. (The name is what the
         benchmark's dropped-push control patches.)"""
-        ids = np.asarray(ids, np.int32)
-        if self.mode == "ps":
-            self.group.add_rows([ids], [delta], self._option)
-            return
-        emb, state, _ = self._group_update(
-            (self._emb,), (self._emb_state,), ids, (delta,),
-            *group_scalars([self._option]), lengths=(len(ids),))
-        self._emb, self._emb_state = emb[0], state[0]
+        self.group.add_rows([np.asarray(ids, np.int32)], [delta],
+                            self._option)
 
     # -- training ----------------------------------------------------------
     def step(self, tokens: np.ndarray) -> float:
         """One batch of packed sequences ``tokens`` [B, S]: pull the rows
         of its distinct ids, run the hybrid step, push the row deltas.
         Returns the loss once the device has finished the step."""
-        cfg = self.cfg
         with span("lm.step", tokens=int(tokens.size)):
             ids, distinct, where, targets, mask = pack_batch(
-                tokens, cfg.row_bucket, self.min_rows)
-            with span("lm.pull", rows=distinct):
-                rows = self._pull(ids, self._rows_on_device)
-            with span("lm.compute"):
-                with span("lm.compute.dispatch"):
-                    if (self._rows_on_device and rows.committed
-                            and not self.params["head"].committed):
-                        # Fresh leaves (init, a checkpoint, a seed) beside
-                        # committed rows: committed too (the same buffers),
-                        # or the step's programs compile once for them and
-                        # again for their own committed outputs.
-                        self.params, self.state = jax.device_put(
-                            (self.params, self.state), next(iter(rows.devices())))
-                    deltas, drows, loss, counts = self._delta(
-                        self.params, rows, self.buffers,
-                        jnp.asarray(where), jnp.asarray(targets),
-                        jnp.asarray(mask))
-                    # The program holds its input: without this name the
-                    # pulled rows go when it ends, not when the step does.
-                    del rows
-                    # Leaf by leaf, the unmerged delta dropped as soon as
-                    # its merge is launched: at most one leaf is held twice.
-                    leaves, treedef = jax.tree_util.tree_flatten(deltas)
-                    del deltas
-                    merged = []
-                    while leaves:
-                        merged.append(self._dense_sync(leaves.pop(0)))
-                    merged = treedef.unflatten(merged)
-                    self.params, self.state = self._apply(
-                        self.params, self.state, merged,
-                        *self._option.scalars())
-                    del merged
-                    self._cp.record(self._cp.ALLREDUCE, self._grad_bytes)
-                with span("lm.compute.sync"):
-                    # The phase ends when the row deltas exist: on the
-                    # device, or copied to the host for a table over a mesh.
-                    drows = jax.block_until_ready(drows) \
-                        if self._rows_on_device else np.asarray(drows)
-            with span("lm.push", rows=distinct):
-                self._push_rows(ids, drows)
+                tokens, self.cfg.row_bucket, self.min_rows)
+            loss, counts = self._hybrid(ids, self.buffers, where, targets,
+                                        mask, rows=distinct)
             loss = float(loss)
             self.last_counts = np.asarray(counts, np.int64)
         self.steps += 1
@@ -472,6 +395,4 @@ class HybridLM:
 
     def local_rows(self) -> np.ndarray:
         """The whole embedding of the local twin (parity tests)."""
-        if self.mode != "local":
-            raise ValueError("local_rows is the local twin's surface")
-        return np.asarray(self._emb)
+        return self.group.local_rows()

@@ -1260,6 +1260,12 @@ class Word2Vec:
             mode = self._dispatch_mode if not sharded else "in_graph"
             W, chunk = self.cfg.window, self.cfg.batch_size
             inflight = _DispatchQueue(self.cfg.dispatch_depth)
+            # On a multi-device CPU mesh every launch is finished before
+            # the next (``ServerStore._finish``, decided by the store):
+            # several such programs in flight starve XLA:CPU's rendezvous
+            # (core/table.py), and pipelined_host would keep
+            # ``dispatch_depth`` of them there. Elsewhere: the identity.
+            finish = st_in._finish
             try:
                 for mat, lens, words in source:
                     # The one timer of a block's host-side dispatch; the
@@ -1278,10 +1284,10 @@ class Word2Vec:
                                 self._neg_table, self._keep_prob, mat,
                                 lens, sub)
                             (st_in.data, st_out.data, st_gin.data,
-                             st_gout.data, loss) = self._grid_step(
+                             st_gout.data, loss) = finish(self._grid_step(
                                 st_in.data, st_out.data, st_gin.data,
                                 st_gout.data, centers2d, contexts2d,
-                                negs, n_pairs, jnp.asarray(lr))
+                                negs, n_pairs, jnp.asarray(lr)))
                             losses.append(loss)
                             pair_counts.append(n_pairs)
                         elif mode == "pipelined_host":
@@ -1298,17 +1304,17 @@ class Word2Vec:
                                       st_gout.data)
                             block_loss = []
                             for i in range(est):
-                                out = self._chunk_step(
+                                out = finish(self._chunk_step(
                                     *tables, centers2d, contexts2d, negs,
-                                    n_pairs, np.int32(i), lr_dev)
+                                    n_pairs, np.int32(i), lr_dev))
                                 tables = out[:4]
                                 block_loss.append(out[4])
                                 # Depth-N backpressure: waits (overlapped)
                                 # only once >depth chunks are in flight.
                                 inflight.push(out[4])
-                            out = self._tail_step(
+                            out = finish(self._tail_step(
                                 *tables, centers2d, contexts2d, negs,
-                                n_pairs, lr_dev, np.int32(est))
+                                n_pairs, lr_dev, np.int32(est)))
                             (st_in.data, st_out.data, st_gin.data,
                              st_gout.data) = out[:4]
                             block_loss.append(out[4])
@@ -1317,10 +1323,11 @@ class Word2Vec:
                             pair_counts.append(n_pairs)
                         else:
                             (st_in.data, st_out.data, st_gin.data,
-                             st_gout.data, loss, pairs) = self._block_step(
-                                st_in.data, st_out.data, st_gin.data,
-                                st_gout.data, self._neg_table,
-                                self._keep_prob, mat, lens, sub, lr)
+                             st_gout.data, loss, pairs) = finish(
+                                self._block_step(
+                                    st_in.data, st_out.data, st_gin.data,
+                                    st_gout.data, self._neg_table,
+                                    self._keep_prob, mat, lens, sub, lr))
                             losses.append(loss)
                             pair_counts.append(pairs)
                     self.trained_words += words

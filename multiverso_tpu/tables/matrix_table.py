@@ -32,21 +32,29 @@ from multiverso_tpu.utils.dashboard import monitor
 from multiverso_tpu.utils.log import check
 
 
+def initial_rows(option: MatrixTableOption) -> Optional[np.ndarray]:
+    """What a table of ``option`` starts from: its uniform ``random_init``
+    draw (ref ``matrix_table.cpp:372-384``), or None for zeros. The ONE
+    draw: a :class:`~multiverso_tpu.tables.table_group.LocalTableGroup`
+    member of the same option starts with the same bytes."""
+    if not option.random_init:
+        return None
+    rng = np.random.default_rng(option.seed)
+    return rng.uniform(option.init_low, option.init_high,
+                       size=(option.num_row, option.num_col)
+                       ).astype(option.dtype)
+
+
 class MatrixTable(WorkerTable):
     def __init__(self, option: MatrixTableOption):
         zoo = Zoo.get()
         check(zoo.started, "call mv.init() before creating tables")
         updater = get_updater(option.dtype, option.updater)
         name = option.name or f"matrix_{len(zoo.tables)}"
-        init = None
-        if option.random_init:
-            rng = np.random.default_rng(option.seed)
-            init = rng.uniform(option.init_low, option.init_high,
-                               size=(option.num_row, option.num_col)
-                               ).astype(option.dtype)
         store = ServerStore(name, (option.num_row, option.num_col),
                             option.dtype, updater, zoo.mesh,
-                            zoo.num_workers(), shard_axis=0, init_array=init,
+                            zoo.num_workers(), shard_axis=0,
+                            init_array=initial_rows(option),
                             use_pallas_rows=option.use_pallas)
         super().__init__(store)
         self.num_row = option.num_row

@@ -29,9 +29,9 @@ width. Nothing is cached across calls: every call reads ``store.data`` /
 ``store.state`` afresh under the stores' locks and writes the new buffers
 back, because checkpoints, publishes and benchmarks swap them.
 
-The two pure program builders (:func:`build_group_access`,
-:func:`build_group_update`) are module-level so a model's local twin can
-drive the identical programs over its own arrays (models/dlrm/model.py).
+A :class:`LocalTableGroup` is the twin without tables: the same calls and
+the identical programs (the two builders below) over arrays the group owns.
+A model holds one kind or the other and asks neither which it is.
 """
 
 from __future__ import annotations
@@ -44,15 +44,17 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from multiverso_tpu.core.options import AddOption, GetOption
+from multiverso_tpu.core.options import (AddOption, GetOption,
+                                         MatrixTableOption)
 from multiverso_tpu.core.table import _CPU_COLLECTIVE_LOCK
-from multiverso_tpu.tables.matrix_table import MatrixTable
+from multiverso_tpu.core.updater import get_updater
+from multiverso_tpu.tables.matrix_table import MatrixTable, initial_rows
 from multiverso_tpu.telemetry import counter, phase
 from multiverso_tpu.utils.dashboard import monitor
 from multiverso_tpu.utils.log import check
 
-__all__ = ["TableGroup", "build_group_access", "build_group_update",
-           "group_scalars"]
+__all__ = ["TableGroup", "LocalTableGroup", "build_group_access",
+           "build_group_update", "group_scalars"]
 
 #: Per-member id counts of a call whose ids arrive as one flat
 #: concatenation; ``None`` is the ``[B, n_tables]`` matrix layout.
@@ -140,7 +142,60 @@ def group_scalars(options: Sequence[AddOption]) -> tuple:
         np.asarray([o.staleness for o in options], np.float32),)
 
 
-class TableGroup:
+class _RowGroup:
+    """The host side of a grouped call, which both kinds of group share:
+    ids and deltas as the programs take them."""
+
+    def __init__(self, widths: Sequence[int], dtype):
+        self.widths = tuple(widths)
+        self.dtype = np.dtype(dtype)
+        self._stackable = len(set(self.widths)) == 1
+
+    def _layout(self, ids) -> Tuple[np.ndarray, Lengths]:
+        """``ids`` as the program takes them: the ``[B, n]`` matrix where
+        the widths agree, else the members' vectors concatenated."""
+        n = len(self.widths)
+        if isinstance(ids, np.ndarray) and ids.ndim == 2:
+            check(ids.shape[1] == n,
+                  f"id matrix has {ids.shape[1]} columns for {n} tables")
+            if self._stackable:
+                return np.asarray(ids, np.int32), None
+            ids = list(ids.T)
+        check(len(ids) == n, f"{len(ids)} id vectors for {n} tables")
+        vectors = [np.asarray(v, np.int32).reshape(-1) for v in ids]
+        return np.concatenate(vectors), tuple(len(v) for v in vectors)
+
+    def _on_device(self, delta) -> bool:
+        return isinstance(delta, jax.Array) and delta.dtype == self.dtype
+
+    def _delta_layout(self, ids, deltas):
+        """``(ids, lengths, deltas, bytes a member, on the device?)`` of an
+        ``add_rows`` call. Device arrays of the group's dtype go to the
+        program as they are (flat layout: a tuple of blocks); anything else
+        is a host array (flat layout: ONE, the blocks raveled and
+        concatenated). Shapes are checked before anything is donated."""
+        ids, lengths = self._layout(ids)
+        n = len(self.widths)
+        if lengths is None:
+            device = self._on_device(deltas)
+            if not device:
+                deltas = np.asarray(deltas, self.dtype)
+            check(deltas.shape == ids.shape + self.widths[:1],
+                  f"row delta shape {deltas.shape} != "
+                  f"{ids.shape + self.widths[:1]}")
+            return ids, lengths, deltas, [deltas.nbytes // n] * n, device
+        device = all(self._on_device(d) for d in deltas)
+        blocks = list(deltas) if device else [
+            np.asarray(d, self.dtype) for d in deltas]
+        want = list(zip(lengths, self.widths))
+        check([b.shape for b in blocks] == want,
+              f"row delta shapes {[b.shape for b in blocks]} != {want}")
+        deltas = tuple(blocks) if device else np.concatenate(
+            [b.reshape(-1) for b in blocks])
+        return ids, lengths, deltas, [b.nbytes for b in blocks], device
+
+
+class TableGroup(_RowGroup):
     """Row get/add for a list of :class:`MatrixTable`\\ s in one launch and
     one copy each way, or no copy where the caller's rows stay on the
     device (``get_rows_device``, device deltas). Columns of a call follow
@@ -174,9 +229,7 @@ class TableGroup:
         self._stores = [t.store for t in self.tables]
         self._by_id = sorted(self.tables, key=lambda t: t.table_id)
         self._serial_exec = first._serial_exec
-        self.dtype = first.dtype
-        self.widths = tuple(t.num_col for t in self.tables)
-        self._stackable = len(set(self.widths)) == 1
+        super().__init__([t.num_col for t in self.tables], first.dtype)
         self._access = build_group_access(
             [s.access_rows_fn for s in self._stores])
         self._update = build_group_update(
@@ -210,21 +263,6 @@ class TableGroup:
             t.comm.record_client_op(nbytes[i], ms if i == 0 else None)
         counter("table.group.calls").inc()
         counter("table.group.member_ops").inc(len(self.tables))
-
-    # -- host-side layout ---------------------------------------------------
-    def _layout(self, ids) -> Tuple[np.ndarray, Lengths]:
-        """``ids`` as the program takes them: the ``[B, n]`` matrix where
-        the widths agree, else the members' vectors concatenated."""
-        n = len(self.tables)
-        if isinstance(ids, np.ndarray) and ids.ndim == 2:
-            check(ids.shape[1] == n,
-                  f"id matrix has {ids.shape[1]} columns for {n} tables")
-            if self._stackable:
-                return np.asarray(ids, np.int32), None
-            ids = list(ids.T)
-        check(len(ids) == n, f"{len(ids)} id vectors for {n} tables")
-        vectors = [np.asarray(v, np.int32).reshape(-1) for v in ids]
-        return np.concatenate(vectors), tuple(len(v) for v in vectors)
 
     # -- row ops -------------------------------------------------------------
     def lives_with(self, array: jax.Array) -> bool:
@@ -287,9 +325,6 @@ class TableGroup:
                 counter("table.group.device_pulls").inc()
         return out if lengths is None else list(out)
 
-    def _on_device(self, delta) -> bool:
-        return isinstance(delta, jax.Array) and delta.dtype == self.dtype
-
     def add_rows(self, ids, deltas,
                  option: Optional[AddOption] = None) -> None:
         """Apply every member's row deltas through its updater; returns
@@ -300,27 +335,8 @@ class TableGroup:
         from the host."""
         with monitor("WORKER_TABLE_SYNC_ADD"):
             with phase("table.add_rows.dispatch"):
-                ids, lengths = self._layout(ids)
-                if lengths is None:
-                    device = self._on_device(deltas)
-                    if not device:
-                        deltas = np.asarray(deltas, self.dtype)
-                    check(deltas.shape == ids.shape + self.widths[:1],
-                          f"row delta shape {deltas.shape} != "
-                          f"{ids.shape + self.widths[:1]}")
-                    nbytes = [deltas.nbytes // len(self.tables)] * \
-                        len(self.tables)
-                else:
-                    device = all(self._on_device(d) for d in deltas)
-                    blocks = list(deltas) if device else [
-                        np.asarray(d, self.dtype) for d in deltas]
-                    want = list(zip(lengths, self.widths))
-                    check([b.shape for b in blocks] == want,
-                          f"row delta shapes {[b.shape for b in blocks]} "
-                          f"!= {want}")
-                    nbytes = [b.nbytes for b in blocks]
-                    deltas = tuple(blocks) if device else np.concatenate(
-                        [b.reshape(-1) for b in blocks])
+                ids, lengths, deltas, nbytes, device = self._delta_layout(
+                    ids, deltas)
                 t0 = time.perf_counter()
                 with contextlib.ExitStack() as gates:
                     opts = {t.table_id: gates.enter_context(
@@ -341,3 +357,61 @@ class TableGroup:
                     counter("table.group.device_pushes").inc()
             with phase("table.add_rows.sync"):
                 jax.block_until_ready(done)
+
+
+class LocalTableGroup(_RowGroup):
+    """The twin of a :class:`TableGroup` without tables: the calls a model
+    makes of a group, over arrays the group owns on the default device, one
+    per ``MatrixTableOption``. A member starts from its option's own draw
+    (``initial_rows``) and its updater's ``init_state``, and the programs
+    are the two builders over the row functions a one-device store on the
+    XLA row plane hands its group (``core/table._build_kernels``): what a
+    ``TableGroup`` of same-option tables holds and runs, to the bit. No
+    ``mv.init``, locks, gates, monitors or ``comm.ps.*`` counters: one
+    worker, one thread."""
+
+    def __init__(self, options: Sequence[MatrixTableOption]):
+        options = list(options)
+        check(len(options) > 0, "a table group needs a member")
+        super().__init__([o.num_col for o in options], options[0].dtype)
+        updaters = [get_updater(o.dtype, o.updater) for o in options]
+        shapes = [(o.num_row, o.num_col) for o in options]
+        self._datas = tuple(
+            jnp.asarray(initial_rows(o)) if o.random_init
+            else jnp.zeros(shape, self.dtype)
+            for o, shape in zip(options, shapes))
+        self._states = tuple(u.init_state(shape, self.dtype, 1)
+                             for u, shape in zip(updaters, shapes))
+
+        def take(data, ids):
+            return jnp.take(data, ids, axis=0, mode="clip")
+
+        def rows_of(updater):
+            def rows(data, state, ids, delta, *opt):
+                return updater.update_rows(data, state, ids, delta, opt)
+            return rows
+
+        self._access = build_group_access([take] * len(options))
+        self._update = build_group_update([rows_of(u) for u in updaters])
+
+    def lives_with(self, array: jax.Array) -> bool:
+        return self._datas[0].devices() == array.devices()
+
+    def get_rows_device(self, ids):
+        ids, lengths = self._layout(ids)
+        out = self._access(self._datas, ids, lengths=lengths,
+                           blocks=lengths is not None)
+        return out if lengths is None else list(out)
+
+    def get_rows(self, ids):
+        return jax.device_get(self.get_rows_device(ids))
+
+    def add_rows(self, ids, deltas, option: AddOption) -> None:
+        ids, lengths, deltas, _, _ = self._delta_layout(ids, deltas)
+        self._datas, self._states, _ = self._update(
+            self._datas, self._states, ids, deltas,
+            *group_scalars([option] * len(self.widths)), lengths=lengths)
+
+    def local_rows(self, member: int = 0) -> np.ndarray:
+        """Whole-table snapshot of one member (parity tests)."""
+        return np.asarray(self._datas[member])

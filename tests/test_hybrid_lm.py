@@ -344,8 +344,8 @@ def test_ps_plane_matches_local_twin_bitwise(table_devices, monkeypatch):
         issubclass(t, jax.Array) == table_devices for t in pushed)
     # the first step compiles what every later one runs (as the twin, whose
     # arrays are all uncommitted: one program a padded shape)
-    assert ps._delta._cache_size() == local._delta._cache_size()
-    assert ps._apply._cache_size() == local._apply._cache_size()
+    assert ps._hybrid.delta._cache_size() == local._hybrid.delta._cache_size()
+    assert ps._hybrid.apply._cache_size() == local._hybrid.apply._cache_size()
     monkeypatch.undo()
     ids = np.unique(batches[0])
     np.testing.assert_array_equal(ps.pull_rows(ids), local.pull_rows(ids))
@@ -383,13 +383,13 @@ def test_step_spans_counters_and_program_names():
     for row, layer in zip(model.last_counts, (1, 3)):
         name = f"lm.moe.assignments_held.l{layer}"
         assert reg.counter(name).value - c0[name] == row.sum()
-    assert model._delta.__name__ == DELTA_PROGRAM
+    assert model._hybrid.delta.__name__ == DELTA_PROGRAM
     ids, _, where, targets, mask = pack_batch(tokens, cfg.row_bucket)
-    text = model._delta.lower(
+    text = model._hybrid.delta.lower(
         model.params, jnp.zeros((len(ids), cfg.hidden_size)), model.buffers,
         where, targets, mask).as_text()
     assert "module @jit_lm_delta_step" in text
-    assert model._apply.__name__ == "lm_apply"
+    assert model._hybrid.apply.__name__ == "lm_apply"
 
 
 def test_benchmark_configuration_keeps_every_published_width():

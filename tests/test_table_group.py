@@ -388,8 +388,8 @@ def test_a_dlrm_step_is_two_grouped_calls_and_copies_no_table(
         assert all(issubclass(t, jax.Array) == one for t in pushed)
         # the first step compiles what every later one runs: the fresh
         # dense leaves are placed beside the (committed) pulled rows
-        assert model._delta._cache_size() == 1
-        assert model._apply._cache_size() == 1
+        assert model._hybrid.delta._cache_size() == 1
+        assert model._hybrid.apply._cache_size() == 1
         assert all(t.store._access._cache_size() == 0 for t in model.tables)
         # the per-table add DOES run one, which is what the group leaves out
         model.tables[0].add_rows([1], np.zeros((1, 8), np.float32),
@@ -397,6 +397,79 @@ def test_a_dlrm_step_is_two_grouped_calls_and_copies_no_table(
         assert model.tables[0].store._access._cache_size() == 1
     finally:
         mv.shutdown()
+
+
+# -- the twin without tables (ISSUE 28) ------------------------------------
+@pytest.fixture(params=["mesh_of_8", "one_device"])
+def table_devices(request):
+    mv.init([], devices=jax.devices()[:1] if request.param == "one_device"
+            else None)
+    yield request.param
+    mv.shutdown()
+
+
+def _twin_and_tables(shapes, updater):
+    """A ``LocalTableGroup`` and a ``TableGroup`` of tables made from the
+    SAME options."""
+    from multiverso_tpu.tables.table_group import LocalTableGroup
+    options = [MatrixTableOption(
+        num_row=r, num_col=c, random_init=i != 1, seed=11 + i,
+        init_low=-0.25, init_high=0.75, updater=updater, name=f"twin{i}")
+        for i, (r, c) in enumerate(shapes)]
+    tables = [mv.create_table(o) for o in options]
+    return LocalTableGroup(options), mv.create_table_group(tables), tables
+
+
+@pytest.mark.parametrize("kind", sorted(SHAPES))
+def test_local_group_starts_and_pulls_as_the_tables_do(table_devices, kind):
+    """The initial draw (one member zeros: no ``random_init``) and both
+    forms of the pull, bit for bit, in the matrix and the flat layout."""
+    shapes = SHAPES[kind]
+    local, group, tables = _twin_and_tables(shapes, "adagrad")
+    for i, t in enumerate(tables):
+        assert np.array_equal(local.local_rows(i), t.get())
+    assert not local.local_rows(1).any() and local.local_rows(0).any()
+    ids = _ids(shapes, kind, np.random.default_rng(3))
+    for form in FORMS:
+        pull = "get_rows" if form == "host" else "get_rows_device"
+        got, want = getattr(local, pull)(ids), getattr(group, pull)(ids)
+        if kind == "equal":
+            got, want = [got], [want]
+        assert len(got) == len(want)
+        for g, w in zip(got, want):
+            assert isinstance(g, jax.Array if form == "device"
+                              else np.ndarray)
+            assert g.dtype == w.dtype and g.shape == w.shape
+            assert np.array_equal(g, w)
+    assert local.lives_with(jnp.zeros(1))
+
+
+@pytest.mark.parametrize("form", FORMS)
+@pytest.mark.parametrize("kind", sorted(SHAPES))
+@pytest.mark.parametrize("updater", ["adagrad", "momentum_sgd"])
+def test_local_group_adds_as_the_table_group_does(table_devices, updater,
+                                                  kind, form):
+    """Data AND state after three pushes with duplicate ids in every batch,
+    the deltas from the host or from the device."""
+    shapes = SHAPES[kind]
+    local, group, tables = _twin_and_tables(shapes, updater)
+    rng = np.random.default_rng(4)
+    for _ in range(3):
+        ids = _ids(shapes, kind, rng)
+        blocks = [rng.normal(size=(len(col), c)).astype(np.float32)
+                  for col, (_, c) in zip(_columns(ids, len(shapes)), shapes)]
+        local.add_rows(ids, _deltas(blocks, kind, form), OPTION)
+        group.add_rows(ids, _deltas(blocks, kind, form), OPTION)
+    for i, t in enumerate(tables):
+        assert np.array_equal(local.local_rows(i), t.get())
+        state = _state_np(t)
+        assert sorted(state) == sorted(local._states[i])
+        for k, v in state.items():
+            assert np.array_equal(local._states[i][k], v), (i, k)
+    ids = _ids(shapes, kind, rng)        # and a pull sees the pushes
+    got, want = local.get_rows(ids), group.get_rows(ids)
+    for g, w in zip(*(([got], [want]) if kind == "equal" else (got, want))):
+        assert np.array_equal(g, w)
 
 
 @pytest.fixture
