@@ -90,7 +90,7 @@ class MatrixTableOption(TableOption):
     init_low: float = -0.5
     init_high: float = 0.5
     seed: int = 0
-    use_pallas: bool = False        # opt-in Pallas row data plane
+    use_pallas: bool = False        # opt-in Pallas row gather/scatter-add
 
     def __init__(self, num_row: int, num_col: int, dtype: Any = np.float32,
                  is_sparse: bool = False, is_pipeline: bool = False,

@@ -35,7 +35,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from multiverso_tpu.core.options import AddOption, GetOption
-from multiverso_tpu.core.updater import Updater, pallas_row_capability
+from multiverso_tpu.core.updater import (Updater, combine_duplicate_rows,
+                                         pallas_row_capability)
 from multiverso_tpu.parallel import mesh as mesh_lib
 from multiverso_tpu.telemetry import gauge, span
 from multiverso_tpu.utils.configure import get_flag
@@ -70,6 +71,60 @@ def _physical_bytes(arr: jax.Array) -> int:
     shard = arr.sharding.shard_shape(arr.shape)
     return (int(np.prod(shard, dtype=np.int64)) * np.dtype(arr.dtype).itemsize
             * len(arr.sharding.device_set))
+
+
+def pallas_rows_eligible(shape: Tuple[int, ...], dtype: Any,
+                         one_shard: bool) -> bool:
+    """Whether the row kernels of ``ops/pallas_rows.py`` can serve a table
+    at all: 2-D float32 with EXACTLY one 128-lane tile of columns, on one
+    shard. Measured grounds: Mosaic packs 2-byte types two rows per
+    sublane in HBM ((8,128)(2,1) tiling), so the kernels' single-row DMA
+    slices fail to compile on real chips for bf16; column counts that are
+    not a multiple of the lane tile fail alike (the reference's 1M x 50
+    matrix, v5e, PR 21); and so does every WIDER multiple (256 .. 2,688:
+    "Slice shape along dimension 0 must be aligned to tiling (8), but is
+    1", compiled for the v5e, PR 29): only at 128 columns is the
+    (8,128)-tiled table row-major, one row one contiguous slice.
+    Multi-shard stays XLA: the row kernels would need per-shard offset
+    remapping under shard_map, and XLA's sharded scatter already overlaps
+    the collective with the update."""
+    return (len(shape) == 2 and np.dtype(dtype) == np.dtype(np.float32)
+            and shape[1] == 128 and one_shard)
+
+
+def fused_rows_selected(updater: Updater, shape: Tuple[int, ...], dtype: Any,
+                        one_shard: bool, state_sharded: bool) -> bool:
+    """Whether a table's stateful row UPDATE runs as the fused Pallas
+    gather-update-scatter kernel. Chosen from what the table shows, by no
+    option: where the kernel applies (``pallas_rows_eligible``, an updater
+    of the ``fused_stateful`` capability, state the kernel can own whole
+    rows of) it walks the live prefix of the folded ids only and keeps a
+    step's row DMAs in flight together, 2.6 against XLA's 9.9 ms for 26
+    tables of 262,144 x 128 at 2,048 Zipf ids each (PERF.md 6, PR 29)."""
+    return (pallas_rows_eligible(shape, dtype, one_shard)
+            and not state_sharded
+            and pallas_row_capability(updater) == "fused_stateful")
+
+
+def build_row_update(updater: Updater, fused: bool,
+                     interpret: bool = False) -> Callable:
+    """The un-jitted row update ``(data, state, row_ids, delta, *opt) ->
+    (data, state)`` of a stateful-or-not updater on the XLA plane, or
+    (``fused``) as the Pallas kernel: the same duplicate folding (stateful
+    set-semantics must combine, not accumulate), then ONE fused
+    gather-update-scatter dispatch over data + every state leaf."""
+    if not fused:
+        def rows(data, state, row_ids, delta, *opt):
+            return updater.update_rows(data, state, row_ids, delta, opt)
+        return rows
+    from multiverso_tpu.ops.pallas_rows import fused_stateful_rows
+
+    def fused_rows(data, state, row_ids, delta, *opt):
+        ids, totals = combine_duplicate_rows(
+            row_ids, delta.astype(data.dtype), data.shape[0])
+        return fused_stateful_rows(data, state, ids, totals, opt, updater,
+                                   interpret=interpret)
+    return fused_rows
 
 
 class ServerStore:
@@ -167,30 +222,22 @@ class ServerStore:
             with span("table.device_put", table=name, leaf=key):
                 self.state[key] = jax.device_put(leaf, leaf_sharding)
 
-        # Opt-in Pallas row data plane (DMA gather / sorted scatter-add /
-        # fused stateful gather-update-scatter, ops/pallas_rows.py),
-        # selected through the per-updater capability registry
-        # (core/updater.PALLAS_ROW_CAPABILITY). Eligibility: 2-D float32
-        # tables, single shard, unsharded state (the fused kernel owns
-        # whole rows). bf16 is EXCLUDED on measured grounds: Mosaic packs
-        # 2-byte types two rows per sublane in HBM ((8,128)(2,1) tiling),
-        # so the kernels' single-row DMA slices fail to compile on real
-        # chips ("Slice shape along dimension 0 must be aligned to
-        # tiling"). So are column counts that are not a multiple of the
-        # 128-lane tile (the reference's 1M x 50 matrix: "Slice shape
-        # along dimension 1 must be aligned to tiling (128), but is 50",
-        # v5e, PR 21). Multi-shard stays XLA: the row kernels would need
-        # per-shard offset remapping under shard_map, and XLA's sharded
-        # scatter already overlaps the collective with the update.
+        # Pallas row data plane (ops/pallas_rows.py), through the
+        # per-updater capability registry
+        # (core/updater.PALLAS_ROW_CAPABILITY). The fused stateful update
+        # is the store's own choice (fused_rows_selected); the DMA gather
+        # and the stateless sorted scatter-add have not beaten XLA and
+        # stay behind the option.
         self._pallas_cap = None
-        if (use_pallas_rows and len(self.padded_shape) == 2
-                and np.dtype(self.dtype) == np.dtype(np.float32)
-                and self.padded_shape[1] % 128 == 0
-                and num_servers == 1):
+        if fused_rows_selected(updater, self.padded_shape, self.dtype,
+                               num_servers == 1, self.state_sharded):
+            self._pallas_cap = "fused_stateful"
+        elif use_pallas_rows and pallas_rows_eligible(
+                self.padded_shape, self.dtype, num_servers == 1):
             cap = pallas_row_capability(updater)
-            if cap in ("scatter_add", "scatter_sub") or (
-                    cap == "fused_stateful" and not self.state_sharded):
+            if cap in ("scatter_add", "scatter_sub"):
                 self._pallas_cap = cap
+        self._pallas_gather = use_pallas_rows and self._pallas_cap is not None
         self._pallas_rows = self._pallas_cap is not None
         self._build_kernels()
         self._lock = make_lock("core.store")
@@ -208,9 +255,11 @@ class ServerStore:
 
     @property
     def row_plane(self) -> str:
-        """Which data plane serves this store's row ops: ``"xla"``, or the
-        Pallas capability selected at construction (``"scatter_add"``,
-        ``"scatter_sub"``, ``"fused_stateful"``)."""
+        """Which data plane serves this store's row UPDATES: ``"xla"``, or
+        the Pallas capability selected at construction
+        (``"fused_stateful"`` from what the store shows; ``"scatter_add"``,
+        ``"scatter_sub"`` by the option, which also moves the row gather
+        to its DMA kernel)."""
         return self._pallas_cap or "xla"
 
     @contextlib.contextmanager
@@ -302,10 +351,6 @@ class ServerStore:
                 return _pin(new_data, new_state)
             return _pin(*updater.update_dense(data, state, delta, opt))
 
-        def rows(data, state, row_ids, delta, *opt):
-            return _pin(*updater.update_rows(data, state, row_ids, delta,
-                                             opt))
-
         def access(data):
             if pad:
                 index = [slice(None)] * ndim
@@ -313,52 +358,41 @@ class ServerStore:
                 return data[tuple(index)]
             return data
 
-        def access_rows(data, row_ids):
-            return jnp.take(data, row_ids, axis=axis, mode="clip")
-
-        self._dense_update = jax.jit(dense, donate_argnums=(0, 1))
+        interpret = False
         if self._pallas_rows:
             from multiverso_tpu.ops import pallas_interpret
-            from multiverso_tpu.ops.pallas_rows import (fused_stateful_rows,
-                                                        gather_rows,
-                                                        scatter_add_rows)
-
             interpret = pallas_interpret(self.sharding.device_set)
+        if self._pallas_cap in ("scatter_add", "scatter_sub"):
+            from multiverso_tpu.ops.pallas_rows import scatter_add_rows
 
-            if self._pallas_cap == "fused_stateful":
-                from multiverso_tpu.core.updater import combine_duplicate_rows
+            # SGD applies data -= delta (client pre-scales lr).
+            sign = -1.0 if self._pallas_cap == "scatter_sub" else 1.0
 
-                def pallas_rows_update(data, state, row_ids, delta, *opt):
-                    # Same duplicate folding as the XLA path (stateful
-                    # set-semantics must combine, not accumulate), then
-                    # ONE fused gather-update-scatter dispatch over data
-                    # + every state leaf.
-                    rows_eff, delta_c = combine_duplicate_rows(
-                        row_ids, delta.astype(data.dtype), data.shape[0])
-                    return fused_stateful_rows(data, state, rows_eff,
-                                               delta_c, opt, updater,
-                                               interpret=interpret)
-            else:
-                # SGD applies data -= delta (client pre-scales lr).
-                sign = -1.0 if self._pallas_cap == "scatter_sub" else 1.0
-
-                def pallas_rows_update(data, state, row_ids, delta, *opt):
-                    del opt
-                    return (scatter_add_rows(data, row_ids,
-                                             delta.astype(data.dtype),
-                                             interpret=interpret,
-                                             sign=sign),
-                            state)
-
-            def pallas_access_rows(data, row_ids):
-                return gather_rows(data, row_ids, interpret=interpret)
-
-            rows, access_rows = pallas_rows_update, pallas_access_rows
-            self._row_update = jax.jit(rows, donate_argnums=(0, 1))
-            self._access_rows = access_rows  # inner fns already jit
+            def update(data, state, row_ids, delta, *opt):
+                del opt
+                return (scatter_add_rows(data, row_ids,
+                                         delta.astype(data.dtype),
+                                         interpret=interpret, sign=sign),
+                        state)
         else:
-            self._row_update = jax.jit(rows, donate_argnums=(0, 1))
+            update = build_row_update(
+                updater, self._pallas_cap == "fused_stateful", interpret)
+
+        def rows(data, state, row_ids, delta, *opt):
+            return _pin(*update(data, state, row_ids, delta, *opt))
+
+        if self._pallas_gather:
+            from multiverso_tpu.ops.pallas_rows import gather_rows
+
+            def access_rows(data, row_ids):
+                return gather_rows(data, row_ids, interpret=interpret)
+            self._access_rows = access_rows  # the inner fn is already jit
+        else:
+            def access_rows(data, row_ids):
+                return jnp.take(data, row_ids, axis=axis, mode="clip")
             self._access_rows = jax.jit(access_rows)
+        self._dense_update = jax.jit(dense, donate_argnums=(0, 1))
+        self._row_update = jax.jit(rows, donate_argnums=(0, 1))
         self._access = jax.jit(access)
         # The un-jitted row functions of this store's row plane: a
         # TableGroup (tables/table_group.py) traces them into its one

@@ -124,30 +124,48 @@ def _opt_staleness(opt: Scalars):
 
 def combine_duplicate_rows(rows: jax.Array, delta: jax.Array, num_rows: int
                            ) -> Tuple[jax.Array, jax.Array]:
-    """Fold duplicate row ids into one combined delta per id.
+    """Fold duplicate row ids into one total per id, compacted to the front.
 
     Stateful updaters gather-compute-set; a ``.at[rows].set`` with duplicate
     ids is last-write-wins, which would drop all but one duplicate's state
     contribution (the reference's sequential per-element loop accumulates,
-    ``src/updater/updater.cpp:22-29``). Shape-stable under jit: sort by id,
-    segment-sum the run, give the run-start position the run total, and remap
-    every other duplicate to the out-of-bounds sentinel ``num_rows`` so
-    ``mode="drop"`` writes discard it.
+    ``src/updater/updater.cpp:22-29``). Shape-stable under jit: sort by id
+    and segment-sum each run in that order.
 
-    Returns ``(rows_eff, delta_combined)`` in sorted order; both same shapes
-    as the inputs.
+    Returns ``(ids, totals)``, both the inputs' shapes, and this contract
+    (pinned by ``tests/test_updater_rows.py``; the fused row kernel,
+    ``ops/pallas_rows.fused_stateful_rows``, skips every group of lanes
+    behind the live prefix and writes its lanes unordered on the strength
+    of it):
+
+    * ``ids`` ascend STRICTLY, so every id is there once;
+    * the first ``n_unique`` are the distinct in-range ids of ``rows``
+      (``np.unique``), and ``totals[s]`` is the sum of the deltas of
+      ``ids[s]``;
+    * every later id is ``>= num_rows`` (``mode="drop"`` writes discard it)
+      and its total is zero.
+
+    Ids outside ``[0, num_rows)`` are dropped with their deltas (their one
+    total sits in the tail, where nothing is written).
     """
-    if rows.shape[0] == 0:   # static shape: empty add is a no-op
+    n = rows.shape[0]
+    if n == 0:   # static shape: empty add is a no-op
         return rows, delta
+    # Out-of-range ids become ONE run behind every live id, so the live
+    # segments stay the first segments.
+    rows = jnp.where((rows < 0) | (rows >= num_rows), num_rows, rows)
     order = jnp.argsort(rows)
     r = jnp.take(rows, order)
     d = jnp.take(delta, order, axis=0)
     is_start = jnp.concatenate([jnp.ones((1,), bool), r[1:] != r[:-1]])
+    live = is_start & (r < num_rows)
     seg = jnp.cumsum(is_start) - 1
-    totals = jax.ops.segment_sum(d, seg, num_segments=r.shape[0])
-    d_comb = jnp.take(totals, seg, axis=0)
-    r_eff = jnp.where(is_start, r, num_rows)
-    return r_eff, d_comb
+    totals = jax.ops.segment_sum(d, seg, num_segments=n)
+    # Segment s IS the s-th smallest distinct id: sorting the run starts to
+    # the front lines them up with their totals. Every other lane gets an
+    # out-of-range id of its own (its position), ascending as well.
+    ids = jnp.sort(jnp.where(live, r, num_rows + jnp.arange(n, dtype=r.dtype)))
+    return ids, totals
 
 
 class Updater:
@@ -192,35 +210,51 @@ class Updater:
                   ) -> Tuple[jax.Array, State]:
         raise NotImplementedError(f"{self.name} has no row-block math")
 
-    def _rows_update_via_math(self, data, state, rows, delta, opt):
-        """Gather touched rows of data AND state, apply :meth:`rows_math`,
-        scatter both back (``mode="drop"`` discards the duplicate-run
-        sentinels ``combine_duplicate_rows`` emits). ``data.at[r].set(
-        d_rows - step)`` is bitwise-identical to the historical
-        ``data.at[r].add(-step)`` (IEEE: a - b == a + (-b)); the gather
-        makes the data rows available to the shared math, which is what
-        lets the Pallas kernel run the exact same function."""
-        wid = opt[0]
-        rows, delta = combine_duplicate_rows(rows, delta, data.shape[0])
-        d_rows = jnp.take(data, rows, axis=0, mode="clip")
+    def gather_row_blocks(self, data, state, rows, delta, wid):
+        """Fold ``rows`` (:func:`combine_duplicate_rows`) and gather the
+        touched rows of data and of every state leaf: ``(ids, totals,
+        data rows, state rows)``. Lanes past the live prefix read the last
+        row (``mode="clip"``); :meth:`scatter_row_blocks` drops them."""
+        ids, totals = combine_duplicate_rows(rows, delta, data.shape[0])
+        d_rows = jnp.take(data, ids, axis=0, mode="clip")
         st_rows: State = {}
         for key, leaf in state.items():
             src = leaf[wid] if key in self.per_worker_state else leaf
-            st_rows[key] = jnp.take(src, rows, axis=0, mode="clip")
+            st_rows[key] = jnp.take(src, ids, axis=0, mode="clip")
+        return ids, totals, d_rows, st_rows
+
+    def scatter_row_blocks(self, data, state, ids, wid, new_d, new_st):
+        """Write updated row blocks back at ``ids`` as
+        :func:`combine_duplicate_rows` returns them: ``mode="drop"``
+        discards the out-of-range tail. The ids are sorted and unique, and
+        the writes do NOT say so: on the v5e ``indices_are_sorted=True``
+        takes XLA's scatter of 2,048 rows into a 134 MB table from 0.145
+        to 0.407 ms and ``unique_indices=True`` moves nothing (PERF.md 6,
+        PR 29)."""
+        out_state: State = {}
+        for key, leaf in state.items():
+            at = (leaf.at[wid, ids] if key in self.per_worker_state
+                  else leaf.at[ids])
+            out_state[key] = at.set(new_st[key], mode="drop")
+        return data.at[ids].set(new_d, mode="drop"), out_state
+
+    def _rows_update_via_math(self, data, state, rows, delta, opt):
+        """:meth:`gather_row_blocks`, :meth:`rows_math` on the blocks,
+        :meth:`scatter_row_blocks`. ``data.at[r].set(d_rows - step)`` is
+        bitwise-identical to the historical ``data.at[r].add(-step)``
+        (IEEE: a - b == a + (-b)); the gather makes the data rows
+        available to the shared math, which is what lets the Pallas
+        kernel run the exact same function."""
+        wid = opt[0]
+        ids, totals, d_rows, st_rows = self.gather_row_blocks(
+            data, state, rows, delta, wid)
         # exact_elementwise: on XLA:CPU the math rounds strictly per
         # primitive so this plane and the fused Pallas kernel agree
         # bitwise (see _strict_rows_math); accelerators keep the fully
         # fused math. worker_id >= 0 is the runtime-true guard.
         new_d, new_st = exact_elementwise(self.rows_math)(
-            wid >= 0, d_rows, st_rows, delta, opt)
-        out_state: State = {}
-        for key, leaf in state.items():
-            if key in self.per_worker_state:
-                out_state[key] = leaf.at[wid, rows].set(new_st[key],
-                                                        mode="drop")
-            else:
-                out_state[key] = leaf.at[rows].set(new_st[key], mode="drop")
-        return data.at[rows].set(new_d, mode="drop"), out_state
+            wid >= 0, d_rows, st_rows, totals, opt)
+        return self.scatter_row_blocks(data, state, ids, wid, new_d, new_st)
 
 
 class SGDUpdater(Updater):
@@ -459,7 +493,9 @@ _REGISTRY: Dict[str, Callable[[], Updater]] = {
 }
 
 # Per-updater Pallas row-plane capability (docs/DESIGN.md "Sharded updater
-# state"): how an opt-in ``use_pallas`` table's row updates lower.
+# state"): how the row updates of a table the row kernels can serve lower
+# (``core/table.pallas_rows_eligible``): the fused kernel by the table's
+# own choice, the stateless ones behind ``use_pallas``.
 #   "scatter_add"/"scatter_sub" — the stateless sorted-run scatter kernel
 #       (ops/pallas_rows.scatter_add_rows, sign +/-1);
 #   "fused_stateful"            — the fused gather-update-scatter kernel

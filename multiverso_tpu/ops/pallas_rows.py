@@ -213,13 +213,23 @@ def scatter_add_rows(table: jax.Array, ids: jax.Array, deltas: jax.Array,
 #
 # Caller contract (core/table.py builds this inside the store's jitted
 # ``pallas_rows_update``): ids come from ``combine_duplicate_rows`` — every
-# id UNIQUE, duplicate lanes remapped to the out-of-bounds sentinel
-# ``num_rows``. Sentinel lanes clamp their load address (matching the XLA
-# path's ``mode="clip"`` gathers) and skip write-back entirely (the XLA
-# ``mode="drop"`` scatters), so no ordering hazards exist between lanes or
-# grid steps and the grid needs no run folding. Bitwise parity with the
+# live id UNIQUE and ascending in a prefix, its run total beside it, and
+# every other lane a sentinel: any id ``>= num_rows`` (the fold's are each
+# there once; the padding below repeats ``num_rows``). Sentinel
+# lanes clamp their load address (matching the XLA path's ``mode="clip"``
+# gathers) and skip write-back entirely (the XLA ``mode="drop"``
+# scatters), so no ordering hazards exist between lanes or grid steps and
+# the grid needs no run folding. Bitwise parity with the
 # XLA path is STRUCTURAL: both planes execute the same ``rows_math``
 # function on identical row blocks.
+
+
+# Rows a grid step of the fused kernel: more rows in flight a step hide
+# more of a row DMA's latency (26 tables of dlrm_train's shape: 4.1 ms at
+# 8 rows, 3.6 at 16, 3.4 at 32 and at 64; PERF.md 6, PR 29). A multiple of
+# the sublane tile; at the one width Mosaic compiles (128 columns) a
+# step's blocks are 64 KB.
+_FUSED_GROUP_ROWS = 32
 
 
 def _make_fused_kernel(group: int, state_keys, per_worker, rows_math,
@@ -239,58 +249,65 @@ def _make_fused_kernel(group: int, state_keys, per_worker, rows_math,
         wid = meta_ref[0]
         num_rows = meta_ref[1]
 
-        def _row_copies(k):
-            """The group's row DMAs (load direction): lane k's data row +
-            each state leaf's row, sentinel ids clamped like mode='clip'."""
-            sid = jnp.minimum(ids_ref[base + k], num_rows - 1)
-            copies = [pltpu.make_async_copy(table_ref.at[sid], drows.at[k],
-                                            sems.at[0, k])]
-            for j in range(n_state):
-                src = (st_refs[j].at[wid, sid] if per_worker[j]
-                       else st_refs[j].at[sid])
-                copies.append(pltpu.make_async_copy(src, srows[j].at[k],
-                                                    sems.at[1 + j, k]))
-            return copies
-
-        for k in range(group):
-            for c in _row_copies(k):
-                c.start()
-        for k in range(group):
-            for c in _row_copies(k):
-                c.wait()
-
-        opt = (wid, opts_ref[0], opts_ref[1], opts_ref[2], opts_ref[3],
-               opts_ref[4])
-        st_rows = {key: srows[j][:] for j, key in enumerate(state_keys)}
-        # exact_elementwise: identical strict-IEEE rounding as the XLA
-        # plane on CPU interpret runs (pass-through on real chips).
-        # wid >= 0 is the runtime-true guard it needs.
-        from multiverso_tpu.core.updater import exact_elementwise
-        new_d, new_st = exact_elementwise(rows_math)(
-            wid >= 0, drows[:], st_rows, delta_ref[:], opt)
-        drows[:] = new_d.astype(row_dtype)
-        for j, key in enumerate(state_keys):
-            srows[j][:] = new_st[key]
-
-        # Write back valid lanes only (sentinel = dropped duplicate run
-        # position or padding; ids are unique so lanes never collide).
-        for k in range(group):
+        def _row_copies(k, load):
+            """Lane k's row DMAs, data row + each state leaf's row: into
+            VMEM (``load``; a sentinel id clamped like mode='clip') or
+            back to HBM."""
             rid = ids_ref[base + k]
+            if load:
+                rid = jnp.minimum(rid, num_rows - 1)
+            ends = [(table_ref.at[rid], drows.at[k], sems.at[0, k])]
+            for j in range(n_state):
+                hbm = (st_refs[j].at[wid, rid] if per_worker[j]
+                       else st_refs[j].at[rid])
+                ends.append((hbm, srows[j].at[k], sems.at[1 + j, k]))
+            return [pltpu.make_async_copy(*((h, v) if load else (v, h)), sem)
+                    for h, v, sem in ends]
 
-            @pl.when(rid < num_rows)
-            def _(k=k, rid=rid):
-                copies = [pltpu.make_async_copy(drows.at[k],
-                                                table_ref.at[rid],
-                                                sems.at[0, k])]
-                for j in range(n_state):
-                    dst = (st_refs[j].at[wid, rid] if per_worker[j]
-                           else st_refs[j].at[rid])
-                    copies.append(pltpu.make_async_copy(srows[j].at[k], dst,
-                                                        sems.at[1 + j, k]))
-                for c in copies:
-                    c.start()
-                for c in copies:
-                    c.wait()
+        def _lanes(act, load):
+            """``act`` (start | wait) every lane's row DMAs; on the way
+            back to HBM the live lanes' only (sentinel = dropped duplicate
+            run position or padding). A loop, not an unrolled body (which
+            runs a fifth faster): the kernel stays small, and so do the
+            seconds Mosaic takes over it for EACH table of a group (26
+            tables: 3 s against 17, PERF.md 6, PR 29), which every
+            start-up pays."""
+            def body(k, carry):
+                def go():
+                    for c in _row_copies(k, load):
+                        getattr(c, act)()
+                if load:
+                    go()
+                else:
+                    pl.when(ids_ref[base + k] < num_rows)(go)
+                return carry
+            jax.lax.fori_loop(0, group, body, 0)
+
+        # The ids ascend, so a group whose FIRST id is a sentinel holds
+        # nothing else: the whole tail behind the live prefix costs a grid
+        # step each and no DMA.
+        @pl.when(ids_ref[base] < num_rows)
+        def _():
+            _lanes("start", True)
+            _lanes("wait", True)
+
+            opt = (wid, opts_ref[0], opts_ref[1], opts_ref[2], opts_ref[3],
+                   opts_ref[4])
+            st_rows = {key: srows[j][:] for j, key in enumerate(state_keys)}
+            # exact_elementwise: identical strict-IEEE rounding as the XLA
+            # plane on CPU interpret runs (pass-through on real chips).
+            # wid >= 0 is the runtime-true guard it needs.
+            from multiverso_tpu.core.updater import exact_elementwise
+            new_d, new_st = exact_elementwise(rows_math)(
+                wid >= 0, drows[:], st_rows, delta_ref[:], opt)
+            drows[:] = new_d.astype(row_dtype)
+            for j, key in enumerate(state_keys):
+                srows[j][:] = new_st[key]
+
+            # Ids are unique, so lanes never collide: every write is in
+            # flight before the first is awaited.
+            _lanes("start", False)
+            _lanes("wait", False)
     return _kernel
 
 
@@ -301,16 +318,17 @@ def fused_stateful_rows(table: jax.Array, state: dict, ids: jax.Array,
 
     ``ids``/``deltas`` must already be duplicate-combined
     (:func:`multiverso_tpu.core.updater.combine_duplicate_rows`): unique
-    ids, duplicates folded, dropped lanes remapped to ``table.shape[0]``.
+    ASCENDING ids, duplicates folded, every dropped lane an id
+    ``>= table.shape[0]`` behind the live ones.
     Returns ``(new_table, new_state)`` with every buffer aliased in place.
     Trace this inside a donating jit (the store's ``_row_update``).
     """
-    group = group_for_dtype(table.dtype)
     num_rows, d = table.shape
     state_keys = sorted(state)
     if not state_keys:
         raise ValueError("fused_stateful_rows needs at least one state "
                          "leaf; stateless updaters use scatter_add_rows")
+    group = _FUSED_GROUP_ROWS
     per_worker = [k in updater.per_worker_state for k in state_keys]
     n = ids.shape[0]
     if n == 0:

@@ -46,8 +46,10 @@ import numpy as np
 
 from multiverso_tpu.core.options import (AddOption, GetOption,
                                          MatrixTableOption)
-from multiverso_tpu.core.table import _CPU_COLLECTIVE_LOCK
+from multiverso_tpu.core.table import (_CPU_COLLECTIVE_LOCK, build_row_update,
+                                       fused_rows_selected)
 from multiverso_tpu.core.updater import get_updater
+from multiverso_tpu.ops import pallas_interpret
 from multiverso_tpu.tables.matrix_table import MatrixTable, initial_rows
 from multiverso_tpu.telemetry import counter, phase
 from multiverso_tpu.utils.dashboard import monitor
@@ -234,6 +236,7 @@ class TableGroup(_RowGroup):
             [s.access_rows_fn for s in self._stores])
         self._update = build_group_update(
             [s.row_update_fn for s in self._stores])
+        self._update_planes = sorted({s.row_plane for s in self._stores})
 
     # -- the donation discipline of ServerStore, for every member ----------
     @contextlib.contextmanager
@@ -355,6 +358,10 @@ class TableGroup(_RowGroup):
                 self._record(nbytes, (time.perf_counter() - t0) * 1e3)
                 if device:
                     counter("table.group.device_pushes").inc()
+                for plane in self._update_planes:
+                    # One of four names (ServerStore.row_plane).
+                    # graftlint: disable=unbounded-metric-name
+                    counter(f"table.rows.plane.{plane}").inc()
             with phase("table.add_rows.sync"):
                 jax.block_until_ready(done)
 
@@ -364,9 +371,10 @@ class LocalTableGroup(_RowGroup):
     makes of a group, over arrays the group owns on the default device, one
     per ``MatrixTableOption``. A member starts from its option's own draw
     (``initial_rows``) and its updater's ``init_state``, and the programs
-    are the two builders over the row functions a one-device store on the
-    XLA row plane hands its group (``core/table._build_kernels``): what a
-    ``TableGroup`` of same-option tables holds and runs, to the bit. No
+    are the two builders over the row functions a one-device store hands
+    its group, on the row plane such a store picks
+    (``core/table.fused_rows_selected``): what a ``TableGroup`` of
+    same-option tables holds and runs, to the bit. No
     ``mv.init``, locks, gates, monitors or ``comm.ps.*`` counters: one
     worker, one thread."""
 
@@ -386,13 +394,12 @@ class LocalTableGroup(_RowGroup):
         def take(data, ids):
             return jnp.take(data, ids, axis=0, mode="clip")
 
-        def rows_of(updater):
-            def rows(data, state, ids, delta, *opt):
-                return updater.update_rows(data, state, ids, delta, opt)
-            return rows
-
+        interpret = pallas_interpret(self._datas[0].devices())
         self._access = build_group_access([take] * len(options))
-        self._update = build_group_update([rows_of(u) for u in updaters])
+        self._update = build_group_update([
+            build_row_update(u, fused_rows_selected(
+                u, shape, self.dtype, True, False), interpret)
+            for u, shape in zip(updaters, shapes)])
 
     def lives_with(self, array: jax.Array) -> bool:
         return self._datas[0].devices() == array.devices()

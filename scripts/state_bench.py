@@ -124,38 +124,19 @@ def _unfused_chain(store):
     """The naive three-dispatch stateful row update: separate jitted
     gather, math, and scatter executables over the SAME shared rows_math
     — what the fused path collapses into one donated dispatch."""
-    import functools
-
     import jax
-    import jax.numpy as jnp
 
-    from multiverso_tpu.core.updater import combine_duplicate_rows
     upd = store.updater
-    pw = upd.per_worker_state
 
-    @jax.jit
-    def gather(data, state, rows, delta, wid):
-        rows, delta = combine_duplicate_rows(rows, delta, data.shape[0])
-        d_rows = jnp.take(data, rows, axis=0, mode="clip")
-        st_rows = {k: jnp.take(leaf[wid] if k in pw else leaf, rows,
-                               axis=0, mode="clip")
-                   for k, leaf in state.items()}
-        return rows, delta, d_rows, st_rows
+    # The stages are the updater's own (core/updater.py): the fold's id
+    # contract and the write-back's hints live there, not here.
+    gather = jax.jit(upd.gather_row_blocks)
 
     @jax.jit
     def math(d_rows, st_rows, delta, *opt):
         return upd.rows_math(d_rows, st_rows, delta, opt)
 
-    @functools.partial(jax.jit, donate_argnums=(0, 1))
-    def scatter(data, state, rows, wid, new_d, new_st):
-        out_state = {}
-        for k, leaf in state.items():
-            if k in pw:
-                out_state[k] = leaf.at[wid, rows].set(new_st[k],
-                                                      mode="drop")
-            else:
-                out_state[k] = leaf.at[rows].set(new_st[k], mode="drop")
-        return data.at[rows].set(new_d, mode="drop"), out_state
+    scatter = jax.jit(upd.scatter_row_blocks, donate_argnums=(0, 1))
 
     def step(rows, delta, opt):
         wid = opt[0]

@@ -14,6 +14,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from multiverso_tpu.core.options import AddOption
+from multiverso_tpu.core.table import build_row_update, fused_rows_selected
 from multiverso_tpu.core.updater import get_updater
 from multiverso_tpu.models.hybrid_lm import (HybridLMConfig, layer_forward,
                                              param_shapes)
@@ -93,9 +94,12 @@ def test_device_form_group_programs_compile_for_v5e(one_chip, tables, rows,
         (spec((ids, width)),) * tables
     access = build_group_access(
         [lambda data, i: jnp.take(data, i, axis=0, mode="clip")] * tables)
-    update = build_group_update(
-        [lambda data, st, i, d, *opt: updater.update_rows(data, st, i, d,
-                                                          opt)] * tables)
+    # Each on the row plane a one-chip store of its shape picks: the fused
+    # Pallas kernel at 128 columns (DLRM), XLA at 2,688 (the LM).
+    fused = fused_rows_selected(updater, (rows, width), np.float32, True,
+                                False)
+    assert fused == (width == 128)
+    update = build_group_update([build_row_update(updater, fused)] * tables)
     pulled = access.lower(datas, id_spec, lengths=lengths,
                           blocks=not matrix).compile()
     assert pulled.memory_analysis().output_size_in_bytes >= \

@@ -1,5 +1,7 @@
 """Pallas row-op kernels vs numpy references (interpret mode on CPU)."""
 
+import contextlib
+
 import numpy as np
 import pytest
 
@@ -265,15 +267,32 @@ def test_tiled_scatter_sgd_sign_and_eligibility():
 # vs the XLA update path — both planes run the updater's shared rows_math,
 # so equality here proves the kernel's gather/scatter plumbing.
 # ---------------------------------------------------------------------------
+@contextlib.contextmanager
+def _on_the_xla_plane():
+    """Tables made inside keep their row updates on the XLA plane: a
+    one-shard 128-column stateful table picks the fused kernel by itself
+    (core/table.fused_rows_selected, ISSUE 29), and no option says
+    otherwise, so the reference is steered here, in the test."""
+    from multiverso_tpu.core import table as table_mod
+    chosen = table_mod.fused_rows_selected
+    table_mod.fused_rows_selected = lambda *a, **k: False
+    try:
+        yield
+    finally:
+        table_mod.fused_rows_selected = chosen
+
+
 @pytest.mark.parametrize("updater", ["momentum_sgd", "adagrad", "ftrl"])
 def test_fused_stateful_bitwise_vs_xla(updater):
     import multiverso_tpu as mv
 
     mv.init([], devices=jax.devices()[:1])
     try:
-        t_xla = mv.create_table(mv.MatrixTableOption(33, 128,
-                                                     updater=updater,
-                                                     name="fx"))
+        with _on_the_xla_plane():
+            t_xla = mv.create_table(mv.MatrixTableOption(33, 128,
+                                                         updater=updater,
+                                                         name="fx"))
+        assert t_xla.store.row_plane == "xla"
         t_pal = mv.create_table(mv.MatrixTableOption(33, 128,
                                                      updater=updater,
                                                      name="fp",
@@ -305,9 +324,11 @@ def test_fused_stateful_duplicates_and_empty():
 
     mv.init([], devices=jax.devices()[:1])
     try:
-        t_xla = mv.create_table(mv.MatrixTableOption(8, 128,
-                                                     updater="adagrad",
-                                                     name="dx"))
+        with _on_the_xla_plane():
+            t_xla = mv.create_table(mv.MatrixTableOption(8, 128,
+                                                         updater="adagrad",
+                                                         name="dx"))
+        assert t_xla.store.row_plane == "xla"
         t_pal = mv.create_table(mv.MatrixTableOption(8, 128,
                                                      updater="adagrad",
                                                      name="dp",
@@ -334,9 +355,11 @@ def test_fused_stateful_per_worker_state_indexing():
 
     mv.init([], num_local_workers=2, devices=jax.devices()[:1])
     try:
-        t_xla = mv.create_table(mv.MatrixTableOption(16, 128,
-                                                     updater="adagrad",
-                                                     name="wx"))
+        with _on_the_xla_plane():
+            t_xla = mv.create_table(mv.MatrixTableOption(16, 128,
+                                                         updater="adagrad",
+                                                         name="wx"))
+        assert t_xla.store.row_plane == "xla"
         t_pal = mv.create_table(mv.MatrixTableOption(16, 128,
                                                      updater="adagrad",
                                                      name="wp",
