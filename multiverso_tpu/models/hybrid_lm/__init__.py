@@ -2,8 +2,10 @@
 layer from a pattern) trained through the parameter-server plane: see
 docs/HYBRID_LM.md."""
 
-from multiverso_tpu.models.hybrid_lm.config import (ATTENTION, EXPERTS,
-                                                    MAMBA, HybridLMConfig)
+from multiverso_tpu.models.hybrid_lm import rope
+from multiverso_tpu.models.hybrid_lm.config import (ATTENTION, DENSE,
+                                                    EXPERTS, LATENT, MAMBA,
+                                                    HybridLMConfig)
 from multiverso_tpu.models.hybrid_lm.model import (APPLY_PROGRAM,
                                                    DELTA_PROGRAM, HybridLM,
                                                    dense_param_count,
@@ -14,6 +16,7 @@ from multiverso_tpu.models.hybrid_lm.model import (APPLY_PROGRAM,
                                                    rmsnorm)
 
 __all__ = ["HybridLMConfig", "HybridLM", "MAMBA", "EXPERTS", "ATTENTION",
+           "LATENT", "DENSE",
            "DELTA_PROGRAM", "APPLY_PROGRAM", "dense_param_count",
            "forward_hidden", "init_buffers", "init_params", "layer_forward",
-           "make_loss", "pack_batch", "param_shapes", "rmsnorm"]
+           "make_loss", "pack_batch", "param_shapes", "rmsnorm", "rope"]

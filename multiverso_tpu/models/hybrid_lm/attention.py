@@ -1,4 +1,4 @@
-"""Causal grouped-query attention, forward and backward in blocks.
+"""Causal grouped-query and latent attention, forward and backward in blocks.
 
 Neither pass ever holds the ``heads x S x S`` scores: the forward keeps a
 running maximum, normaliser and output per query block while it walks the
@@ -8,6 +8,9 @@ pairs again, recomputing each block's probabilities from the saved
 log-normaliser. Blocks above the diagonal are never visited.
 ``ops/pallas_attention.flash_block_attn`` has no backward; this is plain
 ``jax.numpy`` under ``jax.custom_vjp``, which XLA compiles for the device.
+The values may be narrower or wider than the queries and keys, and the
+caller may give the softmax scale: latent attention (:func:`latent_attention_
+mixer`) has 192-wide rotary-carrying keys against 128-wide values.
 """
 
 from __future__ import annotations
@@ -17,7 +20,10 @@ import functools
 import jax
 import jax.numpy as jnp
 
-__all__ = ["causal_gqa", "attention_mixer"]
+from multiverso_tpu.models.hybrid_lm import rope
+from multiverso_tpu.models.hybrid_lm.norm import rmsnorm
+
+__all__ = ["causal_gqa", "attention_mixer", "latent_attention_mixer"]
 
 
 def _scores(qi, kj, i, j, blk, scale):
@@ -34,9 +40,11 @@ def _block(x, i):
 
 
 def _forward(q, k, v, scale, blk):
-    """``q`` [B, nb, blk, K, G, D], ``k``/``v`` [B, nb, blk, K, D] ->
-    (out like q, lse [nb, B, K, G, blk])."""
-    bsz, nb, _, kh, g, d = q.shape
+    """``q`` [B, nb, blk, K, G, D], ``k`` [B, nb, blk, K, D], ``v``
+    [B, nb, blk, K, Dv] -> (out [B, nb, blk, K, G, Dv], lse [nb, B, K, G,
+    blk])."""
+    bsz, nb, _, kh, g, _ = q.shape
+    d = v.shape[-1]
 
     def query_block(i):
         qi = _block(q, i)
@@ -109,12 +117,13 @@ def _vjp_bwd(scale, blk, saved, dout):
 _blocked_attention.defvjp(_vjp_fwd, _vjp_bwd)
 
 
-def causal_gqa(q: jax.Array, k: jax.Array, v: jax.Array,
-               block: int) -> jax.Array:
+def causal_gqa(q: jax.Array, k: jax.Array, v: jax.Array, block: int,
+               scale: float = None) -> jax.Array:
     """``q`` [B, S, K, G, D] (G query heads share each of K key-value
-    heads), ``k``/``v`` [B, S, K, D] -> [B, S, K, G, D]; softmax over the
-    keys at or before each query, scale ``D ** -0.5``. Any S: padded keys
-    lie after every real query, padded queries are cut away."""
+    heads), ``k`` [B, S, K, D], ``v`` [B, S, K, Dv] -> [B, S, K, G, Dv];
+    softmax over the keys at or before each query, scale ``D ** -0.5``
+    unless given. Any S: padded keys lie after every real query, padded
+    queries are cut away."""
     bsz, s, kh, g, d = q.shape
     blk = min(block, s)
     pad = (-s) % blk
@@ -124,8 +133,9 @@ def causal_gqa(q: jax.Array, k: jax.Array, v: jax.Array,
     nb = (s + pad) // blk
     out = _blocked_attention(
         q.reshape(bsz, nb, blk, kh, g, d), k.reshape(bsz, nb, blk, kh, d),
-        v.reshape(bsz, nb, blk, kh, d), float(d) ** -0.5, blk)
-    return out.reshape(bsz, nb * blk, kh, g, d)[:, :s]
+        v.reshape(bsz, nb, blk, kh, v.shape[-1]),
+        float(d) ** -0.5 if scale is None else float(scale), blk)
+    return out.reshape(bsz, nb * blk, kh, g, v.shape[-1])[:, :s]
 
 
 def attention_mixer(p: dict, n: jax.Array, cfg) -> jax.Array:
@@ -139,3 +149,28 @@ def attention_mixer(p: dict, n: jax.Array, cfg) -> jax.Array:
     v = (n @ p["wv"]).reshape(bsz, s, kh, cfg.head_dim)
     o = causal_gqa(q, k, v, cfg.attn_block)
     return o.reshape(bsz, s, cfg.q_dim) @ p["wo"]
+
+
+def latent_attention_mixer(p: dict, n: jax.Array, cfg) -> jax.Array:
+    """Multi-head latent attention in its training form: keys and values
+    are expanded from a ``kv_lora_rank``-wide latent (RMSNorm'd), queries
+    come straight from the input (no query latent). A head's query and key
+    are ``[nope | rope]``; the rotary key is ONE vector a token, shared by
+    all heads, and positions count from the start of the packed sequence.
+    The softmax scale carries YaRN's ``mscale ** 2`` (:mod:`.rope`)."""
+    bsz, s, _ = n.shape
+    h, nope, rot = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
+                    cfg.qk_rope_head_dim)
+    cos, sin = rope.rope_tables(s, rot, cfg.rope_theta, cfg.rope_scaling)
+    q = (n @ p["wq"]).reshape(bsz, s, h, nope + rot)
+    q = jnp.concatenate(
+        [q[..., :nope], rope.apply_rope(q[..., nope:], cos, sin)], axis=-1)
+    kva = n @ p["wkva"]
+    latent = rmsnorm(kva[..., :cfg.kv_lora_rank], p["kv_norm"], cfg.norm_eps)
+    k_rot = rope.apply_rope(kva[..., None, cfg.kv_lora_rank:], cos, sin)
+    kv = (latent @ p["wkvb"]).reshape(bsz, s, h, nope + cfg.v_head_dim)
+    k = jnp.concatenate(
+        [kv[..., :nope], jnp.broadcast_to(k_rot, (bsz, s, h, rot))], axis=-1)
+    o = causal_gqa(q[:, :, :, None, :], k, kv[..., nope:], cfg.attn_block,
+                   rope.softmax_scale(nope + rot, cfg.rope_scaling))
+    return o.reshape(bsz, s, h * cfg.v_head_dim) @ p["wo"]

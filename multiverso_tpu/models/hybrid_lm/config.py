@@ -5,21 +5,30 @@ from __future__ import annotations
 
 import dataclasses
 import json
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, Optional, Tuple
 
-__all__ = ["HybridLMConfig", "MAMBA", "EXPERTS", "ATTENTION"]
+__all__ = ["HybridLMConfig", "MAMBA", "EXPERTS", "ATTENTION", "LATENT",
+           "DENSE"]
 
-MAMBA, EXPERTS, ATTENTION = "M", "E", "*"
+MAMBA, EXPERTS, ATTENTION, LATENT, DENSE = "M", "E", "*", "L", "D"
 
-#: Keys a file gives under the published ``config.json``'s own names.
-_PUBLISHED_KEYS = (
-    "hidden_size", "vocab_size", "mamba_num_heads", "mamba_head_dim",
-    "ssm_state_size", "n_groups", "conv_kernel", "chunk_size",
-    "time_step_min", "time_step_max", "time_step_floor",
-    "num_attention_heads", "num_key_value_heads", "head_dim",
-    "num_experts_per_tok", "moe_intermediate_size",
-    "moe_shared_expert_intermediate_size", "routed_scaling_factor",
-    "norm_topk_prob")
+#: Keys a file gives under the published ``config.json``'s own names: those
+#: every file has, those a kind of block needs (a file has the keys of the
+#: blocks its pattern runs), and those with a default.
+_ALWAYS_KEYS = ("hidden_size", "vocab_size")
+_KEYS_OF_KIND = {
+    MAMBA: ("mamba_num_heads", "mamba_head_dim", "ssm_state_size", "n_groups",
+            "conv_kernel", "chunk_size", "time_step_min", "time_step_max",
+            "time_step_floor"),
+    ATTENTION: ("num_attention_heads", "num_key_value_heads", "head_dim"),
+    LATENT: ("num_attention_heads", "kv_lora_rank", "qk_nope_head_dim",
+             "qk_rope_head_dim", "v_head_dim", "rope_theta"),
+    DENSE: ("intermediate_size",),
+    EXPERTS: ("num_experts_per_tok", "moe_intermediate_size",
+              "routed_scaling_factor", "norm_topk_prob"),
+}
+_OPTIONAL_KEYS = ("moe_shared_expert_intermediate_size", "scoring_func",
+                  "hidden_act", "aux_loss_alpha")
 #: Keys of this repo, optional in a file.
 _OWN_KEYS = ("learning_rate", "adagrad_step", "init_std", "attn_block",
              "moe_block", "loss_block", "row_bucket", "comm_policy")
@@ -27,12 +36,15 @@ _OWN_KEYS = ("learning_rate", "adagrad_step", "init_std", "attn_block",
 
 @dataclasses.dataclass
 class HybridLMConfig:
-    """Shape + optimizer of one chip's share of a hybrid state-space /
-    expert / attention LM. Widths are the published ones; ``pattern``,
-    ``held`` and ``vocab_size`` say what of the model lives here."""
+    """Shape + optimizer of one chip's share of a layer-typed LM. Widths are
+    the published ones; ``pattern``, ``held`` and ``vocab_size`` say what of
+    the model lives here."""
     hidden_size: int = 64
     vocab_size: int = 64
-    #: One mixer per layer: ``M`` Mamba-2, ``E`` expert layer, ``*`` attention.
+    #: One pre-norm residual block per letter: ``M`` Mamba-2, ``*`` grouped-
+    #: query attention, ``L`` latent attention, ``D`` a dense gated
+    #: feed-forward, ``E`` an expert block. A layer of two blocks (attention,
+    #: then a feed-forward) is two letters.
     pattern: str = "MEM*E"
     norm_eps: float = 1e-5
     # -- Mamba-2 ------------------------------------------------------------
@@ -49,16 +61,34 @@ class HybridLMConfig:
     num_attention_heads: int = 4
     num_key_value_heads: int = 2
     head_dim: int = 16
-    # -- expert layer ---------------------------------------------------------
+    # -- latent attention: keys and values expanded from one latent -----------
+    kv_lora_rank: int = 32
+    qk_nope_head_dim: int = 16
+    qk_rope_head_dim: int = 8
+    v_head_dim: int = 16
+    rope_theta: float = 10000.0
+    #: The published ``rope_scaling`` group (``type: yarn``), or None.
+    rope_scaling: Optional[Dict[str, Any]] = None
+    # -- dense feed-forward ---------------------------------------------------
+    intermediate_size: int = 128
+    # -- expert block ---------------------------------------------------------
     #: Width of the router: every expert of the model, held here or not.
     router_experts: int = 8
     #: The experts this chip holds (ids among ``router_experts``).
     held: Tuple[int, ...] = (0, 1)
     num_experts_per_tok: int = 2
     moe_intermediate_size: int = 32
+    #: Width of the shared experts together (in a file that gives none:
+    #: ``n_shared_experts * moe_intermediate_size``).
     moe_shared_expert_intermediate_size: int = 64
     routed_scaling_factor: float = 2.5
     norm_topk_prob: bool = True
+    #: ``sigmoid`` (with a selection bias) or ``softmax`` (without).
+    scoring_func: str = "sigmoid"
+    #: ``relu2``: two matrices an expert; ``silu``: three, ``silu(gate) * up``.
+    hidden_act: str = "relu2"
+    #: Weight of the sequence-wise balance loss of every expert block.
+    aux_loss_alpha: float = 0.0
     # -- optimizer: the server plane's AdaGrad on every parameter -------------
     #: Client-side prescale of every pushed delta (the PSModel contract:
     #: the server reconstructs ``grad = delta / learning_rate``).
@@ -104,13 +134,30 @@ class HybridLMConfig:
     def kv_dim(self) -> int:
         return self.num_key_value_heads * self.head_dim
 
+    @property
+    def qk_head_dim(self) -> int:
+        """A latent-attention head's query / key width: ``[nope | rope]``."""
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def gated_experts(self) -> bool:
+        return self.hidden_act == "silu"
+
+    @property
+    def balanced(self) -> bool:
+        """Whether the loss carries the expert blocks' balance term."""
+        return self.aux_loss_alpha > 0 and EXPERTS in self.pattern
+
     def expert_layers(self) -> Tuple[int, ...]:
         return tuple(i for i, k in enumerate(self.pattern) if k == EXPERTS)
 
+    def attention_blocks(self) -> int:
+        return sum(k in (ATTENTION, LATENT) for k in self.pattern)
+
     def validate(self) -> None:
         from multiverso_tpu.utils.log import check
-        check(set(self.pattern) <= {MAMBA, EXPERTS, ATTENTION} and
-              self.pattern, f"bad layer pattern {self.pattern!r}")
+        check(set(self.pattern) <= {MAMBA, EXPERTS, ATTENTION, LATENT, DENSE}
+              and self.pattern, f"bad layer pattern {self.pattern!r}")
         check(self.mamba_num_heads % self.n_groups == 0,
               "mamba heads must divide into n_groups")
         check(self.d_inner % self.n_groups == 0, "d_inner % n_groups")
@@ -121,14 +168,26 @@ class HybridLMConfig:
             f"held experts {self.held} not among {self.router_experts}")
         check(self.num_experts_per_tok <= self.router_experts,
               "more experts a token than experts")
+        check(self.scoring_func in ("sigmoid", "softmax"),
+              f"unknown scoring_func {self.scoring_func!r}")
+        check(self.hidden_act in ("relu2", "silu"),
+              f"unknown hidden_act {self.hidden_act!r}")
+        check(self.qk_rope_head_dim % 2 == 0, "rotary width must be even")
+        check(self.rope_scaling is None
+              or self.rope_scaling.get("type") == "yarn",
+              f"unknown rope_scaling {self.rope_scaling!r}")
 
     # -- files ------------------------------------------------------------
     @classmethod
     def from_dict(cls, d: Dict[str, Any], **overrides) -> "HybridLMConfig":
         """From the published keys. ``n_routed_experts`` counts the experts
         HELD (``held_experts`` names them, default the first ones) and
-        ``published.n_routed_experts`` the router's width; the pattern is
-        the first ``num_hidden_layers`` of ``hybrid_override_pattern``."""
+        ``published.n_routed_experts`` the router's width. The pattern is
+        the first ``num_hidden_layers`` of ``hybrid_override_pattern``
+        where the file has one; else every layer is two blocks, attention
+        (latent where the file has a ``kv_lora_rank``) and a feed-forward:
+        experts from layer ``first_k_dense_replace`` on at every
+        ``moe_layer_freq``-th layer, dense before and between."""
         published = d.get("published", {})
         n_held = int(d["n_routed_experts"])
         router = int(published.get("n_routed_experts", n_held))
@@ -136,10 +195,35 @@ class HybridLMConfig:
         if len(held) != n_held:
             raise ValueError(f"held_experts names {len(held)} experts, "
                              f"n_routed_experts says {n_held}")
-        kw = {key: d[key] for key in _PUBLISHED_KEYS}
+        if "hybrid_override_pattern" in d:
+            pattern = d["hybrid_override_pattern"][:d["num_hidden_layers"]]
+        else:
+            if d.get("q_lora_rank") is not None:
+                raise ValueError("a query latent (q_lora_rank) is not "
+                                 "implemented")
+            if d.get("aux_loss_alpha", 0) and not d.get("seq_aux", True):
+                raise ValueError("only the sequence-wise balance loss "
+                                 "(seq_aux) is implemented")
+            first, freq = d.get("first_k_dense_replace", 0), \
+                d.get("moe_layer_freq", 1)
+            pattern = "".join(
+                (LATENT if "kv_lora_rank" in d else ATTENTION)
+                + (EXPERTS if i >= first and i % freq == 0 else DENSE)
+                for i in range(d["num_hidden_layers"]))
+        needed = _ALWAYS_KEYS + tuple(
+            key for kind in sorted(set(pattern))
+            for key in _KEYS_OF_KIND.get(kind, ()))
+        kw = {key: d[key] for key in needed}
+        kw.update({key: d[key] for key in _OPTIONAL_KEYS if key in d})
+        kw.setdefault("moe_shared_expert_intermediate_size",
+                      d.get("n_shared_experts", 0)
+                      * d.get("moe_intermediate_size", 0))
+        kw.setdefault("hidden_act", d.get("mlp_hidden_act", "relu2"))
         kw.update(
-            pattern=d["hybrid_override_pattern"][:d["num_hidden_layers"]],
-            norm_eps=d.get("layer_norm_epsilon", d.get("norm_eps", 1e-5)),
+            pattern=pattern,
+            norm_eps=d.get("layer_norm_epsilon", d.get(
+                "rms_norm_eps", d.get("norm_eps", 1e-5))),
+            rope_scaling=d.get("rope_scaling"),
             router_experts=router, held=held, source=d.get("source", ""),
             reduced=tuple(d.get("reduced", ())),
             assumed=dict(d.get("assumed", {})))

@@ -1,13 +1,17 @@
 """A layer-typed LM trained through the parameter-server plane.
 
-Every layer is ``u <- u + mixer(RMSNorm_w(u))`` with the mixer chosen per
-layer from a pattern: ``M`` a Mamba-2 state-space mixer
-(:mod:`.mamba2`), ``*`` causal grouped-query attention without positions
-(:mod:`.attention`), ``E`` this chip's share of a sigmoid top-k expert
-layer with a shared expert (:func:`~multiverso_tpu.parallel.expert.
-held_topk_moe`). Then a final RMSNorm and an untied head; the loss is
-next-token cross-entropy over the vocabulary slice, taken in blocks of
-tokens so that the logits of a step never exist at once.
+Every block is ``u <- u + mixer(RMSNorm_w(u))`` with the mixer chosen per
+block from a pattern: ``M`` a Mamba-2 state-space mixer
+(:mod:`.mamba2`), ``*`` causal grouped-query attention without positions,
+``L`` latent attention with a decoupled rotary key (:mod:`.attention`,
+:mod:`.rope`), ``D`` a dense gated feed-forward, ``E`` this chip's share
+of a top-k expert layer with shared experts (:func:`~multiverso_tpu.
+parallel.expert.held_topk_moe`: sigmoid or softmax router, ``relu2`` or
+gated experts, by the configuration's published keys). A layer of two
+blocks is two letters. Then a final RMSNorm and an untied head; the loss
+is next-token cross-entropy over the vocabulary slice, taken in blocks of
+tokens so that the logits of a step never exist at once, plus the expert
+blocks' sequence-wise balance loss where the configuration weighs one.
 
 Trained as DLRM is (models/dlrm/model.py), by the same hybrid step
 (:class:`~multiverso_tpu.parallel.hybrid_step.HybridStep`; docs/DESIGN.md
@@ -41,14 +45,17 @@ import numpy as np
 import multiverso_tpu as mv
 from multiverso_tpu.core.options import AddOption, MatrixTableOption
 from multiverso_tpu.core.updater import get_updater
-from multiverso_tpu.models.hybrid_lm.attention import attention_mixer
-from multiverso_tpu.models.hybrid_lm.config import (ATTENTION, EXPERTS,
-                                                    MAMBA, HybridLMConfig)
+from multiverso_tpu.models.hybrid_lm.attention import (
+    attention_mixer, latent_attention_mixer)
+from multiverso_tpu.models.hybrid_lm.config import (ATTENTION, DENSE,
+                                                    EXPERTS, LATENT, MAMBA,
+                                                    HybridLMConfig)
 from multiverso_tpu.models.hybrid_lm.mamba2 import mamba2_mixer
+from multiverso_tpu.models.hybrid_lm.norm import rmsnorm
 from multiverso_tpu.parallel.expert import held_topk_moe
 from multiverso_tpu.parallel.hybrid_step import HybridStep
 from multiverso_tpu.tables.table_group import LocalTableGroup
-from multiverso_tpu.telemetry import counter, span
+from multiverso_tpu.telemetry import counter, gauge, span
 from multiverso_tpu.utils.log import check
 
 __all__ = ["HybridLM", "init_params", "init_buffers", "rmsnorm",
@@ -74,11 +81,24 @@ def _layer_shapes(cfg: HybridLMConfig, kind: str) -> Dict[str, tuple]:
     if kind == ATTENTION:
         return {"norm": (d,), "wq": (d, cfg.q_dim), "wk": (d, cfg.kv_dim),
                 "wv": (d, cfg.kv_dim), "wo": (cfg.q_dim, d)}
+    if kind == LATENT:
+        h, r = cfg.num_attention_heads, cfg.kv_lora_rank
+        return {"norm": (d,), "wq": (d, h * cfg.qk_head_dim),
+                "wkva": (d, r + cfg.qk_rope_head_dim), "kv_norm": (r,),
+                "wkvb": (r, h * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
+                "wo": (h * cfg.v_head_dim, d)}
+    if kind == DENSE:
+        f = cfg.intermediate_size
+        return {"norm": (d,), "ffn_gate": (d, f), "ffn_up": (d, f),
+                "ffn_down": (f, d)}
     f, fs, e = (cfg.moe_intermediate_size,
                 cfg.moe_shared_expert_intermediate_size, len(cfg.held))
-    return {"norm": (d,), "router": (d, cfg.router_experts),
-            "w_up": (e, d, f), "w_down": (e, f, d), "s_up": (d, fs),
-            "s_down": (fs, d)}
+    shapes = {"norm": (d,), "router": (d, cfg.router_experts),
+              "w_up": (e, d, f), "w_down": (e, f, d), "s_up": (d, fs),
+              "s_down": (fs, d)}
+    if cfg.gated_experts:
+        shapes.update(w_gate=(e, d, f), s_gate=(d, fs))
+    return shapes
 
 
 def param_shapes(cfg: HybridLMConfig) -> dict:
@@ -94,7 +114,7 @@ def dense_param_count(cfg: HybridLMConfig) -> int:
 
 #: Leaves that project back into the residual stream: scaled down by
 #: ``sqrt(layers)`` (``rescale_prenorm_residual``).
-_OUT_PROJECTIONS = ("out_proj", "wo", "w_down", "s_down")
+_OUT_PROJECTIONS = ("out_proj", "wo", "w_down", "s_down", "ffn_down")
 
 
 def init_params(cfg: HybridLMConfig) -> dict:
@@ -107,7 +127,7 @@ def init_params(cfg: HybridLMConfig) -> dict:
     depth = math.sqrt(len(cfg.pattern))
 
     def leaf(name, shape):
-        if name in ("norm", "gnorm", "D", "final_norm"):
+        if name in ("norm", "gnorm", "kv_norm", "D", "final_norm"):
             return np.ones(shape, np.float32)
         if name == "A_log":
             return np.log(rng.uniform(1.0, 16.0, shape)).astype(np.float32)
@@ -132,49 +152,65 @@ def init_params(cfg: HybridLMConfig) -> dict:
 
 
 def init_buffers(cfg: HybridLMConfig) -> list:
-    """Per layer what is carried but not trained: an expert layer's
-    ``e_score_correction_bias`` (seeded, small; the published scheme moves
-    it outside the gradient, here it stays fixed)."""
+    """Per block what is carried but not trained: a sigmoid-routed expert
+    block's ``e_score_correction_bias`` (seeded, small; the published scheme
+    moves it outside the gradient, here it stays fixed)."""
     rng = np.random.default_rng(cfg.seed + 7)
+    biased = cfg.scoring_func == "sigmoid"
     return [jnp.asarray(rng.uniform(-0.01, 0.01, cfg.router_experts)
-                        .astype(np.float32)) if k == EXPERTS else None
-            for k in cfg.pattern]
+                        .astype(np.float32)) if k == EXPERTS and biased
+            else None for k in cfg.pattern]
 
 
 # -- the forward pass ---------------------------------------------------------
-def rmsnorm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
-    return x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True)
-                             + eps) * w
+def dense_ffn_mixer(p: dict, n: jax.Array, cfg) -> jax.Array:
+    return (jax.nn.silu(n @ p["ffn_gate"]) * (n @ p["ffn_up"])) \
+        @ p["ffn_down"]
+
+
+#: Blocks that mix inside a sequence only, or token by token with a working
+#: set worth bounding: (mixer, scope the profiler shows).
+_SEQUENCE_MIXERS = {
+    MAMBA: (mamba2_mixer, "lm_mamba2"),
+    ATTENTION: (attention_mixer, "lm_attention"),
+    LATENT: (latent_attention_mixer, "lm_mla"),
+    DENSE: (dense_ffn_mixer, "lm_dense_ffn"),
+}
 
 
 def layer_forward(kind: str, p: dict, bias, u: jax.Array,
                   cfg: HybridLMConfig, remat: bool = False):
-    """One layer: (``u + mixer(RMSNorm_w(u))``, assignments per held expert
-    or None). With ``remat`` the layer is rematerialised in the backward
-    pass: what a step keeps of its forward is the [B, S, hidden] input. A
-    Mamba-2 or attention layer mixes inside a sequence only, so it runs
-    (and is rematerialised) one sequence at a time, and its working set is
-    a sequence's and not the batch's."""
+    """One block: (``u + mixer(RMSNorm_w(u))``, assignments per held expert
+    or None), and third, for an expert block of a configuration that
+    weighs one, its balance loss. With ``remat`` the block is rematerialised
+    in the backward pass: what a step keeps of its forward is the [B, S,
+    hidden] input. A Mamba-2 or attention block mixes inside a sequence
+    only, and a dense feed-forward token by token, so each runs (and is
+    rematerialised) one sequence at a time, and its working set is a
+    sequence's and not the batch's."""
     keep = jax.checkpoint if remat else (lambda fn: fn)
-    if kind in (MAMBA, ATTENTION):
-        mixer = mamba2_mixer if kind == MAMBA else attention_mixer
+    if kind in _SEQUENCE_MIXERS:
+        mixer, scope = _SEQUENCE_MIXERS[kind]
 
         def one_sequence(seq):
             n = rmsnorm(seq[None], p["norm"], cfg.norm_eps)
             return seq + mixer(p, n, cfg)[0]
 
-        with jax.named_scope("lm_mamba2" if kind == MAMBA
-                             else "lm_attention"):
+        with jax.named_scope(scope):
             return jax.lax.map(keep(one_sequence), u), None
 
     def tokens(p, u):
         bsz, s, d = u.shape
         n = rmsnorm(u, p["norm"], cfg.norm_eps).reshape(bsz * s, d)
-        y, counts = held_topk_moe(
+        # All positional: a wrapper ``(n, router, bias, w_up, w_down,
+        # *rest)`` (the benchmark's controls) passes the rest on.
+        y, counts, *balance = held_topk_moe(
             n, p["router"], bias, p["w_up"], p["w_down"], p["s_up"],
             p["s_down"], cfg.held, cfg.num_experts_per_tok,
-            cfg.routed_scaling_factor, cfg.norm_topk_prob, cfg.moe_block)
-        return u + y.reshape(u.shape), counts
+            cfg.routed_scaling_factor, cfg.norm_topk_prob, cfg.moe_block,
+            True, cfg.scoring_func, p.get("w_gate"), p.get("s_gate"),
+            (cfg.aux_loss_alpha, bsz) if cfg.balanced else None)
+        return (u + y.reshape(u.shape), counts, *balance)
 
     with jax.named_scope("lm_experts"):
         return keep(tokens)(p, u)
@@ -182,16 +218,19 @@ def layer_forward(kind: str, p: dict, bias, u: jax.Array,
 
 def forward_hidden(params: dict, buffers: list, u: jax.Array,
                    cfg: HybridLMConfig, remat: bool = True):
-    """The layer stack over ``u`` [B, S, hidden] -> (hidden states before
-    the final norm, [expert layers, held] assignment counts)."""
-    counts = []
+    """The block stack over ``u`` [B, S, hidden] -> (hidden states before
+    the final norm, [expert blocks, held] assignment counts), and third,
+    where the configuration weighs one, the summed balance loss."""
+    counts, balance = [], []
     for i, kind in enumerate(cfg.pattern):
-        u, c = layer_forward(kind, params["layers"][i], buffers[i], u, cfg,
-                             remat)
+        u, c, *b = layer_forward(kind, params["layers"][i], buffers[i], u,
+                                 cfg, remat)
         if c is not None:
             counts.append(c)
-    return u, (jnp.stack(counts) if counts
-               else jnp.zeros((0, len(cfg.held)), jnp.int32))
+        balance.extend(b)
+    counts = jnp.stack(counts) if counts \
+        else jnp.zeros((0, len(cfg.held)), jnp.int32)
+    return (u, counts, sum(balance)) if cfg.balanced else (u, counts)
 
 
 def blocked_cross_entropy(u: jax.Array, norm_w: jax.Array, head: jax.Array,
@@ -226,14 +265,18 @@ def blocked_cross_entropy(u: jax.Array, norm_w: jax.Array, head: jax.Array,
 def make_loss(cfg: HybridLMConfig, remat: bool = True):
     """``(params, rows [n, hidden], buffers, where [B, S], targets [B, S],
     mask [B, S]) -> (loss, counts)``: ``rows[where]`` is the embedded
-    input (``rows`` the pulled rows of the step's distinct ids)."""
+    input (``rows`` the pulled rows of the step's distinct ids). Where the
+    configuration weighs a balance loss (``cfg.balanced``) the loss carries
+    it and the second result is ``(counts, balance loss)``."""
     def loss_fn(params, rows, buffers, where, targets, mask):
         u = jnp.take(rows, where, axis=0)
-        u, counts = forward_hidden(params, buffers, u, cfg, remat)
+        u, counts, *balance = forward_hidden(params, buffers, u, cfg, remat)
         loss = blocked_cross_entropy(
             u.reshape(-1, cfg.hidden_size), params["final_norm"],
             params["head"], targets.reshape(-1), mask.reshape(-1),
             cfg.norm_eps, cfg.loss_block)
+        if balance:
+            return loss + balance[0], (counts, balance[0])
         return loss, counts
 
     return loss_fn
@@ -367,14 +410,21 @@ class HybridLM:
             loss, counts = self._hybrid(ids, self.buffers, where, targets,
                                         mask, rows=distinct)
             loss = float(loss)
+            if self.cfg.balanced:
+                counts, balance = counts
+                gauge("lm.moe.balance_loss").set(float(balance))
             self.last_counts = np.asarray(counts, np.int64)
         self.steps += 1
-        self._count(tokens.size, distinct)
+        self._count(tokens, distinct)
         return loss
 
-    def _count(self, tokens: int, distinct: int) -> None:
-        counter("lm.tokens").inc(int(tokens))
+    def _count(self, tokens: np.ndarray, distinct: int) -> None:
+        counter("lm.tokens").inc(int(tokens.size))
         counter("lm.rows_pulled").inc(int(distinct))
+        # Causal query-key pairs, summed over the attention blocks.
+        seqs, length = tokens.shape
+        counter("lm.attn.pairs").inc(
+            self.cfg.attention_blocks() * seqs * length * (length + 1) // 2)
         for layer, per_expert in zip(self.cfg.expert_layers(),
                                      self.last_counts):
             # One pair per expert layer of the pattern: bounded.

@@ -1,5 +1,7 @@
 """Expert parallelism: top-1 routed MoE with expert-sharded weights, and the
-held-share expert layer of a published sigmoid top-k router.
+held-share expert layer of a published top-k router (sigmoid scores with a
+selection bias, or softmax scores; two-matrix ``relu2`` experts or gated
+three-matrix ``silu`` ones).
 
 The reference predates MoE entirely; this module supplies the
 expert-parallel building block the same way ``parallel/sequence.py``
@@ -18,11 +20,13 @@ outnumber the chips: the layer is TOLD which experts it holds, routes over
 all of them, and computes its own experts' terms for every assignment that
 lands here — no capacity, nothing dropped. The assignments are sorted by
 expert into blocks of one expert each, and a loop over the blocks IN USE
-gathers a block's tokens, runs the two products against that expert's
+gathers a block's tokens, runs the products against that expert's
 weights and adds the weighted result back; the backward is the same loop
 with the products transposed, so nothing of a block outlives it. What the
 absent experts would add is left out: on one chip the layer runs without
-its exchange (docs/HYBRID_LM.md).
+its exchange (docs/HYBRID_LM.md). A softmax router can hand back its
+sequence-wise balance loss (:func:`sequence_balance_loss`): the router is
+whole on every share, so a share computes it exactly.
 """
 
 from __future__ import annotations
@@ -148,6 +152,39 @@ def sigmoid_topk_route(n: jax.Array, router: jax.Array, bias: jax.Array,
     return chosen, w * scaling
 
 
+def softmax_topk_route(n: jax.Array, router: jax.Array, top_k: int,
+                       scaling: float, normalize: bool = False
+                       ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """``n`` [T, D] -> (experts [T, k], weights [T, k], probabilities
+    [T, E]): ``softmax(n W_r)`` over ALL experts in float32 at ``highest``,
+    the ``k`` largest chosen (greedy, no bias), their own probabilities the
+    weights: as they are, or normalised over the chosen (``+ 1e-20``);
+    then scaled."""
+    probs = jax.nn.softmax(jnp.dot(
+        n.astype(jnp.float32), router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST), axis=-1)
+    w, chosen = jax.lax.top_k(probs, top_k)
+    if normalize:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return chosen, w * scaling, probs
+
+
+def sequence_balance_loss(probs: jax.Array, chosen: jax.Array,
+                          sequences: int, alpha: float) -> jax.Array:
+    """``alpha * mean over sequences of sum_e f_e P_e``: per sequence of
+    ``S`` tokens ``f_e = E / (k S) * (assignments to e)`` and ``P_e`` the
+    mean probability of ``e``. The counts carry no gradient; it reaches the
+    router (and the block's input) through ``P``."""
+    t, e = probs.shape
+    k = chosen.shape[1]
+    s = t // sequences
+    counts = jnp.sum(chosen.reshape(sequences, s * k, 1)
+                     == jnp.arange(e, dtype=chosen.dtype), axis=1)
+    f = counts.astype(probs.dtype) * (e / (k * s))
+    mean_p = jnp.mean(probs.reshape(sequences, s, e), axis=1)
+    return alpha * jnp.mean(jnp.sum(f * mean_p, axis=-1))
+
+
 def group_held_assignments(chosen: jax.Array, weights: jax.Array,
                            held: Sequence[int], num_experts: int,
                            block: int):
@@ -247,22 +284,109 @@ def _grouped_bwd(block, saved, dout):
 grouped_relu2_experts.defvjp(_grouped_fwd, _grouped_bwd)
 
 
+def _silu_parts(a):
+    """(silu(a), its derivative)."""
+    sig = jax.nn.sigmoid(a)
+    return a * sig, sig * (1.0 + a * (1.0 - sig))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8,))
+def grouped_gated_experts(n, gates, w_gate, w_up, w_down, tokens,
+                          block_expert, blocks_in_use, block):
+    """``sum_e gate_e (silu(n W_gate,e) * n W_up,e) W_down,e`` over the
+    grouped assignments: ``n`` [T, D], ``w_gate`` / ``w_up`` [E_held, D, F],
+    ``w_down`` [E_held, F, D] -> [T, D]."""
+    def body(b, out):
+        idx, gate, e = _block_of(tokens, gates, block_expert, b, block)
+        x = _rows(n, idx)
+        h = jax.nn.silu(x @ w_gate[e]) * (x @ w_up[e])
+        return out.at[idx].add((h @ w_down[e]) * gate[:, None], mode="drop")
+
+    return jax.lax.fori_loop(0, blocks_in_use, body, jnp.zeros_like(n))
+
+
+def _gated_fwd(n, gates, w_gate, w_up, w_down, tokens, block_expert,
+               blocks_in_use, block):
+    out = grouped_gated_experts(n, gates, w_gate, w_up, w_down, tokens,
+                                block_expert, blocks_in_use, block)
+    return out, (n, gates, w_gate, w_up, w_down, tokens, block_expert,
+                 blocks_in_use)
+
+
+def _gated_bwd(block, saved, dout):
+    n, gates, w_gate, w_up, w_down, tokens, block_expert, blocks_in_use = \
+        saved
+
+    def body(b, carry):
+        dn, dgates, dgate_w, dup, ddown = carry
+        idx, gate, e = _block_of(tokens, gates, block_expert, b, block)
+        x = _rows(n, idx)
+        a, u = x @ w_gate[e], x @ w_up[e]
+        act, dact = _silu_parts(a)
+        h = act * u
+        dy = _rows(dout, idx)
+        dgates = jax.lax.dynamic_update_slice_in_dim(
+            dgates, jnp.sum(dy * (h @ w_down[e]), axis=-1), b * block, 0)
+        dy = dy * gate[:, None]
+        dh = dy @ w_down[e].T
+        da, du = dh * u * dact, dh * act
+        ddown = ddown.at[e].add(h.T @ dy)
+        dgate_w = dgate_w.at[e].add(x.T @ da)
+        dup = dup.at[e].add(x.T @ du)
+        return (dn.at[idx].add(da @ w_gate[e].T + du @ w_up[e].T,
+                               mode="drop"), dgates, dgate_w, dup, ddown)
+
+    dn, dgates, dgate_w, dup, ddown = jax.lax.fori_loop(
+        0, blocks_in_use, body,
+        (jnp.zeros_like(n), jnp.zeros_like(gates), jnp.zeros_like(w_gate),
+         jnp.zeros_like(w_up), jnp.zeros_like(w_down)))
+    no_grad = lambda x: np.zeros(x.shape, jax.dtypes.float0)  # noqa: E731
+    return (dn, dgates, dgate_w, dup, ddown, no_grad(tokens),
+            no_grad(block_expert), no_grad(blocks_in_use))
+
+
+grouped_gated_experts.defvjp(_gated_fwd, _gated_bwd)
+
+
 def held_topk_moe(n: jax.Array, router: jax.Array, bias: jax.Array,
                   w_up: jax.Array, w_down: jax.Array, s_up: jax.Array,
                   s_down: jax.Array, held: Sequence[int], top_k: int,
                   scaling: float, normalize: bool = True, block: int = 512,
-                  shared: bool = True) -> Tuple[jax.Array, jax.Array]:
-    """One chip's share of a sigmoid top-k expert layer: ``n`` [T, D] ->
+                  shared: bool = True, scoring: str = "sigmoid",
+                  w_gate: Optional[jax.Array] = None,
+                  s_gate: Optional[jax.Array] = None,
+                  balance: Optional[Tuple[float, int]] = None):
+    """One chip's share of a top-k expert layer: ``n`` [T, D] ->
     (y [T, D], assignments per held expert [len(held)]). ``router`` is
     [D, E] over ALL experts, ``w_up`` / ``w_down`` hold the experts
     ``held`` names, in that order. ``shared=False`` leaves the shared
-    expert to another share (a deployment computes it once)."""
-    chosen, weights = sigmoid_topk_route(n, router, bias, top_k, scaling,
-                                         normalize)
+    expert to another share (a deployment computes it once).
+
+    ``scoring``: ``sigmoid`` (the ``k`` largest of ``score + bias``) or
+    ``softmax`` (greedy, ``bias`` unused). With ``w_gate`` / ``s_gate`` the
+    experts are gated, ``(silu(n W_gate) * n W_up) W_down``; without, they
+    are ``relu(n W_up)^2 W_down``. ``balance = (alpha, sequences)`` (softmax
+    only; ``n`` is ``sequences`` equal runs of tokens) adds a third result,
+    the sequence-wise balance loss."""
+    if scoring == "softmax":
+        chosen, weights, probs = softmax_topk_route(n, router, top_k,
+                                                    scaling, normalize)
+    else:
+        chosen, weights = sigmoid_topk_route(n, router, bias, top_k, scaling,
+                                             normalize)
     tokens, gates, block_expert, in_use, counts = group_held_assignments(
         chosen, weights, held, router.shape[1], block)
-    y = grouped_relu2_experts(n, gates, w_up, w_down, tokens, block_expert,
-                              in_use, block)
-    if shared:
-        y = y + jnp.square(jax.nn.relu(n @ s_up)) @ s_down
-    return y, counts
+    if w_gate is None:
+        y = grouped_relu2_experts(n, gates, w_up, w_down, tokens,
+                                  block_expert, in_use, block)
+        if shared:
+            y = y + jnp.square(jax.nn.relu(n @ s_up)) @ s_down
+    else:
+        y = grouped_gated_experts(n, gates, w_gate, w_up, w_down, tokens,
+                                  block_expert, in_use, block)
+        if shared:
+            y = y + (jax.nn.silu(n @ s_gate) * (n @ s_up)) @ s_down
+    if balance is None:
+        return y, counts
+    return y, counts, sequence_balance_loss(probs, chosen, balance[1],
+                                            balance[0])

@@ -68,6 +68,38 @@ def test_mixer_compiles_for_v5e_at_published_widths(one_chip, cfg, kind,
     assert stats.temp_size_in_bytes < temp_gb * 1e9, stats
 
 
+@pytest.fixture(scope="module")
+def two_block_cfg():
+    return HybridLMConfig.from_file(os.path.join(
+        ROOT, "benchmark", "configs", "deepseek-v2-lite-ep4.json"))
+
+
+# Latent attention (192-wide rotary-carrying keys against 128-wide values),
+# the dense gated feed-forward and the gated expert block with its balance
+# loss, forward + backward: (letter, its index in the pattern, sequences,
+# HBM beside the arguments, GB).
+@pytest.mark.parametrize("kind,layer,seqs,temp_gb", [
+    ("L", 0, 1, 2.5), ("D", 1, 1, 3.0), ("E", 3, 2, 3.0)])
+def test_two_block_layer_compiles_for_v5e_at_published_widths(
+        one_chip, two_block_cfg, kind, layer, seqs, temp_gb):
+    cfg = two_block_cfg
+    assert cfg.pattern[layer] == kind
+
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    p = {k: spec(s) for k, s in param_shapes(cfg)["layers"][layer].items()}
+    u = spec((seqs, SEQ, cfg.hidden_size))
+
+    def loss(p, u):
+        out, _, *balance = layer_forward(kind, p, None, u, cfg, remat=True)
+        return jnp.sum(out) + sum(balance)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(p, u).compile()
+    stats = compiled.memory_analysis()
+    assert stats.temp_size_in_bytes < temp_gb * 1e9, stats
+
+
 # (tables, rows a table, width, ids a table, one [B, n] id matrix?): the two
 # cells whose steps keep their pulled rows on the device (ISSUE 27).
 @pytest.mark.parametrize("tables,rows,width,ids,matrix", [
