@@ -34,10 +34,7 @@ from multiverso_tpu.models.word2vec.dictionary import (Dictionary,
                                                        HuffmanEncoder)
 from multiverso_tpu.models.word2vec.model import (Word2VecConfig,
                                                   build_scan_step,
-                                                  raw_cbow_hs_step,
-                                                  raw_cbow_ns_step,
-                                                  raw_sg_hs_step,
-                                                  raw_sg_ns_step)
+                                                  raw_step_factory)
 from multiverso_tpu.core.options import GetOption
 from multiverso_tpu.parallel.ps_service import (DistributedKVTable,
                                                 DistributedMatrixTable,
@@ -117,15 +114,8 @@ class DistributedWord2Vec:
         self.huffman = (HuffmanEncoder(dictionary.counts,
                                        cfg.max_code_length)
                         if cfg.hs else None)
-        if cfg.sg and not cfg.hs:
-            raw = raw_sg_ns_step(self._adagrad)
-        elif cfg.sg and cfg.hs:
-            raw = raw_sg_hs_step(self._adagrad)
-        elif not cfg.sg and not cfg.hs:
-            raw = raw_cbow_ns_step(self._adagrad)
-        else:
-            raw = raw_cbow_hs_step(self._adagrad)
-        self._scan_step = build_scan_step(raw)
+        self._scan_step = build_scan_step(
+            raw_step_factory(cfg.sg, cfg.hs)(self._adagrad), self._adagrad)
         self.trained_words = 0
         self.total_words = dictionary.total_count * max(cfg.epochs, 1)
         self.words_per_sec = 0.0

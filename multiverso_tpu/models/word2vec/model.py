@@ -21,6 +21,7 @@ parity semantics (checkpointing, row gets) coexist with fused speed.
 
 from __future__ import annotations
 
+import contextvars
 import dataclasses
 import functools
 import time
@@ -38,7 +39,7 @@ from multiverso_tpu.models.word2vec.data import (BatchGenerator, BlockStream,
 from multiverso_tpu.models.word2vec.dictionary import (Dictionary,
                                                        HuffmanEncoder,
                                                        Sampler)
-from multiverso_tpu.telemetry import gauge, span
+from multiverso_tpu.telemetry import counter, gauge, span
 from multiverso_tpu.utils.dashboard import monitor
 from multiverso_tpu.utils.log import check, log
 
@@ -79,10 +80,14 @@ class Word2VecConfig:
     # all-padding chunks (~2x fewer chunk steps at typical subsample rates).
     compact_pairs: bool = True
     # How the fused chunk loop executes (sg-ns, single device):
-    #   "in_graph"       — one jitted block program; the chunk loop is a
-    #                      lax.fori_loop (pays XLA's ~20x loop-body scatter
-    #                      de-optimization, docs/BENCHMARK.md Round 2 #3,
-    #                      but costs ONE launch per block);
+    #   "in_graph"       — one jitted block program, ONE launch per
+    #                      block; the chunk loop is a lax.fori_loop. On
+    #                      tables the row kernel serves (float32, 128
+    #                      columns, one shard, AdaGrad: row_kernel_selected)
+    #                      a chunk's row updates are Pallas kernels over
+    #                      sorted ids; elsewhere XLA's scatter-adds, which
+    #                      write a row of a big table in 71-86 ns and are
+    #                      then all of a chunk (PERF.md 5-6, PR 31);
     #   "pipelined_host" — per-chunk host dispatches with a depth-N
     #                      in-flight window (dispatch_depth): donated table
     #                      carries chain through the queue and the host
@@ -219,8 +224,11 @@ def _cbow_arrays(sents, lengths, keep_prob, k_keep, k_win, window):
     wpos = jax.random.randint(k_win, (S, L), 1, window + 1)
     ctx_cols, m_cols = [], []
     for d in range(1, window + 1):
+        # Traced, not a host loop: the only caller is block_step, which
+        # _PlacedStep jits (the lint follows jax.jit(...) lexically only).
+        # graftlint: disable=host-jnp-in-loop
         pad_i = jnp.zeros((S, d), sents.dtype)
-        pad_b = jnp.zeros((S, d), bool)
+        pad_b = jnp.zeros((S, d), bool)  # graftlint: disable=host-jnp-in-loop
         right = jnp.concatenate([sents[:, d:], pad_i], axis=1)
         rmask = jnp.concatenate([tok_valid[:, d:], pad_b], axis=1) \
             & (wpos >= d)
@@ -239,10 +247,106 @@ def _cbow_arrays(sents, lengths, keep_prob, k_keep, k_win, window):
 # ---------------------------------------------------------------------------
 # Fused jitted steps. All take/return the (padded) table arrays.
 # ---------------------------------------------------------------------------
+# Ids a sort takes at once on the fused plane: XLA's TPU sort compiles in
+# 0.5 s at 8,192 ids, 3 s at 16,384 and 13-16 s from 32,768 on (compiled
+# for the v5e, PR 31), and every start-up pays it.
+_SORT_SLAB = 8192
+
+
+def row_kernel_selected(w, adagrad: bool, one_shard: bool) -> bool:
+    """Whether a table's AdaGrad row update runs as the Pallas row kernel
+    (``ops/pallas_rows.adagrad_fold_rows``): ``ServerStore``'s rule
+    (``core/table.pallas_rows_eligible``: 2-D float32, exactly 128 columns,
+    one shard), AdaGrad on. A trace shows shape and dtype but not the
+    placement, so the rule is applied to the arrays a program is CALLED
+    with (:class:`_PlacedStep`), once for the whole program."""
+    from multiverso_tpu.core.table import pallas_rows_eligible
+    return adagrad and pallas_rows_eligible(w.shape, w.dtype, one_shard)
+
+
+# The plane the row updates of the program being traced run on: None for
+# XLA's scatter-adds, else the row kernel, the value its ``interpret``
+# (``ops.pallas_interpret``). A program sets it around its own body
+# (``_on_row_kernel``), so the raw steps keep the signature they have
+# always had and ``_apply_update`` reads it where it traces.
+_ROW_KERNEL = contextvars.ContextVar("w2v_row_kernel", default=None)
+
+
+def _on_row_kernel(fn, interpret: Optional[bool]):
+    """``fn`` with its row updates on the Pallas plane wherever it is
+    traced (under ``fn``'s own name, which names the jitted program);
+    ``fn`` itself for ``None``, XLA's lines."""
+    if interpret is None:
+        return fn
+
+    @functools.wraps(fn)
+    def placed(*args):
+        token = _ROW_KERNEL.set(interpret)
+        try:
+            return fn(*args)
+        finally:
+            _ROW_KERNEL.reset(token)
+    return placed
+
+
+def _sorted_in_slabs(rows, grad, num_rows: int, slab: int):
+    """``(ids, back, grads)`` for the row kernel: the ids ascending within
+    slabs of ``slab``, an id out of range turned into ``num_rows`` (the
+    kernel's dropped lane, behind its slab's live ones); for each position
+    the number of positions directly before it that hold its id; and the
+    gradients permuted alike."""
+    n = rows.shape[0]
+    pad = (-n) % slab
+    rows = jnp.where((rows < 0) | (rows >= num_rows), num_rows,
+                     rows).astype(jnp.int32)
+    if pad:
+        rows = jnp.concatenate([rows, jnp.full((pad,), num_rows, jnp.int32)])
+    lane = jnp.arange(slab, dtype=jnp.int32)
+    ids, order = jax.lax.map(
+        lambda r: jax.lax.sort((r, lane), num_keys=1),
+        rows.reshape(-1, slab))
+    order = order + (jnp.arange(ids.shape[0], dtype=jnp.int32)
+                     * slab)[:, None]
+    # A pad lane's position is past the gradients: it reads the last row
+    # (clipped) into a lane that is never written.
+    grads = jnp.take(grad.astype(jnp.float32), order.reshape(-1), axis=0,
+                     mode="clip")
+    # Runs are read off the whole stream: where a slab's last id is the
+    # next slab's first, the run goes on (to the kernel a run is equal
+    # neighbours, wherever the slabs' seams fall).
+    ids = ids.reshape(-1)
+    pos = jnp.arange(ids.shape[0], dtype=jnp.int32)
+    starts = jnp.concatenate([jnp.ones((1,), bool), ids[1:] != ids[:-1]])
+    back = pos - jax.lax.cummax(jnp.where(starts, pos, 0))
+    return ids, back, grads
+
+
+def _fused_adagrad_update(w, g2, rows, grad, lr, interpret: bool):
+    """The AdaGrad row update on the Pallas plane: sort, then the row
+    kernel folds the duplicates and walks the touched rows. One slab: the
+    whole update in one pass. More: two additive passes, exact whatever the
+    order (``ops/pallas_rows.py``)."""
+    from multiverso_tpu.ops.pallas_rows import adagrad_fold_rows
+    one_slab = rows.shape[0] <= _SORT_SLAB
+    ids, back, grads = _sorted_in_slabs(
+        rows, grad, w.shape[0], rows.shape[0] if one_slab else _SORT_SLAB)
+    for phase in (("both",) if one_slab else ("accumulate", "step")):
+        w, g2 = adagrad_fold_rows(w, g2, ids, back, grads, lr, phase,
+                                  interpret)
+    return w, g2
+
+
 def _apply_update(w, g2, rows, grad, lr, adagrad: bool):
-    """Scatter an embedding update (+AdaGrad) for possibly-duplicated rows.
-    Gradients arrive f32; the step is cast to the storage dtype (bf16
-    tables keep f32 math)."""
+    """Apply an embedding update (+AdaGrad) for possibly-duplicated rows:
+    for a row with gradients g_1..g_k, ``G += sum(g_i^2)`` then ``w -= lr
+    sum(g_i) / sqrt(G + 1e-6)``; ids out of range are dropped. Gradients
+    arrive f32; the step is cast to the storage dtype (bf16 tables keep f32
+    math). In a program whose tables the row kernel serves
+    (``_ROW_KERNEL``, set from ``row_kernel_selected``) the kernel runs;
+    else XLA's scatter-adds, one write an id."""
+    interpret = _ROW_KERNEL.get()
+    if interpret is not None:
+        return _fused_adagrad_update(w, g2, rows, grad, lr, interpret)
     if adagrad:
         g2 = g2.at[rows].add(jnp.square(grad).astype(g2.dtype), mode="drop")
         denom = jnp.sqrt(jnp.take(g2, rows, axis=0, mode="clip")
@@ -290,7 +394,9 @@ def _hs_grads(u, v_nodes, codes, lmask):
 def raw_sg_ns_step(adagrad: bool):
     """Unjitted skip-gram/negative-sampling step — callers apply their own
     jit/shardings (the multi-chip dry run shards vocab rows over a model
-    axis and the batch over a data axis)."""
+    axis and the batch over a data axis). Like its three siblings it
+    returns ``(w_in, w_out, g_in, g_out, loss)``; which plane its row
+    updates run on is the enclosing program's to say (``_apply_update``)."""
     def step(w_in, w_out, g_in, g_out, centers, contexts, negatives, mask,
              lr):
         u = jnp.take(w_in, centers, axis=0, mode="clip")
@@ -307,8 +413,58 @@ def raw_sg_ns_step(adagrad: bool):
     return step
 
 
+class _PlacedStep:
+    """A jitted word2vec program that adapts to where its tables live.
+
+    The row kernel serves tables on ONE shard, and a trace cannot see the
+    placement; the caller's arrays can. So the rule is applied to the four
+    tables a call brings (``row_kernel``) and the program is jitted once a
+    plane, ``jax.jit(_on_row_kernel(fn, plane), donating the tables)``:
+    eligible tables on one device, committed there or not, run the kernel
+    (interpreted off the TPU); anything else (a mesh, host arrays, another
+    width or dtype, AdaGrad off) runs ``fn`` as it stands. Each call counts
+    ``w2v.rows.plane.<fused|xla>``, the plane its row updates ran on."""
+
+    def __init__(self, fn, adagrad: bool):
+        self._fn, self._adagrad = fn, adagrad
+        self._programs = {}
+        self.__name__ = fn.__name__
+
+    def row_kernel(self, tables) -> Optional[bool]:
+        """``_ROW_KERNEL``'s value for a call with these four tables."""
+        from multiverso_tpu.ops import pallas_interpret
+        devices = set()
+        for t in tables:
+            sharding = getattr(t, "sharding", None)
+            if sharding is None:
+                return None
+            devices |= set(sharding.device_set)
+        if not all(row_kernel_selected(t, self._adagrad, len(devices) == 1)
+                   for t in tables[:2]):
+            return None
+        return pallas_interpret(devices)
+
+    def program(self, *args):
+        """``(jitted program, its plane)`` for these arguments (the four
+        tables first)."""
+        plane = self.row_kernel(args[:4])
+        if plane not in self._programs:
+            self._programs[plane] = jax.jit(
+                _on_row_kernel(self._fn, plane), donate_argnums=(0, 1, 2, 3))
+        return self._programs[plane], plane
+
+    def lower(self, *args, **kwargs):
+        return self.program(*args)[0].lower(*args, **kwargs)
+
+    def __call__(self, *args, **kwargs):
+        program, plane = self.program(*args)
+        counter("w2v.rows.plane.xla" if plane is None
+                else "w2v.rows.plane.fused").inc()
+        return program(*args, **kwargs)
+
+
 def build_sg_ns_step(adagrad: bool):
-    return jax.jit(raw_sg_ns_step(adagrad), donate_argnums=(0, 1, 2, 3))
+    return _PlacedStep(raw_sg_ns_step(adagrad), adagrad)
 
 
 def raw_sg_hs_step(adagrad: bool):
@@ -372,6 +528,13 @@ def raw_cbow_hs_step(adagrad: bool):
     return step
 
 
+def raw_step_factory(sg: bool, hs: bool):
+    """The variant's raw-step maker, ``maker(adagrad)``."""
+    return {(True, False): raw_sg_ns_step, (True, True): raw_sg_hs_step,
+            (False, False): raw_cbow_ns_step,
+            (False, True): raw_cbow_hs_step}[(bool(sg), bool(hs))]
+
+
 def _make_block_fn(window: int, negative: int, chunk: int,
                    adagrad: bool, compact: bool, sg: bool = True,
                    hs: bool = False, huffman=None, constrain=None):
@@ -398,14 +561,7 @@ def _make_block_fn(window: int, negative: int, chunk: int,
     proportional to true examples — the TPU answer to the reference's
     exact dynamic-window loop (``wordembedding.cpp:120-135``).
     """
-    if sg and not hs:
-        raw = raw_sg_ns_step(adagrad)
-    elif sg:
-        raw = raw_sg_hs_step(adagrad)
-    elif not hs:
-        raw = raw_cbow_ns_step(adagrad)
-    else:
-        raw = raw_cbow_hs_step(adagrad)
+    raw = raw_step_factory(sg, hs)(adagrad)
     if hs:
         check(huffman is not None, "HS device pipeline needs the encoder")
         # Device-resident Huffman path tables; [V, Lc] gathers happen
@@ -543,10 +699,11 @@ def build_device_block_step(window: int, negative: int, chunk: int,
     The host uploads only raw token ids; pairing/windowing, subsampling,
     compaction, negative sampling or Huffman path gathers, and the chunk
     training loop all run in one jitted program (details in
-    :func:`_make_block_fn`'s body)."""
-    return jax.jit(_make_block_fn(window, negative, chunk, adagrad,
-                                  compact, sg=sg, hs=hs, huffman=huffman),
-                   donate_argnums=(0, 1, 2, 3))
+    :func:`_make_block_fn`'s body), built for where the tables it is
+    called with live (:class:`_PlacedStep`)."""
+    return _PlacedStep(_make_block_fn(window, negative, chunk, adagrad,
+                                      compact, sg=sg, hs=hs, huffman=huffman),
+                       adagrad)
 
 
 def build_sharded_block_step(mesh, window: int, negative: int, chunk: int,
@@ -566,6 +723,9 @@ def build_sharded_block_step(mesh, window: int, negative: int, chunk: int,
 
     Semantics are identical to the single-device step (same keys -> same
     pairs, negatives and update order), so losses match the unsharded run.
+    Tables over a ``model`` axis are never on one shard: the row updates
+    stay XLA's sharded scatters (the row kernel would need per-shard offset
+    remapping, ``core/table.pallas_rows_eligible``).
     """
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -587,12 +747,15 @@ def build_sharded_block_step(mesh, window: int, negative: int, chunk: int,
         donate_argnums=(0, 1, 2, 3))
 
 
-# Dispatch-latency threshold for chunk_dispatch AUTO: below this, host
-# launches are cheap enough that per-chunk dispatch beats the in-graph
-# loop's de-optimized scatter (round-2 measurements: standalone chunk
-# 0.05-0.12ms vs 2.2-2.6ms in-loop; high launch latency, ~40ms, loses).
-# The v5e host measures 0.5-0.8ms (PR 21); ROADMAP A4 re-measures the
-# threshold itself.
+# Dispatch-latency threshold for chunk_dispatch AUTO on XLA's row plane:
+# below this, host launches are cheap enough that per-chunk dispatch beats
+# the in-graph loop's scatters (jax 0.4.37: standalone chunk 0.05-0.12ms
+# vs 2.2-2.6ms in-loop; on jax 0.9 XLA writes a row in 71 ns alone and 86
+# in the loop, and pipelined_host read 1.6x in_graph at V=4M, PERF.md 6,
+# PR 23-29). The v5e host measures 0.5-1.07ms, a coin flip against this
+# threshold, so the benchmark pins in_graph. Tables on the row kernel
+# (PR 31) do not come here (resolve_dispatch_mode, rule 2): a Mosaic call
+# costs in a loop body what it costs alone.
 CHUNK_DISPATCH_LATENCY_MS = 1.0
 
 
@@ -615,7 +778,8 @@ def measured_dispatch_latency_ms(n: int = 7) -> float:
 DISPATCH_MODES = ("in_graph", "pipelined_host", "pallas_grid")
 
 
-def resolve_dispatch_mode(cfg: "Word2VecConfig") -> str:
+def resolve_dispatch_mode(cfg: "Word2VecConfig",
+                          row_kernel: bool = False) -> str:
     """Dispatch-mode decision (the extended chunk_dispatch AUTO).
 
     Explicit ``dispatch_mode`` wins; the deprecated ``chunk_dispatch`` bool
@@ -623,10 +787,15 @@ def resolve_dispatch_mode(cfg: "Word2VecConfig") -> str:
 
     1. variant is not sg-ns, or a dp x tp mesh is configured -> in_graph
        (the fused block step is the only implementation of those paths);
-    2. measured launch latency < CHUNK_DISPATCH_LATENCY_MS ->
-       pipelined_host (standalone dispatches are ~20x faster than the
-       in-graph loop and the depth-N window hides cheap launches);
-    3. otherwise (high launch latency) -> in_graph.
+    2. the tables' row updates run the row kernel (``row_kernel``, which
+       the caller reads off its live tables: ``_PlacedStep.row_kernel``)
+       -> in_graph: on the kernel pipelined_host read 9% slower on the
+       v5e (347,000 against 381,000 samples/s at V=4M) and holds 0.12 GB
+       more (PERF.md 6, PR 31);
+    3. measured launch latency < CHUNK_DISPATCH_LATENCY_MS ->
+       pipelined_host (the depth-N window hides cheap launches; XLA's
+       scatters run faster standalone than in the loop);
+    4. otherwise (high launch latency) -> in_graph.
 
     ``pallas_grid`` is never chosen here: Mosaic refuses that kernel on a
     TPU (ops/pallas_sgns.py), so it is reachable by name only.
@@ -641,7 +810,7 @@ def resolve_dispatch_mode(cfg: "Word2VecConfig") -> str:
         return mode
     eligible = (cfg.sg and not cfg.hs
                 and cfg.mesh_data * cfg.mesh_model == 1)
-    if not eligible:
+    if not eligible or row_kernel:
         return "in_graph"
     lat = measured_dispatch_latency_ms()
     mode = ("pipelined_host" if lat < CHUNK_DISPATCH_LATENCY_MS
@@ -754,10 +923,10 @@ def build_chunked_pipeline(window: int, negative: int, chunk: int,
                            adagrad: bool):
     """Device pair-gen + HOST-dispatched per-chunk training steps.
 
-    Profiling on v5e showed the identical sg-ns update runs ~0.05-0.12ms as
-    a standalone jitted dispatch but 2.2-2.6ms inside ``lax.scan`` /
-    ``while_loop`` (XLA de-optimizes the gather/scatter hot path in loop
-    bodies; unrolling does not recover it). So the block loop moves to the
+    Profiling on v5e (jax 0.4.37) showed the identical sg-ns update running
+    ~0.05-0.12ms as a standalone jitted dispatch but 2.2-2.6ms inside
+    ``lax.scan`` / ``while_loop``; on jax 0.9 the gap is 71 against 86 ns a
+    row written (PERF.md 6, PR 29). Here the block loop moves to the
     host: ``pair_gen`` runs once per block on device (pairing, compaction,
     row-gathered negatives — everything stays in HBM), then the host
     dispatches one jitted ``chunk_step`` per live chunk (async dispatch
@@ -791,13 +960,11 @@ def build_chunked_pipeline(window: int, negative: int, chunk: int,
         m = ((i * chunk + lane) < n_pairs).astype(jnp.float32)
         return raw(*tables, c, o, neg, m, lr)
 
-    @functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3))
     def chunk_step(w_in, w_out, g_in, g_out, centers2d, contexts2d,
                    negatives2d, n_pairs, i, lr):
         return _chunk_body((w_in, w_out, g_in, g_out), centers2d,
                            contexts2d, negatives2d, n_pairs, i, lr)
 
-    @functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3))
     def tail_step(w_in, w_out, g_in, g_out, centers2d, contexts2d,
                   negatives2d, n_pairs, lr, start):
         # ``start`` is a traced operand (NOT static): the estimate varies
@@ -815,7 +982,8 @@ def build_chunked_pipeline(window: int, negative: int, chunk: int,
             start, jnp.maximum(n_live, start), body,
             (w_in, w_out, g_in, g_out, jnp.float32(0.0)))
 
-    return pair_gen, chunk_step, tail_step
+    return (pair_gen, _PlacedStep(chunk_step, adagrad),
+            _PlacedStep(tail_step, adagrad))
 
 
 def expected_live_chunks(keep_prob: np.ndarray, mat: np.ndarray,
@@ -836,8 +1004,10 @@ def expected_live_chunks(keep_prob: np.ndarray, mat: np.ndarray,
     return min(int(np.ceil((e_pairs + margin) / chunk)), n_static)
 
 
-def build_scan_step(raw_step):
-    """Wrap a raw step into a jitted ``lax.scan`` over a GROUP of batches.
+def build_scan_step(raw_step, adagrad: bool):
+    """Wrap a raw step (``raw_*_step(adagrad)``) into a jitted ``lax.scan``
+    over a GROUP of batches, on the row plane of the tables it is called
+    with (:class:`_PlacedStep`).
 
     The batch args arrive stacked with a leading [N] group axis; one dispatch
     trains N minibatches. This is the TPU-idiomatic answer to the
@@ -857,7 +1027,7 @@ def build_scan_step(raw_step):
             body, (w_in, w_out, g_in, g_out), tuple(batch_args))
         return (*carry, losses.sum())
 
-    return jax.jit(scan_step, donate_argnums=(0, 1, 2, 3))
+    return _PlacedStep(scan_step, adagrad)
 
 
 class Word2Vec:
@@ -918,15 +1088,8 @@ class Word2Vec:
         check(cfg.mesh_data * cfg.mesh_model == 1 or cfg.device_pipeline,
               "mesh_data/mesh_model need device_pipeline=True (the host "
               "batch path has no sharded step)")
-        if cfg.sg and not cfg.hs:
-            raw = raw_sg_ns_step(adagrad)
-        elif cfg.sg and cfg.hs:
-            raw = raw_sg_hs_step(adagrad)
-        elif not cfg.sg and not cfg.hs:
-            raw = raw_cbow_ns_step(adagrad)
-        else:
-            raw = raw_cbow_hs_step(adagrad)
-        self._scan_step = build_scan_step(raw)
+        self._scan_step = build_scan_step(
+            raw_step_factory(cfg.sg, cfg.hs)(adagrad), adagrad)
 
         if cfg.device_pipeline:
             sampler = self.generator.sampler
@@ -942,7 +1105,10 @@ class Word2Vec:
                 cfg.window, cfg.negative, cfg.batch_size, adagrad,
                 compact=cfg.compact_pairs, sg=cfg.sg, hs=cfg.hs,
                 huffman=self.huffman)
-            self._dispatch_mode = resolve_dispatch_mode(cfg)
+            self._dispatch_mode = resolve_dispatch_mode(
+                cfg, self._block_step.row_kernel([t.store.data for t in (
+                    self.input_table, self.output_table, self.adagrad_in,
+                    self.adagrad_out)]) is not None)
             if self._dispatch_mode != "in_graph":
                 check(cfg.sg and not cfg.hs,
                       f"dispatch_mode={self._dispatch_mode} (per-chunk "
@@ -1322,6 +1488,8 @@ class Word2Vec:
                             losses.append(jnp.sum(jnp.stack(block_loss)))
                             pair_counts.append(n_pairs)
                         else:
+                            if sharded:     # a _PlacedStep counts its own
+                                counter("w2v.rows.plane.xla").inc()
                             (st_in.data, st_out.data, st_gin.data,
                              st_gout.data, loss, pairs) = finish(
                                 self._block_step(

@@ -474,3 +474,208 @@ def tiled_scatter_add_rows(table: jax.Array, ids: jax.Array,
     return tiled_scatter_add_sorted_rows(
         table, jnp.take(ids, order), jnp.take(deltas, order, axis=0),
         interpret=interpret, sign=sign)
+
+
+# ---------------------------------------------------------------------------
+# word2vec's AdaGrad row update over sorted ids, folded inside the kernel
+# ---------------------------------------------------------------------------
+# ``models/word2vec/model._apply_update`` on the Pallas plane. Its row math
+# is not ``AdaGradUpdater.rows_math`` (which squares a folded total): for a
+# row that occurs with gradients g_1..g_k it is G' = G + sum(g_i^2), then
+# w' = w - lr sum(g_i) / sqrt(G' + 1e-6), so it needs BOTH run sums. A
+# chunk brings 49,152 ids; a fold ahead of the kernel
+# (``combine_duplicate_rows``) with ``fused_stateful_rows`` behind it read
+# no faster than XLA's scatters at that size (PERF.md 5, PR 31). So the
+# caller only sorts (out-of-range ids turned into ``num_rows``; the
+# gradients permuted alike) and the kernel folds: a grid step takes 32
+# positions, sums each run of equal ids down its lanes by a segmented scan
+# on whole VMEM blocks (the open run's sum rides from step to step in a
+# scratch row), and only the LAST lane of a run applies the update and
+# writes its rows back. A step's row DMAs are all in flight together, and
+# every write has landed before the next step reads.
+#
+# Three phases share the kernel. ``both`` is the whole update in one pass
+# and needs every id's occurrences in ONE run (a full sort). The update is
+# also the sum of two ADDITIVE passes, ``accumulate`` (G += sum g^2) and,
+# once G is final, ``step`` (w -= lr sum(g) / sqrt(G + 1e-6)): each is exact
+# whatever the order of the ids, a run is merely what it can fold, so they
+# take a stream that is sorted in slabs (XLA's TPU sort compiles in 0.5 s at
+# 8,192 ids and in 15 s at 49,152).
+
+_FOLD_GROUP_ROWS = 32
+ADAGRAD_PHASES = ("both", "accumulate", "step")
+
+
+def _make_adagrad_fold_kernel(group: int, num_rows: int, phase: str):
+    read_w = phase != "accumulate"
+    write_w, write_g = phase != "accumulate", phase != "step"
+
+    def _kernel(ids_ref, ends_ref, live_ref, lr_ref, grad_ref, back_ref,
+                w_hbm, g_hbm, *refs):
+        # refs: the aliased outputs (w and/or g2), wrows, grows, carry,
+        # sems. An output IS its input's buffer: read through it.
+        n_out = write_w + write_g
+        outs = list(refs[:n_out])
+        w_ref = outs.pop(0) if write_w else w_hbm
+        g_ref = outs.pop(0) if write_g else g_hbm
+        wrows, grows, carry, sems = refs[n_out:]
+        step = pl.program_id(0)
+        base = step * group
+
+        def _tables(load):
+            pairs = [(g_ref, grows, 0)] if (load or write_g) else []
+            if (load and read_w) or (not load and write_w):
+                pairs.append((w_ref, wrows, 1))
+            return pairs
+
+        def _start(load):
+            """Start the lanes' row DMAs, one semaphore a table. Into VMEM
+            (``load``): every lane's, with no branch (a lane that writes
+            nothing reads its clamped row for nothing, which costs less
+            than asking). Back to HBM: of the lanes that END a live run,
+            which ``ends`` flags. Unrolled: the scalar core's loop over
+            lanes, not the DMAs, is what a step's time is made of."""
+            def body(k, c):
+                def go():
+                    rid = ids_ref[base + k]
+                    if load:
+                        rid = jnp.minimum(rid, num_rows - 1)
+                    for hbm, vmem, j in _tables(load):
+                        ends = ((hbm.at[rid], vmem.at[k]) if load
+                                else (vmem.at[k], hbm.at[rid]))
+                        pltpu.make_async_copy(*ends, sems.at[j]).start()
+                if load:
+                    go()
+                else:
+                    pl.when(ends_ref[base + k] != 0)(go)
+                return c
+            jax.lax.fori_loop(0, group, body, 0, unroll=True)
+
+        def _wait(load):
+            """Wait for what ``_start`` started: a DMA semaphore counts
+            bytes, so ONE wait a table takes all 32 reads, and the writes'
+            number (``live``, the step's flagged lanes) is waited for by
+            its binary digits."""
+            def wait_rows(n):
+                for hbm, vmem, j in _tables(load):
+                    pair = (hbm.at[pl.ds(0, n)], vmem.at[pl.ds(0, n)])
+                    pltpu.make_async_copy(*(pair if load else pair[::-1]),
+                                          sems.at[j]).wait()
+            if load:
+                return wait_rows(group)
+            n = group
+            while n:
+                pl.when((live_ref[step] & n) != 0)(
+                    functools.partial(wait_rows, n))
+                n //= 2
+
+        # Sorted ids: a step whose FIRST id is the sentinel holds nothing
+        # else (in a slab-sorted stream: nothing else of its slab, whose
+        # length the group divides), and costs a grid step and no DMA.
+        @pl.when(ids_ref[base] < num_rows)
+        def _():
+            _start(True)
+            # The fold, while the reads fly: a segmented inclusive scan
+            # down the step's 32 lanes in five doubling strides, on whole
+            # blocks. ``back`` says how many positions before a lane lie in
+            # its run; within the step that is at most the lane's own
+            # index, and a run that began earlier takes the step before's
+            # last lane, which rides in ``carry``.
+            back = back_ref[:]
+            lane = jax.lax.broadcasted_iota(jnp.int32, back.shape, 0)
+            within = jnp.minimum(back, lane)
+            d = grad_ref[:]
+            sums = []
+            for j, term in ((0, d), (1, d * d)):
+                if not (write_w, write_g)[j]:
+                    sums.append(None)
+                    continue
+                stride = 1
+                while stride < group:
+                    term = term + jnp.where(
+                        within >= stride, pltpu.roll(term, stride, 0), 0.0)
+                    stride *= 2
+                term = term + jnp.where(back > lane, carry[j:j + 1, :], 0.0)
+                carry[j:j + 1, :] = term[group - 1:group, :]
+                sums.append(term)
+            _wait(True)
+            g_new = grows[:] + sums[1] if write_g else grows[:]
+            if write_w:
+                wrows[:] = (wrows[:] - lr_ref[0] * sums[0]
+                            / jnp.sqrt(g_new + 1e-6))
+            if write_g:
+                grows[:] = g_new
+            _start(False)
+            _wait(False)
+    return _kernel
+
+
+def adagrad_fold_rows(w: jax.Array, g2: jax.Array, sorted_ids: jax.Array,
+                      run_back: jax.Array, sorted_grads: jax.Array, lr,
+                      phase: str = "both", interpret: bool = False
+                      ) -> Tuple[jax.Array, jax.Array]:
+    """word2vec's AdaGrad row update, ``G += sum(g^2)`` then ``w -= lr
+    sum(g) / sqrt(G + 1e-6)`` per touched row, over float32 ``[V, 128]``
+    table and accumulator; what a phase writes is aliased in place.
+
+    ``phase="both"``: the whole update; ``sorted_ids`` ascend (duplicates
+    allowed: the kernel folds them). ``"accumulate"`` then ``"step"``: the
+    same update as two additive passes over ids sorted in slabs of a
+    multiple of 32. An id that is to be dropped is ``>= V``, behind every
+    live one of its slab. ``run_back[p]`` is the number of positions
+    directly before ``p`` that hold ``p``'s id (0 at a run's first).
+    ``sorted_grads`` are the float32 gradients in the ids' order. Returns
+    ``(w, g2)``."""
+    if phase not in ADAGRAD_PHASES:
+        raise ValueError(f"phase must be one of {ADAGRAD_PHASES}; "
+                         f"got {phase!r}")
+    num_rows, d = w.shape
+    group = _FOLD_GROUP_ROWS
+    n = sorted_ids.shape[0]
+    if n == 0:
+        return w, g2
+    pad = (-n) % group
+    ids = sorted_ids.astype(jnp.int32)
+    if pad:
+        ids = jnp.concatenate([ids, jnp.full((pad,), num_rows, jnp.int32)])
+        run_back = jnp.concatenate([run_back, jnp.zeros((pad,), jnp.int32)])
+        sorted_grads = jnp.concatenate(
+            [sorted_grads, jnp.zeros((pad, d), sorted_grads.dtype)])
+    # a lane writes where its live run ends: the next id differs
+    ends = ((ids != jnp.concatenate([ids[1:], ids[-1:] + 1]))
+            & (ids < num_rows)).astype(jnp.int32)
+    live = ends.reshape(-1, group).sum(axis=1)      # a step's written lanes
+    written = [i for i, on in enumerate((phase != "accumulate",
+                                         phase != "step")) if on]
+    tables = (w, g2)
+    block = pl.BlockSpec((group, d), lambda g, *refs: (g, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,      # ids, ends, live, lr
+        grid=((n + pad) // group,),
+        in_specs=[block, block,
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=[pl.BlockSpec(memory_space=pl.ANY)] * len(written),
+        scratch_shapes=[pltpu.VMEM((group, d), jnp.float32),
+                        pltpu.VMEM((group, d), jnp.float32),
+                        pltpu.VMEM((8, d), jnp.float32),
+                        pltpu.SemaphoreType.DMA((2,))],
+    )
+    outs = pl.pallas_call(
+        _make_adagrad_fold_kernel(group, num_rows, phase),
+        out_shape=[jax.ShapeDtypeStruct(tables[i].shape, tables[i].dtype)
+                   for i in written],
+        grid_spec=grid_spec,
+        # inputs: ids(0) ends(1) live(2) lr(3) grads(4) back(5) w(6) g2(7)
+        input_output_aliases={6 + i: o for o, i in enumerate(written)},
+        compiler_params=pltpu.CompilerParams(has_side_effects=True),
+        interpret=interpret,
+    )(ids, ends, live, jnp.asarray(lr, jnp.float32).reshape(1),
+      sorted_grads.astype(jnp.float32),
+      # one value a lane, laid along the lanes' own axis: a full-width plane
+      jnp.broadcast_to(run_back.astype(jnp.int32)[:, None], (n + pad, d)),
+      w, g2)
+    new = list(tables)
+    for o, i in enumerate(written):
+        new[i] = outs[o]
+    return tuple(new)

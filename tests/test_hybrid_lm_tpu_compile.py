@@ -1,5 +1,5 @@
-"""The layer-typed LM's mixers, and the grouped row programs of the device
-form, compile for a v5e at the published widths and
+"""The layer-typed LM's mixers, the grouped row programs of the device
+form and word2vec's fused block program compile for a v5e at the published widths and
 the benchmark's sequence length (no chip: the TPU compiler is installed and
 compiles for a described device; docs/HYBRID_LM.md). What it guards: a slice
 the tiling refuses, a loop the compiler cannot lower, a working set that does
@@ -142,3 +142,34 @@ def test_device_form_group_programs_compile_for_v5e(one_chip, tables, rows,
     # donated: the tables and their accumulators are updated in place
     assert pushed.memory_analysis().alias_size_in_bytes >= \
         2 * tables * rows * width * 4
+
+
+def test_word2vec_fused_block_program_compiles_for_v5e(one_chip):
+    """``w2v_train``'s block program at the cell's own size (four tables of
+    4,000,000 x 128 float32, chunks of 8,192 pairs, K=5) with the chunk
+    loop's row updates on the Pallas plane (ISSUE 31): Mosaic takes the
+    three kernels (``both`` for w_in's 8,192 ids, ``accumulate`` and
+    ``step`` for w_out's 49,152), the tables are updated in place, and no
+    table-sized copy sits in the loop (2 GB a chunk each would)."""
+    from multiverso_tpu.models.word2vec.model import (_make_block_fn,
+                                                      _on_row_kernel)
+    V, D, S, L = 4_000_000, 128, 512, 512
+
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    fn = _on_row_kernel(_make_block_fn(window=5, negative=5, chunk=8192,
+                                       adagrad=True, compact=True), False)
+    compiled = jax.jit(fn, donate_argnums=(0, 1, 2, 3)).lower(
+        *[spec((V, D))] * 4, spec((1 << 20,), jnp.int32), spec((V,)),
+        spec((S, L), jnp.int32), spec((S,), jnp.int32),
+        spec((2,), jnp.uint32), spec(())).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 3
+    assert not [line for line in text.splitlines()
+                if " copy(" in line and f"f32[{V},{D}]" in line]
+    stats = compiled.memory_analysis()
+    assert stats.alias_size_in_bytes >= 4 * V * D * 4
+    # a block's pair streams and a chunk's sorted planes (1.37 GB, 9 MB
+    # under the XLA plane's program), and no table (2.05 GB) beside them
+    assert stats.temp_size_in_bytes < 1.5e9, stats
