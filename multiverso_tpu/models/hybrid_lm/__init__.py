@@ -3,7 +3,7 @@ layer from a pattern) trained through the parameter-server plane: see
 docs/HYBRID_LM.md."""
 
 from multiverso_tpu.models.hybrid_lm import rope
-from multiverso_tpu.models.hybrid_lm.config import (ATTENTION, DENSE,
+from multiverso_tpu.models.hybrid_lm.config import (ATTENTION, DENSE, EVA,
                                                     EXPERTS, LATENT, MAMBA,
                                                     HybridLMConfig)
 from multiverso_tpu.models.hybrid_lm.model import (APPLY_PROGRAM,
@@ -16,7 +16,7 @@ from multiverso_tpu.models.hybrid_lm.model import (APPLY_PROGRAM,
                                                    rmsnorm)
 
 __all__ = ["HybridLMConfig", "HybridLM", "MAMBA", "EXPERTS", "ATTENTION",
-           "LATENT", "DENSE",
+           "LATENT", "DENSE", "EVA",
            "DELTA_PROGRAM", "APPLY_PROGRAM", "dense_param_count",
            "forward_hidden", "init_buffers", "init_params", "layer_forward",
            "make_loss", "pack_batch", "param_shapes", "rmsnorm", "rope"]

@@ -8,13 +8,16 @@ import json
 from typing import Any, Dict, Optional, Tuple
 
 __all__ = ["HybridLMConfig", "MAMBA", "EXPERTS", "ATTENTION", "LATENT",
-           "DENSE"]
+           "DENSE", "EVA"]
 
-MAMBA, EXPERTS, ATTENTION, LATENT, DENSE = "M", "E", "*", "L", "D"
+MAMBA, EXPERTS, ATTENTION, LATENT, DENSE, EVA = "M", "E", "*", "L", "D", "V"
 
 #: Keys a file gives under the published ``config.json``'s own names: those
 #: every file has, those a kind of block needs (a file has the keys of the
-#: blocks its pattern runs), and those with a default.
+#: blocks its pattern runs), and those with a default. ``file key:field``
+#: where the dataclass calls it otherwise: two published families use
+#: ``chunk_size``, Mamba-2 for its scan's chunk and EVA for the positions a
+#: summary pools, and a file's key is read by the kind of block it runs.
 _ALWAYS_KEYS = ("hidden_size", "vocab_size")
 _KEYS_OF_KIND = {
     MAMBA: ("mamba_num_heads", "mamba_head_dim", "ssm_state_size", "n_groups",
@@ -26,12 +29,16 @@ _KEYS_OF_KIND = {
     DENSE: ("intermediate_size",),
     EXPERTS: ("num_experts_per_tok", "moe_intermediate_size",
               "routed_scaling_factor", "norm_topk_prob"),
+    EVA: ("num_attention_heads", "window_size", "chunk_size:eva_chunk_size",
+          "rope_theta"),
 }
 _OPTIONAL_KEYS = ("moe_shared_expert_intermediate_size", "scoring_func",
-                  "hidden_act", "aux_loss_alpha")
+                  "hidden_act", "aux_loss_alpha", "num_pred_heads",
+                  "norm_add_unit_offset")
 #: Keys of this repo, optional in a file.
 _OWN_KEYS = ("learning_rate", "adagrad_step", "init_std", "attn_block",
-             "moe_block", "loss_block", "row_bucket", "comm_policy")
+             "moe_block", "loss_block", "ffn_slab", "row_bucket",
+             "comm_policy")
 
 
 @dataclasses.dataclass
@@ -42,11 +49,17 @@ class HybridLMConfig:
     hidden_size: int = 64
     vocab_size: int = 64
     #: One pre-norm residual block per letter: ``M`` Mamba-2, ``*`` grouped-
-    #: query attention, ``L`` latent attention, ``D`` a dense gated
-    #: feed-forward, ``E`` an expert block. A layer of two blocks (attention,
-    #: then a feed-forward) is two letters.
+    #: query attention, ``L`` latent attention, ``V`` EVA (exact keys inside
+    #: a window, pooled chunk summaries of every earlier window), ``D`` a
+    #: dense gated feed-forward, ``E`` an expert block. A layer of two blocks
+    #: (attention, then a feed-forward) is two letters.
     pattern: str = "MEM*E"
     norm_eps: float = 1e-5
+    #: Every RMSNorm scales by ``1 + w`` (``w`` drawn at zero), not by ``w``.
+    norm_add_unit_offset: bool = False
+    #: Targets a position: head ``h`` of the ``num_pred_heads * vocab_size``
+    #: wide output predicts the token ``1 + h`` ahead.
+    num_pred_heads: int = 1
     # -- Mamba-2 ------------------------------------------------------------
     mamba_num_heads: int = 2
     mamba_head_dim: int = 16
@@ -69,6 +82,12 @@ class HybridLMConfig:
     rope_theta: float = 10000.0
     #: The published ``rope_scaling`` group (``type: yarn``), or None.
     rope_scaling: Optional[Dict[str, Any]] = None
+    # -- EVA: rotary over the whole head (``num_attention_heads`` heads of
+    # ``head_dim``, ``rope_theta``, no scaling) ---------------------------------
+    #: Positions a query sees key by key: its own aligned window's.
+    window_size: int = 8
+    #: Positions one summary pools (the published ``chunk_size``).
+    eva_chunk_size: int = 2
     # -- dense feed-forward ---------------------------------------------------
     intermediate_size: int = 128
     # -- expert block ---------------------------------------------------------
@@ -101,8 +120,12 @@ class HybridLMConfig:
     attn_block: int = 512
     moe_block: int = 512
     loss_block: int = 2048
+    #: A dense feed-forward works on at most this many positions at a time
+    #: (token by token, any cut is exact); a sequence no longer is one slab.
+    ffn_slab: int = 8192
     #: Pulled-row counts are rounded up to a multiple of this, so that a
-    #: step's distinct-id count picks one of few compiled shapes.
+    #: step's distinct-id count picks one of few compiled shapes. Never more
+    #: than the vocabulary: a table is not asked for more rows than it has.
     row_bucket: int = 1024
     table_name: str = "hybrid_lm_emb"
     comm_policy: str = "ps"
@@ -110,6 +133,9 @@ class HybridLMConfig:
     source: str = ""
     reduced: Tuple[str, ...] = ()
     assumed: Dict[str, Any] = dataclasses.field(default_factory=dict)
+
+    def __post_init__(self) -> None:
+        self.row_bucket = min(self.row_bucket, self.vocab_size)
 
     # -- derived widths -------------------------------------------------------
     @property
@@ -154,9 +180,13 @@ class HybridLMConfig:
     def attention_blocks(self) -> int:
         return sum(k in (ATTENTION, LATENT) for k in self.pattern)
 
+    def eva_blocks(self) -> int:
+        return self.pattern.count(EVA)
+
     def validate(self) -> None:
         from multiverso_tpu.utils.log import check
-        check(set(self.pattern) <= {MAMBA, EXPERTS, ATTENTION, LATENT, DENSE}
+        check(set(self.pattern) <= {MAMBA, EXPERTS, ATTENTION, LATENT, DENSE,
+                                    EVA}
               and self.pattern, f"bad layer pattern {self.pattern!r}")
         check(self.mamba_num_heads % self.n_groups == 0,
               "mamba heads must divide into n_groups")
@@ -173,6 +203,14 @@ class HybridLMConfig:
         check(self.hidden_act in ("relu2", "silu"),
               f"unknown hidden_act {self.hidden_act!r}")
         check(self.qk_rope_head_dim % 2 == 0, "rotary width must be even")
+        if EVA in self.pattern:
+            check(self.head_dim % 2 == 0, "rotary width must be even")
+            check(self.window_size % self.eva_chunk_size == 0,
+                  "an EVA window is a whole number of chunks")
+            check(self.window_size % min(self.attn_block, self.window_size)
+                  == 0, "an EVA window is a whole number of attention blocks")
+        check(self.num_pred_heads >= 1 and self.ffn_slab >= 1,
+              "num_pred_heads and ffn_slab are at least one")
         check(self.rope_scaling is None
               or self.rope_scaling.get("type") == "yarn",
               f"unknown rope_scaling {self.rope_scaling!r}")
@@ -181,15 +219,18 @@ class HybridLMConfig:
     @classmethod
     def from_dict(cls, d: Dict[str, Any], **overrides) -> "HybridLMConfig":
         """From the published keys. ``n_routed_experts`` counts the experts
-        HELD (``held_experts`` names them, default the first ones) and
-        ``published.n_routed_experts`` the router's width. The pattern is
-        the first ``num_hidden_layers`` of ``hybrid_override_pattern``
-        where the file has one; else every layer is two blocks, attention
-        (latent where the file has a ``kv_lora_rank``) and a feed-forward:
-        experts from layer ``first_k_dense_replace`` on at every
-        ``moe_layer_freq``-th layer, dense before and between."""
+        HELD (``held_experts`` names them, default the first ones; a file
+        without the key holds none) and ``published.n_routed_experts`` the
+        router's width. The pattern is the first ``num_hidden_layers`` of
+        ``hybrid_override_pattern`` where the file has one; else every layer
+        is two blocks, attention (latent where the file has a
+        ``kv_lora_rank``, EVA where its ``attention_class`` is ``eva``) and a
+        feed-forward: experts, where the file has any, from layer
+        ``first_k_dense_replace`` on at every ``moe_layer_freq``-th layer,
+        dense before and between. A file that gives no ``head_dim`` has heads
+        of ``hidden_size / num_attention_heads``."""
         published = d.get("published", {})
-        n_held = int(d["n_routed_experts"])
+        n_held = int(d.get("n_routed_experts", 0))
         router = int(published.get("n_routed_experts", n_held))
         held = tuple(d.get("held_experts", range(n_held)))
         if len(held) != n_held:
@@ -204,16 +245,34 @@ class HybridLMConfig:
             if d.get("aux_loss_alpha", 0) and not d.get("seq_aux", True):
                 raise ValueError("only the sequence-wise balance loss "
                                  "(seq_aux) is implemented")
-            first, freq = d.get("first_k_dense_replace", 0), \
-                d.get("moe_layer_freq", 1)
+            eva = d.get("attention_class")
+            if eva not in (None, "eva"):
+                raise ValueError(f"unknown attention_class {eva!r}")
+            if eva and d.get("num_key_value_heads",
+                             d["num_attention_heads"]) \
+                    != d["num_attention_heads"]:
+                raise ValueError("EVA with grouped key-value heads is not "
+                                 "implemented")
+            first = d.get("first_k_dense_replace", 0) if n_held \
+                else d["num_hidden_layers"]
+            freq = d.get("moe_layer_freq", 1)
+            mixer = LATENT if "kv_lora_rank" in d else \
+                EVA if eva else ATTENTION
             pattern = "".join(
-                (LATENT if "kv_lora_rank" in d else ATTENTION)
-                + (EXPERTS if i >= first and i % freq == 0 else DENSE)
+                mixer + (EXPERTS if i >= first and i % freq == 0 else DENSE)
                 for i in range(d["num_hidden_layers"]))
-        needed = _ALWAYS_KEYS + tuple(
-            key for kind in sorted(set(pattern))
-            for key in _KEYS_OF_KIND.get(kind, ()))
-        kw = {key: d[key] for key in needed}
+        kinds = sorted(set(pattern))
+        if MAMBA in kinds and EVA in kinds:
+            raise ValueError("a file's chunk_size is Mamba-2's or EVA's, "
+                             "not both")
+        kw = {key: d[key] for key in _ALWAYS_KEYS}
+        for kind in kinds:
+            for key in _KEYS_OF_KIND.get(kind, ()):
+                key, _, field = key.partition(":")
+                kw[field or key] = d[key]
+        if EVA in kinds:
+            kw["head_dim"] = d.get("head_dim") or \
+                d["hidden_size"] // d["num_attention_heads"]
         kw.update({key: d[key] for key in _OPTIONAL_KEYS if key in d})
         kw.setdefault("moe_shared_expert_intermediate_size",
                       d.get("n_shared_experts", 0)
@@ -223,10 +282,11 @@ class HybridLMConfig:
             pattern=pattern,
             norm_eps=d.get("layer_norm_epsilon", d.get(
                 "rms_norm_eps", d.get("norm_eps", 1e-5))),
-            rope_scaling=d.get("rope_scaling"),
-            router_experts=router, held=held, source=d.get("source", ""),
+            rope_scaling=d.get("rope_scaling"), source=d.get("source", ""),
             reduced=tuple(d.get("reduced", ())),
             assumed=dict(d.get("assumed", {})))
+        if "n_routed_experts" in d:
+            kw.update(router_experts=router, held=held)
         kw.update({key: d[key] for key in _OWN_KEYS if key in d})
         kw.update(overrides)
         cfg = cls(**kw)

@@ -3,15 +3,19 @@
 Every block is ``u <- u + mixer(RMSNorm_w(u))`` with the mixer chosen per
 block from a pattern: ``M`` a Mamba-2 state-space mixer
 (:mod:`.mamba2`), ``*`` causal grouped-query attention without positions,
-``L`` latent attention with a decoupled rotary key (:mod:`.attention`,
-:mod:`.rope`), ``D`` a dense gated feed-forward, ``E`` this chip's share
+``L`` latent attention with a decoupled rotary key, ``V`` EVA: exact keys
+inside a window and pooled chunk summaries of every earlier one
+(:mod:`.attention`, :mod:`.rope`), ``D`` a dense gated feed-forward, ``E``
+this chip's share
 of a top-k expert layer with shared experts (:func:`~multiverso_tpu.
 parallel.expert.held_topk_moe`: sigmoid or softmax router, ``relu2`` or
 gated experts, by the configuration's published keys). A layer of two
 blocks is two letters. Then a final RMSNorm and an untied head; the loss
-is next-token cross-entropy over the vocabulary slice, taken in blocks of
-tokens so that the logits of a step never exist at once, plus the expert
-blocks' sequence-wise balance loss where the configuration weighs one.
+is next-token cross-entropy over the vocabulary slice (with
+``num_pred_heads`` > 1 the head is that many vocabularies wide and head
+``h`` predicts the token ``1 + h`` ahead), taken in blocks of tokens so that
+the logits of a step never exist at once, plus the expert blocks'
+sequence-wise balance loss where the configuration weighs one.
 
 Trained as DLRM is (models/dlrm/model.py), by the same hybrid step
 (:class:`~multiverso_tpu.parallel.hybrid_step.HybridStep`; docs/DESIGN.md
@@ -46,8 +50,8 @@ import multiverso_tpu as mv
 from multiverso_tpu.core.options import AddOption, MatrixTableOption
 from multiverso_tpu.core.updater import get_updater
 from multiverso_tpu.models.hybrid_lm.attention import (
-    attention_mixer, latent_attention_mixer)
-from multiverso_tpu.models.hybrid_lm.config import (ATTENTION, DENSE,
+    in_blocks, attention_mixer, eva_mixer, latent_attention_mixer)
+from multiverso_tpu.models.hybrid_lm.config import (ATTENTION, DENSE, EVA,
                                                     EXPERTS, LATENT, MAMBA,
                                                     HybridLMConfig)
 from multiverso_tpu.models.hybrid_lm.mamba2 import mamba2_mixer
@@ -87,6 +91,11 @@ def _layer_shapes(cfg: HybridLMConfig, kind: str) -> Dict[str, tuple]:
                 "wkva": (d, r + cfg.qk_rope_head_dim), "kv_norm": (r,),
                 "wkvb": (r, h * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
                 "wo": (h * cfg.v_head_dim, d)}
+    if kind == EVA:
+        h = (cfg.num_attention_heads, cfg.head_dim)
+        return {"norm": (d,), "wq": (d, cfg.q_dim), "wk": (d, cfg.q_dim),
+                "wv": (d, cfg.q_dim), "adaptive_phi": h,
+                "adaptive_mu_k": h, "wo": (cfg.q_dim, d)}
     if kind == DENSE:
         f = cfg.intermediate_size
         return {"norm": (d,), "ffn_gate": (d, f), "ffn_up": (d, f),
@@ -104,7 +113,7 @@ def _layer_shapes(cfg: HybridLMConfig, kind: str) -> Dict[str, tuple]:
 def param_shapes(cfg: HybridLMConfig) -> dict:
     return {"layers": [_layer_shapes(cfg, k) for k in cfg.pattern],
             "final_norm": (cfg.hidden_size,),
-            "head": (cfg.hidden_size, cfg.vocab_size)}
+            "head": (cfg.hidden_size, cfg.num_pred_heads * cfg.vocab_size)}
 
 
 def dense_param_count(cfg: HybridLMConfig) -> int:
@@ -120,15 +129,22 @@ _OUT_PROJECTIONS = ("out_proj", "wo", "w_down", "s_down", "ffn_down")
 def init_params(cfg: HybridLMConfig) -> dict:
     """Deterministic from ``cfg.seed`` (so ``ps`` and ``local`` start
     bitwise alike): matrices normal ``init_std``, projections back into
-    the stream divided by ``sqrt(layers)``, norms and ``D`` one, ``A`` in
-    [1, 16], ``dt`` log-uniform in ``[time_step_min, time_step_max]``
-    through the inverse softplus, as Mamba-2 draws them."""
+    the stream divided by ``sqrt(layers)``, norms and ``D`` one (a norm
+    with the unit offset: zero), ``A`` in [1, 16], ``dt`` log-uniform in
+    ``[time_step_min, time_step_max]`` through the inverse softplus, as
+    Mamba-2 draws them; EVA's ``phi`` and ``mu`` normal, clamped to [-1, 1],
+    times ``head_dim ** -0.5``."""
     rng = np.random.default_rng(cfg.seed)
     depth = math.sqrt(len(cfg.pattern))
 
     def leaf(name, shape):
+        if name in ("norm", "final_norm") and cfg.norm_add_unit_offset:
+            return np.zeros(shape, np.float32)
         if name in ("norm", "gnorm", "kv_norm", "D", "final_norm"):
             return np.ones(shape, np.float32)
+        if name in ("adaptive_phi", "adaptive_mu_k"):
+            return np.clip(rng.standard_normal(shape, dtype=np.float32),
+                           -1.0, 1.0) * np.float32(cfg.head_dim ** -0.5)
         if name == "A_log":
             return np.log(rng.uniform(1.0, 16.0, shape)).astype(np.float32)
         if name == "dt_bias":
@@ -174,6 +190,7 @@ _SEQUENCE_MIXERS = {
     MAMBA: (mamba2_mixer, "lm_mamba2"),
     ATTENTION: (attention_mixer, "lm_attention"),
     LATENT: (latent_attention_mixer, "lm_mla"),
+    EVA: (eva_mixer, "lm_eva"),
     DENSE: (dense_ffn_mixer, "lm_dense_ffn"),
 }
 
@@ -187,21 +204,30 @@ def layer_forward(kind: str, p: dict, bias, u: jax.Array,
     hidden] input. A Mamba-2 or attention block mixes inside a sequence
     only, and a dense feed-forward token by token, so each runs (and is
     rematerialised) one sequence at a time, and its working set is a
-    sequence's and not the batch's."""
+    sequence's and not the batch's; the feed-forward cuts a sequence longer
+    than ``cfg.ffn_slab`` into slabs of that many positions."""
     keep = jax.checkpoint if remat else (lambda fn: fn)
     if kind in _SEQUENCE_MIXERS:
         mixer, scope = _SEQUENCE_MIXERS[kind]
+        offset = cfg.norm_add_unit_offset
 
         def one_sequence(seq):
-            n = rmsnorm(seq[None], p["norm"], cfg.norm_eps)
+            n = rmsnorm(seq[None], p["norm"], cfg.norm_eps, offset)
             return seq + mixer(p, n, cfg)[0]
 
         with jax.named_scope(scope):
+            if kind == DENSE and u.shape[1] > cfg.ffn_slab:
+                slabs = in_blocks(u, cfg.ffn_slab)
+                out = jnp.stack([keep(one_sequence)(slab) for slab in
+                                 slabs.reshape((-1,) + slabs.shape[2:])])
+                return out.reshape(u.shape[0], -1, u.shape[2])[
+                    :, :u.shape[1]], None
             return jax.lax.map(keep(one_sequence), u), None
 
     def tokens(p, u):
         bsz, s, d = u.shape
-        n = rmsnorm(u, p["norm"], cfg.norm_eps).reshape(bsz * s, d)
+        n = rmsnorm(u, p["norm"], cfg.norm_eps,
+                    cfg.norm_add_unit_offset).reshape(bsz * s, d)
         # All positional: a wrapper ``(n, router, bias, w_up, w_down,
         # *rest)`` (the benchmark's controls) passes the rest on.
         y, counts, *balance = held_topk_moe(
@@ -235,31 +261,41 @@ def forward_hidden(params: dict, buffers: list, u: jax.Array,
 
 def blocked_cross_entropy(u: jax.Array, norm_w: jax.Array, head: jax.Array,
                           targets: jax.Array, mask: jax.Array, eps: float,
-                          block: int) -> jax.Array:
+                          block: int, unit_offset: bool = False):
     """Mean over the unmasked positions of ``-log softmax(RMSNorm_w(u)
     W_head)[target]``: ``u`` [T, hidden], in blocks of ``block`` tokens,
-    each block's logits recomputed in the backward pass."""
+    each block's logits recomputed in the backward pass. With ``targets``
+    and ``mask`` [T, H] the head is ``H`` vocabularies wide, one softmax a
+    prediction head: the mean is over every unmasked (position, head), and
+    each head's own mean is returned beside it, [H]."""
     t = u.shape[0]
     blk = min(block, t)
     pad = (-t) % blk
+    heads = targets.shape[1:]
     if pad:
         u = jnp.pad(u, ((0, pad), (0, 0)))
-        targets, mask = jnp.pad(targets, (0, pad)), jnp.pad(mask, (0, pad))
+        targets, mask = (jnp.pad(x, ((0, pad),) + ((0, 0),) * len(heads))
+                         for x in (targets, mask))
     nb = (t + pad) // blk
 
     @jax.checkpoint
     def block_loss(ub, tb, mb):
-        logits = (rmsnorm(ub, norm_w, eps) @ head).astype(jnp.float32)
-        picked = jnp.take_along_axis(logits, tb[:, None], axis=-1)[:, 0]
-        return jnp.sum((jax.nn.logsumexp(logits, axis=-1) - picked) * mb)
+        logits = (rmsnorm(ub, norm_w, eps, unit_offset) @ head).astype(
+            jnp.float32).reshape((blk,) + heads + (-1,))
+        picked = jnp.take_along_axis(logits, tb[..., None], axis=-1)[..., 0]
+        return jnp.sum((jax.nn.logsumexp(logits, axis=-1) - picked) * mb,
+                       axis=0)
 
     def add(total, xs):
         return total + block_loss(*xs), None
 
-    total, _ = jax.lax.scan(add, jnp.float32(0.0), (
-        u.reshape(nb, blk, -1), targets.reshape(nb, blk),
-        mask.reshape(nb, blk)))
-    return total / jnp.maximum(jnp.sum(mask), 1.0)
+    total, _ = jax.lax.scan(add, jnp.zeros(heads, jnp.float32), (
+        u.reshape(nb, blk, -1), targets.reshape((nb, blk) + heads),
+        mask.reshape((nb, blk) + heads)))
+    if not heads:
+        return total / jnp.maximum(jnp.sum(mask), 1.0)
+    return (jnp.sum(total) / jnp.maximum(jnp.sum(mask), 1.0),
+            total / jnp.maximum(jnp.sum(mask, axis=0), 1.0))
 
 
 def make_loss(cfg: HybridLMConfig, remat: bool = True):
@@ -267,35 +303,51 @@ def make_loss(cfg: HybridLMConfig, remat: bool = True):
     mask [B, S]) -> (loss, counts)``: ``rows[where]`` is the embedded
     input (``rows`` the pulled rows of the step's distinct ids). Where the
     configuration weighs a balance loss (``cfg.balanced``) the loss carries
-    it and the second result is ``(counts, balance loss)``."""
+    it and the second result is ``(counts, balance loss)``; with
+    ``num_pred_heads`` > 1 (``targets``, ``mask`` [B, S, heads]) each
+    head's own loss [heads] comes last in it."""
     def loss_fn(params, rows, buffers, where, targets, mask):
         u = jnp.take(rows, where, axis=0)
         u, counts, *balance = forward_hidden(params, buffers, u, cfg, remat)
         loss = blocked_cross_entropy(
             u.reshape(-1, cfg.hidden_size), params["final_norm"],
-            params["head"], targets.reshape(-1), mask.reshape(-1),
-            cfg.norm_eps, cfg.loss_block)
+            params["head"], targets.reshape((-1,) + targets.shape[2:]),
+            mask.reshape((-1,) + mask.shape[2:]), cfg.norm_eps,
+            cfg.loss_block, cfg.norm_add_unit_offset)
+        aux = (counts,)
         if balance:
-            return loss + balance[0], (counts, balance[0])
-        return loss, counts
+            aux += (balance[0],)
+        if cfg.num_pred_heads > 1:
+            loss, per_head = loss
+            aux += (per_head,)
+        loss = loss + balance[0] if balance else loss
+        return loss, aux if len(aux) > 1 else counts
 
     return loss_fn
 
 
-def pack_batch(tokens: np.ndarray, bucket: int, min_rows: int = 0):
+def pack_batch(tokens: np.ndarray, bucket: int, min_rows: int = 0,
+               heads: int = 1):
     """Host side of a step: ``tokens`` [B, S] -> (ids [n] the distinct
     token ids padded to a multiple of ``bucket`` with repeats of the first,
     whose deltas are zero; distinct count; where [B, S] each position's row
     among ``ids``; targets [B, S] the next token; mask [B, S] 0 at each
-    sequence's last position)."""
+    sequence's last position). With ``heads`` > 1, targets and mask are
+    [B, S, heads]: head ``h``'s target is the token ``1 + h`` ahead, masked
+    at the sequence's last ``1 + h`` positions."""
     tokens = np.asarray(tokens, np.int32)
     ids, where = np.unique(tokens, return_inverse=True)
     n = len(ids)
     cap = max(-(-n // bucket) * bucket, min_rows)
     ids = np.concatenate([ids, np.full(cap - n, ids[0], ids.dtype)])
-    targets = np.concatenate([tokens[:, 1:], tokens[:, :1]], axis=1)
-    mask = np.ones(tokens.shape, np.float32)
-    mask[:, -1] = 0.0
+    targets = np.stack([np.roll(tokens, -(1 + h), axis=1)
+                        for h in range(heads)], axis=-1)
+    # [S, heads]: position t has a target 1 + h ahead while t + 1 + h < S
+    mask = np.broadcast_to(
+        np.add.outer(np.arange(tokens.shape[1]), np.arange(heads) + 1)
+        < tokens.shape[1], targets.shape).astype(np.float32)
+    if heads == 1:
+        targets, mask = targets[..., 0], mask[..., 0]
     return (ids.astype(np.int32), n,
             where.reshape(tokens.shape).astype(np.int32), targets, mask)
 
@@ -347,6 +399,8 @@ class HybridLM:
         self.min_rows = 0
         self.last_counts = np.zeros((len(cfg.expert_layers()),
                                      len(cfg.held)), np.int64)
+        #: Each prediction head's own loss in the last step.
+        self.last_head_losses = np.zeros(cfg.num_pred_heads, np.float32)
 
         # Uniform of the parameters' standard deviation: the table's own
         # random_init draws uniformly.
@@ -406,13 +460,17 @@ class HybridLM:
         Returns the loss once the device has finished the step."""
         with span("lm.step", tokens=int(tokens.size)):
             ids, distinct, where, targets, mask = pack_batch(
-                tokens, self.cfg.row_bucket, self.min_rows)
-            loss, counts = self._hybrid(ids, self.buffers, where, targets,
-                                        mask, rows=distinct)
+                tokens, self.cfg.row_bucket, self.min_rows,
+                self.cfg.num_pred_heads)
+            loss, aux = self._hybrid(ids, self.buffers, where, targets,
+                                     mask, rows=distinct)
             loss = float(loss)
+            # make_loss's order: counts, the balance term, the heads' losses
+            counts, *extra = aux if isinstance(aux, tuple) else (aux,)
             if self.cfg.balanced:
-                counts, balance = counts
-                gauge("lm.moe.balance_loss").set(float(balance))
+                gauge("lm.moe.balance_loss").set(float(extra.pop(0)))
+            if extra:
+                self.last_head_losses = np.asarray(extra.pop(0))
             self.last_counts = np.asarray(counts, np.int64)
         self.steps += 1
         self._count(tokens, distinct)
@@ -421,10 +479,24 @@ class HybridLM:
     def _count(self, tokens: np.ndarray, distinct: int) -> None:
         counter("lm.tokens").inc(int(tokens.size))
         counter("lm.rows_pulled").inc(int(distinct))
-        # Causal query-key pairs, summed over the attention blocks.
+        # Causal query-key pairs, summed over the attention blocks; an EVA
+        # block's are those inside its windows, and beside them every
+        # (query, summary of a chunk of an earlier window).
         seqs, length = tokens.shape
-        counter("lm.attn.pairs").inc(
-            self.cfg.attention_blocks() * seqs * length * (length + 1) // 2)
+        cfg = self.cfg
+        pairs = cfg.attention_blocks() * seqs * length * (length + 1) // 2
+        if cfg.eva_blocks():
+            window, chunk = cfg.window_size, cfg.eva_chunk_size
+            whole, rest = divmod(length, window)
+            pairs += cfg.eva_blocks() * seqs * (
+                whole * window * (window + 1) // 2 + rest * (rest + 1) // 2)
+            counter("lm.eva.summary_pairs").inc(
+                cfg.eva_blocks() * seqs * (window // chunk) * (
+                    window * whole * (whole - 1) // 2 + rest * whole))
+            counter("lm.eva.chunks").inc(
+                cfg.eva_blocks() * seqs * (-(-length // chunk))
+                * (length > window))
+        counter("lm.attn.pairs").inc(pairs)
         for layer, per_expert in zip(self.cfg.expert_layers(),
                                      self.last_counts):
             # One pair per expert layer of the pattern: bounded.

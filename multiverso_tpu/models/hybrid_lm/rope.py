@@ -1,7 +1,11 @@
 """Rotary position embedding with YaRN's stretched frequencies.
 
-A rotary part of width ``d`` turns its pairs ``(2i, 2i+1)`` by the angle
-``position * inv_freq[i]``. Plain RoPE has ``inv_freq_i = base ** (-2i/d)``.
+A rotary part of width ``d`` turns its pairs by the angle ``position *
+inv_freq[i]``: pair ``i`` is ``(2i, 2i+1)`` (the interleaved layout, as
+``deepseek_v2`` stores a rotary key) or ``(i, i + d/2)`` (the half layout,
+``rotate_half`` of a whole head, as ``evabyte`` and most published code have
+it; :func:`apply_rope`'s ``half``). The two differ by a fixed permutation of
+a head's columns. Plain RoPE has ``inv_freq_i = base ** (-2i/d)``.
 YaRN (``rope_scaling.type == "yarn"``) divides the slow frequencies by
 ``factor`` and leaves the fast ones, with a linear ramp between the pair
 that makes ``beta_fast`` turns over the original context and the pair that
@@ -66,10 +70,18 @@ def rope_tables(length: int, dim: int, base: float,
     return jnp.cos(angle) * on_tables, jnp.sin(angle) * on_tables
 
 
-def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
-    """``x`` [..., S, heads, dim]: pair ``(2i, 2i+1)`` of every head turned
-    by position's angle ``i``; the layout is kept."""
-    pairs = x.reshape(x.shape[:-1] + (x.shape[-1] // 2, 2))
-    a, b = pairs[..., 0], pairs[..., 1]
+def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array,
+               half: bool = False) -> jax.Array:
+    """``x`` [..., S, heads, dim]: pair ``i`` of every head, ``(2i, 2i+1)``
+    or with ``half`` ``(i, i + dim/2)``, turned by position's angle ``i``;
+    the layout is kept."""
+    if half:
+        a, b = jnp.split(x, 2, axis=-1)
+    else:
+        pairs = x.reshape(x.shape[:-1] + (x.shape[-1] // 2, 2))
+        a, b = pairs[..., 0], pairs[..., 1]
     c, s = cos[:, None, :].astype(x.dtype), sin[:, None, :].astype(x.dtype)
-    return jnp.stack([a * c - b * s, a * s + b * c], axis=-1).reshape(x.shape)
+    turned = [a * c - b * s, a * s + b * c]
+    if half:
+        return jnp.concatenate(turned, axis=-1)
+    return jnp.stack(turned, axis=-1).reshape(x.shape)

@@ -293,7 +293,8 @@ def policy_for_option(explicit: Optional[str], shape: Sequence[int],
 
 
 # -- plane helpers -----------------------------------------------------------
-def build_dense_sync(mesh, axis: Optional[str] = None):
+def build_dense_sync(mesh, axis: Optional[str] = None,
+                     donate: bool = False):
     """One jitted in-graph allreduce dispatch for a small replicated dense
     operand: ``psum`` over ``axis`` normalized by the axis size, so the
     value is preserved (exactly, for power-of-two axis sizes) while the
@@ -305,20 +306,28 @@ def build_dense_sync(mesh, axis: Optional[str] = None):
     dispatch (there is nothing to reduce over).
 
     Build ONCE per model (compiles one executable); dispatch per block.
+    With ``donate`` the result takes its operand's buffer (the caller gives
+    its only reference up): a merge then allocates nothing, so a step that
+    merges many large leaves while its delta program's temporaries are
+    still alive has one peak whatever the host's and the device's timing
+    (the hybrid step; ``peak_hbm_gb`` read 12.09 to 12.37 GB by the run
+    without it at 180 MB leaves, PERF.md 6, PR 32).
     """
     from multiverso_tpu.parallel.mesh import SERVER_AXIS
     from jax.sharding import PartitionSpec as P
 
     axis = axis or SERVER_AXIS
     n_axis = mesh.shape.get(axis, 1) if mesh is not None else 1
+    donated = (0,) if donate else ()
     if mesh is None or n_axis <= 1:
-        return jax.jit(lambda x: x + 0.0)
+        return jax.jit(lambda x: x + 0.0, donate_argnums=donated)
 
     def _sync(v):
         return jax.lax.psum(v, axis) / n_axis
 
     return jax.jit(jax.shard_map(_sync, mesh=mesh, in_specs=P(),
-                                 out_specs=P(), check_vma=False))
+                                 out_specs=P(), check_vma=False),
+                   donate_argnums=donated)
 
 
 def model_average_arrays(arrays: Sequence[np.ndarray]) -> List[np.ndarray]:

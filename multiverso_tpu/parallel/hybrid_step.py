@@ -50,8 +50,9 @@ class HybridStep:
         self.delta = jax.jit(delta_fn)  # graftlint: disable=missing-donation
         self.apply = jax.jit(apply_fn,
                              donate_argnums=tuple(range(len(dense))))
-        # Dispatched once a leaf between the delta and apply programs.
-        self.dense_sync = cp.build_dense_sync(dp_mesh, dp_axis)
+        # Dispatched once a leaf between the delta and apply programs; the
+        # merged leaf takes the unmerged one's buffer.
+        self.dense_sync = cp.build_dense_sync(dp_mesh, dp_axis, donate=True)
         self._model, self._dense, self._prefix = model, tuple(dense), prefix
         self._pull, self._push = pull, push
         self._apply_args, self._grad_bytes = tuple(apply_args), grad_bytes
@@ -84,8 +85,8 @@ class HybridStep:
                 # The program holds its input: without this name the pulled
                 # rows go when it ends, not when the step does.
                 del rows
-                # Leaf by leaf, the unmerged delta dropped as soon as its
-                # merge is launched: at most one leaf is held twice.
+                # Leaf by leaf, each merge handed the only reference to its
+                # unmerged delta (donated): no leaf is ever held twice.
                 leaves, treedef = jax.tree_util.tree_flatten(deltas)
                 del deltas
                 merged = []
