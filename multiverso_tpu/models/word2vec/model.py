@@ -83,8 +83,9 @@ class Word2VecConfig:
     #   "in_graph"       — one jitted block program, ONE launch per
     #                      block; the chunk loop is a lax.fori_loop. On
     #                      tables the row kernel serves (float32, 128
-    #                      columns, one shard, AdaGrad: row_kernel_selected)
-    #                      a chunk's row updates are Pallas kernels over
+    #                      columns, AdaGrad, on one device or in row ranges
+    #                      over one mesh axis: _PlacedStep.row_kernel) a
+    #                      chunk's row updates are Pallas kernels over
     #                      sorted ids; elsewhere XLA's scatter-adds, which
     #                      write a row of a big table in 71-86 ns and are
     #                      then all of a chunk (PERF.md 5-6, PR 31);
@@ -257,36 +258,65 @@ def row_kernel_selected(w, adagrad: bool, one_shard: bool) -> bool:
     """Whether a table's AdaGrad row update runs as the Pallas row kernel
     (``ops/pallas_rows.adagrad_fold_rows``): ``ServerStore``'s rule
     (``core/table.pallas_rows_eligible``: 2-D float32, exactly 128 columns,
-    one shard), AdaGrad on. A trace shows shape and dtype but not the
-    placement, so the rule is applied to the arrays a program is CALLED
-    with (:class:`_PlacedStep`), once for the whole program."""
+    one shard), AdaGrad on, and no fewer rows than a kernel step's lanes
+    (it waits for that many rows' DMA against a slice of the table). A
+    trace shows shape and dtype but not the placement, so the rule is
+    applied to the arrays a program is CALLED with (:class:`_PlacedStep`),
+    once for the whole program."""
     from multiverso_tpu.core.table import pallas_rows_eligible
-    return adagrad and pallas_rows_eligible(w.shape, w.dtype, one_shard)
+    from multiverso_tpu.ops.pallas_rows import _FOLD_GROUP_ROWS
+    return (adagrad and pallas_rows_eligible(w.shape, w.dtype, one_shard)
+            and w.shape[0] >= _FOLD_GROUP_ROWS)
+
+
+@dataclasses.dataclass(frozen=True)
+class _ShardedRows:
+    """The row kernel a SHARD: tables whose rows lie in equal ranges over
+    ``axis`` of ``mesh`` (``P(axis, None)``; replicated over its other
+    axes), each shard a table the row kernel serves."""
+    mesh: jax.sharding.Mesh
+    axis: str
+    interpret: bool
 
 
 # The plane the row updates of the program being traced run on: None for
-# XLA's scatter-adds, else the row kernel, the value its ``interpret``
-# (``ops.pallas_interpret``). A program sets it around its own body
-# (``_on_row_kernel``), so the raw steps keep the signature they have
-# always had and ``_apply_update`` reads it where it traces.
+# XLA's scatter-adds; the row kernel over tables on one device, the value
+# its ``interpret`` (``ops.pallas_interpret``); or the row kernel a shard of
+# tables over one mesh axis (``_ShardedRows``). A program sets it around
+# its own body (``_on_row_kernel``), so the raw steps keep the signature
+# they have always had and ``_apply_update`` reads it where it traces.
 _ROW_KERNEL = contextvars.ContextVar("w2v_row_kernel", default=None)
 
 
-def _on_row_kernel(fn, interpret: Optional[bool]):
-    """``fn`` with its row updates on the Pallas plane wherever it is
-    traced (under ``fn``'s own name, which names the jitted program);
+def _on_row_kernel(fn, plane):
+    """``fn`` with its row updates on the Pallas plane ``plane`` wherever
+    it is traced (under ``fn``'s own name, which names the jitted program);
     ``fn`` itself for ``None``, XLA's lines."""
-    if interpret is None:
+    if plane is None:
         return fn
 
     @functools.wraps(fn)
     def placed(*args):
-        token = _ROW_KERNEL.set(interpret)
+        token = _ROW_KERNEL.set(plane)
         try:
             return fn(*args)
         finally:
             _ROW_KERNEL.reset(token)
     return placed
+
+
+def _row_shard_axis(sharding, shape) -> Optional[str]:
+    """The ONE mesh axis the rows of a ``[rows, columns]`` table are divided
+    over in equal ranges, columns whole: ``NamedSharding`` of spec ``(axis,
+    None)``, rows divisible by the axis' size. Else ``None``."""
+    if not isinstance(sharding, jax.sharding.NamedSharding) \
+            or len(shape) != 2:
+        return None
+    rows, cols = (tuple(sharding.spec) + (None, None))[:2]
+    if not isinstance(rows, str) or cols is not None \
+            or shape[0] % sharding.mesh.shape[rows]:
+        return None
+    return rows
 
 
 def _sorted_in_slabs(rows, grad, num_rows: int, slab: int):
@@ -336,17 +366,44 @@ def _fused_adagrad_update(w, g2, rows, grad, lr, interpret: bool):
     return w, g2
 
 
+def _sharded_adagrad_update(w, g2, rows, grad, lr, plane: _ShardedRows):
+    """``_fused_adagrad_update`` a shard, under ``shard_map`` over the axis
+    the rows are divided over: a shard takes the whole (replicated) id
+    stream moved to its own row range, so another shard's id falls out of
+    range and becomes the sentinel the slab sort puts behind the live ones
+    (a kernel step that starts with one costs no DMA). A shard writes only
+    its rows and replicas over the other axes compute alike: no collective,
+    and the kernels' aliasing reaches the shards of the donated tables."""
+    from jax.sharding import PartitionSpec as P
+    shard_rows = w.shape[0] // plane.mesh.shape[plane.axis]
+
+    def shard(w, g2, rows, grad, lr):
+        lo = jax.lax.axis_index(plane.axis) * shard_rows
+        return _fused_adagrad_update(w, g2, rows - lo, grad, lr,
+                                     plane.interpret)
+
+    table = P(plane.axis, None)
+    # check_vma off: a pallas_call's internals mix varying and unvarying
+    # values under shard_map (parallel/sequence.py does the same).
+    return jax.shard_map(
+        shard, mesh=plane.mesh, in_specs=(table, table, P(), P(), P()),
+        out_specs=(table, table), check_vma=False)(w, g2, rows, grad, lr)
+
+
 def _apply_update(w, g2, rows, grad, lr, adagrad: bool):
     """Apply an embedding update (+AdaGrad) for possibly-duplicated rows:
     for a row with gradients g_1..g_k, ``G += sum(g_i^2)`` then ``w -= lr
     sum(g_i) / sqrt(G + 1e-6)``; ids out of range are dropped. Gradients
     arrive f32; the step is cast to the storage dtype (bf16 tables keep f32
     math). In a program whose tables the row kernel serves
-    (``_ROW_KERNEL``, set from ``row_kernel_selected``) the kernel runs;
-    else XLA's scatter-adds, one write an id."""
-    interpret = _ROW_KERNEL.get()
-    if interpret is not None:
-        return _fused_adagrad_update(w, g2, rows, grad, lr, interpret)
+    (``_ROW_KERNEL``, set from ``_PlacedStep.row_kernel``) the kernel runs,
+    over the table or over each of its shards; else XLA's scatter-adds, one
+    write an id."""
+    plane = _ROW_KERNEL.get()
+    if isinstance(plane, _ShardedRows):
+        return _sharded_adagrad_update(w, g2, rows, grad, lr, plane)
+    if plane is not None:
+        return _fused_adagrad_update(w, g2, rows, grad, lr, plane)
     if adagrad:
         g2 = g2.at[rows].add(jnp.square(grad).astype(g2.dtype), mode="drop")
         denom = jnp.sqrt(jnp.take(g2, rows, axis=0, mode="clip")
@@ -416,33 +473,52 @@ def raw_sg_ns_step(adagrad: bool):
 class _PlacedStep:
     """A jitted word2vec program that adapts to where its tables live.
 
-    The row kernel serves tables on ONE shard, and a trace cannot see the
-    placement; the caller's arrays can. So the rule is applied to the four
-    tables a call brings (``row_kernel``) and the program is jitted once a
-    plane, ``jax.jit(_on_row_kernel(fn, plane), donating the tables)``:
-    eligible tables on one device, committed there or not, run the kernel
-    (interpreted off the TPU); anything else (a mesh, host arrays, another
-    width or dtype, AdaGrad off) runs ``fn`` as it stands. Each call counts
+    A trace cannot see the placement; the caller's arrays can. So the rule
+    is applied to the four tables a call brings (``row_kernel``) and the
+    program is jitted once a plane, ``jax.jit(_on_row_kernel(fn, plane),
+    donating the tables)``. Tables the row kernel serves (float32, 128
+    columns, AdaGrad on: ``row_kernel_selected``) on one device, committed
+    there or not, run the kernel (interpreted off the TPU); such tables in
+    equal row ranges over ONE mesh axis run it a shard
+    (``_sharded_adagrad_update``); anything else (rows over two axes or not
+    divisible, host arrays, another width or dtype, AdaGrad off) runs
+    ``fn`` as it stands. A program that lays its tables out itself (the
+    ``in_shardings`` among its ``jit_options``) is judged by that layout,
+    whatever the arrays come in. Each call counts
     ``w2v.rows.plane.<fused|xla>``, the plane its row updates ran on."""
 
-    def __init__(self, fn, adagrad: bool):
+    def __init__(self, fn, adagrad: bool, **jit_options):
         self._fn, self._adagrad = fn, adagrad
+        self._jit_options = jit_options
         self._programs = {}
         self.__name__ = fn.__name__
 
-    def row_kernel(self, tables) -> Optional[bool]:
+    def row_kernel(self, tables):
         """``_ROW_KERNEL``'s value for a call with these four tables."""
         from multiverso_tpu.ops import pallas_interpret
-        devices = set()
-        for t in tables:
-            sharding = getattr(t, "sharding", None)
-            if sharding is None:
-                return None
-            devices |= set(sharding.device_set)
-        if not all(row_kernel_selected(t, self._adagrad, len(devices) == 1)
-                   for t in tables[:2]):
+        layout = self._jit_options.get("in_shardings")
+        placed = (list(layout[:4]) if layout else
+                  [getattr(t, "sharding", None) for t in tables])
+        if any(p is None for p in placed):
             return None
-        return pallas_interpret(devices)
+        devices = set().union(*(p.device_set for p in placed))
+        plane = pallas_interpret(devices)
+        shards = [t.shape for t in tables]
+        if len(devices) > 1:
+            axes = {_row_shard_axis(p, t.shape)
+                    for p, t in zip(placed, tables)}
+            if len(axes) > 1 or None in axes \
+                    or len({p.mesh for p in placed}) > 1:
+                return None
+            mesh, (axis,) = placed[0].mesh, axes
+            shards = [(rows // mesh.shape[axis], cols)
+                      for rows, cols in shards]
+            plane = _ShardedRows(mesh, axis, plane)
+        if not all(row_kernel_selected(
+                jax.ShapeDtypeStruct(shard, t.dtype), self._adagrad, True)
+                for shard, t in zip(shards[:2], tables)):
+            return None
+        return plane
 
     def program(self, *args):
         """``(jitted program, its plane)`` for these arguments (the four
@@ -450,7 +526,8 @@ class _PlacedStep:
         plane = self.row_kernel(args[:4])
         if plane not in self._programs:
             self._programs[plane] = jax.jit(
-                _on_row_kernel(self._fn, plane), donate_argnums=(0, 1, 2, 3))
+                _on_row_kernel(self._fn, plane), donate_argnums=(0, 1, 2, 3),
+                **self._jit_options)
         return self._programs[plane], plane
 
     def lower(self, *args, **kwargs):
@@ -715,17 +792,21 @@ def build_sharded_block_step(mesh, window: int, negative: int, chunk: int,
     plus data-parallel workers (SURVEY.md §2.4):
 
     * embedding + accumulator tables: vocab rows sharded over ``model``,
-      replicated over ``data`` (``P("model", None)``) — gathers/scatters
-      become XLA collectives over the mesh;
+      replicated over ``data`` (``P("model", None)``) — the gathers become
+      XLA collectives over the mesh;
     * the sentence block: sharded over ``data`` (each data shard generates
       pairs from its own sentences);
     * negative table / keep probabilities / RNG key / lr: replicated.
 
     Semantics are identical to the single-device step (same keys -> same
     pairs, negatives and update order), so losses match the unsharded run.
-    Tables over a ``model`` axis are never on one shard: the row updates
-    stay XLA's sharded scatters (the row kernel would need per-shard offset
-    remapping, ``core/table.pallas_rows_eligible``).
+    The pair streams are pinned REPLICATED right after they are generated
+    (``constrain``): ``data`` divides pair generation only, every chip runs
+    every chunk over all its pairs. A :class:`_PlacedStep` like the
+    one-device program, judged by the layout it gives its tables: where a
+    ``model`` shard is a table the row kernel serves, each shard runs the
+    kernel over the ids of its own row range (ISSUE 33); else the row
+    updates are XLA's sharded scatters.
     """
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -739,12 +820,11 @@ def build_sharded_block_step(mesh, window: int, negative: int, chunk: int,
 
     fn = _make_block_fn(window, negative, chunk, adagrad, compact,
                         sg=sg, hs=hs, huffman=huffman, constrain=_repl)
-    return jax.jit(
-        fn,
+    return _PlacedStep(
+        fn, adagrad,
         in_shardings=(table, table, table, table, repl, repl, data2, data1,
                       repl, repl),
-        out_shardings=(table, table, table, table, repl, repl),
-        donate_argnums=(0, 1, 2, 3))
+        out_shardings=(table, table, table, table, repl, repl))
 
 
 # Dispatch-latency threshold for chunk_dispatch AUTO on XLA's row plane:
@@ -1488,8 +1568,6 @@ class Word2Vec:
                             losses.append(jnp.sum(jnp.stack(block_loss)))
                             pair_counts.append(n_pairs)
                         else:
-                            if sharded:     # a _PlacedStep counts its own
-                                counter("w2v.rows.plane.xla").inc()
                             (st_in.data, st_out.data, st_gin.data,
                              st_gout.data, loss, pairs) = finish(
                                 self._block_step(

@@ -1,6 +1,6 @@
 """The layer-typed LM's mixers, the grouped row programs of the device
-form and word2vec's fused block program compile for a v5e at the published widths and
-the benchmark's sequence length (no chip: the TPU compiler is installed and
+form and word2vec's fused block programs (one chip; a 2 x 2 mesh) compile
+for a v5e at the published widths and the benchmark's sequence length (no chip: the TPU compiler is installed and
 compiles for a described device; docs/HYBRID_LM.md). What it guards: a slice
 the tiling refuses, a loop the compiler cannot lower, a working set that does
 not fit. One file, and the topology only inside a fixture: one xdist worker
@@ -27,14 +27,18 @@ SEQ = 8192
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001 - no TPU compiler here
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -203,4 +207,40 @@ def test_word2vec_fused_block_program_compiles_for_v5e(one_chip):
     assert stats.alias_size_in_bytes >= 4 * V * D * 4
     # a block's pair streams and a chunk's sorted planes (1.37 GB, 9 MB
     # under the XLA plane's program), and no table (2.05 GB) beside them
+    assert stats.temp_size_in_bytes < 1.5e9, stats
+
+
+def test_word2vec_mesh_block_program_compiles_for_four_v5e(topo):
+    """``w2v_train_x4``'s block program at the cell's own size (four tables
+    of 8,000,000 x 128 float32 in row ranges over ``model`` of a 2 x 2 mesh,
+    chunks of 8,192 pairs, K=5) with the row kernel a shard (ISSUE 33): the
+    plane is read off the layout, Mosaic takes the three kernels under
+    ``shard_map``, every shard is updated in place, no shard-sized copy sits
+    in the program, and the row update adds no collective of table rows."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from multiverso_tpu.models.word2vec.model import (
+        _ShardedRows, build_sharded_block_step)
+    V, D, S, L = 8_000_000, 128, 512, 512
+    mesh = Mesh(np.asarray(topo.devices).reshape(2, 2), ("data", "model"))
+
+    def spec(shape, dtype=jnp.float32, p=P()):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, p))
+
+    step = build_sharded_block_step(mesh, window=5, negative=5, chunk=8192,
+                                    adagrad=True, compact=True)
+    args = (*[spec((V, D), p=P("model", None))] * 4,
+            spec((1 << 20,), jnp.int32), spec((V,)),
+            spec((S, L), jnp.int32, P("data", None)),
+            spec((S,), jnp.int32, P("data")), spec((2,), jnp.uint32),
+            spec(()))
+    program, plane = step.program(*args)
+    assert plane == _ShardedRows(mesh, "model", False)
+    compiled = program.lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 3
+    assert not [line for line in text.splitlines()
+                if " copy(" in line and f"f32[{V // 2},{D}]" in line]
+    stats = compiled.memory_analysis()     # of one chip
+    assert stats.alias_size_in_bytes >= 4 * (V // 2) * D * 4
     assert stats.temp_size_in_bytes < 1.5e9, stats
