@@ -307,13 +307,15 @@ def make_loss(cfg: HybridLMConfig, remat: bool = True):
     ``num_pred_heads`` > 1 (``targets``, ``mask`` [B, S, heads]) each
     head's own loss [heads] comes last in it."""
     def loss_fn(params, rows, buffers, where, targets, mask):
-        u = jnp.take(rows, where, axis=0)
+        with jax.named_scope("lm_embed"):
+            u = jnp.take(rows, where, axis=0)
         u, counts, *balance = forward_hidden(params, buffers, u, cfg, remat)
-        loss = blocked_cross_entropy(
-            u.reshape(-1, cfg.hidden_size), params["final_norm"],
-            params["head"], targets.reshape((-1,) + targets.shape[2:]),
-            mask.reshape((-1,) + mask.shape[2:]), cfg.norm_eps,
-            cfg.loss_block, cfg.norm_add_unit_offset)
+        with jax.named_scope("lm_head_loss"):
+            loss = blocked_cross_entropy(
+                u.reshape(-1, cfg.hidden_size), params["final_norm"],
+                params["head"], targets.reshape((-1,) + targets.shape[2:]),
+                mask.reshape((-1,) + mask.shape[2:]), cfg.norm_eps,
+                cfg.loss_block, cfg.norm_add_unit_offset)
         aux = (counts,)
         if balance:
             aux += (balance[0],)
@@ -382,8 +384,11 @@ class HybridLM:
             (loss, counts), (gp, grows) = jax.value_and_grad(
                 loss_fn, argnums=(0, 1), has_aux=True)(
                     params, rows, buffers, where, targets, mask)
-            deltas = jax.tree_util.tree_map(lambda g: lr * barrier(g), gp)
-            return deltas, lr * barrier(grows), loss, counts
+            with jax.named_scope("lm_scale"):
+                deltas = jax.tree_util.tree_map(
+                    lambda g: lr * barrier(g), gp)
+                row_deltas = lr * barrier(grows)
+            return deltas, row_deltas, loss, counts
 
         def lm_apply(params, state, deltas, *opt):
             leaves, treedef = jax.tree_util.tree_flatten(params)

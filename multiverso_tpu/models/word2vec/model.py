@@ -39,7 +39,8 @@ from multiverso_tpu.models.word2vec.data import (BatchGenerator, BlockStream,
 from multiverso_tpu.models.word2vec.dictionary import (Dictionary,
                                                        HuffmanEncoder,
                                                        Sampler)
-from multiverso_tpu.telemetry import counter, gauge, span
+from multiverso_tpu.telemetry import (counter, gauge, register_program,
+                                      span)
 from multiverso_tpu.utils.dashboard import monitor
 from multiverso_tpu.utils.log import check, log
 
@@ -456,15 +457,23 @@ def raw_sg_ns_step(adagrad: bool):
     updates run on is the enclosing program's to say (``_apply_update``)."""
     def step(w_in, w_out, g_in, g_out, centers, contexts, negatives, mask,
              lr):
-        u = jnp.take(w_in, centers, axis=0, mode="clip")
-        v_pos = jnp.take(w_out, contexts, axis=0, mode="clip")
-        v_neg = jnp.take(w_out, negatives, axis=0, mode="clip")
-        loss, grad_u, grad_vpos, grad_vneg = _ns_grads(u, v_pos, v_neg, mask)
-        w_in, g_in = _apply_update(w_in, g_in, centers, grad_u, lr, adagrad)
-        B, K, D = grad_vneg.shape
-        rows = jnp.concatenate([contexts, negatives.reshape(B * K)])
-        grads = jnp.concatenate([grad_vpos, grad_vneg.reshape(B * K, D)])
-        w_out, g_out = _apply_update(w_out, g_out, rows, grads, lr, adagrad)
+        with jax.named_scope("w2v_gather"):
+            u = jnp.take(w_in, centers, axis=0, mode="clip")
+            v_pos = jnp.take(w_out, contexts, axis=0, mode="clip")
+            v_neg = jnp.take(w_out, negatives, axis=0, mode="clip")
+        with jax.named_scope("w2v_grads"):
+            loss, grad_u, grad_vpos, grad_vneg = _ns_grads(u, v_pos, v_neg,
+                                                           mask)
+        with jax.named_scope("w2v_rows"), jax.named_scope("w2v_rows_in"):
+            w_in, g_in = _apply_update(w_in, g_in, centers, grad_u, lr,
+                                       adagrad)
+        with jax.named_scope("w2v_rows"), jax.named_scope("w2v_rows_out"):
+            B, K, D = grad_vneg.shape
+            rows = jnp.concatenate([contexts, negatives.reshape(B * K)])
+            grads = jnp.concatenate([grad_vpos,
+                                     grad_vneg.reshape(B * K, D)])
+            w_out, g_out = _apply_update(w_out, g_out, rows, grads, lr,
+                                         adagrad)
         return w_in, w_out, g_in, g_out, loss
 
     return step
@@ -537,6 +546,8 @@ class _PlacedStep:
         program, plane = self.program(*args)
         counter("w2v.rows.plane.xla" if plane is None
                 else "w2v.rows.plane.fused").inc()
+        if not kwargs:      # traced keywords are no part of a registration
+            register_program(program, args)
         return program(*args, **kwargs)
 
 
@@ -546,13 +557,19 @@ def build_sg_ns_step(adagrad: bool):
 
 def raw_sg_hs_step(adagrad: bool):
     def step(w_in, w_out, g_in, g_out, centers, points, codes, lmask, lr):
-        u = jnp.take(w_in, centers, axis=0, mode="clip")
-        v = jnp.take(w_out, points, axis=0, mode="clip")
-        loss, grad_u, grad_v = _hs_grads(u, v, codes, lmask)
-        w_in, g_in = _apply_update(w_in, g_in, centers, grad_u, lr, adagrad)
-        B, L, D = grad_v.shape
-        w_out, g_out = _apply_update(w_out, g_out, points.reshape(B * L),
-                                     grad_v.reshape(B * L, D), lr, adagrad)
+        with jax.named_scope("w2v_gather"):
+            u = jnp.take(w_in, centers, axis=0, mode="clip")
+            v = jnp.take(w_out, points, axis=0, mode="clip")
+        with jax.named_scope("w2v_grads"):
+            loss, grad_u, grad_v = _hs_grads(u, v, codes, lmask)
+        with jax.named_scope("w2v_rows"), jax.named_scope("w2v_rows_in"):
+            w_in, g_in = _apply_update(w_in, g_in, centers, grad_u, lr,
+                                       adagrad)
+        with jax.named_scope("w2v_rows"), jax.named_scope("w2v_rows_out"):
+            B, L, D = grad_v.shape
+            w_out, g_out = _apply_update(
+                w_out, g_out, points.reshape(B * L),
+                grad_v.reshape(B * L, D), lr, adagrad)
         return w_in, w_out, g_in, g_out, loss
 
     return step
@@ -561,23 +578,31 @@ def raw_sg_hs_step(adagrad: bool):
 def raw_cbow_ns_step(adagrad: bool):
     def step(w_in, w_out, g_in, g_out, centers, contexts, cmask, negatives,
              mask, lr):
-        ctx = jnp.take(w_in, contexts, axis=0,
-                       mode="clip").astype(jnp.float32)         # [B,C,D]
-        counts = jnp.maximum(cmask.sum(axis=-1, keepdims=True), 1.0)
-        u = (ctx * cmask[..., None]).sum(axis=1) / counts       # [B,D]
-        v_pos = jnp.take(w_out, centers, axis=0, mode="clip")
-        v_neg = jnp.take(w_out, negatives, axis=0, mode="clip")
-        loss, grad_u, grad_vpos, grad_vneg = _ns_grads(u, v_pos, v_neg, mask)
-        # distribute grad_u to each contributing context row
+        with jax.named_scope("w2v_gather"):
+            ctx = jnp.take(w_in, contexts, axis=0,
+                           mode="clip").astype(jnp.float32)     # [B,C,D]
+            counts = jnp.maximum(cmask.sum(axis=-1, keepdims=True), 1.0)
+            u = (ctx * cmask[..., None]).sum(axis=1) / counts   # [B,D]
+            v_pos = jnp.take(w_out, centers, axis=0, mode="clip")
+            v_neg = jnp.take(w_out, negatives, axis=0, mode="clip")
+        with jax.named_scope("w2v_grads"):
+            loss, grad_u, grad_vpos, grad_vneg = _ns_grads(u, v_pos, v_neg,
+                                                           mask)
         B, C = contexts.shape
         D = grad_u.shape[-1]
-        gctx = (grad_u[:, None, :] * cmask[..., None] / counts[..., None])
-        w_in, g_in = _apply_update(w_in, g_in, contexts.reshape(B * C),
-                                   gctx.reshape(B * C, D), lr, adagrad)
-        K = negatives.shape[1]
-        rows = jnp.concatenate([centers, negatives.reshape(B * K)])
-        grads = jnp.concatenate([grad_vpos, grad_vneg.reshape(B * K, D)])
-        w_out, g_out = _apply_update(w_out, g_out, rows, grads, lr, adagrad)
+        with jax.named_scope("w2v_rows"), jax.named_scope("w2v_rows_in"):
+            # distribute grad_u to each contributing context row
+            gctx = (grad_u[:, None, :] * cmask[..., None]
+                    / counts[..., None])
+            w_in, g_in = _apply_update(w_in, g_in, contexts.reshape(B * C),
+                                       gctx.reshape(B * C, D), lr, adagrad)
+        with jax.named_scope("w2v_rows"), jax.named_scope("w2v_rows_out"):
+            K = negatives.shape[1]
+            rows = jnp.concatenate([centers, negatives.reshape(B * K)])
+            grads = jnp.concatenate([grad_vpos,
+                                     grad_vneg.reshape(B * K, D)])
+            w_out, g_out = _apply_update(w_out, g_out, rows, grads, lr,
+                                         adagrad)
         return w_in, w_out, g_in, g_out, loss
 
     return step
@@ -586,20 +611,26 @@ def raw_cbow_ns_step(adagrad: bool):
 def raw_cbow_hs_step(adagrad: bool):
     def step(w_in, w_out, g_in, g_out, centers, contexts, cmask, points,
              codes, lmask, lr):
-        ctx = jnp.take(w_in, contexts, axis=0,
-                       mode="clip").astype(jnp.float32)
-        counts = jnp.maximum(cmask.sum(axis=-1, keepdims=True), 1.0)
-        u = (ctx * cmask[..., None]).sum(axis=1) / counts
-        v = jnp.take(w_out, points, axis=0, mode="clip")
-        loss, grad_u, grad_v = _hs_grads(u, v, codes, lmask)
+        with jax.named_scope("w2v_gather"):
+            ctx = jnp.take(w_in, contexts, axis=0,
+                           mode="clip").astype(jnp.float32)
+            counts = jnp.maximum(cmask.sum(axis=-1, keepdims=True), 1.0)
+            u = (ctx * cmask[..., None]).sum(axis=1) / counts
+            v = jnp.take(w_out, points, axis=0, mode="clip")
+        with jax.named_scope("w2v_grads"):
+            loss, grad_u, grad_v = _hs_grads(u, v, codes, lmask)
         B, C = contexts.shape
         D = grad_u.shape[-1]
-        gctx = (grad_u[:, None, :] * cmask[..., None] / counts[..., None])
-        w_in, g_in = _apply_update(w_in, g_in, contexts.reshape(B * C),
-                                   gctx.reshape(B * C, D), lr, adagrad)
-        L = points.shape[1]
-        w_out, g_out = _apply_update(w_out, g_out, points.reshape(B * L),
-                                     grad_v.reshape(B * L, D), lr, adagrad)
+        with jax.named_scope("w2v_rows"), jax.named_scope("w2v_rows_in"):
+            gctx = (grad_u[:, None, :] * cmask[..., None]
+                    / counts[..., None])
+            w_in, g_in = _apply_update(w_in, g_in, contexts.reshape(B * C),
+                                       gctx.reshape(B * C, D), lr, adagrad)
+        with jax.named_scope("w2v_rows"), jax.named_scope("w2v_rows_out"):
+            L = points.shape[1]
+            w_out, g_out = _apply_update(
+                w_out, g_out, points.reshape(B * L),
+                grad_v.reshape(B * L, D), lr, adagrad)
         return w_in, w_out, g_in, g_out, loss
 
     return step
@@ -674,47 +705,48 @@ def _make_block_fn(window: int, negative: int, chunk: int,
 
     def block_step(w_in, w_out, g_in, g_out, neg_table, keep_prob, sents,
                    lengths, key, lr):
-        k_keep, k_win, k_neg = jax.random.split(key, 3)
-        if sg:
-            centers, contexts, pmask = _pair_arrays(
-                sents, lengths, keep_prob, k_keep, k_win, window)
-            arrays1d, arrays2d = [centers, contexts], []
-        else:
-            centers, contexts, cmask, pmask = _cbow_arrays(
-                sents, lengths, keep_prob, k_keep, k_win, window)
-            arrays1d, arrays2d = [centers], [contexts, cmask]
-        if constrain is not None:
-            # Under dp x tp GSPMD, XLA reshards the concatenated pair
-            # streams (slices of the data-sharded sentence block) with a
-            # partial-sum representation that double-counts every element
-            # across the model axis (observed on jax 0.4.37 CPU: the
-            # resharded stream comes back exactly 2x the true token ids).
-            # Pinning the streams to an explicit layout right after
-            # construction keeps the partitioner out of that path.
-            arrays1d = [constrain(a) for a in arrays1d]
-            arrays2d = [constrain(a) for a in arrays2d]
-            pmask = constrain(pmask)
+        with jax.named_scope("w2v_pairs"):
+            k_keep, k_win, k_neg = jax.random.split(key, 3)
             if sg:
-                centers, contexts = arrays1d
+                centers, contexts, pmask = _pair_arrays(
+                    sents, lengths, keep_prob, k_keep, k_win, window)
+                arrays1d, arrays2d = [centers, contexts], []
             else:
-                (centers,), (contexts, cmask) = arrays1d, arrays2d
-        P = pmask.shape[0]
-        pad = (-P) % chunk
-        n = (P + pad) // chunk
+                centers, contexts, cmask, pmask = _cbow_arrays(
+                    sents, lengths, keep_prob, k_keep, k_win, window)
+                arrays1d, arrays2d = [centers], [contexts, cmask]
+            if constrain is not None:
+                # Under dp x tp GSPMD, XLA reshards the concatenated pair
+                # streams (slices of the data-sharded sentence block) with a
+                # partial-sum representation that double-counts every element
+                # across the model axis (observed on jax 0.4.37 CPU: the
+                # resharded stream comes back exactly 2x the true token ids).
+                # Pinning the streams to an explicit layout right after
+                # construction keeps the partitioner out of that path.
+                arrays1d = [constrain(a) for a in arrays1d]
+                arrays2d = [constrain(a) for a in arrays2d]
+                pmask = constrain(pmask)
+                if sg:
+                    centers, contexts = arrays1d
+                else:
+                    (centers,), (contexts, cmask) = arrays1d, arrays2d
+            P = pmask.shape[0]
+            pad = (-P) % chunk
+            n = (P + pad) // chunk
 
-        if compact:
-            out1, out2, n_pairs, n = _compact_examples(
-                pmask, chunk, arrays1d, arrays2d)
-            streams = out1 + out2
-        else:
-            n_pairs = pmask.sum()
-            streams = [jnp.pad(a, (0, pad)).reshape(n, chunk)
-                       for a in arrays1d]
-            streams += [jnp.pad(a, ((0, pad), (0, 0)))
-                        .reshape(n, chunk, a.shape[1]) for a in arrays2d]
-        negatives = (None if hs else
-                     _row_gather_negatives(neg_table, k_neg,
-                                           (n, chunk, negative)))
+            if compact:
+                out1, out2, n_pairs, n = _compact_examples(
+                    pmask, chunk, arrays1d, arrays2d)
+                streams = out1 + out2
+            else:
+                n_pairs = pmask.sum()
+                streams = [jnp.pad(a, (0, pad)).reshape(n, chunk)
+                           for a in arrays1d]
+                streams += [jnp.pad(a, ((0, pad), (0, 0)))
+                            .reshape(n, chunk, a.shape[1]) for a in arrays2d]
+            negatives = (None if hs else
+                         _row_gather_negatives(neg_table, k_neg,
+                                               (n, chunk, negative)))
 
         if compact:
             # After compaction the first n_pairs slots are exactly the

@@ -23,7 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from multiverso_tpu.parallel import comm_policy as cp
-from multiverso_tpu.telemetry import span
+from multiverso_tpu.telemetry import register_program, span
 
 __all__ = ["HybridStep"]
 
@@ -80,8 +80,11 @@ class HybridStep:
                     dense = jax.device_put(dense, next(iter(rows.devices())))
                     self._keep(dense)
                 # The batch's host arrays go up inside the launching phase.
-                deltas, row_deltas, *aux = self.delta(
-                    dense[0], rows, *jax.tree_util.tree_map(jnp.asarray, batch))
+                args = (dense[0], rows,
+                        *jax.tree_util.tree_map(jnp.asarray, batch))
+                register_program(self.delta, args)
+                deltas, row_deltas, *aux = self.delta(*args)
+                del args
                 # The program holds its input: without this name the pulled
                 # rows go when it ends, not when the step does.
                 del rows
@@ -93,6 +96,8 @@ class HybridStep:
                 while leaves:
                     merged.append(self.dense_sync(leaves.pop(0)))
                 merged = treedef.unflatten(merged)
+                register_program(
+                    self.apply, (*dense, merged, *self._apply_args))
                 out = self.apply(*dense, merged, *self._apply_args)
                 self._keep(out if len(self._dense) > 1 else (out,))
                 del merged

@@ -51,7 +51,7 @@ from multiverso_tpu.core.table import (_CPU_COLLECTIVE_LOCK, build_row_update,
 from multiverso_tpu.core.updater import get_updater
 from multiverso_tpu.ops import pallas_interpret
 from multiverso_tpu.tables.matrix_table import MatrixTable, initial_rows
-from multiverso_tpu.telemetry import counter, phase
+from multiverso_tpu.telemetry import counter, phase, register_program
 from multiverso_tpu.utils.dashboard import monitor
 from multiverso_tpu.utils.log import check
 
@@ -290,10 +290,11 @@ class TableGroup(_RowGroup):
                 for t in self._by_id:
                     gates.enter_context(t._bsp_get(option))
                 with self._dispatch_scope():
-                    out = self._finish(self._access(
-                        tuple(s.data for s in self._stores), ids,
-                        lengths=lengths,
-                        blocks=device and lengths is not None))
+                    datas = tuple(s.data for s in self._stores)
+                    static = {"lengths": lengths,
+                              "blocks": device and lengths is not None}
+                    register_program(self._access, (datas, ids), static)
+                    out = self._finish(self._access(datas, ids, **static))
             counts = lengths or (len(ids),) * len(self.tables)
             self._record([n * w * self.dtype.itemsize
                           for n, w in zip(counts, self.widths)],
@@ -347,10 +348,13 @@ class TableGroup(_RowGroup):
                     scalars = group_scalars(
                         [opts[t.table_id] for t in self.tables])
                     with self._dispatch_scope():
+                        args = (tuple(s.data for s in self._stores),
+                                tuple(s.state for s in self._stores),
+                                ids, deltas, *scalars)
+                        register_program(self._update, args,
+                                         {"lengths": lengths})
                         datas, states, done = self._update(
-                            tuple(s.data for s in self._stores),
-                            tuple(s.state for s in self._stores),
-                            ids, deltas, *scalars, lengths=lengths)
+                            *args, lengths=lengths)
                         for s, data, state in zip(self._stores, datas,
                                                   states):
                             s.data, s.state = data, state

@@ -8,6 +8,9 @@ are histogram-backed through this package) plus cross-actor tracing:
 * :func:`span` — host-side begin/end regions exported as Chrome
   trace-event JSON, nested under ``jax.profiler.TraceAnnotation``
   (``spans.py``);
+* :func:`register_program` / :func:`program_scopes` — a device program's
+  ``jax.named_scope`` parts read back from its compiled text, for the
+  reader of a device trace (``device_scopes.py``);
 * :func:`start_exporter` / ``-telemetry_dir`` — periodic JSON snapshot +
   trace export, with a multi-worker merge tool (``export.py``,
   ``scripts/telemetry_report.py``).
@@ -40,6 +43,8 @@ from multiverso_tpu.telemetry.critical_path import (CONCURRENT_PHASES,
                                                     phase_for_span,
                                                     reset_critical_path,
                                                     set_exemplars_enabled)
+from multiverso_tpu.telemetry.device_scopes import (program_scopes,
+                                                    register_program)
 from multiverso_tpu.telemetry.profile import (PROFILE_SCHEMA, FoldedStacks,
                                               SamplingProfiler,
                                               get_profiler, merge_profiles,
@@ -94,7 +99,7 @@ __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "counter", "gauge",
     "get_registry", "histogram",
     "TraceBuffer", "current_identity", "emit_span", "get_trace_buffer",
-    "phase", "span",
+    "phase", "span", "program_scopes", "register_program",
     "TraceContext", "activate", "child_of", "current_context",
     "maybe_new_root", "new_root",
     "AlertEngine", "AlertManager", "AlertRule", "BurnRateRule",

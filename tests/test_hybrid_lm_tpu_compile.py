@@ -21,9 +21,18 @@ from multiverso_tpu.models.hybrid_lm import (HybridLMConfig, layer_forward,
 from multiverso_tpu.tables.table_group import (build_group_access,
                                                build_group_update,
                                                group_scalars)
+from multiverso_tpu.telemetry.device_scopes import (parse_scopes,
+                                                    scope_names)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SEQ = 8192
+
+
+def _scopes_of(compiled) -> set:
+    """Every path component of the executable's instructions' op_names, the
+    wrappers jax puts around a scope (``transpose(jvp(...))``) taken off."""
+    return {name for path in parse_scopes(compiled.as_text())[1].values()
+            for name in scope_names(path)}
 
 
 @pytest.fixture(scope="module")
@@ -133,6 +142,10 @@ def test_evabyte_step_compiles_for_v5e_inside_a_chips_memory(one_chip):
     stats = compiled.memory_analysis()
     assert stats.argument_size_in_bytes > 3.28e9
     assert stats.temp_size_in_bytes < 4.0e9, stats
+    # the TPU compiler keeps the program's scopes on its instructions
+    # (telemetry/device_scopes.py reads them back from this text)
+    assert {"lm_embed", "lm_head_loss", "lm_eva", "lm_eva_prep",
+            "lm_eva_agg", "lm_dense_ffn"} <= _scopes_of(compiled)
 
 
 # (tables, rows a table, width, ids a table, one [B, n] id matrix?): the two
@@ -203,6 +216,8 @@ def test_word2vec_fused_block_program_compiles_for_v5e(one_chip):
     assert text.count("tpu_custom_call") == 3
     assert not [line for line in text.splitlines()
                 if " copy(" in line and f"f32[{V},{D}]" in line]
+    assert {"w2v_pairs", "w2v_gather", "w2v_grads", "w2v_rows",
+            "w2v_rows_in", "w2v_rows_out"} <= _scopes_of(compiled)
     stats = compiled.memory_analysis()
     assert stats.alias_size_in_bytes >= 4 * V * D * 4
     # a block's pair streams and a chunk's sorted planes (1.37 GB, 9 MB
@@ -241,6 +256,13 @@ def test_word2vec_mesh_block_program_compiles_for_four_v5e(topo):
     assert text.count("tpu_custom_call") == 3
     assert not [line for line in text.splitlines()
                 if " copy(" in line and f"f32[{V // 2},{D}]" in line]
+    # the kernels a shard and the gathers' all-reduce are owned
+    owned = parse_scopes(text)[1]
+    kernels = [path for name, path in owned.items()
+               if name.startswith("shard_map")]
+    assert len(kernels) == 3 and all("/w2v_rows/" in p for p in kernels)
+    assert any("/w2v_gather/" in path for name, path in owned.items()
+               if name.startswith("all-reduce"))
     stats = compiled.memory_analysis()     # of one chip
     assert stats.alias_size_in_bytes >= 4 * (V // 2) * D * 4
     assert stats.temp_size_in_bytes < 1.5e9, stats

@@ -81,8 +81,10 @@ def test_log_levels_filter(capsys):
     assert "shown message" in out.err
 
 
-def test_profiler_annotate_smoke():
-    from multiverso_tpu.utils.profiler import annotate
-    with annotate("annotated_region"):
+def test_span_annotates_a_region_and_counts_it():
+    """The one ``jax.profiler`` wrapper (``utils/profiler.annotate`` was a
+    second): a named region under ``TraceAnnotation``, counted once."""
+    from multiverso_tpu.telemetry import get_registry, span
+    with span("annotated_region"):
         pass
-    assert Dashboard.get("annotated_region").count == 1
+    assert get_registry().histogram("span.annotated_region").count == 1
