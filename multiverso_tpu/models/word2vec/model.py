@@ -1143,6 +1143,30 @@ def build_scan_step(raw_step, adagrad: bool):
 
 
 class Word2Vec:
+    """The word2vec trainer over its five tables (module docstring).
+
+    A word's row. The tables are ``[V, D]`` arrays addressed by ROW, and
+    the device programs take row ids. Off a mesh a word's row is its
+    dictionary id. On a dp x tp mesh whose ``model`` axis has ``n > 1``
+    shards the tables are ``P("model", None)``, n row RANGES, and
+    dictionary ids are frequency ranks: by range, shard 0 would own nearly
+    every id a chunk touches and the step would go at its pace (PERF.md 6,
+    PR 33, PR 36). So the MODEL deals its words round-robin into the
+    ranges, :meth:`rows_of`: ranks 0, n, 2n, .. fall in shard 0, ranks 1,
+    n + 1, .. in shard 1, and every shard owns 1/n of every part of the
+    Zipf curve. It is a relabelling at the model's edge, decided by the
+    number of row shards alone (no option): what the block program takes
+    BY word or holding words is relabelled once at construction (the
+    keep-probabilities, the negative table's values, the rows of the
+    Huffman tables handed to the builder), each block's sentence matrix as
+    it is built; the program then draws the same pairs and negatives OF
+    THE SAME WORDS and updates their rows. What leaves the model by word
+    (:meth:`embeddings`, :meth:`save`, the queries on them) comes back
+    through ``rows_of``; the tables' own accessors (``get_rows``, a
+    checkpoint) stay by row, so a table-level checkpoint of a mesh-trained
+    model belongs to its ``mesh_model`` (docs/DURABILITY.md).
+    ``train()``'s ``stats["row_layout"]`` names the layout."""
+
     def __init__(self, cfg: Word2VecConfig, dictionary: Dictionary):
         check(len(dictionary) >= 2, "vocabulary too small")
         self.cfg = cfg
@@ -1200,6 +1224,10 @@ class Word2Vec:
         check(cfg.mesh_data * cfg.mesh_model == 1 or cfg.device_pipeline,
               "mesh_data/mesh_model need device_pipeline=True (the host "
               "batch path has no sharded step)")
+        # The row ranges the dp x tp block step shards the tables into
+        # (rows_of). The pure client plane trains by dictionary id through
+        # the table API and never runs that step: by range.
+        self._row_shards = 1 if self.comm_mode == "ps" else cfg.mesh_model
         self._scan_step = build_scan_step(
             raw_step_factory(cfg.sg, cfg.hs)(adagrad), adagrad)
 
@@ -1208,15 +1236,26 @@ class Word2Vec:
             # Shuffled so 128-wide rows are iid draws (row-gather sampling).
             perm = np.random.default_rng(cfg.seed + 17).permutation(
                 len(sampler.table))
-            self._neg_table = jnp.asarray(sampler.table[perm])
-            keep_host = Sampler.keep_probability(
-                dictionary.counts, cfg.sample).astype(np.float32)
+            # The program draws the same POSITIONS of the table, so the
+            # same words; its values and what it reads by word are rows.
+            self._neg_table = jnp.asarray(self.rows_of(sampler.table[perm]))
+            keep_host = self._by_row(Sampler.keep_probability(
+                dictionary.counts, cfg.sample).astype(np.float32))
             self._keep_prob_host = keep_host
             self._keep_prob = jnp.asarray(keep_host)
+            huffman = self.huffman
+            if huffman is not None and self._row_shards > 1:
+                # The paths' ROWS follow their words; their values index
+                # inner nodes, another id space, and stay.
+                import copy
+                huffman = copy.copy(huffman)
+                for name in ("points", "codes", "lengths"):
+                    setattr(huffman, name,
+                            self._by_row(getattr(huffman, name)))
             self._block_step = build_device_block_step(
                 cfg.window, cfg.negative, cfg.batch_size, adagrad,
                 compact=cfg.compact_pairs, sg=cfg.sg, hs=cfg.hs,
-                huffman=self.huffman)
+                huffman=huffman)
             self._dispatch_mode = resolve_dispatch_mode(
                 cfg, self._block_step.row_kernel([t.store.data for t in (
                     self.input_table, self.output_table, self.adagrad_in,
@@ -1264,7 +1303,7 @@ class Word2Vec:
                 self._block_step = build_sharded_block_step(
                     self._sharded_mesh, cfg.window, cfg.negative,
                     cfg.batch_size, adagrad, compact=cfg.compact_pairs,
-                    sg=cfg.sg, hs=cfg.hs, huffman=self.huffman)
+                    sg=cfg.sg, hs=cfg.hs, huffman=huffman)
             self._key = jax.random.PRNGKey(cfg.seed)
 
         self.total_words = dictionary.total_count * max(cfg.epochs, 1)
@@ -1274,6 +1313,38 @@ class Word2Vec:
         if scale is None:
             scale = 1.0
         self._push_scale = scale
+
+    # -- a word's row (class docstring) ------------------------------------
+    def rows_of(self, word_ids) -> np.ndarray:
+        """Table rows of dictionary ids. Off a mesh, and on one whose
+        ``model`` axis is not divided, the ids themselves. Over ``n`` row
+        shards word ``w`` is the ``w // n``-th of the words dealt to shard
+        ``w % n``, whose rows follow the earlier shards': at ``V % n == 0``
+        row ``(w % n) * (V // n) + w // n``. A bijection on ``range(V)``
+        for any ``V`` (the first ``V % n`` shards take one word more);
+        the pad id 0 stays 0."""
+        ids = np.asarray(word_ids)
+        n = self._row_shards
+        if n == 1:
+            return ids
+        per, longer = divmod(len(self.dict), n)
+        shard = ids % n
+        return shard * per + np.minimum(shard, longer) + ids // n
+
+    def _by_row(self, by_word: np.ndarray) -> np.ndarray:
+        """An array indexed by dictionary id, laid out by row:
+        ``out[rows_of(w)] = by_word[w]``."""
+        if self._row_shards == 1:
+            return by_word
+        out = np.empty_like(by_word)
+        out[self.rows_of(np.arange(len(by_word)))] = by_word
+        return out
+
+    @property
+    def row_layout(self) -> str:
+        """``"range"`` (a word's row is its id) or ``"interleaved/<n>"``."""
+        n = self._row_shards
+        return "range" if n == 1 else f"interleaved/{n}"
 
     # -- comm-policy hooks (docs/DESIGN.md "CommPolicy") -------------------
     def _hybrid_sync(self, words: int) -> None:
@@ -1409,8 +1480,9 @@ class Word2Vec:
             # baseline the hybrid mode exists to beat.
             from multiverso_tpu.models.word2vec.commplane import \
                 PSPlaneTrainer
-            return PSPlaneTrainer(self).train(sentences, corpus_path,
-                                              epochs)
+            return {**PSPlaneTrainer(self).train(sentences, corpus_path,
+                                                 epochs),
+                    "row_layout": self.row_layout}
         if self.cfg.device_pipeline:
             return self._train_device(sentences, corpus_path, epochs)
         t0 = time.perf_counter()
@@ -1463,7 +1535,8 @@ class Word2Vec:
         return {"words": self.trained_words, "pairs": total_pairs,
                 "words_per_sec": self.words_per_sec, "loss": mean_loss,
                 "seconds": elapsed, "comm_mode": self.comm_mode,
-                "synced_words": self._synced_words()}
+                "synced_words": self._synced_words(),
+                "row_layout": self.row_layout}
 
     # -- device-pipeline training loop -------------------------------------
     def _sentence_blocks(self, sentences):
@@ -1503,6 +1576,7 @@ class Word2Vec:
         st_gin = self.adagrad_in.store
         st_gout = self.adagrad_out.store
         sharded = getattr(self, "_sharded_mesh", None) is not None
+        interleaved = self._row_shards > 1
         if sharded:
             # Re-lay the tables onto the dp x tp mesh once; the step's
             # donated outputs keep that sharding for every later block.
@@ -1522,6 +1596,11 @@ class Word2Vec:
             else:
                 sents = iter(sentences)
             blocks = self._sentence_blocks(sents)
+            if interleaved:
+                # The program takes ROW ids (on the prefetch thread in
+                # pipeline mode: one numpy map a block).
+                blocks = ((self.rows_of(mat), lens, words)
+                          for mat, lens, words in blocks)
             if self.cfg.pipeline:
                 it = blocks
                 buf: ASyncBuffer = ASyncBuffer(lambda: next(it, None))
@@ -1608,6 +1687,8 @@ class Word2Vec:
                                     self._keep_prob, mat, lens, sub, lr))
                             losses.append(loss)
                             pair_counts.append(pairs)
+                    if interleaved:
+                        counter("w2v.rows.layout.interleaved").inc()
                     self.trained_words += words
                     self.wordcount_table.add([_WORDCOUNT_KEY], [words])
                     self._hybrid_sync(words)
@@ -1631,11 +1712,17 @@ class Word2Vec:
                 "seconds": elapsed, "comm_mode": self.comm_mode,
                 "dispatch_mode": ("in_graph" if sharded
                                   else self._dispatch_mode),
-                "synced_words": self._synced_words()}
+                "synced_words": self._synced_words(),
+                "row_layout": self.row_layout}
 
     # -- embeddings out ----------------------------------------------------
     def embeddings(self) -> np.ndarray:
-        return self.input_table.get()
+        """The input embeddings ``[V, D]`` in WORD order, whatever the
+        rows' layout."""
+        table = self.input_table.get()
+        if self._row_shards == 1:
+            return table
+        return table[self.rows_of(np.arange(len(self.dict)))]
 
     def save(self, path: str, batch_rows: int = 100_000) -> None:
         """Rank-0 batched text export (ref :263-306 saves in 100K-row
@@ -1652,7 +1739,8 @@ class Word2Vec:
                 rows = list(range(start,
                                   min(start + batch_rows, len(self.dict))))
                 # astype: bf16 scalars don't support the 'f' format code
-                emb = self.input_table.get_rows(rows).astype(np.float32)
+                emb = self.input_table.get_rows(
+                    self.rows_of(rows)).astype(np.float32)
                 chunk = []
                 for r, vec in zip(rows, emb):
                     vec_s = " ".join(f"{x:.6f}" for x in vec)

@@ -22,6 +22,40 @@ def _corpus(n_sentences=300, seed=0):
 
 
 
+W2V_TABLES = ("input_table", "output_table", "adagrad_in", "adagrad_out")
+
+
+def read_start_by_row(w2v):
+    """The four tables as they stand (rows in table order)."""
+    return [np.asarray(getattr(w2v, a).get()) for a in W2V_TABLES]
+
+
+def _by_word_tables(w2v):
+    """The tables whose rows are words: hierarchical softmax's output
+    rows are inner nodes of the Huffman tree."""
+    return W2V_TABLES[::2] if w2v.cfg.hs else W2V_TABLES
+
+
+def lay_start_by_word(w2v, start):
+    """Give ``w2v``'s four tables ``start`` laid out through ``rows_of``:
+    word ``i`` starts from ``start[.][i]`` wherever its row is."""
+    rows = w2v.rows_of(np.arange(len(w2v.dict)))
+    for attr, by_word in zip(W2V_TABLES, start):
+        by_row = by_word
+        if attr in _by_word_tables(w2v):
+            by_row = np.empty_like(by_word)
+            by_row[rows] = by_word
+        getattr(w2v, attr).store.write_dense(by_row)
+
+
+def read_by_word(w2v):
+    """The four tables, every table of words in WORD order."""
+    rows = w2v.rows_of(np.arange(len(w2v.dict)))
+    return [np.asarray(getattr(w2v, a).get())[
+        rows if a in _by_word_tables(w2v) else slice(None)]
+        for a in W2V_TABLES]
+
+
 def _assert_topic_separation(w2v, d, margin=0.1):
     emb = w2v.embeddings().astype(np.float32)
     emb = emb / (np.linalg.norm(emb, axis=1, keepdims=True) + 1e-12)
@@ -524,7 +558,7 @@ def test_sharded_dpxtp_matches_single_device_losses(mv_env):
     pairs/negatives/update order; only the layout differs."""
     sents = _corpus(300)
     d = Dictionary.build(sents, min_count=1)
-    runs = []
+    runs, start = [], None
     for mesh_data, mesh_model in ((1, 1), (4, 2)):
         cfg = Word2VecConfig(embedding_size=32, batch_size=256, window=4,
                              negative=5, min_count=1, sample=0, sg=True,
@@ -533,9 +567,14 @@ def test_sharded_dpxtp_matches_single_device_losses(mv_env):
                              pad_sentence_length=16, pipeline=False,
                              mesh_data=mesh_data, mesh_model=mesh_model)
         w2v = Word2Vec(cfg, d)
+        # The same start BY WORD: on the mesh a word's row is not its id
+        # (ISSUE 36), and the tables are seeded by row.
+        start = start or read_start_by_row(w2v)
+        lay_start_by_word(w2v, start)
         stats = w2v.train(sentences=[d.encode(s) for s in sents])
         runs.append((stats, w2v.embeddings().astype(np.float32)))
     (s1, e1), (s2, e2) = runs
+    assert (s1["row_layout"], s2["row_layout"]) == ("range", "interleaved/2")
     assert s1["pairs"] == s2["pairs"] > 0
     np.testing.assert_allclose(s2["loss"], s1["loss"], rtol=1e-4)
     np.testing.assert_allclose(e2, e1, rtol=1e-3, atol=1e-5)
@@ -638,3 +677,185 @@ def test_device_cbow_example_mask_semantics(mv_env):
                 assert 0 <= src < int(lengths[row]), \
                     f"context slot ({p},{j}) points at pad position {src}"
     assert cm[ex].sum() > 0
+
+
+# -- a word's row on a mesh (ISSUE 36) ---------------------------------------
+def _counts_dictionary(vocab):
+    """Frequency-ranked counts without a corpus (training is by ids)."""
+    d = Dictionary(min_count=1)
+    d.counts = [max(1, 4000 // (i + 1)) for i in range(vocab)]
+    d.words = [f"w{i}" for i in range(vocab)]
+    d.word2id = {w: i for i, w in enumerate(d.words)}
+    return d
+
+
+def _mesh_cfg(mesh_data, mesh_model, cols=32, **kw):
+    base = dict(embedding_size=cols, window=2, negative=3, sample=0,
+                batch_size=64, block_sentences=4, pad_sentence_length=16,
+                device_pipeline=True, pipeline=False, seed=3,
+                learning_rate=0.05, mesh_data=mesh_data,
+                mesh_model=mesh_model)
+    return Word2VecConfig(**{**base, **kw})
+
+
+@pytest.mark.parametrize("vocab,mesh_data,mesh_model", [
+    (80, 1, 1), (80, 2, 1), (80, 2, 2), (80, 2, 4), (81, 2, 2), (82, 2, 4),
+    (83, 1, 4)])
+def test_rows_of_deals_the_words_round_robin_over_the_row_shards(
+        mv_env, vocab, mesh_data, mesh_model):
+    """``rows_of``: a bijection on ``range(V)``; the identity where the
+    ``model`` axis is not divided; ``n`` neighbouring ranks fall in ``n``
+    different shards, in rank order inside a shard; the pad id stays."""
+    w2v = Word2Vec(_mesh_cfg(mesh_data, mesh_model), _counts_dictionary(vocab))
+    n, words = mesh_model, np.arange(vocab, dtype=np.int32)
+    rows = w2v.rows_of(words)
+    assert rows.dtype == np.int32 and rows[0] == 0
+    assert sorted(rows.tolist()) == list(range(vocab))
+    assert w2v.row_layout == ("range" if n == 1 else f"interleaved/{n}")
+    if n == 1:
+        assert np.array_equal(rows, words)
+        return
+    if vocab % n == 0:
+        np.testing.assert_array_equal(
+            rows, (words % n) * (vocab // n) + words // n)
+    # The shard of a row as jax cuts [V, D] over n: ceil(V / n) rows each.
+    # (Where n does not divide V the dealt ranges miss jax's by under n
+    # rows: the balance does not feel it.)
+    shard = rows // -(-vocab // n)
+    if vocab % n == 0 or n == 2:
+        for r in range(0, vocab - n + 1):
+            assert len(set(shard[r:r + n].tolist())) == n
+    assert np.abs(np.bincount(shard, minlength=n) - vocab / n).max() < n
+    for k in range(n):      # a shard holds its words in rank order
+        mine = rows[words % n == k]
+        assert (np.diff(mine) == 1).all()
+    # any shape, lists too
+    assert w2v.rows_of([[1, 2], [3, 0]]).tolist() == \
+        [rows[[1, 2]].tolist(), rows[[3, 0]].tolist()]
+
+
+def test_a_shard_owns_half_of_a_zipf_stream_interleaved_not_by_range(mv_env):
+    """Counted on ids, no device: by range the first of two shards owns
+    over 0.8 of a Zipf stream's positions and of the negative table;
+    through ``rows_of`` between 0.45 and 0.55."""
+    vocab = 4096
+    d = _counts_dictionary(vocab)
+    d.counts = [max(1, 10 ** 6 // (i + 1)) for i in range(vocab)]
+    by_range = Word2Vec(_mesh_cfg(1, 1, cols=8), d)
+    dealt = Word2Vec(_mesh_cfg(2, 2, cols=8), d)
+    p = np.asarray(d.counts, np.float64)
+    ids = np.random.default_rng(0).choice(vocab, size=1 << 16, p=p / p.sum())
+
+    def share(rows):
+        return float((np.asarray(rows) < vocab // 2).mean())
+
+    assert share(by_range.rows_of(ids)) > 0.8
+    assert 0.45 < share(dealt.rows_of(ids)) < 0.55
+    assert share(by_range._neg_table) > 0.8
+    assert 0.45 < share(dealt._neg_table) < 0.55
+    # the same POSITIONS of the negative table hold the same words
+    np.testing.assert_array_equal(
+        dealt.rows_of(np.asarray(by_range._neg_table)),
+        np.asarray(dealt._neg_table))
+    np.testing.assert_array_equal(
+        np.asarray(dealt._keep_prob)[dealt.rows_of(np.arange(vocab))],
+        np.asarray(by_range._keep_prob))
+
+
+def _id_sentences(vocab, n=12, length=16, seed=0):
+    p = 1.0 / np.arange(1, vocab + 1)
+    rng = np.random.default_rng(seed)
+    return [rng.choice(vocab, size=length, p=p / p.sum()).tolist()
+            for _ in range(n)]
+
+
+@pytest.mark.parametrize("case,vocab,cols,variant", [
+    ("sg_ns_on_the_row_kernel", 80, 128, dict(sg=True, hs=False)),
+    ("sg_ns_odd_vocabulary", 81, 32, dict(sg=True, hs=False)),
+    ("cbow_ns", 80, 32, dict(sg=False, hs=False)),
+    ("sg_hs", 80, 32, dict(sg=True, hs=True)),
+    ("cbow_hs", 80, 32, dict(sg=False, hs=True)),
+    ("sg_ns_sgd_subsampled", 80, 32, dict(sg=True, hs=False, sample=1e-2,
+                                          optimizer="sgd"))])
+def test_the_2x2_mesh_model_trains_the_one_device_models_words(
+        mv_env, case, vocab, cols, variant):
+    """From the same start BY WORD (laid out through ``rows_of``) the mesh
+    model draws the same pairs and negatives of the same words and updates
+    their rows: pair count, loss and every table read back by word are the
+    one-device run's, to ``test_sharded_dpxtp_matches_single_device_
+    losses``' tolerance. For hierarchical softmax the Huffman tables' rows
+    follow their words and the points (inner nodes) stay."""
+    d = _counts_dictionary(vocab)
+    sents = _id_sentences(vocab)
+    runs, start = [], None
+    for mesh in ((1, 1), (2, 2)):
+        w2v = Word2Vec(_mesh_cfg(*mesh, cols=cols, **variant), d)
+        start = start or read_start_by_row(w2v)
+        lay_start_by_word(w2v, start)
+        stats = w2v.train(sentences=sents)
+        runs.append((stats, read_by_word(w2v),
+                     w2v.embeddings().astype(np.float32)))
+    (s1, t1, e1), (s2, t2, e2) = runs
+    assert (s1["row_layout"], s2["row_layout"]) == ("range", "interleaved/2")
+    assert s1["pairs"] == s2["pairs"] > 0
+    np.testing.assert_allclose(s2["loss"], s1["loss"], rtol=1e-4)
+    np.testing.assert_allclose(e2, e1, rtol=1e-3, atol=1e-5)
+    np.testing.assert_array_equal(e2, t2[0])
+    for got, want, was in zip(t2, t1, start):
+        np.testing.assert_allclose(got, want, rtol=1e-3, atol=1e-5)
+    assert np.abs(t1[0] - start[0]).max() > 1e-4     # it trained
+
+
+def test_save_writes_each_words_vector_on_its_own_line_on_a_mesh(
+        tmp_path, mv_env):
+    """``save()`` and ``embeddings()`` are by WORD on a mesh: line ``i``
+    holds word ``i`` and the trained vector of ITS row; ``most_similar``
+    and ``analogy`` name words, not rows."""
+    vocab = 80
+    d = _counts_dictionary(vocab)
+    w2v = Word2Vec(_mesh_cfg(2, 2), d)
+    w2v.train(sentences=_id_sentences(vocab))
+    path = str(tmp_path / "vectors.txt")
+    w2v.save(path, batch_rows=32)       # several batches of row lookups
+    lines = open(path).read().splitlines()
+    assert lines[0] == f"{vocab} 32"
+    table = np.asarray(w2v.input_table.get())
+    rows = w2v.rows_of(np.arange(vocab))
+    assert not np.array_equal(rows, np.arange(vocab))
+    for i, line in enumerate(lines[1:]):
+        word, *vec = line.split()
+        assert word == d.words[i]
+        np.testing.assert_allclose(np.asarray(vec, np.float32),
+                                   table[rows[i]], atol=1e-6)
+    np.testing.assert_array_equal(w2v.embeddings(), table[rows])
+    emb = w2v.embeddings()
+    unit = emb / np.linalg.norm(emb, axis=1, keepdims=True)
+    sims = unit @ unit[5]
+    sims[5] = -np.inf
+    assert w2v.most_similar("w5", topk=1)[0][0] == f"w{int(sims.argmax())}"
+    assert len(w2v.analogy("w1", "w2", "w3", topk=3)) == 3
+
+
+@pytest.mark.parametrize("case,mesh,extra,layout", [
+    ("one_device", (1, 1), {}, "range"),
+    ("host_batches", (1, 1), dict(device_pipeline=False), "range"),
+    ("data_axis_only", (2, 1), {}, "range"),
+    ("model_axis_of_two", (2, 2), {}, "interleaved/2"),
+    ("model_axis_of_four", (1, 4), {}, "interleaved/4"),
+    ("client_plane_on_a_mesh", (2, 2), dict(comm_policy="ps"), "range")])
+def test_the_layout_is_named_in_the_stats_and_counted_a_block(
+        mv_env, case, mesh, extra, layout):
+    """``stats["row_layout"]`` and ``w2v.rows.layout.interleaved`` (one a
+    block) follow the number of row shards on the ``model`` axis, nothing
+    else; the pure client plane trains by id and stays by range."""
+    from multiverso_tpu.telemetry import counter
+    vocab = 80
+    w2v = Word2Vec(_mesh_cfg(*mesh, **extra), _counts_dictionary(vocab))
+    was = counter("w2v.rows.layout.interleaved").value
+    stats = w2v.train(sentences=_id_sentences(vocab, n=8))    # two blocks
+    assert stats["row_layout"] == w2v.row_layout == layout
+    assert counter("w2v.rows.layout.interleaved").value - was == \
+        (0 if layout == "range" else 2)
+    rows = w2v.rows_of(np.arange(vocab))
+    assert np.array_equal(rows, np.arange(vocab)) == (layout == "range")
+    assert stats["pairs"] > 0 and np.isfinite(stats["loss"])
