@@ -4,7 +4,8 @@ docs/HYBRID_LM.md."""
 
 from multiverso_tpu.models.hybrid_lm import rope
 from multiverso_tpu.models.hybrid_lm.config import (ATTENTION, DENSE, EVA,
-                                                    EXPERTS, LATENT, MAMBA,
+                                                    EXPERTS, LATENT,
+                                                    LIGHTNING, MAMBA, SPARSE,
                                                     HybridLMConfig)
 from multiverso_tpu.models.hybrid_lm.model import (APPLY_PROGRAM,
                                                    DELTA_PROGRAM, HybridLM,
@@ -16,7 +17,7 @@ from multiverso_tpu.models.hybrid_lm.model import (APPLY_PROGRAM,
                                                    rmsnorm)
 
 __all__ = ["HybridLMConfig", "HybridLM", "MAMBA", "EXPERTS", "ATTENTION",
-           "LATENT", "DENSE", "EVA",
+           "LATENT", "DENSE", "EVA", "SPARSE", "LIGHTNING",
            "DELTA_PROGRAM", "APPLY_PROGRAM", "dense_param_count",
            "forward_hidden", "init_buffers", "init_params", "layer_forward",
            "make_loss", "pack_batch", "param_shapes", "rmsnorm", "rope"]

@@ -13,13 +13,18 @@ The values may be narrower or wider than the queries and keys, and the
 caller may give the softmax scale: latent attention (:func:`latent_attention_
 mixer`) has 192-wide rotary-carrying keys against 128-wide values.
 
-What a query block sees is one of two shapes. Plain causal (``*``, ``L``):
-every key block at or before it. Windowed with remote keys (EVA,
-:func:`eva_attention`): the key blocks of its own aligned window at or before
-it, and every key of a second, dense key set, grouped a window's worth to a
-block, that belongs to an EARLIER window; one softmax over both. The second
-set's keys are not positions of the sequence (EVA: pooled summaries of
-chunks), so its gradients are sums over every later window's queries.
+What a query block sees is one of three shapes. Plain causal (``*``, ``L``):
+every key block at or before it. Chosen (``S``, :func:`sparse_attention`):
+causal, and of those keys the ones in the small key blocks a per-query mask
+names, which is DATA (each query and key-value head chooses its blocks from
+scores the layer computes itself, :func:`sparse_select`); the mask decides
+what is attended, not what is computed: the walk is the causal one. Windowed
+with remote keys (EVA, :func:`eva_attention`): the key blocks of its own
+aligned window at or before it, and every key of a second, dense key set,
+grouped a window's worth to a block, that belongs to an EARLIER window; one
+softmax over both. The second set's keys are not positions of the sequence
+(EVA: pooled summaries of chunks), so its gradients are sums over every later
+window's queries.
 """
 
 from __future__ import annotations
@@ -33,16 +38,25 @@ from multiverso_tpu.models.hybrid_lm import rope
 from multiverso_tpu.models.hybrid_lm.norm import rmsnorm
 
 __all__ = ["causal_gqa", "attention_mixer", "latent_attention_mixer",
-           "eva_attention", "eva_summaries", "eva_mixer", "in_blocks"]
+           "eva_attention", "eva_summaries", "eva_mixer", "in_blocks",
+           "sparse_select", "sparse_attention", "sparse_mixer",
+           "output_gate"]
 
 
-def _scores(qi, kj, i, j, blk, scale):
+def _scores(qi, kj, i, j, blk, scale, chosen=None):
     """Masked scores of query block ``i`` against key block ``j``:
-    ``qi`` [B, blk, K, G, D], ``kj`` [B, blk, K, D] -> [B, K, G, blk, blk]."""
+    ``qi`` [B, blk, K, G, D], ``kj`` [B, blk, K, D] -> [B, K, G, blk, blk].
+    ``chosen`` [nb, B, K, blk, small key blocks] bool: beside the causal
+    mask, what each query of each key-value head chose."""
     s = jnp.einsum("bqkgd,bskd->bkgqs", qi, kj) * scale
     rows = i * blk + jnp.arange(blk)
     cols = j * blk + jnp.arange(blk)
-    return jnp.where(rows[:, None] >= cols[None, :], s, -jnp.inf)
+    seen = rows[:, None] >= cols[None, :]
+    if chosen is not None:
+        per = chosen.shape[-1] // chosen.shape[0]
+        mine = jax.lax.dynamic_slice_in_dim(chosen[i], j * per, per, axis=-1)
+        seen = seen & jnp.repeat(mine, blk // per, axis=-1)[:, :, None]
+    return jnp.where(seen, s, -jnp.inf)
 
 
 def _remote_scores(qi, kr, scale):
@@ -55,10 +69,12 @@ def _block(x, i):
     return jax.lax.dynamic_index_in_dim(x, i, axis=1, keepdims=False)
 
 
-def _forward(q, k, v, remote, scale, blk, span):
+def _forward(q, k, v, remote, chosen, scale, blk, span):
     """``q`` [B, nb, blk, K, G, D], ``k`` [B, nb, blk, K, D], ``v``
     [B, nb, blk, K, Dv] -> (out [B, nb, blk, K, G, Dv], lse [nb, B, K, G,
-    blk]). ``span`` None: query block ``i`` sees key blocks ``0..i``. Else a
+    blk]). ``span`` None: query block ``i`` sees key blocks ``0..i``, all of
+    their keys at or before each query or, with ``chosen`` (:func:`_scores`),
+    those of them a query chose: every query has to have chosen key 0. Else a
     window is ``span`` blocks: it sees those of its window, ``i // span *
     span .. i``, and of ``remote`` = (keys [B, nw, R, K, D], values [B, nw,
     R, K, Dv]) the blocks ``0 .. i // span - 1``, unmasked."""
@@ -78,8 +94,8 @@ def _forward(q, k, v, remote, scale, blk, span):
             return m_new, l * alpha + jnp.sum(p, axis=-1), acc
 
         def key_block(j, carry):
-            return merge(carry, _scores(qi, _block(k, j), i, j, blk, scale),
-                         v, j)
+            return merge(carry, _scores(qi, _block(k, j), i, j, blk, scale,
+                                        chosen), v, j)
 
         carry = jax.lax.fori_loop(
             0 if span is None else i // span * span, i + 1, key_block, (
@@ -100,7 +116,7 @@ def _forward(q, k, v, remote, scale, blk, span):
     return jnp.moveaxis(out, 0, 1), lse
 
 
-def _backward(q, k, v, remote, out, lse, dout, scale, blk, span):
+def _backward(q, k, v, remote, chosen, out, lse, dout, scale, blk, span):
     nb = q.shape[1]
     delta = jnp.einsum("bnqkgd,bnqkgd->nbkgq", dout.astype(jnp.float32),
                        out.astype(jnp.float32))
@@ -125,7 +141,8 @@ def _backward(q, k, v, remote, out, lse, dout, scale, blk, span):
         kj, vj = _block(k, j), _block(v, j)
         dq, dkj, dvj = jax.lax.fori_loop(
             j, nb if span is None else jnp.minimum(nb, (j // span + 1) * span),
-            pull(lambda qi, i: _scores(qi, kj, i, j, blk, scale), kj, vj),
+            pull(lambda qi, i: _scores(qi, kj, i, j, blk, scale, chosen),
+                 kj, vj),
             (dq, jnp.zeros_like(kj), jnp.zeros_like(vj)))
         return dq, (dkj, dvj)
 
@@ -133,7 +150,7 @@ def _backward(q, k, v, remote, out, lse, dout, scale, blk, span):
                                 jnp.arange(nb))
     dk, dv = jnp.moveaxis(dk, 0, 1), jnp.moveaxis(dv, 0, 1)
     if remote is None:
-        return dq, dk, dv, None
+        return dq, dk, dv, None, None
 
     def remote_block(dq, r):
         # seen by every query block of every LATER window
@@ -146,22 +163,24 @@ def _backward(q, k, v, remote, out, lse, dout, scale, blk, span):
 
     dq, (dkr, dvr) = jax.lax.scan(remote_block, dq,
                                   jnp.arange(remote[0].shape[1]))
-    return dq, dk, dv, (jnp.moveaxis(dkr, 0, 1), jnp.moveaxis(dvr, 0, 1))
+    return (dq, dk, dv, (jnp.moveaxis(dkr, 0, 1), jnp.moveaxis(dvr, 0, 1)),
+            None)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _blocked_attention(q, k, v, remote, scale, blk, span):
-    return _forward(q, k, v, remote, scale, blk, span)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _blocked_attention(q, k, v, remote, chosen, scale, blk, span):
+    return _forward(q, k, v, remote, chosen, scale, blk, span)[0]
 
 
-def _vjp_fwd(q, k, v, remote, scale, blk, span):
-    out, lse = _forward(q, k, v, remote, scale, blk, span)
-    return out, (q, k, v, remote, out, lse)
+def _vjp_fwd(q, k, v, remote, chosen, scale, blk, span):
+    out, lse = _forward(q, k, v, remote, chosen, scale, blk, span)
+    return out, (q, k, v, remote, chosen, out, lse)
 
 
 def _vjp_bwd(scale, blk, span, saved, dout):
-    q, k, v, remote, out, lse = saved
-    return _backward(q, k, v, remote, out, lse, dout, scale, blk, span)
+    q, k, v, remote, chosen, out, lse = saved
+    return _backward(q, k, v, remote, chosen, out, lse, dout, scale, blk,
+                     span)
 
 
 _blocked_attention.defvjp(_vjp_fwd, _vjp_bwd)
@@ -185,7 +204,7 @@ def causal_gqa(q: jax.Array, k: jax.Array, v: jax.Array, block: int,
     bsz, s, kh, g, d = q.shape
     blk = min(block, s)
     out = _blocked_attention(
-        in_blocks(q, blk), in_blocks(k, blk), in_blocks(v, blk), None,
+        in_blocks(q, blk), in_blocks(k, blk), in_blocks(v, blk), None, None,
         float(d) ** -0.5 if scale is None else float(scale), blk, None)
     return out.reshape(bsz, -1, kh, g, v.shape[-1])[:, :s]
 
@@ -233,8 +252,8 @@ def eva_attention(q: jax.Array, k: jax.Array, v: jax.Array, phi: jax.Array,
     with jax.named_scope("lm_eva_agg"):
         out = _blocked_attention(
             qw.reshape(bsz, -1, blk, h, 1, d), kw.reshape(bsz, -1, blk, h, d),
-            vw.reshape(bsz, -1, blk, h, v.shape[-1]), remote, scale, blk,
-            window // blk)
+            vw.reshape(bsz, -1, blk, h, v.shape[-1]), remote, None, scale,
+            blk, window // blk)
     return out.reshape(bsz, nw * window, h, v.shape[-1])[:, :s]
 
 
@@ -251,6 +270,129 @@ def eva_mixer(p: dict, n: jax.Array, cfg) -> jax.Array:
     o = eva_attention(q, k, v, p["adaptive_phi"], p["adaptive_mu_k"],
                       cfg.window_size, cfg.eva_chunk_size, cfg.attn_block)
     return o.reshape(bsz, s, h * d) @ p["wo"]
+
+
+def sparse_select(q: jax.Array, k: jax.Array, length: int, cfg) -> jax.Array:
+    """What each query attends, chosen by the layer itself (InfLLM-v2; no
+    parameter, no gradient): ``q`` [B, nb, blk, K, G, D], ``k`` [B, nb * blk,
+    K, D] (zero past ``length``) -> [nb, B, K, blk, key blocks] bool over key
+    blocks of ``sparse_block_size``.
+
+    A pooled key ``m`` is the mean of the ``sparse_kernel_size`` keys from
+    ``sparse_kernel_stride * m`` on, for every ``m`` whose positions all
+    exist; query ``t`` sees it once its last position is at or before ``t``.
+    ``softmax_m(scale q_t . kc_m)`` over the seen ``m``, summed over the G
+    heads of a key-value head, is a pooled key's weight; a key block's score
+    is the largest weight of the pooled keys that reach into it. FORCED are
+    the first ``sparse_init_blocks`` blocks and every block that holds one of
+    the query's last ``sparse_window_size`` positions; CHOSEN are the
+    ``sparse_topk`` highest-scoring blocks that lie wholly before those
+    positions and are not forced (all of them if fewer; ties to the lower
+    block). A query block at a time: the weights of one are [B, K, G, blk,
+    pooled keys] float32, at ``highest`` precision (a choice flips on less
+    than a bfloat16 product's rounding)."""
+    q, k = jax.lax.stop_gradient(q), jax.lax.stop_gradient(k)
+    bsz, nb, blk, kh, g, d = q.shape
+    size, stride, cb = (cfg.sparse_kernel_size, cfg.sparse_kernel_stride,
+                        cfg.sparse_block_size)
+    per, before = cfg.sparse_pool
+    nkb = nb * blk // cb
+    pooled = max((length - size) // stride + 1, 0)
+    parts = k[:, :(pooled + size // stride - 1) * stride].reshape(
+        bsz, -1, stride, kh, d).sum(axis=2)
+    kc = sum(parts[:, o:o + pooled] for o in range(size // stride)) / size
+    m_end = stride * jnp.arange(pooled) + size - 1      # a pooled key's last
+    blocks = jnp.arange(nkb)[None, :]
+    first, last = cb * blocks, cb * blocks + cb - 1     # a key block's keys
+    scale = float(d) ** -0.5
+
+    def query_block(xs):
+        i, qi = xs
+        t = i * blk + jnp.arange(blk)
+        seen = m_end[None, :] <= t[:, None]
+        logits = scale * jnp.einsum("bqkgd,bmkd->bkgqm", qi, kc,
+                                    precision=jax.lax.Precision.HIGHEST)
+        top = jnp.max(jnp.where(seen, logits, -jnp.inf), axis=-1,
+                      keepdims=True)
+        e = jnp.where(seen, jnp.exp(logits - jnp.where(
+            jnp.isfinite(top), top, 0.0)), 0.0)
+        weight = jnp.sum(e / jnp.maximum(
+            jnp.sum(e, axis=-1, keepdims=True), 1e-30), axis=2)
+        # pooled keys ``per * b - before .. per * b + per - 1`` reach into
+        # key block b: the largest seen weight among them
+        reach = jnp.pad(jnp.where(seen, weight, -jnp.inf),
+                        ((0, 0),) * 3 + ((before, per * nkb - pooled),),
+                        constant_values=-jnp.inf)
+        score = functools.reduce(jnp.maximum, [
+            reach[..., o::per][..., :nkb] for o in range(per + before)])
+        recent = t[:, None] - (cfg.sparse_window_size - 1)
+        forced = (first <= t[:, None]) & (
+            (blocks < cfg.sparse_init_blocks) | (last >= recent))
+        free = (last < recent) & (blocks >= cfg.sparse_init_blocks)
+        values, picked = jax.lax.top_k(jnp.where(free, score, -jnp.inf),
+                                       min(cfg.sparse_topk, nkb))
+        chosen = jnp.any((picked[..., None] == blocks[0])
+                         & jnp.isfinite(values)[..., None], axis=-2)
+        return forced | chosen
+
+    return jax.lax.map(query_block, (jnp.arange(nb), jnp.moveaxis(q, 1, 0)))
+
+
+def sparse_attention(q: jax.Array, k: jax.Array, v: jax.Array, cfg):
+    """``q`` [B, S, K, G, D], ``k``, ``v`` [B, S, K, D] -> (out [B, S, K, G,
+    D], chosen, pairs). Up to ``sparse_dense_len`` positions: causal attention,
+    nothing chosen (``chosen`` None). Longer: one softmax, scale ``D ** -0.5``,
+    over the keys at or before a query in the blocks :func:`sparse_select`
+    names for it; ``chosen`` [B, K, S, key blocks] bool says which.
+    ``pairs`` int32: the (query, key) pairs a head attended (the mean over
+    the key-value heads)."""
+    bsz, s, kh, g, d = q.shape
+    scale = float(d) ** -0.5
+    if s <= cfg.sparse_dense_len:
+        with jax.named_scope("lm_sparse_attn"):
+            out = causal_gqa(q, k, v, cfg.attn_block, scale)
+        return out, None, jnp.int32(bsz * s * (s + 1) // 2)
+    blk, cb = cfg.attn_block, cfg.sparse_block_size
+    qb, kb, vb = (in_blocks(x, blk) for x in (q, k, v))
+    nb = qb.shape[1]
+    with jax.named_scope("lm_sparse_select"):
+        chosen = sparse_select(qb, kb.reshape(bsz, nb * blk, kh, d), s, cfg)
+        t = jnp.arange(nb * blk)
+        keys = jnp.clip(t[:, None] + 1 - cb * jnp.arange(nb * blk // cb), 0,
+                        cb) * (t[:, None] < s)          # of a block, <= t
+        pairs = (jnp.sum(chosen * keys.reshape(nb, 1, 1, blk, -1))
+                 // kh).astype(jnp.int32)
+    with jax.named_scope("lm_sparse_attn"):
+        out = _blocked_attention(qb, kb, vb, None, chosen, scale, blk, None)
+    chosen = jnp.moveaxis(chosen, 0, 2).reshape(bsz, kh, nb * blk, -1)
+    return (out.reshape(bsz, nb * blk, kh, g, d)[:, :s],
+            chosen[:, :, :s, :-(-s // cb)], pairs)
+
+
+def output_gate(o: jax.Array, n: jax.Array, wg: jax.Array) -> jax.Array:
+    """A mixer's output ``o`` [B, S, width] times ``sigmoid(n Wg)``, ``n`` the
+    block's normed input: what the sparse and the Lightning mixer put before
+    their output projection."""
+    return o * jax.nn.sigmoid(n @ wg)
+
+
+def sparse_mixer(p: dict, n: jax.Array, cfg):
+    """Block-sparse grouped-query attention (``minicpm4``): no bias, NO
+    positions, an RMSNorm over each query and key head (one weight vector of
+    ``head_dim`` each, shared by the heads), :func:`sparse_attention`, then a
+    gate ``sigmoid(n Wg)`` on its output before the output projection.
+    Returns (output, chosen, pairs)."""
+    bsz, s, _ = n.shape
+    kh = cfg.num_key_value_heads
+    g = cfg.num_attention_heads // kh
+    q = rmsnorm((n @ p["wq"]).reshape(bsz, s, kh, g, cfg.head_dim),
+                p["q_norm"], cfg.norm_eps)
+    k = rmsnorm((n @ p["wk"]).reshape(bsz, s, kh, cfg.head_dim),
+                p["k_norm"], cfg.norm_eps)
+    v = (n @ p["wv"]).reshape(bsz, s, kh, cfg.head_dim)
+    o, chosen, pairs = sparse_attention(q, k, v, cfg)
+    o = output_gate(o.reshape(bsz, s, cfg.q_dim), n, p["wg"])
+    return o @ p["wo"], chosen, pairs
 
 
 def attention_mixer(p: dict, n: jax.Array, cfg) -> jax.Array:

@@ -5,12 +5,24 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import math
 from typing import Any, Dict, Optional, Tuple
 
 __all__ = ["HybridLMConfig", "MAMBA", "EXPERTS", "ATTENTION", "LATENT",
-           "DENSE", "EVA"]
+           "DENSE", "EVA", "SPARSE", "LIGHTNING", "MIXER_TYPES"]
 
 MAMBA, EXPERTS, ATTENTION, LATENT, DENSE, EVA = "M", "E", "*", "L", "D", "V"
+SPARSE, LIGHTNING = "S", "N"
+#: A published ``mixer_types`` entry -> the letter of its block.
+MIXER_TYPES = {"minicpm4": SPARSE, "lightning-attn": LIGHTNING}
+#: The family's switches as published (``minicpm_sala``): each kind of mixer
+#: is implemented so and in no other form.
+_MIXER_SWITCHES = {
+    "minicpm4": {"attn_use_rope": False, "qk_norm": True,
+                 "attn_use_output_gate": True},
+    "lightning-attn": {"lightning_use_rope": True, "qk_norm": True,
+                       "use_output_norm": True, "use_output_gate": True,
+                       "lightning_scale": "1/sqrt(d)"}}
 
 #: Keys a file gives under the published ``config.json``'s own names: those
 #: every file has, those a kind of block needs (a file has the keys of the
@@ -31,14 +43,21 @@ _KEYS_OF_KIND = {
               "routed_scaling_factor", "norm_topk_prob"),
     EVA: ("num_attention_heads", "window_size", "chunk_size:eva_chunk_size",
           "rope_theta"),
+    SPARSE: ("num_attention_heads", "num_key_value_heads", "head_dim"),
+    LIGHTNING: ("lightning_nh", "lightning_nkv", "lightning_head_dim",
+                "rope_theta"),
 }
 _OPTIONAL_KEYS = ("moe_shared_expert_intermediate_size", "scoring_func",
                   "hidden_act", "aux_loss_alpha", "num_pred_heads",
-                  "norm_add_unit_offset")
+                  "norm_add_unit_offset", "scale_emb", "scale_depth",
+                  "dim_model_base")
+#: A sparse block's sizes, as the published ``sparse_config`` group names them.
+_SPARSE_KEYS = ("kernel_size", "kernel_stride", "block_size", "window_size",
+                "init_blocks", "topk", "dense_len")
 #: Keys of this repo, optional in a file.
 _OWN_KEYS = ("learning_rate", "adagrad_step", "init_std", "attn_block",
              "moe_block", "loss_block", "ffn_slab", "row_bucket",
-             "comm_policy")
+             "comm_policy", "lightning_chunk")
 
 
 @dataclasses.dataclass
@@ -51,8 +70,11 @@ class HybridLMConfig:
     #: One pre-norm residual block per letter: ``M`` Mamba-2, ``*`` grouped-
     #: query attention, ``L`` latent attention, ``V`` EVA (exact keys inside
     #: a window, pooled chunk summaries of every earlier window), ``D`` a
-    #: dense gated feed-forward, ``E`` an expert block. A layer of two blocks
-    #: (attention, then a feed-forward) is two letters.
+    #: dense gated feed-forward, ``E`` an expert block, ``S`` block-sparse
+    #: attention (per query and key-value head, the keys of a window, of the
+    #: first block and of ``sparse_topk`` blocks it chooses by itself), ``N``
+    #: Lightning linear attention (a fixed decay a head). A layer of two
+    #: blocks (attention, then a feed-forward) is two letters.
     pattern: str = "MEM*E"
     norm_eps: float = 1e-5
     #: Every RMSNorm scales by ``1 + w`` (``w`` drawn at zero), not by ``w``.
@@ -88,6 +110,45 @@ class HybridLMConfig:
     window_size: int = 8
     #: Positions one summary pools (the published ``chunk_size``).
     eva_chunk_size: int = 2
+    # -- block-sparse attention (InfLLM-v2): ``num_attention_heads`` query and
+    # ``num_key_value_heads`` key-value heads of ``head_dim``, no positions, a
+    # norm over each query and key head, a gate on the output. Sizes under
+    # the published ``sparse_config``'s names --------------------------------
+    #: Positions one pooled key is the mean of, and the step between two.
+    sparse_kernel_size: int = 4
+    sparse_kernel_stride: int = 2
+    #: Keys a block: what a query chooses, ``sparse_topk`` of them beside the
+    #: forced ones (the first ``sparse_init_blocks`` and those that hold one
+    #: of its last ``sparse_window_size`` positions).
+    sparse_block_size: int = 4
+    sparse_window_size: int = 8
+    sparse_init_blocks: int = 1
+    sparse_topk: int = 2
+    #: A sequence no longer than this is attended densely (plain causal).
+    sparse_dense_len: int = 16
+    # -- Lightning linear attention: rotary over the whole head
+    # (``rope_theta``, half layout), a norm over each query and key head and
+    # over the output, a gate on it ------------------------------------------
+    lightning_nh: int = 2
+    lightning_nkv: int = 2
+    lightning_head_dim: int = 16
+    #: The heads held here, by their number ``h`` (1..) among the model's
+    #: ``lightning_published_nh`` (empty / 0: all ``lightning_nh``, the whole
+    #: model's): a head's decay reads both, so a share of the heads keeps
+    #: the rates they were published with.
+    lightning_heads: Tuple[int, ...] = ()
+    lightning_published_nh: int = 0
+    #: Positions a chunk of the scan.
+    lightning_chunk: int = 8
+    # -- muP (``minicpm``): the embedding times ``scale_emb``, every residual
+    # branch times ``scale_depth / sqrt(published layers)``, the head's input
+    # over ``hidden_size / dim_model_base``; 0 leaves a scaling out -----------
+    scale_emb: float = 1.0
+    scale_depth: float = 0.0
+    dim_model_base: int = 0
+    #: Layers of the whole model (0: those of ``pattern``, which are its
+    #: first): a Lightning head's decay and the residual scale read it.
+    published_layers: int = 0
     # -- dense feed-forward ---------------------------------------------------
     intermediate_size: int = 128
     # -- expert block ---------------------------------------------------------
@@ -183,10 +244,36 @@ class HybridLMConfig:
     def eva_blocks(self) -> int:
         return self.pattern.count(EVA)
 
+    def layer_of(self, block: int) -> int:
+        """Published index of the layer block ``block`` belongs to: a layer
+        is a mixer and the feed-forward blocks after it."""
+        return sum(k not in (DENSE, EXPERTS) for k in self.pattern[:block])
+
+    @property
+    def layers(self) -> int:
+        return self.published_layers or self.layer_of(len(self.pattern))
+
+    @property
+    def residual_scale(self) -> float:
+        return self.scale_depth / math.sqrt(self.layers) \
+            if self.scale_depth else 1.0
+
+    @property
+    def logit_divisor(self) -> float:
+        return self.hidden_size / self.dim_model_base \
+            if self.dim_model_base else 1.0
+
+    @property
+    def sparse_pool(self) -> Tuple[int, int]:
+        """(pooled keys a block holds the start of, those before them that
+        reach into it): a block's score is the largest over both."""
+        return (self.sparse_block_size // self.sparse_kernel_stride,
+                self.sparse_kernel_size // self.sparse_kernel_stride - 1)
+
     def validate(self) -> None:
         from multiverso_tpu.utils.log import check
         check(set(self.pattern) <= {MAMBA, EXPERTS, ATTENTION, LATENT, DENSE,
-                                    EVA}
+                                    EVA, SPARSE, LIGHTNING}
               and self.pattern, f"bad layer pattern {self.pattern!r}")
         check(self.mamba_num_heads % self.n_groups == 0,
               "mamba heads must divide into n_groups")
@@ -209,6 +296,29 @@ class HybridLMConfig:
                   "an EVA window is a whole number of chunks")
             check(self.window_size % min(self.attn_block, self.window_size)
                   == 0, "an EVA window is a whole number of attention blocks")
+        if SPARSE in self.pattern:
+            stride, block = self.sparse_kernel_stride, self.sparse_block_size
+            check(self.sparse_kernel_size % stride == 0 and block % stride
+                  == 0, "pooled keys step evenly through a key block")
+            check(self.attn_block % block == 0,
+                  "an attention block is a whole number of key blocks")
+            check(self.sparse_init_blocks >= 1 and self.sparse_dense_len
+                  >= self.sparse_kernel_size,
+                  "every query sees the first key block, and a sequence "
+                  "that chooses has a pooled key")
+        if LIGHTNING in self.pattern:
+            check(self.lightning_nkv == self.lightning_nh
+                  and self.lightning_head_dim % 2 == 0,
+                  "Lightning attention has a key head a query head, of "
+                  "even width")
+            held, whole = self.lightning_heads, self.lightning_published_nh
+            check((whole or self.lightning_nh) == self.lightning_nh
+                  or held, "a share of the Lightning heads names them")
+            check(not held or (len(set(held)) == len(held)
+                               == self.lightning_nh and all(
+                1 <= h <= (whole or self.lightning_nh) for h in held)),
+                f"held Lightning heads {held} are not {self.lightning_nh} "
+                f"of 1..{whole or self.lightning_nh}")
         check(self.num_pred_heads >= 1 and self.ffn_slab >= 1,
               "num_pred_heads and ffn_slab are at least one")
         check(self.rope_scaling is None
@@ -221,9 +331,15 @@ class HybridLMConfig:
         """From the published keys. ``n_routed_experts`` counts the experts
         HELD (``held_experts`` names them, default the first ones; a file
         without the key holds none) and ``published.n_routed_experts`` the
-        router's width. The pattern is the first ``num_hidden_layers`` of
-        ``hybrid_override_pattern`` where the file has one; else every layer
-        is two blocks, attention (latent where the file has a
+        router's width; likewise ``lightning_nh`` counts the Lightning heads
+        held, ``held_lightning_heads`` names them by their published number
+        (1..) and ``published.lightning_nh`` says of how many (a file that
+        holds fewer than published has to name them). The pattern is the
+        first ``num_hidden_layers`` of ``hybrid_override_pattern`` where the
+        file has one, or two blocks a layer from the first
+        ``num_hidden_layers`` of ``mixer_types`` (an entry this program does
+        not know raises); else every layer is two
+        blocks, attention (latent where the file has a
         ``kv_lora_rank``, EVA where its ``attention_class`` is ``eva``) and a
         feed-forward: experts, where the file has any, from layer
         ``first_k_dense_replace`` on at every ``moe_layer_freq``-th layer,
@@ -238,6 +354,8 @@ class HybridLMConfig:
                              f"n_routed_experts says {n_held}")
         if "hybrid_override_pattern" in d:
             pattern = d["hybrid_override_pattern"][:d["num_hidden_layers"]]
+        elif "mixer_types" in d:
+            pattern = cls._pattern_of_mixers(d)
         else:
             if d.get("q_lora_rank") is not None:
                 raise ValueError("a query latent (q_lora_rank) is not "
@@ -274,6 +392,17 @@ class HybridLMConfig:
             kw["head_dim"] = d.get("head_dim") or \
                 d["hidden_size"] // d["num_attention_heads"]
         kw.update({key: d[key] for key in _OPTIONAL_KEYS if key in d})
+        if SPARSE in kinds:
+            kw.update({"sparse_" + key: d["sparse_config"][key]
+                       for key in _SPARSE_KEYS})
+        if "scale_depth" in d or LIGHTNING in kinds:
+            kw["published_layers"] = published.get("num_hidden_layers",
+                                                   d["num_hidden_layers"])
+        if LIGHTNING in kinds:
+            kw.update(
+                lightning_published_nh=published.get("lightning_nh",
+                                                     d["lightning_nh"]),
+                lightning_heads=tuple(d.get("held_lightning_heads", ())))
         kw.setdefault("moe_shared_expert_intermediate_size",
                       d.get("n_shared_experts", 0)
                       * d.get("moe_intermediate_size", 0))
@@ -292,6 +421,23 @@ class HybridLMConfig:
         cfg = cls(**kw)
         cfg.validate()
         return cfg
+
+    @staticmethod
+    def _pattern_of_mixers(d: Dict[str, Any]) -> str:
+        """Two blocks a layer, the mixer ``mixer_types`` names and a dense
+        feed-forward, for the first ``num_hidden_layers`` of the list."""
+        names = d["mixer_types"][:d["num_hidden_layers"]]
+        unknown = sorted(set(names) - set(MIXER_TYPES))
+        if unknown or len(names) < d["num_hidden_layers"]:
+            raise ValueError(
+                f"mixer_types names {len(names)} layers of "
+                f"{d['num_hidden_layers']}, unknown kinds {unknown}")
+        for name in set(names):
+            for key, value in _MIXER_SWITCHES[name].items():
+                if d.get(key) != value:
+                    raise ValueError(f"{name} with {key} = {d.get(key)!r} "
+                                     f"is not implemented")
+        return "".join(MIXER_TYPES[name] + DENSE for name in names)
 
     @classmethod
     def from_file(cls, path: str, **overrides) -> "HybridLMConfig":

@@ -5,12 +5,17 @@ block from a pattern: ``M`` a Mamba-2 state-space mixer
 (:mod:`.mamba2`), ``*`` causal grouped-query attention without positions,
 ``L`` latent attention with a decoupled rotary key, ``V`` EVA: exact keys
 inside a window and pooled chunk summaries of every earlier one
-(:mod:`.attention`, :mod:`.rope`), ``D`` a dense gated feed-forward, ``E``
+(:mod:`.attention`, :mod:`.rope`), ``S`` block-sparse attention whose
+queries choose their key blocks themselves (:func:`.attention.sparse_mixer`),
+``N`` Lightning linear attention with a fixed decay a head
+(:mod:`.lightning`), ``D`` a dense gated feed-forward, ``E``
 this chip's share
 of a top-k expert layer with shared experts (:func:`~multiverso_tpu.
 parallel.expert.held_topk_moe`: sigmoid or softmax router, ``relu2`` or
 gated experts, by the configuration's published keys). A layer of two
-blocks is two letters. Then a final RMSNorm and an untied head; the loss
+blocks is two letters. Under muP (``scale_emb``, ``scale_depth``,
+``dim_model_base``) the embedding, every residual branch and the head's input
+are scaled by constants. Then a final RMSNorm and an untied head; the loss
 is next-token cross-entropy over the vocabulary slice (with
 ``num_pred_heads`` > 1 the head is that many vocabularies wide and head
 ``h`` predicts the token ``1 + h`` ahead), taken in blocks of tokens so that
@@ -39,6 +44,7 @@ here): :meth:`HybridLM.dense_leaves` hands the live leaves out on demand.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Dict, List, Optional, Tuple
 
@@ -50,10 +56,14 @@ import multiverso_tpu as mv
 from multiverso_tpu.core.options import AddOption, MatrixTableOption
 from multiverso_tpu.core.updater import get_updater
 from multiverso_tpu.models.hybrid_lm.attention import (
-    in_blocks, attention_mixer, eva_mixer, latent_attention_mixer)
+    in_blocks, attention_mixer, eva_mixer, latent_attention_mixer,
+    sparse_mixer)
 from multiverso_tpu.models.hybrid_lm.config import (ATTENTION, DENSE, EVA,
-                                                    EXPERTS, LATENT, MAMBA,
+                                                    EXPERTS, LATENT,
+                                                    LIGHTNING, MAMBA, SPARSE,
                                                     HybridLMConfig)
+from multiverso_tpu.models.hybrid_lm.lightning import (lightning_mixer,
+                                                       lightning_slopes)
 from multiverso_tpu.models.hybrid_lm.mamba2 import mamba2_mixer
 from multiverso_tpu.models.hybrid_lm.norm import rmsnorm
 from multiverso_tpu.parallel.expert import held_topk_moe
@@ -96,6 +106,17 @@ def _layer_shapes(cfg: HybridLMConfig, kind: str) -> Dict[str, tuple]:
         return {"norm": (d,), "wq": (d, cfg.q_dim), "wk": (d, cfg.q_dim),
                 "wv": (d, cfg.q_dim), "adaptive_phi": h,
                 "adaptive_mu_k": h, "wo": (cfg.q_dim, d)}
+    if kind == SPARSE:
+        hd = (cfg.head_dim,)
+        return {"norm": (d,), "wq": (d, cfg.q_dim), "wk": (d, cfg.kv_dim),
+                "wv": (d, cfg.kv_dim), "q_norm": hd, "k_norm": hd,
+                "wg": (d, cfg.q_dim), "wo": (cfg.q_dim, d)}
+    if kind == LIGHTNING:
+        hd = (cfg.lightning_head_dim,)
+        w = cfg.lightning_nh * cfg.lightning_head_dim
+        return {"norm": (d,), "wq": (d, w), "wk": (d, w), "wv": (d, w),
+                "q_norm": hd, "k_norm": hd, "o_norm": (w,), "wg": (d, w),
+                "wo": (w, d)}
     if kind == DENSE:
         f = cfg.intermediate_size
         return {"norm": (d,), "ffn_gate": (d, f), "ffn_up": (d, f),
@@ -122,8 +143,11 @@ def dense_param_count(cfg: HybridLMConfig) -> int:
 
 
 #: Leaves that project back into the residual stream: scaled down by
-#: ``sqrt(layers)`` (``rescale_prenorm_residual``).
+#: ``sqrt(layers)`` (``rescale_prenorm_residual``), unless the residual
+#: branches themselves are (``scale_depth``).
 _OUT_PROJECTIONS = ("out_proj", "wo", "w_down", "s_down", "ffn_down")
+_NORMS = ("norm", "gnorm", "kv_norm", "q_norm", "k_norm", "o_norm",
+          "final_norm")
 
 
 def init_params(cfg: HybridLMConfig) -> dict:
@@ -135,12 +159,12 @@ def init_params(cfg: HybridLMConfig) -> dict:
     Mamba-2 draws them; EVA's ``phi`` and ``mu`` normal, clamped to [-1, 1],
     times ``head_dim ** -0.5``."""
     rng = np.random.default_rng(cfg.seed)
-    depth = math.sqrt(len(cfg.pattern))
+    depth = 1.0 if cfg.scale_depth else math.sqrt(len(cfg.pattern))
 
     def leaf(name, shape):
         if name in ("norm", "final_norm") and cfg.norm_add_unit_offset:
             return np.zeros(shape, np.float32)
-        if name in ("norm", "gnorm", "kv_norm", "D", "final_norm"):
+        if name in _NORMS or name == "D":
             return np.ones(shape, np.float32)
         if name in ("adaptive_phi", "adaptive_mu_k"):
             return np.clip(rng.standard_normal(shape, dtype=np.float32),
@@ -170,12 +194,23 @@ def init_params(cfg: HybridLMConfig) -> dict:
 def init_buffers(cfg: HybridLMConfig) -> list:
     """Per block what is carried but not trained: a sigmoid-routed expert
     block's ``e_score_correction_bias`` (seeded, small; the published scheme
-    moves it outside the gradient, here it stays fixed)."""
+    moves it outside the gradient, here it stays fixed); a Lightning block's
+    decay a head (:func:`~.lightning.lightning_slopes` of its published
+    layer)."""
     rng = np.random.default_rng(cfg.seed + 7)
     biased = cfg.scoring_func == "sigmoid"
-    return [jnp.asarray(rng.uniform(-0.01, 0.01, cfg.router_experts)
-                        .astype(np.float32)) if k == EXPERTS and biased
-            else None for k in cfg.pattern]
+
+    def buffer(i, kind):
+        if kind == EXPERTS and biased:
+            return jnp.asarray(rng.uniform(-0.01, 0.01, cfg.router_experts)
+                               .astype(np.float32))
+        if kind == LIGHTNING:
+            return jnp.asarray(lightning_slopes(
+                cfg.lightning_published_nh or cfg.lightning_nh,
+                cfg.layer_of(i), cfg.layers, cfg.lightning_heads or None))
+        return None
+
+    return [buffer(i, kind) for i, kind in enumerate(cfg.pattern)]
 
 
 # -- the forward pass ---------------------------------------------------------
@@ -191,6 +226,8 @@ _SEQUENCE_MIXERS = {
     ATTENTION: (attention_mixer, "lm_attention"),
     LATENT: (latent_attention_mixer, "lm_mla"),
     EVA: (eva_mixer, "lm_eva"),
+    SPARSE: (sparse_mixer, "lm_attention"),
+    LIGHTNING: (lightning_mixer, "lm_lightning"),
     DENSE: (dense_ffn_mixer, "lm_dense_ffn"),
 }
 
@@ -199,7 +236,12 @@ def layer_forward(kind: str, p: dict, bias, u: jax.Array,
                   cfg: HybridLMConfig, remat: bool = False):
     """One block: (``u + mixer(RMSNorm_w(u))``, assignments per held expert
     or None), and third, for an expert block of a configuration that
-    weighs one, its balance loss. With ``remat`` the block is rematerialised
+    weighs one, its balance loss; the mixer's output times
+    ``cfg.residual_scale`` where the configuration scales its residual
+    branches. A sparse block's second result is what it chose, ``{"chosen":
+    [B, K, S, key blocks] bool or None, "pairs": int32 [B]}``
+    (:func:`~.attention.sparse_attention`); a Lightning block's ``bias`` is
+    its heads' decay. With ``remat`` the block is rematerialised
     in the backward pass: what a step keeps of its forward is the [B, S,
     hidden] input. A Mamba-2 or attention block mixes inside a sequence
     only, and a dense feed-forward token by token, so each runs (and is
@@ -209,13 +251,22 @@ def layer_forward(kind: str, p: dict, bias, u: jax.Array,
     keep = jax.checkpoint if remat else (lambda fn: fn)
     if kind in _SEQUENCE_MIXERS:
         mixer, scope = _SEQUENCE_MIXERS[kind]
-        offset = cfg.norm_add_unit_offset
+        offset, scale = cfg.norm_add_unit_offset, cfg.residual_scale
+        if kind == LIGHTNING:
+            mixer = functools.partial(mixer, slopes=bias)
 
         def one_sequence(seq):
             n = rmsnorm(seq[None], p["norm"], cfg.norm_eps, offset)
-            return seq + mixer(p, n, cfg)[0]
+            y, *chose = mixer(p, n, cfg) if kind == SPARSE \
+                else (mixer(p, n, cfg),)
+            y = y[0] if scale == 1.0 else scale * y[0]
+            return (seq + y, *chose) if chose else seq + y
 
         with jax.named_scope(scope):
+            if kind == SPARSE:
+                out, chosen, pairs = jax.lax.map(keep(one_sequence), u)
+                return out, {"pairs": pairs, "chosen": None
+                             if chosen is None else chosen[:, 0]}
             if kind == DENSE and u.shape[1] > cfg.ffn_slab:
                 slabs = in_blocks(u, cfg.ffn_slab)
                 out = jnp.stack([keep(one_sequence)(slab) for slab in
@@ -236,6 +287,7 @@ def layer_forward(kind: str, p: dict, bias, u: jax.Array,
             cfg.routed_scaling_factor, cfg.norm_topk_prob, cfg.moe_block,
             True, cfg.scoring_func, p.get("w_gate"), p.get("s_gate"),
             (cfg.aux_loss_alpha, bsz) if cfg.balanced else None)
+        y = y if cfg.residual_scale == 1.0 else cfg.residual_scale * y
         return (u + y.reshape(u.shape), counts, *balance)
 
     with jax.named_scope("lm_experts"):
@@ -245,25 +297,31 @@ def layer_forward(kind: str, p: dict, bias, u: jax.Array,
 def forward_hidden(params: dict, buffers: list, u: jax.Array,
                    cfg: HybridLMConfig, remat: bool = True):
     """The block stack over ``u`` [B, S, hidden] -> (hidden states before
-    the final norm, [expert blocks, held] assignment counts), and third,
-    where the configuration weighs one, the summed balance loss."""
-    counts, balance = [], []
+    the final norm, [expert blocks, held] assignment counts), then, where
+    the configuration weighs one, the summed balance loss, then, where it
+    has sparse blocks, what each of them chose (:func:`layer_forward`)."""
+    counts, balance, chose = [], [], []
     for i, kind in enumerate(cfg.pattern):
         u, c, *b = layer_forward(kind, params["layers"][i], buffers[i], u,
                                  cfg, remat)
-        if c is not None:
+        if isinstance(c, dict):
+            chose.append(c)
+        elif c is not None:
             counts.append(c)
         balance.extend(b)
     counts = jnp.stack(counts) if counts \
         else jnp.zeros((0, len(cfg.held)), jnp.int32)
-    return (u, counts, sum(balance)) if cfg.balanced else (u, counts)
+    return ((u, counts) + ((sum(balance),) if cfg.balanced else ())
+            + ((chose,) if SPARSE in cfg.pattern else ()))
 
 
 def blocked_cross_entropy(u: jax.Array, norm_w: jax.Array, head: jax.Array,
                           targets: jax.Array, mask: jax.Array, eps: float,
-                          block: int, unit_offset: bool = False):
+                          block: int, unit_offset: bool = False,
+                          divisor: float = 1.0):
     """Mean over the unmasked positions of ``-log softmax(RMSNorm_w(u)
-    W_head)[target]``: ``u`` [T, hidden], in blocks of ``block`` tokens,
+    W_head)[target]`` (the normed input over ``divisor`` where one is
+    given): ``u`` [T, hidden], in blocks of ``block`` tokens,
     each block's logits recomputed in the backward pass. With ``targets``
     and ``mask`` [T, H] the head is ``H`` vocabularies wide, one softmax a
     prediction head: the mean is over every unmasked (position, head), and
@@ -280,8 +338,10 @@ def blocked_cross_entropy(u: jax.Array, norm_w: jax.Array, head: jax.Array,
 
     @jax.checkpoint
     def block_loss(ub, tb, mb):
-        logits = (rmsnorm(ub, norm_w, eps, unit_offset) @ head).astype(
-            jnp.float32).reshape((blk,) + heads + (-1,))
+        n = rmsnorm(ub, norm_w, eps, unit_offset)
+        n = n if divisor == 1.0 else n / divisor
+        logits = (n @ head).astype(jnp.float32).reshape(
+            (blk,) + heads + (-1,))
         picked = jnp.take_along_axis(logits, tb[..., None], axis=-1)[..., 0]
         return jnp.sum((jax.nn.logsumexp(logits, axis=-1) - picked) * mb,
                        axis=0)
@@ -305,23 +365,25 @@ def make_loss(cfg: HybridLMConfig, remat: bool = True):
     configuration weighs a balance loss (``cfg.balanced``) the loss carries
     it and the second result is ``(counts, balance loss)``; with
     ``num_pred_heads`` > 1 (``targets``, ``mask`` [B, S, heads]) each
-    head's own loss [heads] comes last in it."""
+    head's own loss [heads] comes next in it, and last what the sparse
+    blocks chose (:func:`forward_hidden`)."""
     def loss_fn(params, rows, buffers, where, targets, mask):
         with jax.named_scope("lm_embed"):
             u = jnp.take(rows, where, axis=0)
-        u, counts, *balance = forward_hidden(params, buffers, u, cfg, remat)
+            u = u if cfg.scale_emb == 1.0 else cfg.scale_emb * u
+        u, counts, *more = forward_hidden(params, buffers, u, cfg, remat)
+        balance = [more.pop(0)] if cfg.balanced else []
         with jax.named_scope("lm_head_loss"):
             loss = blocked_cross_entropy(
                 u.reshape(-1, cfg.hidden_size), params["final_norm"],
                 params["head"], targets.reshape((-1,) + targets.shape[2:]),
                 mask.reshape((-1,) + mask.shape[2:]), cfg.norm_eps,
-                cfg.loss_block, cfg.norm_add_unit_offset)
-        aux = (counts,)
-        if balance:
-            aux += (balance[0],)
+                cfg.loss_block, cfg.norm_add_unit_offset, cfg.logit_divisor)
+        aux = (counts, *balance)
         if cfg.num_pred_heads > 1:
             loss, per_head = loss
             aux += (per_head,)
+        aux += tuple(more)
         loss = loss + balance[0] if balance else loss
         return loss, aux if len(aux) > 1 else counts
 
@@ -406,6 +468,10 @@ class HybridLM:
                                      len(cfg.held)), np.int64)
         #: Each prediction head's own loss in the last step.
         self.last_head_losses = np.zeros(cfg.num_pred_heads, np.float32)
+        #: Per sparse block what the last step's queries chose, on the
+        #: device: [B, K, S, key blocks] bool, or None where the sequences
+        #: were short enough to be attended densely.
+        self.last_sparse_chosen: list = []
 
         # Uniform of the parameters' standard deviation: the table's own
         # random_init draws uniformly.
@@ -470,18 +536,23 @@ class HybridLM:
             loss, aux = self._hybrid(ids, self.buffers, where, targets,
                                      mask, rows=distinct)
             loss = float(loss)
-            # make_loss's order: counts, the balance term, the heads' losses
+            # make_loss's order: counts, the balance term, the heads' losses,
+            # the sparse blocks' choices
             counts, *extra = aux if isinstance(aux, tuple) else (aux,)
             if self.cfg.balanced:
                 gauge("lm.moe.balance_loss").set(float(extra.pop(0)))
-            if extra:
+            if self.cfg.num_pred_heads > 1:
                 self.last_head_losses = np.asarray(extra.pop(0))
+            chose = extra.pop(0) if extra else []
+            self.last_sparse_chosen = [c["chosen"] for c in chose]
             self.last_counts = np.asarray(counts, np.int64)
         self.steps += 1
-        self._count(tokens, distinct)
+        self._count(tokens, distinct, [int(np.asarray(c["pairs"], np.int64)
+                                           .sum()) for c in chose])
         return loss
 
-    def _count(self, tokens: np.ndarray, distinct: int) -> None:
+    def _count(self, tokens: np.ndarray, distinct: int,
+               sparse_pairs: list = ()) -> None:
         counter("lm.tokens").inc(int(tokens.size))
         counter("lm.rows_pulled").inc(int(distinct))
         # Causal query-key pairs, summed over the attention blocks; an EVA
@@ -502,6 +573,21 @@ class HybridLM:
                 cfg.eva_blocks() * seqs * (-(-length // chunk))
                 * (length > window))
         counter("lm.attn.pairs").inc(pairs)
+        if sparse_pairs:
+            # Per sparse block: what a head attended of the causal pairs, and
+            # the (query, pooled key) pairs scored to choose it.
+            size, stride = cfg.sparse_kernel_size, cfg.sparse_kernel_stride
+            scored = np.clip((np.arange(length) - size + 1) // stride + 1, 0,
+                             None).sum() * (length > cfg.sparse_dense_len)
+            counter("lm.sparse.pairs").inc(sum(sparse_pairs))
+            counter("lm.sparse.causal_pairs").inc(
+                len(sparse_pairs) * seqs * length * (length + 1) // 2)
+            counter("lm.sparse.select_pairs").inc(
+                len(sparse_pairs) * seqs * int(scored))
+        if LIGHTNING in cfg.pattern:
+            counter("lm.lightning.chunks").inc(
+                cfg.pattern.count(LIGHTNING) * seqs
+                * (-(-length // cfg.lightning_chunk)))
         for layer, per_expert in zip(self.cfg.expert_layers(),
                                      self.last_counts):
             # One pair per expert layer of the pattern: bounded.

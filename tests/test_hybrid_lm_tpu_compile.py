@@ -16,8 +16,8 @@ from jax.sharding import SingleDeviceSharding
 from multiverso_tpu.core.options import AddOption
 from multiverso_tpu.core.table import build_row_update, fused_rows_selected
 from multiverso_tpu.core.updater import get_updater
-from multiverso_tpu.models.hybrid_lm import (HybridLMConfig, layer_forward,
-                                             param_shapes)
+from multiverso_tpu.models.hybrid_lm import (HybridLMConfig, init_buffers,
+                                             layer_forward, param_shapes)
 from multiverso_tpu.tables.table_group import (build_group_access,
                                                build_group_update,
                                                group_scalars)
@@ -82,6 +82,7 @@ def test_mixer_compiles_for_v5e_at_published_widths(one_chip, cfg, kind,
 
 
 DSV2, EVABYTE = "deepseek-v2-lite-ep4", "evabyte-6.5b-pp8"
+SALA = "minicpm-sala-9b-pp8"
 
 
 # Two-block layers, forward + backward: (configuration, letter, its index in
@@ -90,11 +91,17 @@ DSV2, EVABYTE = "deepseek-v2-lite-ep4", "evabyte-6.5b-pp8"
 # the dense gated feed-forward and the gated expert block with its balance
 # loss at 2 x 8,192; EVA (windows of four attention blocks, 128 summaries a
 # window, seven remote blocks for the last window's queries) and the
-# feed-forward in slabs at ONE sequence of 16,384.
+# feed-forward in slabs at ONE sequence of 16,384; at the same length the
+# sparse block (the selection a query block at a time, the blocked walk under
+# a mask that is data), the Lightning block (the chunked scan at a group a
+# head and 128 x 128 states), each with the 16 heads the cell holds (1.58 and
+# 2.09 GB read here; 2.99 and 4.04-4.17 with all 32), and a feed-forward of
+# 16,384 (3.29).
 @pytest.mark.parametrize("config,kind,layer,seqs,length,temp_gb", [
     (DSV2, "L", 0, 1, SEQ, 2.5), (DSV2, "D", 1, 1, SEQ, 3.0),
     (DSV2, "E", 3, 2, SEQ, 3.0), (EVABYTE, "V", 0, 1, 2 * SEQ, 2.5),
-    (EVABYTE, "D", 1, 1, 2 * SEQ, 2.5)])
+    (EVABYTE, "D", 1, 1, 2 * SEQ, 2.5), (SALA, "S", 0, 1, 2 * SEQ, 2.0),
+    (SALA, "N", 2, 1, 2 * SEQ, 2.5), (SALA, "D", 1, 1, 2 * SEQ, 3.7)])
 def test_two_block_layer_compiles_for_v5e_at_published_widths(
         one_chip, config, kind, layer, seqs, length, temp_gb):
     cfg = HybridLMConfig.from_file(os.path.join(
@@ -106,12 +113,15 @@ def test_two_block_layer_compiles_for_v5e_at_published_widths(
 
     p = {k: spec(s) for k, s in param_shapes(cfg)["layers"][layer].items()}
     u = spec((seqs, length, cfg.hidden_size))
+    bias = init_buffers(cfg)[layer]     # a Lightning block's decay a head
+    bias = None if bias is None else spec(bias.shape)
 
-    def loss(p, u):
-        out, _, *balance = layer_forward(kind, p, None, u, cfg, remat=True)
+    def loss(p, bias, u):
+        out, _, *balance = layer_forward(kind, p, bias, u, cfg, remat=True)
         return jnp.sum(out) + sum(balance)
 
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(p, u).compile()
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 2))).lower(
+        p, bias, u).compile()
     stats = compiled.memory_analysis()
     assert stats.temp_size_in_bytes < temp_gb * 1e9, stats
 
@@ -146,6 +156,45 @@ def test_evabyte_step_compiles_for_v5e_inside_a_chips_memory(one_chip):
     # (telemetry/device_scopes.py reads them back from this text)
     assert {"lm_embed", "lm_head_loss", "lm_eva", "lm_eva_prep",
             "lm_eva_agg", "lm_dense_ffn"} <= _scopes_of(compiled)
+
+
+def test_sala_step_compiles_for_v5e_inside_a_chips_memory(one_chip):
+    """``sala_train``'s whole loss-and-gradient at the cell's size (one
+    sequence of 16,384 tokens): a TPU program RESERVES its temporaries beside
+    every live buffer (``peak_bytes_in_use`` does not count them), so what has
+    to stay under the 15.75 GiB a v5e chip reports is parameters + gradients
+    + accumulators + the embedding's rows and accumulator + the program's
+    temporaries (the eight saved block inputs alone are 2.15 GB). With every
+    head of every mixer that sum is 18.8 GB (PERF.md 4): the configuration
+    holds half of each mixer's heads."""
+    from multiverso_tpu.models.hybrid_lm import make_loss
+    cfg = HybridLMConfig.from_file(os.path.join(
+        ROOT, "benchmark", "configs", SALA + ".json"))
+
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        spec, param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple))
+    buffers = [None if b is None else spec(b.shape)
+               for b in init_buffers(cfg)]
+    length = 2 * SEQ
+    compiled = jax.jit(jax.value_and_grad(
+        make_loss(cfg), argnums=(0, 1), has_aux=True)).lower(
+            params, spec((7 * cfg.row_bucket, cfg.hidden_size)), buffers,
+            spec((1, length), jnp.int32), spec((1, length), jnp.int32),
+            spec((1, length))).compile()
+    stats = compiled.memory_analysis()
+    plane = 4 * sum(int(np.prod(s)) for s in jax.tree_util.tree_leaves(
+        param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple)))
+    table = 2 * 4 * cfg.vocab_size * cfg.hidden_size
+    assert stats.argument_size_in_bytes > plane
+    reserved = (stats.argument_size_in_bytes + stats.output_size_in_bytes
+                + stats.temp_size_in_bytes + plane + table)
+    assert reserved < 15.75 * 2 ** 30 - 0.5e9, (reserved, stats)
+    assert {"lm_embed", "lm_head_loss", "lm_attention", "lm_sparse_select",
+            "lm_sparse_attn", "lm_lightning", "lm_lightning_scan",
+            "lm_dense_ffn"} <= _scopes_of(compiled)
 
 
 # (tables, rows a table, width, ids a table, one [B, n] id matrix?): the two
