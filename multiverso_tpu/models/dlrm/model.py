@@ -271,7 +271,9 @@ class DLRMModel:
                                     fields=self.cfg.fields, batch=len(labels))
         with span("recsys.finish"):
             self.steps += 1
-            return float(loss), np.asarray(scores)
+            # Both results come down in one copy.
+            loss, scores = jax.device_get((loss, scores))
+            return float(loss), scores
 
     # -- inference ---------------------------------------------------------
     def predict(self, ids: np.ndarray, dense_x: np.ndarray) -> np.ndarray:

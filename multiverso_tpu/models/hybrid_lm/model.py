@@ -535,20 +535,26 @@ class HybridLM:
                 self.cfg.num_pred_heads)
             loss, aux = self._hybrid(ids, self.buffers, where, targets,
                                      mask, rows=distinct)
-            loss = float(loss)
             # make_loss's order: counts, the balance term, the heads' losses,
             # the sparse blocks' choices
             counts, *extra = aux if isinstance(aux, tuple) else (aux,)
-            if self.cfg.balanced:
-                gauge("lm.moe.balance_loss").set(float(extra.pop(0)))
-            if self.cfg.num_pred_heads > 1:
-                self.last_head_losses = np.asarray(extra.pop(0))
+            balance = extra.pop(0) if self.cfg.balanced else None
+            heads = extra.pop(0) if self.cfg.num_pred_heads > 1 else None
             chose = extra.pop(0) if extra else []
+            # What the host reads comes down in one copy; what the queries
+            # chose stays on the device.
             self.last_sparse_chosen = [c["chosen"] for c in chose]
+            loss, counts, balance, heads, pairs = jax.device_get(
+                (loss, counts, balance, heads, [c["pairs"] for c in chose]))
+            loss = float(loss)
+            if balance is not None:
+                gauge("lm.moe.balance_loss").set(float(balance))
+            if heads is not None:
+                self.last_head_losses = heads
             self.last_counts = np.asarray(counts, np.int64)
         self.steps += 1
-        self._count(tokens, distinct, [int(np.asarray(c["pairs"], np.int64)
-                                           .sum()) for c in chose])
+        self._count(tokens, distinct,
+                    [int(np.asarray(p, np.int64).sum()) for p in pairs])
         return loss
 
     def _count(self, tokens: np.ndarray, distinct: int,

@@ -293,37 +293,48 @@ def policy_for_option(explicit: Optional[str], shape: Sequence[int],
 
 
 # -- plane helpers -----------------------------------------------------------
+def reduce_axis_size(mesh, axis: Optional[str] = None) -> int:
+    """The one rule for "is there an axis to reduce over": the size of
+    ``axis`` (the server axis by default) in ``mesh``, 1 without a mesh.
+    At 1 a merge has nothing to merge."""
+    from multiverso_tpu.parallel.mesh import SERVER_AXIS
+
+    return 1 if mesh is None else mesh.shape.get(axis or SERVER_AXIS, 1)
+
+
 def build_dense_sync(mesh, axis: Optional[str] = None,
                      donate: bool = False):
-    """One jitted in-graph allreduce dispatch for a small replicated dense
-    operand: ``psum`` over ``axis`` normalized by the axis size, so the
-    value is preserved (exactly, for power-of-two axis sizes) while the
-    dispatch exercises a real ICI/mesh collective. This is the hybrid
-    step's dense-plane merge point: in a one-process world every
-    contribution is identical and the op is an identity-preserving
-    barrier; data-parallel hybrids feed per-worker partials through the
-    same function. On a 1-device mesh it degenerates to a plain jitted
-    dispatch (there is nothing to reduce over).
+    """One jitted in-graph allreduce dispatch for a replicated dense operand,
+    ONE array or a whole tree of them: every leaf ``psum`` over ``axis``
+    normalized by the axis size, so the value is preserved (exactly, for
+    power-of-two axis sizes) while the dispatch exercises a real ICI/mesh
+    collective. In a one-process world every contribution is identical and
+    the op is an identity-preserving barrier; data-parallel callers feed
+    per-worker partials through the same function. word2vec's block step
+    hands it one scalar a block; the hybrid step its whole tree of dense
+    deltas, one launch for all of them.
 
-    Build ONCE per model (compiles one executable); dispatch per block.
-    With ``donate`` the result takes its operand's buffer (the caller gives
-    its only reference up): a merge then allocates nothing, so a step that
-    merges many large leaves while its delta program's temporaries are
-    still alive has one peak whatever the host's and the device's timing
-    (the hybrid step; ``peak_hbm_gb`` read 12.09 to 12.37 GB by the run
-    without it at 180 MB leaves, PERF.md 6, PR 32).
+    Build ONCE per model (compiles one executable). Without an axis to
+    reduce over (:func:`reduce_axis_size` 1) the dispatch is a jitted
+    identity: a caller that can do without the launch asks the rule first
+    and builds nothing (the hybrid step). With ``donate`` the result takes
+    its operand's buffers (the caller gives its only reference up): a merge
+    then allocates nothing, so no leaf is held twice while the delta
+    program's temporaries are alive (``peak_hbm_gb`` read 12.09 to 12.37 GB
+    by the run without it at 180 MB leaves, PERF.md 6, PR 32).
     """
     from multiverso_tpu.parallel.mesh import SERVER_AXIS
     from jax.sharding import PartitionSpec as P
 
     axis = axis or SERVER_AXIS
-    n_axis = mesh.shape.get(axis, 1) if mesh is not None else 1
+    n_axis = reduce_axis_size(mesh, axis)
     donated = (0,) if donate else ()
-    if mesh is None or n_axis <= 1:
+    if n_axis <= 1:
         return jax.jit(lambda x: x + 0.0, donate_argnums=donated)
 
-    def _sync(v):
-        return jax.lax.psum(v, axis) / n_axis
+    def _sync(tree):
+        return jax.tree_util.tree_map(
+            lambda v: jax.lax.psum(v, axis) / n_axis, tree)
 
     return jax.jit(jax.shard_map(_sync, mesh=mesh, in_specs=P(),
                                  out_specs=P(), check_vma=False),
