@@ -5,7 +5,8 @@ docs/HYBRID_LM.md."""
 from multiverso_tpu.models.hybrid_lm import rope
 from multiverso_tpu.models.hybrid_lm.config import (ATTENTION, DENSE, EVA,
                                                     EXPERTS, LATENT,
-                                                    LIGHTNING, MAMBA, SPARSE,
+                                                    LIGHTNING, MAMBA,
+                                                    SHORTCONV, SPARSE,
                                                     HybridLMConfig)
 from multiverso_tpu.models.hybrid_lm.model import (APPLY_PROGRAM,
                                                    DELTA_PROGRAM, HybridLM,
@@ -14,10 +15,12 @@ from multiverso_tpu.models.hybrid_lm.model import (APPLY_PROGRAM,
                                                    init_buffers, init_params,
                                                    layer_forward, make_loss,
                                                    pack_batch, param_shapes,
-                                                   rmsnorm)
+                                                   rmsnorm,
+                                                   updated_expert_bias)
 
 __all__ = ["HybridLMConfig", "HybridLM", "MAMBA", "EXPERTS", "ATTENTION",
-           "LATENT", "DENSE", "EVA", "SPARSE", "LIGHTNING",
+           "LATENT", "DENSE", "EVA", "SPARSE", "LIGHTNING", "SHORTCONV",
            "DELTA_PROGRAM", "APPLY_PROGRAM", "dense_param_count",
            "forward_hidden", "init_buffers", "init_params", "layer_forward",
-           "make_loss", "pack_batch", "param_shapes", "rmsnorm", "rope"]
+           "make_loss", "pack_batch", "param_shapes", "rmsnorm", "rope",
+           "updated_expert_bias"]

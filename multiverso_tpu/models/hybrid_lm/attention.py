@@ -396,13 +396,29 @@ def sparse_mixer(p: dict, n: jax.Array, cfg):
 
 
 def attention_mixer(p: dict, n: jax.Array, cfg) -> jax.Array:
-    """No bias and no positions (the published ``nemotron_h`` code applies
-    none)."""
+    """Causal grouped-query attention, no bias. As the configuration says:
+    with ``attn_qk_norm`` an RMSNorm over each query and key head (one weight
+    vector of ``head_dim`` each, shared by the heads); with ``attn_rope`` both
+    then turned over the whole head in the half layout (``rotate_half``),
+    plain ``rope_theta``, positions from the start of the packed sequence.
+    With neither the block has no positions (the published ``nemotron_h`` code
+    applies none) and is the function it was before the switches."""
     bsz, s, _ = n.shape
-    kh = cfg.num_key_value_heads
+    kh, hd = cfg.num_key_value_heads, cfg.head_dim
     g = cfg.num_attention_heads // kh
-    q = (n @ p["wq"]).reshape(bsz, s, kh, g, cfg.head_dim)
-    k = (n @ p["wk"]).reshape(bsz, s, kh, cfg.head_dim)
+    if cfg.attn_rope:
+        cos, sin = rope.rope_tables(s, hd, cfg.rope_theta, None)
+
+    def heads(w, norm, shape):
+        x = n @ p[w]
+        if cfg.attn_qk_norm:
+            x = rmsnorm(x.reshape(bsz, s, -1, hd), p[norm], cfg.norm_eps)
+        if cfg.attn_rope:
+            x = rope.apply_rope(x.reshape(bsz, s, -1, hd), cos, sin, True)
+        return x.reshape(shape)
+
+    q = heads("wq", "q_norm", (bsz, s, kh, g, hd))
+    k = heads("wk", "k_norm", (bsz, s, kh, hd))
     v = (n @ p["wv"]).reshape(bsz, s, kh, cfg.head_dim)
     o = causal_gqa(q, k, v, cfg.attn_block)
     return o.reshape(bsz, s, cfg.q_dim) @ p["wo"]

@@ -9,12 +9,17 @@ import math
 from typing import Any, Dict, Optional, Tuple
 
 __all__ = ["HybridLMConfig", "MAMBA", "EXPERTS", "ATTENTION", "LATENT",
-           "DENSE", "EVA", "SPARSE", "LIGHTNING", "MIXER_TYPES"]
+           "DENSE", "EVA", "SPARSE", "LIGHTNING", "SHORTCONV", "MIXER_TYPES",
+           "LAYER_TYPES"]
 
 MAMBA, EXPERTS, ATTENTION, LATENT, DENSE, EVA = "M", "E", "*", "L", "D", "V"
-SPARSE, LIGHTNING = "S", "N"
+SPARSE, LIGHTNING, SHORTCONV = "S", "N", "C"
 #: A published ``mixer_types`` entry -> the letter of its block.
 MIXER_TYPES = {"minicpm4": SPARSE, "lightning-attn": LIGHTNING}
+#: A published ``layer_types`` entry (``lfm2_moe``) -> the letter of its mixer.
+#: The family's ``full_attention`` is grouped-query attention with an RMSNorm
+#: over each query and key head and a rotary turn over the whole head.
+LAYER_TYPES = {"conv": SHORTCONV, "full_attention": ATTENTION}
 #: The family's switches as published (``minicpm_sala``): each kind of mixer
 #: is implemented so and in no other form.
 _MIXER_SWITCHES = {
@@ -46,18 +51,19 @@ _KEYS_OF_KIND = {
     SPARSE: ("num_attention_heads", "num_key_value_heads", "head_dim"),
     LIGHTNING: ("lightning_nh", "lightning_nkv", "lightning_head_dim",
                 "rope_theta"),
+    SHORTCONV: ("conv_L_cache",),
 }
 _OPTIONAL_KEYS = ("moe_shared_expert_intermediate_size", "scoring_func",
                   "hidden_act", "aux_loss_alpha", "num_pred_heads",
                   "norm_add_unit_offset", "scale_emb", "scale_depth",
-                  "dim_model_base")
+                  "dim_model_base", "use_expert_bias", "tie_word_embeddings")
 #: A sparse block's sizes, as the published ``sparse_config`` group names them.
 _SPARSE_KEYS = ("kernel_size", "kernel_stride", "block_size", "window_size",
                 "init_blocks", "topk", "dense_len")
 #: Keys of this repo, optional in a file.
 _OWN_KEYS = ("learning_rate", "adagrad_step", "init_std", "attn_block",
              "moe_block", "loss_block", "ffn_slab", "row_bucket",
-             "comm_policy", "lightning_chunk")
+             "comm_policy", "lightning_chunk", "expert_bias_update_rate")
 
 
 @dataclasses.dataclass
@@ -74,7 +80,9 @@ class HybridLMConfig:
     #: attention (per query and key-value head, the keys of a window, of the
     #: first block and of ``sparse_topk`` blocks it chooses by itself), ``N``
     #: Lightning linear attention (a fixed decay a head). A layer of two
-    #: blocks (attention, then a feed-forward) is two letters.
+    #: blocks (attention, then a feed-forward) is two letters. ``C`` a gated
+    #: short convolution (two gates around a depthwise causal convolution of
+    #: ``conv_L_cache`` taps).
     pattern: str = "MEM*E"
     norm_eps: float = 1e-5
     #: Every RMSNorm scales by ``1 + w`` (``w`` drawn at zero), not by ``w``.
@@ -96,6 +104,14 @@ class HybridLMConfig:
     num_attention_heads: int = 4
     num_key_value_heads: int = 2
     head_dim: int = 16
+    #: The ``*`` block norms each query and key head (one weight vector of
+    #: ``head_dim`` each) and turns both over the whole head, half layout,
+    #: plain ``rope_theta``: as the ``layer_types`` family publishes its
+    #: ``full_attention``. Off, the block has no positions (``nemotron_h``).
+    attn_qk_norm: bool = False
+    attn_rope: bool = False
+    # -- gated short convolution: taps of the depthwise causal convolution ----
+    conv_L_cache: int = 3
     # -- latent attention: keys and values expanded from one latent -----------
     kv_lora_rank: int = 32
     qk_nope_head_dim: int = 16
@@ -165,6 +181,16 @@ class HybridLMConfig:
     norm_topk_prob: bool = True
     #: ``sigmoid`` (with a selection bias) or ``softmax`` (without).
     scoring_func: str = "sigmoid"
+    #: A sigmoid router's selection bias: seeded small, or (False) zero.
+    use_expert_bias: bool = True
+    #: Rate ``u`` of the selection bias's own update after every step, outside
+    #: the gradient: ``b_e += u * sign(mean(c) - c_e)`` from the step's
+    #: assignments ``c`` to EVERY expert (balancing without an auxiliary
+    #: loss). 0: the bias stays as seeded.
+    expert_bias_update_rate: float = 0.0
+    #: The head is the embedding table itself: logits against the rows a
+    #: step pulled, no ``head`` leaf (docs/HYBRID_LM.md "A tied head").
+    tie_word_embeddings: bool = False
     #: ``relu2``: two matrices an expert; ``silu``: three, ``silu(gate) * up``.
     hidden_act: str = "relu2"
     #: Weight of the sequence-wise balance loss of every expert block.
@@ -273,8 +299,18 @@ class HybridLMConfig:
     def validate(self) -> None:
         from multiverso_tpu.utils.log import check
         check(set(self.pattern) <= {MAMBA, EXPERTS, ATTENTION, LATENT, DENSE,
-                                    EVA, SPARSE, LIGHTNING}
+                                    EVA, SPARSE, LIGHTNING, SHORTCONV}
               and self.pattern, f"bad layer pattern {self.pattern!r}")
+        check(not self.attn_rope or self.head_dim % 2 == 0,
+              "rotary width must be even")
+        check(self.conv_L_cache >= 1, "a convolution has at least one tap")
+        check(not self.tie_word_embeddings or self.num_pred_heads == 1,
+              "a tied head is one vocabulary wide")
+        check(self.expert_bias_update_rate >= 0.0
+              and (not self.expert_bias_update_rate
+                   or (self.scoring_func == "sigmoid"
+                       and self.use_expert_bias)),
+              "only a sigmoid router's selection bias is updated")
         check(self.mamba_num_heads % self.n_groups == 0,
               "mamba heads must divide into n_groups")
         check(self.d_inner % self.n_groups == 0, "d_inner % n_groups")
@@ -328,17 +364,19 @@ class HybridLMConfig:
     # -- files ------------------------------------------------------------
     @classmethod
     def from_dict(cls, d: Dict[str, Any], **overrides) -> "HybridLMConfig":
-        """From the published keys. ``n_routed_experts`` counts the experts
+        """From the published keys. ``n_routed_experts`` (``num_experts`` in
+        a file that publishes its experts under that name) counts the experts
         HELD (``held_experts`` names them, default the first ones; a file
-        without the key holds none) and ``published.n_routed_experts`` the
+        without the key holds none) and ``published.<that key>`` the
         router's width; likewise ``lightning_nh`` counts the Lightning heads
         held, ``held_lightning_heads`` names them by their published number
         (1..) and ``published.lightning_nh`` says of how many (a file that
         holds fewer than published has to name them). The pattern is the
         first ``num_hidden_layers`` of ``hybrid_override_pattern`` where the
         file has one, or two blocks a layer from the first
-        ``num_hidden_layers`` of ``mixer_types`` (an entry this program does
-        not know raises); else every layer is two
+        ``num_hidden_layers`` of ``mixer_types`` or of ``layer_types`` (an
+        entry this program does not know raises; :meth:`_pattern_of_layers`);
+        else every layer is two
         blocks, attention (latent where the file has a
         ``kv_lora_rank``, EVA where its ``attention_class`` is ``eva``) and a
         feed-forward: experts, where the file has any, from layer
@@ -346,16 +384,20 @@ class HybridLMConfig:
         dense before and between. A file that gives no ``head_dim`` has heads
         of ``hidden_size / num_attention_heads``."""
         published = d.get("published", {})
-        n_held = int(d.get("n_routed_experts", 0))
-        router = int(published.get("n_routed_experts", n_held))
+        experts_key = "num_experts" if "num_experts" in d \
+            else "n_routed_experts"
+        n_held = int(d.get(experts_key, 0))
+        router = int(published.get(experts_key, n_held))
         held = tuple(d.get("held_experts", range(n_held)))
         if len(held) != n_held:
             raise ValueError(f"held_experts names {len(held)} experts, "
-                             f"n_routed_experts says {n_held}")
+                             f"{experts_key} says {n_held}")
         if "hybrid_override_pattern" in d:
             pattern = d["hybrid_override_pattern"][:d["num_hidden_layers"]]
         elif "mixer_types" in d:
             pattern = cls._pattern_of_mixers(d)
+        elif "layer_types" in d:
+            pattern = cls._pattern_of_layers(d, n_held)
         else:
             if d.get("q_lora_rank") is not None:
                 raise ValueError("a query latent (q_lora_rank) is not "
@@ -387,10 +429,15 @@ class HybridLMConfig:
         for kind in kinds:
             for key in _KEYS_OF_KIND.get(kind, ()):
                 key, _, field = key.partition(":")
-                kw[field or key] = d[key]
-        if EVA in kinds:
+                if key != "head_dim" or "layer_types" not in d:
+                    kw[field or key] = d[key]
+        if EVA in kinds or "layer_types" in d:
             kw["head_dim"] = d.get("head_dim") or \
                 d["hidden_size"] // d["num_attention_heads"]
+        if "layer_types" in d:
+            # the family's attention and experts: as LAYER_TYPES says
+            kw.update(attn_qk_norm=True, attn_rope=True,
+                      rope_theta=d["rope_theta"], scoring_func="sigmoid")
         kw.update({key: d[key] for key in _OPTIONAL_KEYS if key in d})
         if SPARSE in kinds:
             kw.update({"sparse_" + key: d["sparse_config"][key]
@@ -414,7 +461,7 @@ class HybridLMConfig:
             rope_scaling=d.get("rope_scaling"), source=d.get("source", ""),
             reduced=tuple(d.get("reduced", ())),
             assumed=dict(d.get("assumed", {})))
-        if "n_routed_experts" in d:
+        if experts_key in d:
             kw.update(router_experts=router, held=held)
         kw.update({key: d[key] for key in _OWN_KEYS if key in d})
         kw.update(overrides)
@@ -438,6 +485,26 @@ class HybridLMConfig:
                     raise ValueError(f"{name} with {key} = {d.get(key)!r} "
                                      f"is not implemented")
         return "".join(MIXER_TYPES[name] + DENSE for name in names)
+
+    @staticmethod
+    def _pattern_of_layers(d: Dict[str, Any], n_held: int) -> str:
+        """Two blocks a layer for the first ``num_hidden_layers`` of
+        ``layer_types``: the mixer the entry names (:data:`LAYER_TYPES`), then
+        a dense feed-forward in the first ``num_dense_layers`` layers (in
+        every layer of a file that holds no expert) and an expert block in
+        each later one."""
+        names = d["layer_types"][:d["num_hidden_layers"]]
+        unknown = sorted(set(names) - set(LAYER_TYPES))
+        if unknown or len(names) < d["num_hidden_layers"]:
+            raise ValueError(
+                f"layer_types names {len(names)} layers of "
+                f"{d['num_hidden_layers']}, unknown kinds {unknown}")
+        if d.get("conv_bias", False):
+            raise ValueError("a short convolution with conv_bias is not "
+                             "implemented")
+        dense = d["num_dense_layers"] if n_held else len(names)
+        return "".join(LAYER_TYPES[name] + (DENSE if i < dense else EXPERTS)
+                       for i, name in enumerate(names))
 
     @classmethod
     def from_file(cls, path: str, **overrides) -> "HybridLMConfig":

@@ -22,15 +22,18 @@ __all__ = ["mamba2_mixer", "ssd_chunked", "causal_conv1d",
            "gated_group_rmsnorm"]
 
 
-def causal_conv1d(x: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
+def causal_conv1d(x: jax.Array, w: jax.Array, b: jax.Array = None
+                  ) -> jax.Array:
     """Depthwise causal convolution along the sequence: ``x`` [B, S, C],
-    ``w`` [C, K], ``out[t] = sum_j w[:, j] x[t - (K-1) + j] + b``."""
+    ``w`` [C, K], ``out[t] = sum_j w[:, j] x[t - (K-1) + j] + b`` (no ``b``:
+    no bias)."""
     k = w.shape[1]
     s = x.shape[1]
     xp = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
     out = b
     for j in range(k):
-        out = out + xp[:, j:j + s, :] * w[:, j]
+        tap = xp[:, j:j + s, :] * w[:, j]
+        out = tap if out is None else out + tap
     return out
 
 

@@ -2,20 +2,28 @@
 
 Every block is ``u <- u + mixer(RMSNorm_w(u))`` with the mixer chosen per
 block from a pattern: ``M`` a Mamba-2 state-space mixer
-(:mod:`.mamba2`), ``*`` causal grouped-query attention without positions,
-``L`` latent attention with a decoupled rotary key, ``V`` EVA: exact keys
+(:mod:`.mamba2`), ``C`` a gated short convolution (:mod:`.shortconv`), ``*``
+causal grouped-query attention, without positions or, where the
+configuration says so, with a norm over each query and key head and a rotary
+turn over the whole head, ``L`` latent attention with a decoupled rotary
+key, ``V`` EVA: exact keys
 inside a window and pooled chunk summaries of every earlier one
 (:mod:`.attention`, :mod:`.rope`), ``S`` block-sparse attention whose
 queries choose their key blocks themselves (:func:`.attention.sparse_mixer`),
 ``N`` Lightning linear attention with a fixed decay a head
 (:mod:`.lightning`), ``D`` a dense gated feed-forward, ``E``
 this chip's share
-of a top-k expert layer with shared experts (:func:`~multiverso_tpu.
-parallel.expert.held_topk_moe`: sigmoid or softmax router, ``relu2`` or
-gated experts, by the configuration's published keys). A layer of two
+of a top-k expert layer, with shared experts where the configuration has any
+(:func:`~multiverso_tpu.parallel.expert.held_topk_moe`: sigmoid or softmax
+router, ``relu2`` or gated experts, by the configuration's published keys; a
+sigmoid router's selection bias is a buffer, moved after every step where the
+configuration gives ``expert_bias_update_rate``:
+:func:`updated_expert_bias`). A layer of two
 blocks is two letters. Under muP (``scale_emb``, ``scale_depth``,
 ``dim_model_base``) the embedding, every residual branch and the head's input
-are scaled by constants. Then a final RMSNorm and an untied head; the loss
+are scaled by constants. Then a final RMSNorm and the head: an untied dense
+leaf, or (``tie_word_embeddings``) the embedding table's own rows, the same
+array that embedded the input; the loss
 is next-token cross-entropy over the vocabulary slice (with
 ``num_pred_heads`` > 1 the head is that many vocabularies wide and head
 ``h`` predicts the token ``1 + h`` ahead), taken in blocks of tokens so that
@@ -29,11 +37,15 @@ Trained as DLRM is (models/dlrm/model.py), by the same hybrid step
 * the **input embedding** is a ``MatrixTable`` with the server-side
   ``adagrad`` updater on the ``ps`` plane, in a table group of one
   (tables/table_group.py). A step pulls the rows of its DISTINCT token ids
-  and pushes their lr-prescaled deltas;
-* the **layer stack, final norm and head** are device-resident: the delta
-  program is ``value_and_grad`` with ``lr * barrier(g)`` outputs, the donated
-  apply runs ``AdaGradUpdater.update_dense`` on (weight, accumulator) — the
-  server plane's own arithmetic and its own state layout;
+  and pushes their lr-prescaled deltas. Under a **tied head** it pulls EVERY
+  row of the vocabulary slice, once: the step embeds with ``rows[where]`` and
+  takes its logits against the same ``rows``, ``value_and_grad`` over ``rows``
+  sums both uses, and ONE push carries the sum through the server's AdaGrad
+  row update (rows no token named move too: the head touches them all);
+* the **layer stack, final norm and (untied) head** are device-resident: the
+  delta program is ``value_and_grad`` with ``lr * barrier(g)`` outputs, the
+  donated apply runs ``AdaGradUpdater.update_dense`` on (weight, accumulator)
+  — the server plane's own arithmetic and its own state layout;
 * ``mode='local'`` is the same model over a ``LocalTableGroup`` of the same
   table option.
 
@@ -60,12 +72,14 @@ from multiverso_tpu.models.hybrid_lm.attention import (
     sparse_mixer)
 from multiverso_tpu.models.hybrid_lm.config import (ATTENTION, DENSE, EVA,
                                                     EXPERTS, LATENT,
-                                                    LIGHTNING, MAMBA, SPARSE,
+                                                    LIGHTNING, MAMBA,
+                                                    SHORTCONV, SPARSE,
                                                     HybridLMConfig)
 from multiverso_tpu.models.hybrid_lm.lightning import (lightning_mixer,
                                                        lightning_slopes)
 from multiverso_tpu.models.hybrid_lm.mamba2 import mamba2_mixer
 from multiverso_tpu.models.hybrid_lm.norm import rmsnorm
+from multiverso_tpu.models.hybrid_lm.shortconv import shortconv_mixer
 from multiverso_tpu.parallel.expert import held_topk_moe
 from multiverso_tpu.parallel.hybrid_step import HybridStep
 from multiverso_tpu.tables.table_group import LocalTableGroup
@@ -74,7 +88,8 @@ from multiverso_tpu.utils.log import check
 
 __all__ = ["HybridLM", "init_params", "init_buffers", "rmsnorm",
            "forward_hidden", "make_loss", "dense_param_count",
-           "pack_batch", "DELTA_PROGRAM", "APPLY_PROGRAM"]
+           "pack_batch", "updated_expert_bias", "DELTA_PROGRAM",
+           "APPLY_PROGRAM"]
 
 #: Names of the two programs of a step as the profiler shows them
 #: (``jit_<name>``): the benchmark's readers find them by these.
@@ -92,9 +107,15 @@ def _layer_shapes(cfg: HybridLMConfig, kind: str) -> Dict[str, tuple]:
                 "conv_b": (cfg.conv_dim,), "dt_bias": (h,), "A_log": (h,),
                 "D": (h,), "gnorm": (cfg.d_inner,),
                 "out_proj": (cfg.d_inner, d)}
+    if kind == SHORTCONV:
+        return {"norm": (d,), "in_proj": (d, 3 * d),
+                "conv_w": (d, cfg.conv_L_cache), "out_proj": (d, d)}
     if kind == ATTENTION:
-        return {"norm": (d,), "wq": (d, cfg.q_dim), "wk": (d, cfg.kv_dim),
-                "wv": (d, cfg.kv_dim), "wo": (cfg.q_dim, d)}
+        shapes = {"norm": (d,), "wq": (d, cfg.q_dim), "wk": (d, cfg.kv_dim),
+                  "wv": (d, cfg.kv_dim), "wo": (cfg.q_dim, d)}
+        if cfg.attn_qk_norm:
+            shapes.update(q_norm=(cfg.head_dim,), k_norm=(cfg.head_dim,))
+        return shapes
     if kind == LATENT:
         h, r = cfg.num_attention_heads, cfg.kv_lora_rank
         return {"norm": (d,), "wq": (d, h * cfg.qk_head_dim),
@@ -128,13 +149,18 @@ def _layer_shapes(cfg: HybridLMConfig, kind: str) -> Dict[str, tuple]:
               "s_down": (fs, d)}
     if cfg.gated_experts:
         shapes.update(w_gate=(e, d, f), s_gate=(d, fs))
-    return shapes
+    # no shared expert: no leaves (not leaves of width zero)
+    return {k: v for k, v in shapes.items() if fs or not k.startswith("s_")}
 
 
 def param_shapes(cfg: HybridLMConfig) -> dict:
-    return {"layers": [_layer_shapes(cfg, k) for k in cfg.pattern],
-            "final_norm": (cfg.hidden_size,),
-            "head": (cfg.hidden_size, cfg.num_pred_heads * cfg.vocab_size)}
+    """A tied head has no leaf: it is the embedding table's rows."""
+    shapes = {"layers": [_layer_shapes(cfg, k) for k in cfg.pattern],
+              "final_norm": (cfg.hidden_size,)}
+    if not cfg.tie_word_embeddings:
+        shapes["head"] = (cfg.hidden_size,
+                          cfg.num_pred_heads * cfg.vocab_size)
+    return shapes
 
 
 def dense_param_count(cfg: HybridLMConfig) -> int:
@@ -154,7 +180,8 @@ def init_params(cfg: HybridLMConfig) -> dict:
     """Deterministic from ``cfg.seed`` (so ``ps`` and ``local`` start
     bitwise alike): matrices normal ``init_std``, projections back into
     the stream divided by ``sqrt(layers)``, norms and ``D`` one (a norm
-    with the unit offset: zero), ``A`` in [1, 16], ``dt`` log-uniform in
+    with the unit offset: zero), a convolution's taps uniform on +-0.5 (the
+    short convolution's as Mamba-2's), ``A`` in [1, 16], ``dt`` log-uniform in
     ``[time_step_min, time_step_max]`` through the inverse softplus, as
     Mamba-2 draws them; EVA's ``phi`` and ``mu`` normal, clamped to [-1, 1],
     times ``head_dim ** -0.5``."""
@@ -184,17 +211,19 @@ def init_params(cfg: HybridLMConfig) -> dict:
         return w / depth if name in _OUT_PROJECTIONS else w
 
     shapes = param_shapes(cfg)
-    return {"layers": [{k: jnp.asarray(leaf(k, s)) for k, s in layer.items()}
-                       for layer in shapes["layers"]],
-            "final_norm": jnp.asarray(leaf("final_norm",
-                                           shapes["final_norm"])),
-            "head": jnp.asarray(leaf("head", shapes["head"]))}
+    layers = [{k: jnp.asarray(leaf(k, s)) for k, s in layer.items()}
+              for layer in shapes.pop("layers")]
+    return dict({k: jnp.asarray(leaf(k, s)) for k, s in shapes.items()},
+                layers=layers)
 
 
 def init_buffers(cfg: HybridLMConfig) -> list:
     """Per block what is carried but not trained: a sigmoid-routed expert
-    block's ``e_score_correction_bias`` (seeded, small; the published scheme
-    moves it outside the gradient, here it stays fixed); a Lightning block's
+    block's ``e_score_correction_bias`` / ``expert_bias`` (seeded, small,
+    or zero without ``use_expert_bias``; it stays so unless the
+    configuration gives ``expert_bias_update_rate``, with which every step
+    moves it outside the gradient: :func:`updated_expert_bias`); a Lightning
+    block's
     decay a head (:func:`~.lightning.lightning_slopes` of its published
     layer)."""
     rng = np.random.default_rng(cfg.seed + 7)
@@ -202,8 +231,9 @@ def init_buffers(cfg: HybridLMConfig) -> list:
 
     def buffer(i, kind):
         if kind == EXPERTS and biased:
-            return jnp.asarray(rng.uniform(-0.01, 0.01, cfg.router_experts)
-                               .astype(np.float32))
+            bias = rng.uniform(-0.01, 0.01, cfg.router_experts)
+            return jnp.asarray((bias if cfg.use_expert_bias
+                                else 0.0 * bias).astype(np.float32))
         if kind == LIGHTNING:
             return jnp.asarray(lightning_slopes(
                 cfg.lightning_published_nh or cfg.lightning_nh,
@@ -211,6 +241,17 @@ def init_buffers(cfg: HybridLMConfig) -> list:
         return None
 
     return [buffer(i, kind) for i, kind in enumerate(cfg.pattern)]
+
+
+def updated_expert_bias(bias, counts: np.ndarray, rate: float) -> np.ndarray:
+    """The selection bias after a step, outside the gradient: ``b_e + rate *
+    sign(mean(c) - c_e)`` with ``c`` [E] the step's assignments to every
+    expert: an expert that got fewer than its share is chosen more readily
+    in the next step, one that got more less. On the host: it goes up with
+    the next step's batch."""
+    counts = np.asarray(counts, np.float64)
+    return np.asarray(bias, np.float32) + np.float32(rate) * np.sign(
+        counts.mean() - counts).astype(np.float32)
 
 
 # -- the forward pass ---------------------------------------------------------
@@ -223,6 +264,7 @@ def dense_ffn_mixer(p: dict, n: jax.Array, cfg) -> jax.Array:
 #: set worth bounding: (mixer, scope the profiler shows).
 _SEQUENCE_MIXERS = {
     MAMBA: (mamba2_mixer, "lm_mamba2"),
+    SHORTCONV: (shortconv_mixer, "lm_shortconv"),
     ATTENTION: (attention_mixer, "lm_attention"),
     LATENT: (latent_attention_mixer, "lm_mla"),
     EVA: (eva_mixer, "lm_eva"),
@@ -281,14 +323,15 @@ def layer_forward(kind: str, p: dict, bias, u: jax.Array,
                     cfg.norm_add_unit_offset).reshape(bsz * s, d)
         # All positional: a wrapper ``(n, router, bias, w_up, w_down,
         # *rest)`` (the benchmark's controls) passes the rest on.
-        y, counts, *balance = held_topk_moe(
-            n, p["router"], bias, p["w_up"], p["w_down"], p["s_up"],
-            p["s_down"], cfg.held, cfg.num_experts_per_tok,
+        y, counts, *more = held_topk_moe(
+            n, p["router"], bias, p["w_up"], p["w_down"], p.get("s_up"),
+            p.get("s_down"), cfg.held, cfg.num_experts_per_tok,
             cfg.routed_scaling_factor, cfg.norm_topk_prob, cfg.moe_block,
-            True, cfg.scoring_func, p.get("w_gate"), p.get("s_gate"),
-            (cfg.aux_loss_alpha, bsz) if cfg.balanced else None)
+            "s_up" in p, cfg.scoring_func, p.get("w_gate"), p.get("s_gate"),
+            (cfg.aux_loss_alpha, bsz) if cfg.balanced else None,
+            cfg.expert_bias_update_rate > 0)
         y = y if cfg.residual_scale == 1.0 else cfg.residual_scale * y
-        return (u + y.reshape(u.shape), counts, *balance)
+        return (u + y.reshape(u.shape), counts, *more)
 
     with jax.named_scope("lm_experts"):
         return keep(tokens)(p, u)
@@ -299,8 +342,10 @@ def forward_hidden(params: dict, buffers: list, u: jax.Array,
     """The block stack over ``u`` [B, S, hidden] -> (hidden states before
     the final norm, [expert blocks, held] assignment counts), then, where
     the configuration weighs one, the summed balance loss, then, where it
-    has sparse blocks, what each of them chose (:func:`layer_forward`)."""
-    counts, balance, chose = [], [], []
+    updates its selection bias, the [expert blocks, ALL experts] assignment
+    counts, then, where it has sparse blocks, what each of them chose
+    (:func:`layer_forward`)."""
+    counts, balance, every, chose = [], [], [], []
     for i, kind in enumerate(cfg.pattern):
         u, c, *b = layer_forward(kind, params["layers"][i], buffers[i], u,
                                  cfg, remat)
@@ -308,10 +353,13 @@ def forward_hidden(params: dict, buffers: list, u: jax.Array,
             chose.append(c)
         elif c is not None:
             counts.append(c)
+            if cfg.expert_bias_update_rate:
+                every.append(b.pop())
         balance.extend(b)
     counts = jnp.stack(counts) if counts \
         else jnp.zeros((0, len(cfg.held)), jnp.int32)
     return ((u, counts) + ((sum(balance),) if cfg.balanced else ())
+            + ((jnp.stack(every),) if every else ())
             + ((chose,) if SPARSE in cfg.pattern else ()))
 
 
@@ -361,7 +409,10 @@ def blocked_cross_entropy(u: jax.Array, norm_w: jax.Array, head: jax.Array,
 def make_loss(cfg: HybridLMConfig, remat: bool = True):
     """``(params, rows [n, hidden], buffers, where [B, S], targets [B, S],
     mask [B, S]) -> (loss, counts)``: ``rows[where]`` is the embedded
-    input (``rows`` the pulled rows of the step's distinct ids). Where the
+    input (``rows`` the pulled rows of the step's distinct ids). Under a tied
+    head ``rows`` is the whole vocabulary slice in id order and the logits
+    are taken against it, ``norm(u) rows^T``: ``params`` has no ``head``, and
+    the gradient of ``rows`` is the sum of both uses. Where the
     configuration weighs a balance loss (``cfg.balanced``) the loss carries
     it and the second result is ``(counts, balance loss)``; with
     ``num_pred_heads`` > 1 (``targets``, ``mask`` [B, S, heads]) each
@@ -374,9 +425,10 @@ def make_loss(cfg: HybridLMConfig, remat: bool = True):
         u, counts, *more = forward_hidden(params, buffers, u, cfg, remat)
         balance = [more.pop(0)] if cfg.balanced else []
         with jax.named_scope("lm_head_loss"):
+            head = rows.T if cfg.tie_word_embeddings else params["head"]
             loss = blocked_cross_entropy(
                 u.reshape(-1, cfg.hidden_size), params["final_norm"],
-                params["head"], targets.reshape((-1,) + targets.shape[2:]),
+                head, targets.reshape((-1,) + targets.shape[2:]),
                 mask.reshape((-1,) + mask.shape[2:]), cfg.norm_eps,
                 cfg.loss_block, cfg.norm_add_unit_offset, cfg.logit_divisor)
         aux = (counts, *balance)
@@ -391,19 +443,26 @@ def make_loss(cfg: HybridLMConfig, remat: bool = True):
 
 
 def pack_batch(tokens: np.ndarray, bucket: int, min_rows: int = 0,
-               heads: int = 1):
+               heads: int = 1, whole_slice: int = 0):
     """Host side of a step: ``tokens`` [B, S] -> (ids [n] the distinct
     token ids padded to a multiple of ``bucket`` with repeats of the first,
     whose deltas are zero; distinct count; where [B, S] each position's row
     among ``ids``; targets [B, S] the next token; mask [B, S] 0 at each
     sequence's last position). With ``heads`` > 1, targets and mask are
     [B, S, heads]: head ``h``'s target is the token ``1 + h`` ahead, masked
-    at the sequence's last ``1 + h`` positions."""
+    at the sequence's last ``1 + h`` positions. With ``whole_slice`` = V (a
+    tied head) ``ids`` is every row ``0..V-1`` of the vocabulary slice, in
+    order and unpadded (``bucket`` and ``min_rows`` unused: it is one shape),
+    so a token's row is its id."""
     tokens = np.asarray(tokens, np.int32)
-    ids, where = np.unique(tokens, return_inverse=True)
-    n = len(ids)
-    cap = max(-(-n // bucket) * bucket, min_rows)
-    ids = np.concatenate([ids, np.full(cap - n, ids[0], ids.dtype)])
+    if whole_slice:
+        ids, where, n = np.arange(whole_slice, dtype=np.int32), tokens, \
+            whole_slice
+    else:
+        ids, where = np.unique(tokens, return_inverse=True)
+        n = len(ids)
+        cap = max(-(-n // bucket) * bucket, min_rows)
+        ids = np.concatenate([ids, np.full(cap - n, ids[0], ids.dtype)])
     targets = np.stack([np.roll(tokens, -(1 + h), axis=1)
                         for h in range(heads)], axis=-1)
     # [S, heads]: position t has a target 1 + h ahead while t + 1 + h < S
@@ -527,12 +586,14 @@ class HybridLM:
     # -- training ----------------------------------------------------------
     def step(self, tokens: np.ndarray) -> float:
         """One batch of packed sequences ``tokens`` [B, S]: pull the rows
-        of its distinct ids, run the hybrid step, push the row deltas.
+        of its distinct ids (under a tied head every row of the slice), run
+        the hybrid step, push the row deltas.
         Returns the loss once the device has finished the step."""
         with span("lm.step", tokens=int(tokens.size)):
             ids, distinct, where, targets, mask = pack_batch(
                 tokens, self.cfg.row_bucket, self.min_rows,
-                self.cfg.num_pred_heads)
+                self.cfg.num_pred_heads, self.cfg.vocab_size
+                if self.cfg.tie_word_embeddings else 0)
             loss, aux = self._hybrid(ids, self.buffers, where, targets,
                                      mask, rows=distinct)
             # make_loss's order: counts, the balance term, the heads' losses,
@@ -540,18 +601,26 @@ class HybridLM:
             counts, *extra = aux if isinstance(aux, tuple) else (aux,)
             balance = extra.pop(0) if self.cfg.balanced else None
             heads = extra.pop(0) if self.cfg.num_pred_heads > 1 else None
+            every = extra.pop(0) if self.cfg.expert_bias_update_rate \
+                else None
             chose = extra.pop(0) if extra else []
             # What the host reads comes down in one copy; what the queries
             # chose stays on the device.
             self.last_sparse_chosen = [c["chosen"] for c in chose]
-            loss, counts, balance, heads, pairs = jax.device_get(
-                (loss, counts, balance, heads, [c["pairs"] for c in chose]))
+            loss, counts, balance, heads, every, pairs = jax.device_get(
+                (loss, counts, balance, heads, every,
+                 [c["pairs"] for c in chose]))
             loss = float(loss)
             if balance is not None:
                 gauge("lm.moe.balance_loss").set(float(balance))
             if heads is not None:
                 self.last_head_losses = heads
             self.last_counts = np.asarray(counts, np.int64)
+            if every is not None:
+                for layer, per_expert in zip(self.cfg.expert_layers(), every):
+                    self.buffers[layer] = updated_expert_bias(
+                        self.buffers[layer], per_expert,
+                        self.cfg.expert_bias_update_rate)
         self.steps += 1
         self._count(tokens, distinct,
                     [int(np.asarray(p, np.int64).sum()) for p in pairs])
@@ -560,7 +629,10 @@ class HybridLM:
     def _count(self, tokens: np.ndarray, distinct: int,
                sparse_pairs: list = ()) -> None:
         counter("lm.tokens").inc(int(tokens.size))
+        # under a tied head the whole slice: the head reads every row
         counter("lm.rows_pulled").inc(int(distinct))
+        if self.cfg.tie_word_embeddings:
+            counter("lm.head.tied").inc()
         # Causal query-key pairs, summed over the attention blocks; an EVA
         # block's are those inside its windows, and beside them every
         # (query, summary of a chunk of an earlier window).

@@ -355,7 +355,8 @@ def held_topk_moe(n: jax.Array, router: jax.Array, bias: jax.Array,
                   shared: bool = True, scoring: str = "sigmoid",
                   w_gate: Optional[jax.Array] = None,
                   s_gate: Optional[jax.Array] = None,
-                  balance: Optional[Tuple[float, int]] = None):
+                  balance: Optional[Tuple[float, int]] = None,
+                  count_all: bool = False):
     """One chip's share of a top-k expert layer: ``n`` [T, D] ->
     (y [T, D], assignments per held expert [len(held)]). ``router`` is
     [D, E] over ALL experts, ``w_up`` / ``w_down`` hold the experts
@@ -367,7 +368,9 @@ def held_topk_moe(n: jax.Array, router: jax.Array, bias: jax.Array,
     experts are gated, ``(silu(n W_gate) * n W_up) W_down``; without, they
     are ``relu(n W_up)^2 W_down``. ``balance = (alpha, sequences)`` (softmax
     only; ``n`` is ``sequences`` equal runs of tokens) adds a third result,
-    the sequence-wise balance loss."""
+    the sequence-wise balance loss. ``count_all`` adds, last, the assignments
+    to EVERY expert of the router, [E] (the router is whole on every share):
+    what a selection bias's update between steps reads."""
     if scoring == "softmax":
         chosen, weights, probs = softmax_topk_route(n, router, top_k,
                                                     scaling, normalize)
@@ -386,7 +389,10 @@ def held_topk_moe(n: jax.Array, router: jax.Array, bias: jax.Array,
                                   block_expert, in_use, block)
         if shared:
             y = y + (jax.nn.silu(n @ s_gate) * (n @ s_up)) @ s_down
-    if balance is None:
-        return y, counts
-    return y, counts, sequence_balance_loss(probs, chosen, balance[1],
-                                            balance[0])
+    out = (y, counts)
+    if balance is not None:
+        out += (sequence_balance_loss(probs, chosen, balance[1], balance[0]),)
+    if count_all:
+        out += (jnp.sum(chosen.reshape(-1, 1) == jnp.arange(router.shape[1]),
+                        axis=0, dtype=jnp.int32),)
+    return out
