@@ -84,7 +84,13 @@ def pallas_rows_eligible(shape: Tuple[int, ...], dtype: Any,
     matrix, v5e, PR 21); and so does every WIDER multiple (256 .. 2,688:
     "Slice shape along dimension 0 must be aligned to tiling (8), but is
     1", compiled for the v5e, PR 29): only at 128 columns is the
-    (8,128)-tiled table row-major, one row one contiguous slice.
+    (8,128)-tiled table row-major, one row one contiguous slice. (The way
+    round for wider rows, not taken by these kernels yet: the same table
+    seen as ``[rows, columns / 128, 128]`` gives whole-row DMA slices at any
+    multiple of 128 columns, one ``[columns / 128, 128]`` plane a row;
+    ``ops/pallas_rows.add_unique_rows`` takes the LMs' expert layer's
+    accumulators that way, compiled for the v5e at 2,048 and 2,688 columns,
+    PR 41.)
     Multi-shard stays XLA: the row kernels would need per-shard offset
     remapping under shard_map, and XLA's sharded scatter already overlaps
     the collective with the update."""

@@ -80,7 +80,10 @@ from multiverso_tpu.models.hybrid_lm.lightning import (lightning_mixer,
 from multiverso_tpu.models.hybrid_lm.mamba2 import mamba2_mixer
 from multiverso_tpu.models.hybrid_lm.norm import rmsnorm
 from multiverso_tpu.models.hybrid_lm.shortconv import shortconv_mixer
-from multiverso_tpu.parallel.expert import held_topk_moe
+from multiverso_tpu.ops import pallas_interpret
+from multiverso_tpu.parallel.comm_policy import reduce_axis_size
+from multiverso_tpu.parallel.expert import (held_topk_moe,
+                                            token_rows_kernel_selected)
 from multiverso_tpu.parallel.hybrid_step import HybridStep
 from multiverso_tpu.tables.table_group import LocalTableGroup
 from multiverso_tpu.telemetry import counter, gauge, span
@@ -275,7 +278,8 @@ _SEQUENCE_MIXERS = {
 
 
 def layer_forward(kind: str, p: dict, bias, u: jax.Array,
-                  cfg: HybridLMConfig, remat: bool = False):
+                  cfg: HybridLMConfig, remat: bool = False,
+                  moe_rows_interpret: Optional[bool] = None):
     """One block: (``u + mixer(RMSNorm_w(u))``, assignments per held expert
     or None), and third, for an expert block of a configuration that
     weighs one, its balance loss; the mixer's output times
@@ -289,7 +293,10 @@ def layer_forward(kind: str, p: dict, bias, u: jax.Array,
     only, and a dense feed-forward token by token, so each runs (and is
     rematerialised) one sequence at a time, and its working set is a
     sequence's and not the batch's; the feed-forward cuts a sequence longer
-    than ``cfg.ffn_slab`` into slabs of that many positions."""
+    than ``cfg.ffn_slab`` into slabs of that many positions.
+    ``moe_rows_interpret`` is an expert block's ``rows_interpret``
+    (:func:`~multiverso_tpu.parallel.expert.held_topk_moe`): None unless the
+    caller knows ``u`` to live on one device."""
     keep = jax.checkpoint if remat else (lambda fn: fn)
     if kind in _SEQUENCE_MIXERS:
         mixer, scope = _SEQUENCE_MIXERS[kind]
@@ -329,7 +336,7 @@ def layer_forward(kind: str, p: dict, bias, u: jax.Array,
             cfg.routed_scaling_factor, cfg.norm_topk_prob, cfg.moe_block,
             "s_up" in p, cfg.scoring_func, p.get("w_gate"), p.get("s_gate"),
             (cfg.aux_loss_alpha, bsz) if cfg.balanced else None,
-            cfg.expert_bias_update_rate > 0)
+            cfg.expert_bias_update_rate > 0, moe_rows_interpret)
         y = y if cfg.residual_scale == 1.0 else cfg.residual_scale * y
         return (u + y.reshape(u.shape), counts, *more)
 
@@ -338,7 +345,8 @@ def layer_forward(kind: str, p: dict, bias, u: jax.Array,
 
 
 def forward_hidden(params: dict, buffers: list, u: jax.Array,
-                   cfg: HybridLMConfig, remat: bool = True):
+                   cfg: HybridLMConfig, remat: bool = True,
+                   moe_rows_interpret: Optional[bool] = None):
     """The block stack over ``u`` [B, S, hidden] -> (hidden states before
     the final norm, [expert blocks, held] assignment counts), then, where
     the configuration weighs one, the summed balance loss, then, where it
@@ -346,9 +354,12 @@ def forward_hidden(params: dict, buffers: list, u: jax.Array,
     counts, then, where it has sparse blocks, what each of them chose
     (:func:`layer_forward`)."""
     counts, balance, every, chose = [], [], [], []
+    # Handed on only where set: a wrapper ``(kind, p, bias, u, cfg, remat)``
+    # around ``layer_forward`` (the benchmark's controls) sees what it knows.
+    rows_plane = () if moe_rows_interpret is None else (moe_rows_interpret,)
     for i, kind in enumerate(cfg.pattern):
         u, c, *b = layer_forward(kind, params["layers"][i], buffers[i], u,
-                                 cfg, remat)
+                                 cfg, remat, *rows_plane)
         if isinstance(c, dict):
             chose.append(c)
         elif c is not None:
@@ -406,7 +417,8 @@ def blocked_cross_entropy(u: jax.Array, norm_w: jax.Array, head: jax.Array,
             total / jnp.maximum(jnp.sum(mask, axis=0), 1.0))
 
 
-def make_loss(cfg: HybridLMConfig, remat: bool = True):
+def make_loss(cfg: HybridLMConfig, remat: bool = True,
+              moe_rows_interpret: Optional[bool] = None):
     """``(params, rows [n, hidden], buffers, where [B, S], targets [B, S],
     mask [B, S]) -> (loss, counts)``: ``rows[where]`` is the embedded
     input (``rows`` the pulled rows of the step's distinct ids). Under a tied
@@ -422,7 +434,8 @@ def make_loss(cfg: HybridLMConfig, remat: bool = True):
         with jax.named_scope("lm_embed"):
             u = jnp.take(rows, where, axis=0)
             u = u if cfg.scale_emb == 1.0 else cfg.scale_emb * u
-        u, counts, *more = forward_hidden(params, buffers, u, cfg, remat)
+        u, counts, *more = forward_hidden(params, buffers, u, cfg, remat,
+                                          moe_rows_interpret)
         balance = [more.pop(0)] if cfg.balanced else []
         with jax.named_scope("lm_head_loss"):
             head = rows.T if cfg.tie_word_embeddings else params["head"]
@@ -498,7 +511,17 @@ class HybridLM:
         self._option = AddOption(
             worker_id=max(mv.worker_id(), 0) if mode == "ps" else 0,
             learning_rate=lr, rho=cfg.adagrad_step)
-        loss_fn = make_loss(cfg)
+        # Where the expert blocks' result rows land, read off the leaves as
+        # every kernel's plane is: the DMA row kernel where the token
+        # accumulator (float32, whole 128-lane planes a row) lives on the
+        # leaves' ONE device and no mesh divides or merges it; None (XLA's
+        # scatter-add) anywhere else. Counter ``lm.moe.rows.plane.<plane>``.
+        devices = jax.tree_util.tree_leaves(self.params)[0].devices()
+        self.moe_rows_interpret = pallas_interpret(devices) if (
+            len(devices) == 1 and reduce_axis_size(dp_mesh, dp_axis) == 1
+            and token_rows_kernel_selected(cfg.hidden_size, np.float32)
+        ) else None
+        loss_fn = make_loss(cfg, moe_rows_interpret=self.moe_rows_interpret)
         barrier = jax.lax.optimization_barrier
 
         def lm_delta_step(params, rows, buffers, where, targets, mask):
@@ -666,6 +689,8 @@ class HybridLM:
             counter("lm.lightning.chunks").inc(
                 cfg.pattern.count(LIGHTNING) * seqs
                 * (-(-length // cfg.lightning_chunk)))
+        counter("lm.moe.rows.plane.xla" if self.moe_rows_interpret is None
+                else "lm.moe.rows.plane.fused").inc(len(cfg.expert_layers()))
         for layer, per_expert in zip(self.cfg.expert_layers(),
                                      self.last_counts):
             # One pair per expert layer of the pattern: bounded.

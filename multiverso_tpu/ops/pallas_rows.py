@@ -679,3 +679,148 @@ def adagrad_fold_rows(w: jax.Array, g2: jax.Array, sorted_ids: jax.Array,
     for o, i in enumerate(written):
         new[i] = outs[o]
     return tuple(new)
+
+
+# ---------------------------------------------------------------------------
+# add a block of UNIQUE rows into a wide accumulator (the LMs' expert layer)
+# ---------------------------------------------------------------------------
+# ``acc[ids[i]] += vals[i]`` where no live id occurs twice: what the block
+# loop of ``parallel/expert.py`` does with a block's result rows (a token
+# chooses an expert once, and a block is one expert's), and what XLA's TPU
+# scatter-add takes 244 ns a row of 2,048 floats for (PERF.md 5, PR 40).
+# Mosaic refuses a one-row DMA slice of a float32 array wider than 128
+# columns (``pallas_rows_eligible``), so the accumulator is handed over as
+# ``[rows, cols / 128, 128]``: a row is then a whole ``[cols / 128, 128]``
+# plane, contiguous in the tiled layout, and ``acc.at[id]`` a slice the DMA
+# engine takes at any multiple of 128 columns (compiled for the v5e at 2,048
+# and 2,688: tests/test_hybrid_lm_tpu_compile.py; the expert layer hands it
+# whole (8, 128) tiles a row, 2,688 columns as 24 planes). A grid step takes
+# a group
+# of rows: their planes are read into one of three VMEM slots, added to the
+# step's values, and written back; the NEXT step's reads are started before
+# this step's are awaited and a step's writes are awaited two steps later
+# (unique ids: no read can meet a write of the same row), so the DMA
+# engines' latency hides behind the scalar core's issuing of the next rows.
+# An id ``>= rows`` starts no DMA either way, which is ``mode="drop"``. No
+# ``has_side_effects``: the result IS the output, and a rematerialised
+# forward whose result nobody reads stays removable.
+
+_ADD_GROUP_ROWS = 64
+_ADD_SLOTS = 3
+_ADD_UNROLL = 8
+
+
+def _make_add_unique_kernel(group: int, num_rows: int, steps: int):
+    slots, unroll = _ADD_SLOTS, min(_ADD_UNROLL, group)
+
+    def _kernel(ids_ref, live_ref, vals_ref, acc_in, acc_ref, rows, sems):
+        del acc_in              # aliased with acc_ref (the output)
+        step = pl.program_id(0)
+
+        def _start(s, load):
+            """Start the row DMAs of step ``s``'s live lanes: into its VMEM
+            slot (``load``) or back to HBM."""
+            slot, base = s % slots, s * group
+
+            def lane(k):
+                rid = ids_ref[base + k]
+
+                @pl.when(rid < num_rows)
+                def _():
+                    ends = (acc_ref.at[rid], rows.at[slot, k])
+                    pltpu.make_async_copy(
+                        *(ends if load else ends[::-1]),
+                        sems.at[1 if load else 0, slot]).start()
+
+            # Mosaic unrolls a loop wholly or not at all: eight lanes a
+            # trip keep the kernel small and the scalar core off the loop's
+            # own bookkeeping.
+            def body(i, c):
+                for j in range(unroll):
+                    lane(i * unroll + j)
+                return c
+            jax.lax.fori_loop(0, group // unroll, body, 0)
+
+        def _wait(s, load):
+            """Wait for what ``_start(s, load)`` started: a DMA semaphore
+            counts bytes, so the step's live lanes (``live``) are waited
+            for by their number's binary digits."""
+            slot, n = s % slots, group
+            while n:
+                def wait_rows(n=n):
+                    ends = (acc_ref.at[pl.ds(0, n)],
+                            rows.at[slot, pl.ds(0, n)])
+                    pltpu.make_async_copy(
+                        *(ends if load else ends[::-1]),
+                        sems.at[1 if load else 0, slot]).wait()
+                pl.when((live_ref[s] & n) != 0)(wait_rows)
+                n //= 2
+
+        pl.when(step == 0)(lambda: _start(step, True))
+        pl.when(step >= 2)(lambda: _wait(step - 2, False))
+        pl.when(step + 1 < steps)(lambda: _start(step + 1, True))
+        _wait(step, True)
+        slot = step % slots
+        rows[slot] = rows[slot] + vals_ref[...]
+        _start(step, False)
+
+        @pl.when(step == steps - 1)
+        def _():
+            if steps > 1:
+                _wait(step - 1, False)
+            _wait(step, False)
+    return _kernel
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def add_unique_rows(acc: jax.Array, ids: jax.Array, vals: jax.Array,
+                    interpret: bool = False) -> jax.Array:
+    """``acc[ids[i]] += vals[i]`` for ids of which no live one occurs twice,
+    in any order: ``acc`` float32 ``[rows, planes, 128]`` (a ``[rows, planes
+    * 128]`` accumulator seen a 128-lane plane at a time), updated in place
+    (aliased: trace it where ``acc`` is a loop's carry or donated); ``ids``
+    ``[n]``, an id ``>= rows`` dropped as ``.at[ids].add(mode="drop")``
+    drops it; ``vals`` ``[n, planes, 128]``. Bitwise the XLA scatter-add's
+    result: every live row is read, added to once and written."""
+    num_rows, planes, lanes = acc.shape
+    n = ids.shape[0]
+    if n == 0:
+        return acc
+    # a power of two (the waits count by binary digits) no larger than the
+    # accumulator, whose leading rows the wait's descriptor names
+    group = min(_ADD_GROUP_ROWS, 1 << (num_rows.bit_length() - 1))
+    pad = (-n) % group
+    ids = ids.astype(jnp.int32)
+    if pad:
+        ids = jnp.concatenate([ids, jnp.full((pad,), num_rows, jnp.int32)])
+        vals = jnp.concatenate(
+            [vals, jnp.zeros((pad, planes, lanes), vals.dtype)])
+    steps = (n + pad) // group
+    live = jnp.sum((ids < num_rows).reshape(steps, group), axis=1,
+                   dtype=jnp.int32)
+    if interpret:
+        # Interpreted, the kernel's add is an XLA:CPU op, and LLVM contracts
+        # it with a multiply that made ``vals`` into one fma (one rounding
+        # where XLA's scatter-add and the chip make two). A division by a
+        # one the compiler cannot see through keeps them apart
+        # (``core/updater._eval_jaxpr_contraction_proof``; a barrier does
+        # not).
+        vals = vals / jnp.where(live[0] >= 0, 1.0, 2.0).astype(vals.dtype)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,      # ids, live
+        grid=(steps,),
+        in_specs=[pl.BlockSpec((group, planes, lanes),
+                               lambda g, *refs: (g, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=[pltpu.VMEM((_ADD_SLOTS, group, planes, lanes),
+                                   acc.dtype),
+                        pltpu.SemaphoreType.DMA((2, _ADD_SLOTS))],
+    )
+    return pl.pallas_call(
+        _make_add_unique_kernel(group, num_rows, steps),
+        out_shape=jax.ShapeDtypeStruct(acc.shape, acc.dtype),
+        grid_spec=grid_spec,
+        input_output_aliases={3: 0},    # ids(0) live(1) vals(2) acc(3)
+        interpret=interpret,
+    )(ids, live, vals.astype(acc.dtype), acc)

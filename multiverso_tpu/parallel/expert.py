@@ -21,8 +21,15 @@ all of them, and computes its own experts' terms for every assignment that
 lands here — no capacity, nothing dropped. The assignments are sorted by
 expert into blocks of one expert each, and a loop over the blocks IN USE
 gathers a block's tokens, runs the products against that expert's
-weights and adds the weighted result back; the backward is the same loop
-with the products transposed, so nothing of a block outlives it. What the
+weights and adds the weighted result rows into a token-ordered float32
+accumulator; the backward is the same loop with the products transposed
+(the input's gradient accumulated the same way), so nothing of a block
+outlives it. A block's token ids are unique, so where the accumulator has
+whole 128-lane planes a row and its caller knows it to live on one device
+(``rows_interpret`` not None) the rows land by the DMA read-modify-write
+kernel ``ops/pallas_rows.add_unique_rows`` on a ``[tokens, hidden / 128,
+128]`` view carried through the loop; anywhere else by XLA's scatter-add,
+to the same bits (:func:`_token_accumulator`). What the
 absent experts would add is left out: on one chip the layer runs without
 its exchange (docs/HYBRID_LM.md). A softmax router can hand back its
 sequence-wise balance loss (:func:`sequence_balance_loss`): the router is
@@ -232,29 +239,79 @@ def _rows(x, idx):
     return jnp.take(x, idx, axis=0, mode="fill", fill_value=0)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
+def token_rows_kernel_selected(hidden: int, dtype) -> bool:
+    """Whether a ``[tokens, hidden]`` accumulator of the block loops can be
+    the DMA row kernel's (``ops/pallas_rows.add_unique_rows``), as far as
+    the array itself says: float32, whole 128-lane planes a row. Its caller
+    adds what only it knows: the accumulator on ONE device, no mesh axis
+    over its rows (``HybridLM`` reads that off its leaves)."""
+    return np.dtype(dtype) == np.dtype(np.float32) and hidden % 128 == 0
+
+
+def _token_accumulator(like: jax.Array, rows_interpret: Optional[bool]):
+    """Where a block's result rows land: ``(zero, add(acc, idx, vals),
+    done(acc))`` for a float32 ``[T, hidden]`` sum over the blocks, ``idx``
+    a block's UNIQUE token ids (``T`` = an empty slot, dropped), ``vals``
+    ``[block, hidden]``. ``rows_interpret`` None, or an accumulator the row
+    kernel cannot serve: XLA's scatter-add into ``[T, hidden]``. Otherwise
+    the loop carries ``[T, hidden / 128, 128]``, the DMA read-modify-write
+    kernel adds a block's rows in place (under the Pallas interpreter where
+    ``rows_interpret``), and ``done`` turns the sum back once after the
+    loop. Either way every row takes the same additions in the same order:
+    the two planes agree to the bit."""
+    t, d = like.shape
+    if rows_interpret is None or not token_rows_kernel_selected(
+            d, like.dtype):
+        return (jnp.zeros_like(like),
+                lambda acc, idx, vals: acc.at[idx].add(vals, mode="drop"),
+                lambda acc: acc)
+    from multiverso_tpu.ops.pallas_rows import add_unique_rows
+    # Whole (8, 128) tiles a row: the 21 planes of hidden 2,688 are carried
+    # as 24, which is what they occupy in HBM anyway, the COLUMNS padded
+    # before the view is taken and cut after it is turned back. (Carried as
+    # 21, XLA re-lays [T, 21, 128] through a transposed layout and the v5e
+    # program's code read 211 MB against 55, 1.9% of nemotron_train's
+    # ``peak_hbm_gb``, PERF.md 6, PR 41; and the DMAs moved partial tiles.)
+    planes = d // 128
+    pad = (-planes) % 8
+
+    def add(acc, idx, vals):
+        if pad:
+            vals = jnp.pad(vals, ((0, 0), (0, pad * 128)))
+        return add_unique_rows(acc, idx, vals.reshape(-1, planes + pad, 128),
+                               interpret=rows_interpret)
+
+    return (jnp.zeros((t, planes + pad, 128), like.dtype), add,
+            lambda acc: acc.reshape(t, -1)[:, :d])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
 def grouped_relu2_experts(n, gates, w_up, w_down, tokens, block_expert,
-                          blocks_in_use, block):
+                          blocks_in_use, block, rows_interpret=None):
     """``sum_e gate_e relu(n W_up,e)^2 W_down,e`` over the grouped
     assignments: ``n`` [T, D], ``w_up`` [E_held, D, F], ``w_down``
-    [E_held, F, D] -> [T, D]."""
+    [E_held, F, D] -> [T, D]. ``rows_interpret``: where a block's rows land
+    (:func:`_token_accumulator`)."""
+    zero, add, done = _token_accumulator(n, rows_interpret)
+
     def body(b, out):
         idx, gate, e = _block_of(tokens, gates, block_expert, b, block)
         h = jnp.square(jax.nn.relu(_rows(n, idx) @ w_up[e]))
-        return out.at[idx].add((h @ w_down[e]) * gate[:, None], mode="drop")
+        return add(out, idx, (h @ w_down[e]) * gate[:, None])
 
-    return jax.lax.fori_loop(0, blocks_in_use, body, jnp.zeros_like(n))
+    return done(jax.lax.fori_loop(0, blocks_in_use, body, zero))
 
 
 def _grouped_fwd(n, gates, w_up, w_down, tokens, block_expert, blocks_in_use,
-                 block):
+                 block, rows_interpret):
     out = grouped_relu2_experts(n, gates, w_up, w_down, tokens, block_expert,
-                                blocks_in_use, block)
+                                blocks_in_use, block, rows_interpret)
     return out, (n, gates, w_up, w_down, tokens, block_expert, blocks_in_use)
 
 
-def _grouped_bwd(block, saved, dout):
+def _grouped_bwd(block, rows_interpret, saved, dout):
     n, gates, w_up, w_down, tokens, block_expert, blocks_in_use = saved
+    zero, add, done = _token_accumulator(n, rows_interpret)
 
     def body(b, carry):
         dn, dgates, dup, ddown = carry
@@ -269,16 +326,15 @@ def _grouped_bwd(block, saved, dout):
         da = (dy @ w_down[e].T) * (2.0 * r)
         ddown = ddown.at[e].add(h.T @ dy)
         dup = dup.at[e].add(x.T @ da)
-        return (dn.at[idx].add(da @ w_up[e].T, mode="drop"), dgates, dup,
-                ddown)
+        return add(dn, idx, da @ w_up[e].T), dgates, dup, ddown
 
     dn, dgates, dup, ddown = jax.lax.fori_loop(
         0, blocks_in_use, body,
-        (jnp.zeros_like(n), jnp.zeros_like(gates), jnp.zeros_like(w_up),
+        (zero, jnp.zeros_like(gates), jnp.zeros_like(w_up),
          jnp.zeros_like(w_down)))
     no_grad = lambda x: np.zeros(x.shape, jax.dtypes.float0)  # noqa: E731
-    return (dn, dgates, dup, ddown, no_grad(tokens), no_grad(block_expert),
-            no_grad(blocks_in_use))
+    return (done(dn), dgates, dup, ddown, no_grad(tokens),
+            no_grad(block_expert), no_grad(blocks_in_use))
 
 
 grouped_relu2_experts.defvjp(_grouped_fwd, _grouped_bwd)
@@ -290,32 +346,38 @@ def _silu_parts(a):
     return a * sig, sig * (1.0 + a * (1.0 - sig))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(8,))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9))
 def grouped_gated_experts(n, gates, w_gate, w_up, w_down, tokens,
-                          block_expert, blocks_in_use, block):
+                          block_expert, blocks_in_use, block,
+                          rows_interpret=None):
     """``sum_e gate_e (silu(n W_gate,e) * n W_up,e) W_down,e`` over the
     grouped assignments: ``n`` [T, D], ``w_gate`` / ``w_up`` [E_held, D, F],
-    ``w_down`` [E_held, F, D] -> [T, D]."""
+    ``w_down`` [E_held, F, D] -> [T, D]. ``rows_interpret``: where a block's
+    rows land (:func:`_token_accumulator`)."""
+    zero, add, done = _token_accumulator(n, rows_interpret)
+
     def body(b, out):
         idx, gate, e = _block_of(tokens, gates, block_expert, b, block)
         x = _rows(n, idx)
         h = jax.nn.silu(x @ w_gate[e]) * (x @ w_up[e])
-        return out.at[idx].add((h @ w_down[e]) * gate[:, None], mode="drop")
+        return add(out, idx, (h @ w_down[e]) * gate[:, None])
 
-    return jax.lax.fori_loop(0, blocks_in_use, body, jnp.zeros_like(n))
+    return done(jax.lax.fori_loop(0, blocks_in_use, body, zero))
 
 
 def _gated_fwd(n, gates, w_gate, w_up, w_down, tokens, block_expert,
-               blocks_in_use, block):
+               blocks_in_use, block, rows_interpret):
     out = grouped_gated_experts(n, gates, w_gate, w_up, w_down, tokens,
-                                block_expert, blocks_in_use, block)
+                                block_expert, blocks_in_use, block,
+                                rows_interpret)
     return out, (n, gates, w_gate, w_up, w_down, tokens, block_expert,
                  blocks_in_use)
 
 
-def _gated_bwd(block, saved, dout):
+def _gated_bwd(block, rows_interpret, saved, dout):
     n, gates, w_gate, w_up, w_down, tokens, block_expert, blocks_in_use = \
         saved
+    zero, add, done = _token_accumulator(n, rows_interpret)
 
     def body(b, carry):
         dn, dgates, dgate_w, dup, ddown = carry
@@ -333,15 +395,15 @@ def _gated_bwd(block, saved, dout):
         ddown = ddown.at[e].add(h.T @ dy)
         dgate_w = dgate_w.at[e].add(x.T @ da)
         dup = dup.at[e].add(x.T @ du)
-        return (dn.at[idx].add(da @ w_gate[e].T + du @ w_up[e].T,
-                               mode="drop"), dgates, dgate_w, dup, ddown)
+        return (add(dn, idx, da @ w_gate[e].T + du @ w_up[e].T), dgates,
+                dgate_w, dup, ddown)
 
     dn, dgates, dgate_w, dup, ddown = jax.lax.fori_loop(
         0, blocks_in_use, body,
-        (jnp.zeros_like(n), jnp.zeros_like(gates), jnp.zeros_like(w_gate),
+        (zero, jnp.zeros_like(gates), jnp.zeros_like(w_gate),
          jnp.zeros_like(w_up), jnp.zeros_like(w_down)))
     no_grad = lambda x: np.zeros(x.shape, jax.dtypes.float0)  # noqa: E731
-    return (dn, dgates, dgate_w, dup, ddown, no_grad(tokens),
+    return (done(dn), dgates, dgate_w, dup, ddown, no_grad(tokens),
             no_grad(block_expert), no_grad(blocks_in_use))
 
 
@@ -356,7 +418,8 @@ def held_topk_moe(n: jax.Array, router: jax.Array, bias: jax.Array,
                   w_gate: Optional[jax.Array] = None,
                   s_gate: Optional[jax.Array] = None,
                   balance: Optional[Tuple[float, int]] = None,
-                  count_all: bool = False):
+                  count_all: bool = False,
+                  rows_interpret: Optional[bool] = None):
     """One chip's share of a top-k expert layer: ``n`` [T, D] ->
     (y [T, D], assignments per held expert [len(held)]). ``router`` is
     [D, E] over ALL experts, ``w_up`` / ``w_down`` hold the experts
@@ -370,7 +433,11 @@ def held_topk_moe(n: jax.Array, router: jax.Array, bias: jax.Array,
     only; ``n`` is ``sequences`` equal runs of tokens) adds a third result,
     the sequence-wise balance loss. ``count_all`` adds, last, the assignments
     to EVERY expert of the router, [E] (the router is whole on every share):
-    what a selection bias's update between steps reads."""
+    what a selection bias's update between steps reads. ``rows_interpret``
+    says where the block loops' result rows land: None (a caller that does
+    not know ``n`` to live on one device) in XLA's scatter-add, else, for a
+    float32 ``n`` of whole 128-lane planes, in the DMA row kernel, run under
+    the Pallas interpreter where true (:func:`_token_accumulator`)."""
     if scoring == "softmax":
         chosen, weights, probs = softmax_topk_route(n, router, top_k,
                                                     scaling, normalize)
@@ -381,12 +448,14 @@ def held_topk_moe(n: jax.Array, router: jax.Array, bias: jax.Array,
         chosen, weights, held, router.shape[1], block)
     if w_gate is None:
         y = grouped_relu2_experts(n, gates, w_up, w_down, tokens,
-                                  block_expert, in_use, block)
+                                  block_expert, in_use, block,
+                                  rows_interpret)
         if shared:
             y = y + jnp.square(jax.nn.relu(n @ s_up)) @ s_down
     else:
         y = grouped_gated_experts(n, gates, w_gate, w_up, w_down, tokens,
-                                  block_expert, in_use, block)
+                                  block_expert, in_use, block,
+                                  rows_interpret)
         if shared:
             y = y + (jax.nn.silu(n @ s_gate) * (n @ s_up)) @ s_down
     out = (y, counts)

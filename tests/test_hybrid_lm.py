@@ -288,6 +288,93 @@ def test_routing_drops_nothing_when_one_expert_takes_half_the_tokens():
     assert rel(y, want) < TOL["loss"]
 
 
+@pytest.mark.parametrize("hidden", [256, 1024])
+@pytest.mark.parametrize("kind", ["gated", "relu2"])
+def test_grouped_experts_on_the_row_kernel_are_the_xla_planes_bits(kind,
+                                                                   hidden):
+    """Where a block's result rows land (ISSUE 41): with the DMA row kernel
+    (interpreted here) the grouped products give the XLA scatter-add's
+    outputs and gradients to the bit, under ``jax.checkpoint``, at 256
+    columns (two planes, carried as a whole tile of eight) and at 1,024
+    (eight, as they are), with an uneven load (every token on expert 0, a
+    few on expert 5: several blocks against one) and a held expert that
+    nobody chose."""
+    from multiverso_tpu.parallel.expert import (group_held_assignments,
+                                                grouped_gated_experts,
+                                                grouped_relu2_experts)
+    tokens, width, held, block = 96, 64, (0, 2, 5), 8
+    rng = np.random.default_rng(41)
+
+    def draw(*shape, scale=0.1):
+        return jnp.asarray(rng.standard_normal(shape, dtype=np.float32)
+                           * scale)
+
+    chosen = np.stack([np.zeros(tokens, np.int32),
+                       np.where(np.arange(tokens) % 7 == 0, 5, 3)], axis=1)
+    layout = group_held_assignments(
+        jnp.asarray(chosen), draw(tokens, 2, scale=1.0), held, 8, block)
+    idx, gates, block_expert, in_use, counts = layout
+    assert list(np.asarray(counts)) == [tokens, 0, 14]
+    n, w = draw(tokens, hidden, scale=1.0), draw(tokens, hidden, scale=1.0)
+    weights = [draw(len(held), hidden, width) for _ in range(
+        2 if kind == "gated" else 1)] + [draw(len(held), width, hidden)]
+    grouped = grouped_gated_experts if kind == "gated" \
+        else grouped_relu2_experts
+
+    def run(rows_interpret):
+        @jax.checkpoint
+        def experts(n, gates, *weights):
+            return grouped(n, gates, *weights, idx, block_expert, in_use,
+                           block, rows_interpret)
+
+        def loss(n, gates, *weights):
+            y = experts(n, gates, *weights)
+            return jnp.sum(y * w), y
+        return jax.jit(jax.value_and_grad(
+            loss, argnums=tuple(range(2 + len(weights))), has_aux=True))(
+                n, gates, *weights)
+
+    (_, y_xla), grads_xla = run(None)
+    (_, y_kernel), grads_kernel = run(True)
+    assert float(jnp.abs(y_xla).max()) > 0
+    np.testing.assert_array_equal(np.asarray(y_xla), np.asarray(y_kernel))
+    for a, b in zip(grads_xla, grads_kernel):
+        assert float(jnp.abs(a).max()) > 0
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("hidden,plane", [(64, "xla"), (128, "fused")])
+def test_step_counts_the_plane_its_expert_blocks_rows_land_on(
+        hidden, plane, monkeypatch):
+    """``lm.moe.rows.plane.<fused/xla>``, one a step and expert block: the
+    row kernel wherever the token accumulator is float32 of whole 128-lane
+    planes on the leaves' one device (here under the interpreter), XLA's
+    scatter-add at hidden 64. A twin kept on the XLA plane steps to the
+    same bits."""
+    from multiverso_tpu.models.hybrid_lm import model as model_module
+    cfg = small(hidden_size=hidden, pattern="E*E")
+    model = HybridLM(cfg, mode="local")
+    assert model.moe_rows_interpret == (True if plane == "fused" else None)
+    monkeypatch.setattr(model_module, "token_rows_kernel_selected",
+                        lambda hidden, dtype: False)
+    twin = HybridLM(cfg, mode="local")
+    monkeypatch.undo()
+    assert twin.moe_rows_interpret is None
+    reg = get_registry()
+    names = [f"lm.moe.rows.plane.{p}" for p in ("fused", "xla")]
+    batches = [batch(cfg, seed=11), batch(cfg, seed=12)]
+    before = [reg.counter(n).value for n in names]
+    losses = [model.step(b) for b in batches]
+    moved = dict(zip(("fused", "xla"), (reg.counter(n).value - b
+                                        for n, b in zip(names, before))))
+    assert moved == {plane: 2 * len(batches),
+                     "xla" if plane == "fused" else "fused": 0}
+    assert losses == [twin.step(b) for b in batches]
+    for (name, a), (_, b) in zip(model.dense_leaves(), twin.dense_leaves()):
+        np.testing.assert_array_equal(a, b, err_msg=name)
+    np.testing.assert_array_equal(model.local_rows(), twin.local_rows())
+
+
 def test_reference_expert_layer_in_token_blocks_is_the_whole(monkeypatch):
     cfg = small(pattern="E")
     p, bias = init_params(cfg)["layers"][0], init_buffers(cfg)[0]

@@ -35,6 +35,24 @@ def _scopes_of(compiled) -> set:
             for name in scope_names(path)}
 
 
+def _experts_on_the_row_kernel(compiled) -> None:
+    """An expert block compiled with ``moe_rows_interpret=False``: the
+    backward's block loop (and the forward's, where the program keeps one)
+    ends in the DMA row kernel under ``lm_experts``, and no XLA scatter
+    touches a [tokens, hidden] accumulator (ISSUE 41; the grouping's own
+    scatters lay out [slots] ids and gates, the weight gradients' are
+    [experts, ...])."""
+    text = compiled.as_text()
+    kernels = [path for name, path in parse_scopes(text)[1].items()
+               if "pallas_call" in path]
+    assert kernels and "tpu_custom_call" in text
+    assert all("lm_experts" in scope_names(path) for path in kernels)
+    scatters = [line for line in text.splitlines() if " scatter(" in line]
+    assert not [line for line in scatters
+                if line.split(" = ")[1].startswith("f32[")
+                and line.split(" = ")[1].count(",") == 1], scatters
+
+
 @pytest.fixture(scope="module")
 def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -72,13 +90,18 @@ def test_mixer_compiles_for_v5e_at_published_widths(one_chip, cfg, kind,
     bias = spec((cfg.router_experts,)) if kind == "E" else None
     u = spec((seqs, SEQ, cfg.hidden_size))
 
+    # an expert block as HybridLM runs it on one chip: on the row kernel
+    plane = (False,) if kind == "E" else ()
+
     def loss(p, bias, u):
-        return jnp.sum(layer_forward(kind, p, bias, u, cfg, remat=True)[0])
+        return jnp.sum(layer_forward(kind, p, bias, u, cfg, True, *plane)[0])
 
     run = jax.grad(loss, argnums=(0, 2))
     compiled = jax.jit(run).lower(p, bias, u).compile()
     stats = compiled.memory_analysis()
     assert stats.temp_size_in_bytes < temp_gb * 1e9, stats
+    if kind == "E":
+        _experts_on_the_row_kernel(compiled)
 
 
 DSV2, EVABYTE = "deepseek-v2-lite-ep4", "evabyte-6.5b-pp8"
@@ -121,8 +144,10 @@ def test_two_block_layer_compiles_for_v5e_at_published_widths(
     bias = init_buffers(cfg)[layer]     # a Lightning block's decay a head
     bias = None if bias is None else spec(bias.shape)
 
+    plane = (False,) if kind == "E" else ()
+
     def loss(p, bias, u):
-        out, _, *more = layer_forward(kind, p, bias, u, cfg, remat=True)
+        out, _, *more = layer_forward(kind, p, bias, u, cfg, True, *plane)
         # past a balance loss: the counts a selection bias's update reads
         return jnp.sum(out) + sum(more[:int(cfg.balanced)])
 
@@ -130,6 +155,35 @@ def test_two_block_layer_compiles_for_v5e_at_published_widths(
         p, bias, u).compile()
     stats = compiled.memory_analysis()
     assert stats.temp_size_in_bytes < temp_gb * 1e9, stats
+    if kind == "E":
+        _experts_on_the_row_kernel(compiled)
+
+
+@pytest.mark.parametrize("tokens,planes", [(32768, 16), (16384, 21),
+                                           (16384, 24)],
+                         ids=["lfm2_train", "2688_columns", "nemotron_train"])
+def test_unique_row_add_kernel_compiles_for_v5e(one_chip, tokens, planes):
+    """The expert layer's DMA row kernel alone, at two cells' accumulators:
+    Mosaic refuses a one-row slice of a float32 [tokens, columns] array
+    wider than 128 columns ("Slice shape along dimension 0 must be aligned
+    to tiling (8), but is 1") and takes the same data as [tokens, columns /
+    128, 128], a row one whole plane: at 16 planes (2,048 columns), at the
+    21 of 2,688 columns, and at the 24 the expert layer carries those as
+    (whole tiles a row). The guard against the tiling's refusal coming
+    back."""
+    from multiverso_tpu.ops.pallas_rows import add_unique_rows
+
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    columns = planes * 128
+    compiled = jax.jit(add_unique_rows, donate_argnums=0).lower(
+        spec((tokens, planes, 128)), spec((512,), jnp.int32),
+        spec((512, planes, 128))).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    # in place: the accumulator goes in and comes out in one buffer
+    assert compiled.memory_analysis().alias_size_in_bytes >= \
+        tokens * columns * 4
 
 
 def test_evabyte_step_compiles_for_v5e_inside_a_chips_memory(one_chip):
@@ -210,7 +264,12 @@ def test_lfm2_step_compiles_for_v5e_inside_a_chips_memory(one_chip):
     back beside the parameters'. What has to stay under the 15.75 GiB a v5e
     chip reports is parameters + gradients + accumulators + the table's rows
     and accumulator + the program's temporaries (7.3 GB here, of which the
-    twelve saved block inputs are 3.2)."""
+    twelve saved block inputs are 3.2). The expert blocks as ``HybridLM``
+    runs them on one chip, their rows landing by the DMA row kernel (ISSUE
+    41): eight kernel instances, the forward's and the backward's of four
+    blocks (a rematerialised forward's result nobody reads, and it goes), no
+    scatter into a [tokens, hidden] accumulator, the temporaries what they
+    were (7.381 GB on both planes)."""
     from multiverso_tpu.models.hybrid_lm import dense_param_count, make_loss
     cfg = HybridLMConfig.from_file(os.path.join(
         ROOT, "benchmark", "configs", LFM2 + ".json"))
@@ -225,10 +284,14 @@ def test_lfm2_step_compiles_for_v5e_inside_a_chips_memory(one_chip):
     buffers = [None if b is None else spec(b.shape)
                for b in init_buffers(cfg)]
     compiled = jax.jit(jax.value_and_grad(
-        make_loss(cfg), argnums=(0, 1), has_aux=True)).lower(
+        make_loss(cfg, moe_rows_interpret=False), argnums=(0, 1),
+        has_aux=True)).lower(
             params, spec((cfg.vocab_size, cfg.hidden_size)), buffers,
             spec((4, SEQ), jnp.int32), spec((4, SEQ), jnp.int32),
             spec((4, SEQ))).compile()
+    _experts_on_the_row_kernel(compiled)
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 8
     stats = compiled.memory_analysis()
     plane = 4 * dense_param_count(cfg)
     table = 2 * 4 * cfg.vocab_size * cfg.hidden_size
@@ -241,6 +304,38 @@ def test_lfm2_step_compiles_for_v5e_inside_a_chips_memory(one_chip):
     assert reserved < 15.75 * 2 ** 30 - 0.5e9, (reserved, stats)
     assert {"lm_embed", "lm_head_loss", "lm_shortconv", "lm_attention",
             "lm_dense_ffn", "lm_experts"} <= _scopes_of(compiled)
+
+
+def test_nemotron_step_on_the_row_kernel_keeps_its_program_small(one_chip,
+                                                                 cfg):
+    """``nemotron_train``'s whole loss-and-gradient with its expert blocks'
+    rows landing by the DMA row kernel (ISSUE 41). Its 2,688 columns are 21
+    planes: carried as 21, XLA re-laid [tokens, 21, 128] through a
+    transposed layout and the executable's code read 211 MB where it reads
+    56 (the chip showed it as +1.9% ``peak_hbm_gb``, past the cell's bound);
+    carried as 24, columns padded before the view and cut after it, the
+    program is the size it was."""
+    from multiverso_tpu.models.hybrid_lm import make_loss
+
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        spec, param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple))
+    buffers = [None if b is None else spec(b.shape)
+               for b in init_buffers(cfg)]
+    compiled = jax.jit(jax.value_and_grad(
+        make_loss(cfg, moe_rows_interpret=False), argnums=(0, 1),
+        has_aux=True)).lower(
+            params, spec((7 * cfg.row_bucket, cfg.hidden_size)), buffers,
+            spec((2, SEQ), jnp.int32), spec((2, SEQ), jnp.int32),
+            spec((2, SEQ))).compile()
+    _experts_on_the_row_kernel(compiled)
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 8
+    stats = compiled.memory_analysis()
+    assert stats.generated_code_size_in_bytes < 100e6, stats
+    assert stats.temp_size_in_bytes < 6.5e9, stats
 
 
 # (tables, rows a table, width, ids a table, one [B, n] id matrix?): the two

@@ -380,3 +380,86 @@ def test_fused_stateful_per_worker_state_indexing():
         assert np.abs(g2x[0] - g2x[1]).max() > 0   # both planes really used
     finally:
         mv.shutdown()
+
+
+# -- a block of unique rows added into a wide accumulator (ISSUE 41) ----------
+def _unique_case(rows, cols, ids, seed=0):
+    from multiverso_tpu.ops.pallas_rows import add_unique_rows
+    rng = np.random.default_rng(seed)
+    acc = jnp.asarray(rng.standard_normal((rows, cols), dtype=np.float32))
+    ids = jnp.asarray(ids, jnp.int32)
+    a = jnp.asarray(rng.standard_normal((len(ids), cols), dtype=np.float32))
+    g = jnp.asarray(rng.standard_normal(len(ids), dtype=np.float32))
+    planes = (cols // 128, 128)
+
+    # the values come out of a multiply, as a block's gated result does: the
+    # kernel's add must not contract with it
+    @jax.jit
+    def xla(acc, a, g):
+        return acc.at[ids].add(a * g[:, None], mode="drop")
+
+    @jax.jit
+    def kernel(acc, a, g):
+        return add_unique_rows(
+            acc.reshape((rows,) + planes), ids,
+            (a * g[:, None]).reshape((-1,) + planes),
+            interpret=True).reshape(rows, cols)
+
+    return acc, xla(acc, a, g), kernel(acc, a, g)
+
+
+@pytest.mark.parametrize("cols", [128, 256, 2688])
+@pytest.mark.parametrize("order", ["random", "descending"])
+def test_add_unique_rows_bitwise_vs_xla_scatter_add(cols, order):
+    """Unique ids in any order, the out-of-range ones (the layout's empty
+    slots) at the tail and dropped; 150 ids are two whole groups of 64 and
+    a padded third, so the in-flight slots are reused."""
+    rows = 200
+    ids = np.random.default_rng(cols).permutation(rows)[:150]
+    if order == "descending":
+        ids = np.sort(ids)[::-1].copy()
+    ids[-9:] = rows + np.arange(9) % 2       # `rows`, `rows + 1`, ...
+    acc, want, got = _unique_case(rows, cols, ids)
+    np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
+    assert not np.array_equal(np.asarray(acc), np.asarray(got))
+
+
+@pytest.mark.parametrize("rows,n", [(200, 64), (5, 7), (64, 0)])
+def test_add_unique_rows_all_empty_block_changes_nothing(rows, n):
+    """Every id out of range (a block of empty slots), an accumulator
+    smaller than a group, no ids at all: the accumulator comes back as it
+    went in."""
+    acc, want, got = _unique_case(rows, 256, np.full(n, rows))
+    np.testing.assert_array_equal(np.asarray(acc), np.asarray(got))
+    np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
+
+
+def test_add_unique_rows_in_a_loop_carry_over_several_blocks():
+    """As the expert layer drives it: the accumulator a ``fori_loop``'s
+    carry, a block of ids a trip, a token in several blocks (once each)."""
+    from multiverso_tpu.ops.pallas_rows import add_unique_rows
+    rows, cols, blocks, block = 96, 256, 5, 24
+    rng = np.random.default_rng(3)
+    ids = np.stack([rng.permutation(rows + 8)[:block] for _ in range(blocks)])
+    ids = jnp.asarray(np.minimum(ids, rows), jnp.int32)   # some empty slots
+    vals = jnp.asarray(rng.standard_normal((blocks, block, cols),
+                                           dtype=np.float32))
+
+    @jax.jit
+    def xla(ids, vals):
+        return jax.lax.fori_loop(
+            0, blocks, lambda b, acc: acc.at[ids[b]].add(vals[b],
+                                                         mode="drop"),
+            jnp.zeros((rows, cols), jnp.float32))
+
+    @jax.jit
+    def kernel(ids, vals):
+        return jax.lax.fori_loop(
+            0, blocks, lambda b, acc: add_unique_rows(
+                acc, ids[b], vals[b].reshape(block, cols // 128, 128),
+                interpret=True),
+            jnp.zeros((rows, cols // 128, 128), jnp.float32)
+        ).reshape(rows, cols)
+
+    np.testing.assert_array_equal(np.asarray(xla(ids, vals)),
+                                  np.asarray(kernel(ids, vals)))
