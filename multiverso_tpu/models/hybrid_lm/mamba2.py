@@ -15,8 +15,12 @@ recurrence as written.
 
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
+
+from multiverso_tpu.ops.pallas_ssd import scan_kernel_selected, ssd_scan
 
 __all__ = ["mamba2_mixer", "ssd_chunked", "causal_conv1d",
            "gated_group_rmsnorm"]
@@ -48,13 +52,61 @@ def gated_group_rmsnorm(y: jax.Array, z: jax.Array, w: jax.Array,
     return g.reshape(shape) * w
 
 
-def ssd_chunked(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
-                c: jax.Array, chunk: int, group: int = 8) -> jax.Array:
-    """``x`` [B, S, H, P], ``dt`` [B, S, H] (positive), ``a`` [H]
-    (negative), ``b``/``c`` [B, S, G, N] (a group serves H/G heads) ->
-    ``y`` [B, S, H, P], without the ``D x`` term. Any S: the tail is
-    padded with ``dt = 0`` tokens, which neither decay nor feed the
-    state. The products inside the chunks run ``group`` chunks at a time
+def ssd_chunked(x: jax.Array, dt: Optional[jax.Array], a: jax.Array,
+                b: jax.Array, c: jax.Array, chunk: int, group: int = 8,
+                interpret: Optional[bool] = None,
+                skip: Optional[jax.Array] = None) -> jax.Array:
+    """``x`` [B, S, H, P], ``dt`` [B, S, H] (positive; None: steps of one),
+    ``a`` [H] (negative), ``b``/``c`` [B, S, G, N] (a group serves H/G
+    heads) -> ``y`` [B, S, H, P], with ``skip`` [H] the ``D x`` term too.
+    Any S: the tail is padded with ``dt = 0`` tokens, which neither decay nor
+    feed the state. ``interpret``: None unless the caller knows the arrays
+    to live on ONE device, and then :func:`multiverso_tpu.ops.
+    pallas_interpret` of it: shapes the kernels take (:func:`~multiverso_tpu.
+    ops.pallas_ssd.scan_kernel_selected`) then run as those, a chunk's
+    ``chunk x chunk`` decay planes in VMEM; every other scan in
+    ``jax.numpy``, ``group`` chunks' planes at a time."""
+    with jax.named_scope("lm_ssd"):
+        if interpret is not None and scan_kernel_selected(
+                chunk, b.shape[3], x.shape[2] // b.shape[2], x.shape[3],
+                *(t.dtype for t in (x, dt, a, b, c, skip) if t is not None)):
+            return _ssd_fused(x, dt, a, b, c, chunk, interpret, skip)
+        y = _ssd_xla(x, jnp.ones(x.shape[:3], x.dtype) if dt is None else dt,
+                     a, b, c, chunk, group)
+        return y if skip is None else y + x * skip[:, None]
+
+
+def _ssd_fused(x, dt, a, b, c, chunk: int, interpret: bool,
+               skip) -> jax.Array:
+    """The scan by :func:`~multiverso_tpu.ops.pallas_ssd.ssd_scan`: here only
+    the padding, ``dt`` and ``dt a`` turned heads before positions, and the
+    skip's weight a lane."""
+    bsz, s, h, p = x.shape
+    g, n = b.shape[2], b.shape[3]
+    pad = (-s) % chunk
+    t = s + pad
+    # without a ``dt``, ``a`` a position, and none on the padded tail
+    dta = jnp.broadcast_to(a, (bsz, s, h)) if dt is None else dt * a
+    if pad:
+        x, dt, dta, b, c = (
+            u if u is None else jnp.pad(u, ((0, 0), (0, pad)) + ((0, 0),) *
+                                        (u.ndim - 2))
+            for u in (x, dt, dta, b, c))
+
+    def a_chunk(steps):                                # [B, nc, G, R, L]
+        return jnp.moveaxis(
+            steps.reshape(bsz, t // chunk, chunk, g, h // g), 2, -1)
+
+    y = ssd_scan(
+        x.reshape(bsz, t, h * p), None if dt is None else a_chunk(dt),
+        a_chunk(dta), b.reshape(bsz, t, g * n), c.reshape(bsz, t, g * n),
+        None if skip is None else jnp.repeat(
+            skip.reshape(g, 1, h // g), p, axis=2), p, interpret)
+    return y.reshape(bsz, t, h, p)[:, :s]
+
+
+def _ssd_xla(x, dt, a, b, c, chunk: int, group: int) -> jax.Array:
+    """The products inside the chunks run ``group`` chunks at a time
     (each group rematerialised in the backward pass), so the ``chunk x
     chunk`` decay planes of a whole sequence never exist at once."""
     bsz, s, h, p = x.shape
@@ -110,8 +162,10 @@ def ssd_chunked(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
     return y.reshape(bsz, nc * chunk, h, p)[:, :s]
 
 
-def mamba2_mixer(p: dict, n: jax.Array, cfg) -> jax.Array:
-    """``n`` [B, S, hidden] (already normed) -> the mixer's output."""
+def mamba2_mixer(p: dict, n: jax.Array, cfg,
+                 scan_interpret: Optional[bool] = None) -> jax.Array:
+    """``n`` [B, S, hidden] (already normed) -> the mixer's output.
+    ``scan_interpret``: :func:`ssd_chunked`'s ``interpret``."""
     bsz, s, _ = n.shape
     h, hp = cfg.mamba_num_heads, cfg.mamba_head_dim
     g, st = cfg.n_groups, cfg.ssm_state_size
@@ -126,8 +180,8 @@ def mamba2_mixer(p: dict, n: jax.Array, cfg) -> jax.Array:
     c = xbc[..., d_inner + g * st:].reshape(bsz, s, g, st)
     dt = jax.nn.softplus(dt + p["dt_bias"])
     a = -jnp.exp(p["A_log"])
-    y = ssd_chunked(x, dt, a, b, c, cfg.chunk_size)
-    y = y + x * p["D"][:, None]
+    y = ssd_chunked(x, dt, a, b, c, cfg.chunk_size,
+                    interpret=scan_interpret, skip=p["D"])
     y = gated_group_rmsnorm(y.reshape(bsz, s, d_inner), z, p["gnorm"], g,
                             cfg.norm_eps)
     return y @ p["out_proj"]

@@ -81,6 +81,7 @@ from multiverso_tpu.models.hybrid_lm.mamba2 import mamba2_mixer
 from multiverso_tpu.models.hybrid_lm.norm import rmsnorm
 from multiverso_tpu.models.hybrid_lm.shortconv import shortconv_mixer
 from multiverso_tpu.ops import pallas_interpret
+from multiverso_tpu.ops.pallas_ssd import scan_kernel_selected
 from multiverso_tpu.parallel.comm_policy import reduce_axis_size
 from multiverso_tpu.parallel.expert import (held_topk_moe,
                                             token_rows_kernel_selected)
@@ -277,9 +278,29 @@ _SEQUENCE_MIXERS = {
 }
 
 
+#: Blocks whose mixer is :func:`~.mamba2.ssd_chunked`'s scan.
+_SCANS = (MAMBA, LIGHTNING)
+
+
+def scan_kernel_blocks(cfg: HybridLMConfig) -> int:
+    """How many of the pattern's scan blocks have shapes the scan kernels take
+    (:func:`~multiverso_tpu.ops.pallas_ssd.scan_kernel_selected`: a Mamba-2
+    block's group of heads, a Lightning block's one head a group)."""
+    taken = {
+        MAMBA: scan_kernel_selected(
+            cfg.chunk_size, cfg.ssm_state_size,
+            cfg.mamba_num_heads // cfg.n_groups, cfg.mamba_head_dim,
+            np.float32),
+        LIGHTNING: scan_kernel_selected(
+            cfg.lightning_chunk, cfg.lightning_head_dim, 1,
+            cfg.lightning_head_dim, np.float32)}
+    return sum(taken[kind] for kind in cfg.pattern if kind in _SCANS)
+
+
 def layer_forward(kind: str, p: dict, bias, u: jax.Array,
                   cfg: HybridLMConfig, remat: bool = False,
-                  moe_rows_interpret: Optional[bool] = None):
+                  moe_rows_interpret: Optional[bool] = None,
+                  scan_interpret: Optional[bool] = None):
     """One block: (``u + mixer(RMSNorm_w(u))``, assignments per held expert
     or None), and third, for an expert block of a configuration that
     weighs one, its balance loss; the mixer's output times
@@ -295,14 +316,18 @@ def layer_forward(kind: str, p: dict, bias, u: jax.Array,
     sequence's and not the batch's; the feed-forward cuts a sequence longer
     than ``cfg.ffn_slab`` into slabs of that many positions.
     ``moe_rows_interpret`` is an expert block's ``rows_interpret``
-    (:func:`~multiverso_tpu.parallel.expert.held_topk_moe`): None unless the
-    caller knows ``u`` to live on one device."""
+    (:func:`~multiverso_tpu.parallel.expert.held_topk_moe`) and
+    ``scan_interpret`` a Mamba-2 or Lightning block's scan's ``interpret``
+    (:func:`~.mamba2.ssd_chunked`): None unless the caller knows ``u`` to
+    live on one device."""
     keep = jax.checkpoint if remat else (lambda fn: fn)
     if kind in _SEQUENCE_MIXERS:
         mixer, scope = _SEQUENCE_MIXERS[kind]
         offset, scale = cfg.norm_add_unit_offset, cfg.residual_scale
         if kind == LIGHTNING:
             mixer = functools.partial(mixer, slopes=bias)
+        if kind in _SCANS and scan_interpret is not None:
+            mixer = functools.partial(mixer, scan_interpret=scan_interpret)
 
         def one_sequence(seq):
             n = rmsnorm(seq[None], p["norm"], cfg.norm_eps, offset)
@@ -346,7 +371,8 @@ def layer_forward(kind: str, p: dict, bias, u: jax.Array,
 
 def forward_hidden(params: dict, buffers: list, u: jax.Array,
                    cfg: HybridLMConfig, remat: bool = True,
-                   moe_rows_interpret: Optional[bool] = None):
+                   moe_rows_interpret: Optional[bool] = None,
+                   scan_interpret: Optional[bool] = None):
     """The block stack over ``u`` [B, S, hidden] -> (hidden states before
     the final norm, [expert blocks, held] assignment counts), then, where
     the configuration weighs one, the summed balance loss, then, where it
@@ -357,9 +383,11 @@ def forward_hidden(params: dict, buffers: list, u: jax.Array,
     # Handed on only where set: a wrapper ``(kind, p, bias, u, cfg, remat)``
     # around ``layer_forward`` (the benchmark's controls) sees what it knows.
     rows_plane = () if moe_rows_interpret is None else (moe_rows_interpret,)
+    scan_plane = {} if scan_interpret is None else {
+        "scan_interpret": scan_interpret}
     for i, kind in enumerate(cfg.pattern):
         u, c, *b = layer_forward(kind, params["layers"][i], buffers[i], u,
-                                 cfg, remat, *rows_plane)
+                                 cfg, remat, *rows_plane, **scan_plane)
         if isinstance(c, dict):
             chose.append(c)
         elif c is not None:
@@ -418,7 +446,8 @@ def blocked_cross_entropy(u: jax.Array, norm_w: jax.Array, head: jax.Array,
 
 
 def make_loss(cfg: HybridLMConfig, remat: bool = True,
-              moe_rows_interpret: Optional[bool] = None):
+              moe_rows_interpret: Optional[bool] = None,
+              scan_interpret: Optional[bool] = None):
     """``(params, rows [n, hidden], buffers, where [B, S], targets [B, S],
     mask [B, S]) -> (loss, counts)``: ``rows[where]`` is the embedded
     input (``rows`` the pulled rows of the step's distinct ids). Under a tied
@@ -435,7 +464,7 @@ def make_loss(cfg: HybridLMConfig, remat: bool = True,
             u = jnp.take(rows, where, axis=0)
             u = u if cfg.scale_emb == 1.0 else cfg.scale_emb * u
         u, counts, *more = forward_hidden(params, buffers, u, cfg, remat,
-                                          moe_rows_interpret)
+                                          moe_rows_interpret, scan_interpret)
         balance = [more.pop(0)] if cfg.balanced else []
         with jax.named_scope("lm_head_loss"):
             head = rows.T if cfg.tie_word_embeddings else params["head"]
@@ -517,11 +546,19 @@ class HybridLM:
         # leaves' ONE device and no mesh divides or merges it; None (XLA's
         # scatter-add) anywhere else. Counter ``lm.moe.rows.plane.<plane>``.
         devices = jax.tree_util.tree_leaves(self.params)[0].devices()
+        one_device = (len(devices) == 1
+                      and reduce_axis_size(dp_mesh, dp_axis) == 1)
         self.moe_rows_interpret = pallas_interpret(devices) if (
-            len(devices) == 1 and reduce_axis_size(dp_mesh, dp_axis) == 1
+            one_device
             and token_rows_kernel_selected(cfg.hidden_size, np.float32)
         ) else None
-        loss_fn = make_loss(cfg, moe_rows_interpret=self.moe_rows_interpret)
+        # The scan of the Mamba-2 and Lightning blocks likewise: the kernels
+        # that keep a chunk's decay planes in VMEM, for the blocks whose
+        # shapes they take. Counter ``lm.scan.plane.<plane>``.
+        self.scan_interpret = pallas_interpret(devices) if (
+            one_device and scan_kernel_blocks(cfg)) else None
+        loss_fn = make_loss(cfg, moe_rows_interpret=self.moe_rows_interpret,
+                            scan_interpret=self.scan_interpret)
         barrier = jax.lax.optimization_barrier
 
         def lm_delta_step(params, rows, buffers, where, targets, mask):
@@ -691,6 +728,10 @@ class HybridLM:
                 * (-(-length // cfg.lightning_chunk)))
         counter("lm.moe.rows.plane.xla" if self.moe_rows_interpret is None
                 else "lm.moe.rows.plane.fused").inc(len(cfg.expert_layers()))
+        scans = sum(kind in _SCANS for kind in cfg.pattern)
+        fused = scan_kernel_blocks(cfg) * (self.scan_interpret is not None)
+        counter("lm.scan.plane.fused").inc(fused)
+        counter("lm.scan.plane.xla").inc(scans - fused)
         for layer, per_expert in zip(self.cfg.expert_layers(),
                                      self.last_counts):
             # One pair per expert layer of the pattern: bounded.

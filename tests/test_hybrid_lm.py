@@ -447,6 +447,47 @@ def test_ps_plane_matches_local_twin_bitwise(table_devices, monkeypatch):
         np.testing.assert_array_equal(a, b)
 
 
+@pytest.mark.parametrize("plane", ["xla", "fused"])
+def test_step_counts_the_plane_its_scan_blocks_run_on(plane, monkeypatch):
+    """``lm.scan.plane.<fused/xla>``, one a step and Mamba-2 block: the scan
+    kernels wherever chunk and state are whole 128-lane tiles and a group's
+    heads side by side fill lane tiles, on the leaves' one device (here
+    under the interpreter: 2 heads of 64, state 128, chunks of 128); the
+    ``jax.numpy`` body at the tiny shapes of every other test here. A twin
+    kept on the body steps to the same numbers."""
+    from multiverso_tpu.models.hybrid_lm import model as model_module
+    wide = dict(mamba_head_dim=64, ssm_state_size=128, chunk_size=128) \
+        if plane == "fused" else {}
+    cfg = small(pattern="M*M", **wide)
+    model = HybridLM(cfg, mode="local")
+    assert model.scan_interpret == (True if plane == "fused" else None)
+    monkeypatch.setattr(model_module, "scan_kernel_selected",
+                        lambda *shape: False)
+    twin = HybridLM(cfg, mode="local")
+    monkeypatch.undo()
+    assert twin.scan_interpret is None
+    reg = get_registry()
+    names = [f"lm.scan.plane.{p}" for p in ("fused", "xla")]
+    batches = [batch(cfg, seed=11), batch(cfg, seed=12)]
+    before = [reg.counter(n).value for n in names]
+    losses = [model.step(b) for b in batches]
+    moved = dict(zip(("fused", "xla"), (reg.counter(n).value - b
+                                        for n, b in zip(names, before))))
+    assert moved == {plane: 2 * len(batches),
+                     "xla" if plane == "fused" else "fused": 0}
+    np.testing.assert_allclose(losses, [twin.step(b) for b in batches],
+                               rtol=1e-6)
+    for (name, a), (_, b) in zip(model.dense_leaves(), twin.dense_leaves()):
+        assert rel(a, b) < 1e-5, name
+    ids, _, where, targets, mask = pack_batch(batches[0], cfg.row_bucket)
+    text = model._hybrid.delta.lower(
+        model.params, jnp.zeros((len(ids), cfg.hidden_size)), model.buffers,
+        where, targets, mask).as_text(debug_info=True)
+    assert ("pallas_call" in text) == (plane == "fused")
+    for scope in ("lm_mamba2", "lm_ssd"):
+        assert scope in text, scope
+
+
 def test_step_spans_counters_and_program_names():
     """``lm_step_ms`` / ``lm_table_ms`` read the spans, ``lm_mfu_share`` the
     counters and the ``jit_lm_delta_step`` program: the names are part of the

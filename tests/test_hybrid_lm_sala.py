@@ -653,7 +653,8 @@ def test_step_spans_counters_scopes_and_program_names():
     before = {n: reg.histogram("span." + n).count for n in names}
     counted = ("lm.tokens", "lm.rows_pulled", "lm.attn.pairs",
                "lm.sparse.pairs", "lm.sparse.causal_pairs",
-               "lm.sparse.select_pairs", "lm.lightning.chunks")
+               "lm.sparse.select_pairs", "lm.lightning.chunks",
+               "lm.scan.plane.fused", "lm.scan.plane.xla")
     c0 = {n: reg.counter(n).value for n in counted}
     model.step(tokens)
     for n in names:
@@ -671,6 +672,8 @@ def test_step_spans_counters_scopes_and_program_names():
         int((s * keys).sum()) // 2 for s in seen)
     assert moved["lm.sparse.pairs"] < moved["lm.sparse.causal_pairs"]
     assert moved["lm.lightning.chunks"] == 3 * 2 * 5
+    # three Lightning blocks, heads of 8 in chunks of 8: no scan kernel's
+    assert (moved["lm.scan.plane.fused"], moved["lm.scan.plane.xla"]) == (0, 3)
     assert model._hybrid.delta.__name__ == DELTA_PROGRAM
     ids, _, where, targets, mask = pack_batch(tokens, cfg.row_bucket)
     text = model._hybrid.delta.lower(
@@ -678,7 +681,8 @@ def test_step_spans_counters_scopes_and_program_names():
         where, targets, mask).as_text(debug_info=True)
     assert "module @jit_lm_delta_step" in text
     for scope in ("lm_attention", "lm_sparse_select", "lm_sparse_attn",
-                  "lm_lightning", "lm_lightning_scan", "lm_dense_ffn",
+                  "lm_lightning", "lm_lightning_scan", "lm_ssd",
+                  "lm_dense_ffn",
                   "lm_head_loss", "lm_embed", "lm_scale"):
         assert scope in text, scope
     # short sequences: the sparse block attends densely and counts so

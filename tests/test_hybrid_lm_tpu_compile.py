@@ -159,6 +159,65 @@ def test_two_block_layer_compiles_for_v5e_at_published_widths(
         _experts_on_the_row_kernel(compiled)
 
 
+# The scan of a Mamba-2 block (64 heads of 64 in 8 groups, one sequence of
+# 8,192) and of a Lightning block (16 heads of 128, a group a head, one of
+# 16,384), forward and backward, as HybridLM runs it on one chip
+# (``scan_interpret=False``: the kernels of ``ops/pallas_ssd.py``) and as it
+# runs anywhere else (the ``jax.numpy`` body, where the check below has to
+# find what it is there to miss).
+@pytest.mark.parametrize("plane", ["fused", "xla"])
+@pytest.mark.parametrize("config,kind,layer,length", [
+    ("nemotron3-nano-30b-a3b-ep16", "M", 0, SEQ), (SALA, "N", 2, 2 * SEQ)])
+def test_scan_block_compiles_for_v5e_with_its_decay_planes_in_vmem(
+        one_chip, config, kind, layer, length, plane):
+    """On the kernels every ``pallas_call`` lies under ``lm_ssd``, and the
+    compiled text holds no float32 tensor of ``chunk x chunk`` planes, a
+    head's or more, nor an ``exp`` that makes one: the decay planes are gone,
+    not moved. (The one tensor of that shape a Lightning block keeps is the
+    backward's chunk-start states, ``state x head_dim`` a chunk and head.)"""
+    import re
+    cfg = HybridLMConfig.from_file(os.path.join(
+        ROOT, "benchmark", "configs", config + ".json"))
+    assert cfg.pattern[layer] == kind
+
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    p = {k: spec(s) for k, s in param_shapes(cfg)["layers"][layer].items()}
+    u = spec((1, length, cfg.hidden_size))
+    bias = init_buffers(cfg)[layer]
+    bias = None if bias is None else spec(bias.shape)
+    scan = {"scan_interpret": False} if plane == "fused" else {}
+
+    def loss(p, bias, u):
+        return jnp.sum(layer_forward(kind, p, bias, u, cfg, True, **scan)[0])
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 2))).lower(
+        p, bias, u).compile()
+    text = compiled.as_text()
+    chunk, state, groups, wide = (
+        (cfg.chunk_size, cfg.ssm_state_size, cfg.n_groups,
+         cfg.d_inner // cfg.n_groups) if kind == "M" else
+        (cfg.lightning_chunk, cfg.lightning_head_dim, cfg.lightning_nh,
+         cfg.lightning_head_dim))
+    assert chunk == 128
+    states = f"f32[1,{length // chunk},{groups},{state},{wide}]"
+    planes = set(re.findall(r"f32\[[\d,]*,128,128\]", text)) - {states}
+    made = [line for line in text.splitlines() if " exponential(" in line
+            and re.search(r"= f32\[[\d,]*,128,128\]", line)]
+    kernels = [path for path in parse_scopes(text)[1].values()
+               if "pallas_call" in path]
+    if plane == "xla":
+        assert planes and made and not kernels
+        return
+    assert not planes and not made, (planes, made)
+    # the remat's forward and the backward's two walks (nothing reads the
+    # first forward's output under a loss that is a sum)
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert all("lm_ssd" in scope_names(path) for path in kernels)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.2e9
+
+
 @pytest.mark.parametrize("tokens,planes", [(32768, 16), (16384, 21),
                                            (16384, 24)],
                          ids=["lfm2_train", "2688_columns", "nemotron_train"])
