@@ -144,8 +144,6 @@ def bench_word2vec(peaks: dict) -> tuple:
 
     # Dispatch-mode timing: the same corpus/seed once per explicit mode,
     # so one bench run settles in-graph loop vs host pipeline.
-    # (``pallas_grid`` is not timed: Mosaic refuses that kernel on a TPU,
-    # ops/pallas_sgns.py.)
     mode_stats = {}
     for mode in ("in_graph", "pipelined_host"):
         wps, roof = run("float32", dispatch_mode=mode)
@@ -319,14 +317,14 @@ def bench_serving() -> float:
     return qps
 
 
-def bench_pallas_rows() -> None:
-    """Pallas vs XLA row scatter-add on the same table shape (stderr only)."""
+def bench_xla_rows() -> None:
+    """XLA's row scatter-add on the bench table shape (stderr only). The
+    Pallas row-DMA and tiled legs it stood beside went with their kernels
+    in PR 43; their last reading is in PERF.md 6."""
     import time as _time
 
     import jax
     import jax.numpy as jnp
-
-    from multiverso_tpu.ops.pallas_rows import scatter_add_sorted_rows
 
     rng = np.random.default_rng(2)
     table = jnp.zeros((100_000, 128), dtype=jnp.float32)
@@ -342,30 +340,7 @@ def bench_pallas_rows() -> None:
         t = xla(t, ids, deltas)
     jax.block_until_ready(t)
     xla_ms = (_time.perf_counter() - t0) / 20 * 1000
-
-    t2 = scatter_add_sorted_rows(jnp.zeros((100_000, 128),
-                                           dtype=jnp.float32), ids, deltas)
-    jax.block_until_ready(t2)
-    t0 = _time.perf_counter()
-    for _ in range(20):
-        t2 = scatter_add_sorted_rows(t2, ids, deltas)
-    jax.block_until_ready(t2)
-    pallas_ms = (_time.perf_counter() - t0) / 20 * 1000
-
-    # Tiled table-sweep variant (ROADMAP perf #2): block-mapped tile DMAs
-    # at sequential-HBM bandwidth instead of one DMA per row.
-    from multiverso_tpu.ops.pallas_rows import tiled_scatter_add_sorted_rows
-    tiled = tiled_scatter_add_sorted_rows     # jitted + donating already
-    t3 = tiled(jnp.zeros((100_000, 128), dtype=jnp.float32), ids, deltas)
-    jax.block_until_ready(t3)
-    t0 = _time.perf_counter()
-    for _ in range(20):
-        t3 = tiled(t3, ids, deltas)
-    jax.block_until_ready(t3)
-    tiled_ms = (_time.perf_counter() - t0) / 20 * 1000
-    _log(f"row scatter-add 8192x128 into 100Kx128: "
-         f"XLA {xla_ms:.2f}ms vs Pallas/row-DMA {pallas_ms:.2f}ms "
-         f"vs Pallas/tiled {tiled_ms:.2f}ms")
+    _log(f"row scatter-add 8192x128 into 100Kx128: XLA {xla_ms:.2f}ms")
 
 
 def main() -> int:
@@ -388,7 +363,7 @@ def main() -> int:
     mv.init([])
     try:
         updates_per_sec = bench_matrix_table()
-        bench_pallas_rows()
+        bench_xla_rows()
         serve_qps = bench_serving()
         words_per_sec, roofline = bench_word2vec(peaks)
         bench_big_vocab()

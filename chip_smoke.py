@@ -78,8 +78,7 @@ class Sizes:
     kernel_rows: int = 100_000
     kernel_dim: int = 128
     kernel_batch: int = 8192
-    kernel_updaters: tuple = ("default", "sgd", "adagrad")
-    sgns_vocab: int = 2048
+    kernel_updaters: tuple = ("momentum_sgd", "adagrad", "ftrl")
     attn_seq: int = 512
     attn_head_dims: tuple = (64, 128)
 
@@ -388,9 +387,7 @@ def stage_kernels(sz: Sizes) -> None:
 
     import multiverso_tpu as mv
     from multiverso_tpu.core.options import AddOption
-    from multiverso_tpu.ops import (gather_rows, pallas_interpret,
-                                    scatter_add_sorted_rows,
-                                    tiled_scatter_add_sorted_rows)
+    from multiverso_tpu.ops import pallas_interpret
     from multiverso_tpu.ops.pallas_attention import paged_decode_attn
 
     if mv.num_servers() > 1:
@@ -403,33 +400,17 @@ def stage_kernels(sz: Sizes) -> None:
     rng = np.random.default_rng(6)
     ids = np.sort(_dup_rows(rng, rows, n))
     deltas = rng.normal(size=(n, d)).astype(np.float32)
-    base = rng.normal(size=(rows, d)).astype(np.float32)
-
-    got = np.asarray(gather_rows(jnp.asarray(base), jnp.asarray(ids),
-                                 interpret=interpret))
-    check(np.array_equal(got, base[ids]), "gather_rows != table[ids]")
-    want = base.copy()
-    np.add.at(want, ids, deltas)
-    for name, fn in (("scatter_add_sorted_rows", scatter_add_sorted_rows),
-                     ("tiled_scatter_add_sorted_rows",
-                      tiled_scatter_add_sorted_rows)):
-        got = np.asarray(fn(jnp.asarray(base), jnp.asarray(ids),
-                            jnp.asarray(deltas), interpret=interpret))
-        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5,
-                                   err_msg=name)
-    say(f"  gather_rows, scatter_add_sorted_rows, "
-        f"tiled_scatter_add_sorted_rows: {n} rows of a {rows}x{d} table "
-        "match NumPy")
 
     opt = AddOption(learning_rate=0.1, rho=0.1, momentum=0.5)
     planes = {}
     from multiverso_tpu.core.table import build_row_update
     for upd in sz.kernel_updaters:
         pal = mv.create_table(mv.MatrixTableOption(
-            rows, d, updater=upd, use_pallas=True, name=f"smoke_p_{upd}"))
+            rows, d, updater=upd, name=f"smoke_p_{upd}"))
         planes[upd] = pal.store.row_plane
-        check(pal.store.row_plane != "xla",
-              f"use_pallas={upd}: plane {pal.store.row_plane}")
+        check(pal.store.row_plane == "fused_stateful",
+              f"a one-shard {rows}x{d} float32 {upd} table picked the "
+              f"plane {pal.store.row_plane}")
         # The XLA plane's row update of the same updater, over copies: a
         # stateful table of this shape picks the fused kernel by itself,
         # so no second table is the reference.
@@ -445,11 +426,10 @@ def stage_kernels(sz: Sizes) -> None:
         # order on the two planes: f32 sums of magnitude ~20 agree to 1e-4.
         np.testing.assert_allclose(pal.get_rows(sample),
                                    np.asarray(xla[0][sample]), rtol=1e-5,
-                                   atol=1e-4, err_msg=f"use_pallas {upd}")
-    say(f"  MatrixTableOption(use_pallas=True) row planes {planes} match "
-        "the XLA row updates")
+                                   atol=1e-4, err_msg=f"fused rows {upd}")
+    say(f"  the row planes the stores picked, {planes}, match the XLA row "
+        "updates")
 
-    _kernel_sgns(sz, interpret)
     _kernel_flash(sz)
 
     h, dh, page, bucket, b = 4, 16, 16, 64, 8
@@ -478,37 +458,6 @@ def stage_kernels(sz: Sizes) -> None:
     np.testing.assert_allclose(got, want, rtol=2e-2, atol=2e-2,
                                err_msg="paged_decode_attn")
     say("  paged_decode_attn matches the gather-then-attend step")
-
-
-def _kernel_sgns(sz: Sizes, interpret: bool) -> None:
-    """The grid-resident sg-ns step. Mosaic refuses it on a TPU (a finding,
-    ops/pallas_sgns.py — not a failure of this run); asking for it by name
-    must then fail with the compiler's message, never run interpreted."""
-    import jax
-    import jax.numpy as jnp
-
-    from multiverso_tpu.ops import build_sgns_grid_step
-
-    v, d, c, k, n = sz.sgns_vocab, sz.kernel_dim, sz.kernel_batch, 5, 2
-    rng = np.random.default_rng(7)
-    w = jnp.asarray(rng.normal(size=(v, d)).astype(np.float32) * 0.01)
-    zeros = [jnp.zeros((v, d), jnp.float32) for _ in range(3)]
-    streams = [jnp.asarray(rng.integers(0, v, shape).astype(np.int32))
-               for shape in ((n, c), (n, c), (n, c, k))]
-    step = build_sgns_grid_step(c, k, True, interpret=interpret)
-    try:
-        out = step(w, *zeros, *streams, jnp.int32(n * c - 7),
-                   jnp.float32(0.05))
-        jax.block_until_ready(out)
-    except Exception as e:  # noqa: BLE001 - the refusal IS the observation
-        check(not interpret, f"pallas_sgns failed under the interpreter: {e}")
-        say(f"  pallas_sgns grid step: refused by the compiler, as recorded "
-            f"({type(e).__name__}: {str(e).splitlines()[0][:120]})")
-        return
-    check(np.isfinite(float(out[4])), "pallas_sgns loss not finite")
-    say("  pallas_sgns grid step: ran "
-        + ("(interpreted)" if interpret else
-           "COMPILED on this chip — ROADMAP C3 may put it back"))
 
 
 def _kernel_flash(sz: Sizes) -> None:

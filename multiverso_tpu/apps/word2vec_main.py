@@ -46,7 +46,7 @@ configure.define_int("pad_sentence_length", 512,
                      "sentence pad length (device pipeline)")
 configure.define_string("dispatch_mode", "auto",
                         "chunk-loop execution: auto|in_graph|"
-                        "pipelined_host|pallas_grid (sg-ns device "
+                        "pipelined_host (sg-ns device "
                         "pipeline; auto probes launch latency — "
                         "docs/MIGRATION.md decision table)")
 configure.define_int("dispatch_depth", 8,

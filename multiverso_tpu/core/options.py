@@ -90,13 +90,11 @@ class MatrixTableOption(TableOption):
     init_low: float = -0.5
     init_high: float = 0.5
     seed: int = 0
-    use_pallas: bool = False        # opt-in Pallas row gather/scatter-add
 
     def __init__(self, num_row: int, num_col: int, dtype: Any = np.float32,
                  is_sparse: bool = False, is_pipeline: bool = False,
                  random_init: bool = False, init_low: float = -0.5,
-                 init_high: float = 0.5, seed: int = 0,
-                 use_pallas: bool = False, **kw: Any):
+                 init_high: float = 0.5, seed: int = 0, **kw: Any):
         super().__init__(**kw)
         self.num_row = int(num_row)
         self.num_col = int(num_col)
@@ -107,7 +105,6 @@ class MatrixTableOption(TableOption):
         self.init_low = float(init_low)
         self.init_high = float(init_high)
         self.seed = int(seed)
-        self.use_pallas = bool(use_pallas)
 
 
 @dataclasses.dataclass

@@ -35,8 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from multiverso_tpu.core.options import AddOption, GetOption
-from multiverso_tpu.core.updater import (Updater, combine_duplicate_rows,
-                                         pallas_row_capability)
+from multiverso_tpu.core.updater import Updater, combine_duplicate_rows
 from multiverso_tpu.parallel import mesh as mesh_lib
 from multiverso_tpu.telemetry import gauge, span
 from multiverso_tpu.utils.configure import get_flag
@@ -103,13 +102,15 @@ def fused_rows_selected(updater: Updater, shape: Tuple[int, ...], dtype: Any,
     """Whether a table's stateful row UPDATE runs as the fused Pallas
     gather-update-scatter kernel. Chosen from what the table shows, by no
     option: where the kernel applies (``pallas_rows_eligible``, an updater
-    of the ``fused_stateful`` capability, state the kernel can own whole
-    rows of) it walks the live prefix of the folded ids only and keeps a
-    step's row DMAs in flight together, 2.6 against XLA's 9.9 ms for 26
-    tables of 262,144 x 128 at 2,048 Zipf ids each (PERF.md 6, PR 29)."""
+    whose class says the kernel may run its ``rows_math``, state the kernel
+    can own whole rows of) it walks the live prefix of the folded ids only
+    and keeps a step's row DMAs in flight together, 2.6 against XLA's 9.9
+    ms for 26 tables of 262,144 x 128 at 2,048 Zipf ids each (PERF.md 6,
+    PR 29). ``Updater.fused_rows`` is read from the instance's OWN class:
+    a subclass may override the math the claim was made for."""
     return (pallas_rows_eligible(shape, dtype, one_shard)
             and not state_sharded
-            and pallas_row_capability(updater) == "fused_stateful")
+            and vars(type(updater)).get("fused_rows", False))
 
 
 def build_row_update(updater: Updater, fused: bool,
@@ -145,7 +146,6 @@ class ServerStore:
                  updater: Updater, mesh: jax.sharding.Mesh,
                  num_workers: int, shard_axis: int = 0,
                  init_array: Optional[np.ndarray] = None,
-                 use_pallas_rows: bool = False,
                  state_sharding: Optional[str] = None):
         self.name = name
         self.logical_shape = tuple(int(s) for s in shape)
@@ -228,23 +228,9 @@ class ServerStore:
             with span("table.device_put", table=name, leaf=key):
                 self.state[key] = jax.device_put(leaf, leaf_sharding)
 
-        # Pallas row data plane (ops/pallas_rows.py), through the
-        # per-updater capability registry
-        # (core/updater.PALLAS_ROW_CAPABILITY). The fused stateful update
-        # is the store's own choice (fused_rows_selected); the DMA gather
-        # and the stateless sorted scatter-add have not beaten XLA and
-        # stay behind the option.
-        self._pallas_cap = None
-        if fused_rows_selected(updater, self.padded_shape, self.dtype,
-                               num_servers == 1, self.state_sharded):
-            self._pallas_cap = "fused_stateful"
-        elif use_pallas_rows and pallas_rows_eligible(
-                self.padded_shape, self.dtype, num_servers == 1):
-            cap = pallas_row_capability(updater)
-            if cap in ("scatter_add", "scatter_sub"):
-                self._pallas_cap = cap
-        self._pallas_gather = use_pallas_rows and self._pallas_cap is not None
-        self._pallas_rows = self._pallas_cap is not None
+        self._row_plane = "fused_stateful" if fused_rows_selected(
+            updater, self.padded_shape, self.dtype, num_servers == 1,
+            self.state_sharded) else "xla"
         self._build_kernels()
         self._lock = make_lock("core.store")
         devices = list(self.sharding.device_set)
@@ -261,12 +247,12 @@ class ServerStore:
 
     @property
     def row_plane(self) -> str:
-        """Which data plane serves this store's row UPDATES: ``"xla"``, or
-        the Pallas capability selected at construction
-        (``"fused_stateful"`` from what the store shows; ``"scatter_add"``,
-        ``"scatter_sub"`` by the option, which also moves the row gather
-        to its DMA kernel)."""
-        return self._pallas_cap or "xla"
+        """Which data plane serves this store's row UPDATES:
+        ``"fused_stateful"`` (the Pallas kernel of
+        ``ops/pallas_rows.fused_stateful_rows``) where
+        :func:`fused_rows_selected` holds of what the store shows,
+        ``"xla"`` elsewhere. Row READS are XLA's gather on both."""
+        return self._row_plane
 
     @contextlib.contextmanager
     def _dispatch_scope(self):
@@ -364,39 +350,19 @@ class ServerStore:
                 return data[tuple(index)]
             return data
 
+        fused = self._row_plane == "fused_stateful"
         interpret = False
-        if self._pallas_rows:
+        if fused:
             from multiverso_tpu.ops import pallas_interpret
             interpret = pallas_interpret(self.sharding.device_set)
-        if self._pallas_cap in ("scatter_add", "scatter_sub"):
-            from multiverso_tpu.ops.pallas_rows import scatter_add_rows
-
-            # SGD applies data -= delta (client pre-scales lr).
-            sign = -1.0 if self._pallas_cap == "scatter_sub" else 1.0
-
-            def update(data, state, row_ids, delta, *opt):
-                del opt
-                return (scatter_add_rows(data, row_ids,
-                                         delta.astype(data.dtype),
-                                         interpret=interpret, sign=sign),
-                        state)
-        else:
-            update = build_row_update(
-                updater, self._pallas_cap == "fused_stateful", interpret)
+        update = build_row_update(updater, fused, interpret)
 
         def rows(data, state, row_ids, delta, *opt):
             return _pin(*update(data, state, row_ids, delta, *opt))
 
-        if self._pallas_gather:
-            from multiverso_tpu.ops.pallas_rows import gather_rows
-
-            def access_rows(data, row_ids):
-                return gather_rows(data, row_ids, interpret=interpret)
-            self._access_rows = access_rows  # the inner fn is already jit
-        else:
-            def access_rows(data, row_ids):
-                return jnp.take(data, row_ids, axis=axis, mode="clip")
-            self._access_rows = jax.jit(access_rows)
+        def access_rows(data, row_ids):
+            return jnp.take(data, row_ids, axis=axis, mode="clip")
+        self._access_rows = jax.jit(access_rows)
         self._dense_update = jax.jit(dense, donate_argnums=(0, 1))
         self._row_update = jax.jit(rows, donate_argnums=(0, 1))
         self._access = jax.jit(access)

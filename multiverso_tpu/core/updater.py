@@ -182,12 +182,20 @@ class Updater:
       XLA scatter path (:meth:`update_rows` via ``_rows_update_via_math``)
       and the fused Pallas gather-update-scatter kernel
       (:mod:`multiverso_tpu.ops.pallas_rows`) — one implementation is the
-      structural bitwise-parity guarantee between the two planes.
+      structural bitwise-parity guarantee between the two planes;
+    * ``fused_rows`` — True on a class whose ``rows_math`` that kernel may
+      run (``core/table.fused_rows_selected``). It is a claim about THIS
+      class's math, read from the class's own namespace: a subclass, which
+      may override the math, does not inherit it and says so again itself
+      where it holds. The DC-ASGD family does not claim it: its per-worker
+      whole-row backup writes dominate, the fused kernel is the wrong
+      trade.
     """
 
     name = "default"
     per_worker_state: Tuple[str, ...] = ()
     staleness_aware = False
+    fused_rows = False
 
     def init_state(self, shape: Tuple[int, ...], dtype: Any,
                    num_workers: int) -> State:
@@ -276,6 +284,7 @@ class MomentumUpdater(Updater):
     (ref momentum_updater.h:9-31)."""
 
     name = "momentum_sgd"
+    fused_rows = True
 
     def init_state(self, shape, dtype, num_workers):
         del num_workers
@@ -308,6 +317,7 @@ class AdaGradUpdater(Updater):
     intended G += grad^2 semantics.) lr==0 is guarded to a no-op scale."""
 
     name = "adagrad"
+    fused_rows = True
     eps = 1e-6
     per_worker_state = ("g2",)
 
@@ -449,6 +459,7 @@ class FTRLUpdater(Updater):
     """
 
     name = "ftrl"
+    fused_rows = True
 
     def init_state(self, shape, dtype, num_workers):
         del num_workers
@@ -492,53 +503,9 @@ _REGISTRY: Dict[str, Callable[[], Updater]] = {
     "dcasgda": DCASGDAUpdater,
 }
 
-# Per-updater Pallas row-plane capability (docs/DESIGN.md "Sharded updater
-# state"): how the row updates of a table the row kernels can serve lower
-# (``core/table.pallas_rows_eligible``): the fused kernel by the table's
-# own choice, the stateless ones behind ``use_pallas``.
-#   "scatter_add"/"scatter_sub" — the stateless sorted-run scatter kernel
-#       (ops/pallas_rows.scatter_add_rows, sign +/-1);
-#   "fused_stateful"            — the fused gather-update-scatter kernel
-#       family (ops/pallas_rows.fused_stateful_rows): data AND every state
-#       leaf stream HBM->VMEM once, ``rows_math`` runs on the row blocks,
-#       both scatter back in the same donated dispatch.
-# Updaters absent here (DC-ASGD family: per-worker full-row backup writes
-# dominate, the fused win is the wrong trade) keep the XLA path.
-PALLAS_ROW_CAPABILITY: Dict[str, str] = {
-    "default": "scatter_add",
-    "sgd": "scatter_sub",
-    "momentum_sgd": "fused_stateful",
-    "adagrad": "fused_stateful",
-    "ftrl": "fused_stateful",
-}
 
-
-def register_updater(name: str, factory: Callable[[], Updater],
-                     pallas_capability: str | None = None) -> None:
-    if pallas_capability is not None and not (
-            isinstance(factory, type) and issubclass(factory, Updater)):
-        # Capability claims bind to a CLASS (pallas_row_capability checks
-        # instance-class identity); a closure factory would make the
-        # declared capability silently inert — refuse loudly instead.
-        raise ValueError(
-            f"register_updater({name!r}): pallas_capability requires the "
-            "factory to be the Updater class itself, not a callable")
+def register_updater(name: str, factory: Callable[[], Updater]) -> None:
     _REGISTRY[name] = factory
-    if pallas_capability is not None:
-        PALLAS_ROW_CAPABILITY[name] = pallas_capability
-
-
-def pallas_row_capability(updater: Updater) -> str | None:
-    """The Pallas row-plane capability that applies to THIS instance, or
-    None (keep the XLA path). The registry entry is a claim about the
-    registered class's math, so it transfers only when the instance's
-    class IS the registered factory class — a subclass inheriting
-    ``name`` (or a custom factory function) may override update math the
-    registered kernels would silently ignore."""
-    cap = PALLAS_ROW_CAPABILITY.get(updater.name)
-    if cap is None or _REGISTRY.get(updater.name) is not type(updater):
-        return None
-    return cap
 
 
 def get_updater(dtype: Any, updater_type: str | None = None) -> Updater:

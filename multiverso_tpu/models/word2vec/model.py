@@ -95,20 +95,11 @@ class Word2VecConfig:
     #                      carries chain through the queue and the host
     #                      never blocks per chunk, so launch latency
     #                      overlaps device compute;
-    #   "pallas_grid"    — ONE launch per block, chunk loop as a sequential
-    #                      Pallas grid with VMEM-resident tables. By name
-    #                      only, never AUTO: Mosaic refuses the kernel on a
-    #                      TPU (ops/pallas_sgns.py), so it runs interpreted
-    #                      off-chip and fails with the compiler's message
-    #                      on one;
     #   None / "auto"    — resolve_dispatch_mode's decision table.
     dispatch_mode: Optional[str] = None
     # In-flight dispatch window for pipelined_host (chunks dispatched ahead
     # of device completion before the host waits on the oldest).
     dispatch_depth: int = 8
-    # DEPRECATED alias (pre-dispatch_mode): True -> "pipelined_host",
-    # False -> "in_graph", None -> AUTO. Ignored when dispatch_mode is set.
-    chunk_dispatch: Optional[bool] = None
     block_sentences: int = 512      # sentences per device block
     pad_sentence_length: int = 512  # fixed sentence pad (longer ones split)
     # dp x tp mesh for the device pipeline: sentences sharded over
@@ -859,7 +850,7 @@ def build_sharded_block_step(mesh, window: int, negative: int, chunk: int,
         out_shardings=(table, table, table, table, repl, repl))
 
 
-# Dispatch-latency threshold for chunk_dispatch AUTO on XLA's row plane:
+# Dispatch-latency threshold for dispatch-mode AUTO on XLA's row plane:
 # below this, host launches are cheap enough that per-chunk dispatch beats
 # the in-graph loop's scatters (jax 0.4.37: standalone chunk 0.05-0.12ms
 # vs 2.2-2.6ms in-loop; on jax 0.9 XLA writes a row in 71 ns alone and 86
@@ -873,7 +864,7 @@ CHUNK_DISPATCH_LATENCY_MS = 1.0
 
 def measured_dispatch_latency_ms(n: int = 7) -> float:
     """Median latency of a trivial jitted dispatch + sync — the signal
-    that decides chunk_dispatch AUTO."""
+    that decides dispatch-mode AUTO."""
     f = jax.jit(lambda a: a + 1.0)
     x = jnp.zeros(8, jnp.float32)
     f(x).block_until_ready()       # compile outside the timing
@@ -887,15 +878,15 @@ def measured_dispatch_latency_ms(n: int = 7) -> float:
     return float(np.median(times))
 
 
-DISPATCH_MODES = ("in_graph", "pipelined_host", "pallas_grid")
+DISPATCH_MODES = ("in_graph", "pipelined_host")
 
 
 def resolve_dispatch_mode(cfg: "Word2VecConfig",
                           row_kernel: bool = False) -> str:
-    """Dispatch-mode decision (the extended chunk_dispatch AUTO).
+    """Dispatch-mode decision.
 
-    Explicit ``dispatch_mode`` wins; the deprecated ``chunk_dispatch`` bool
-    maps onto it; AUTO applies the decision table (docs/MIGRATION.md):
+    Explicit ``dispatch_mode`` wins; AUTO applies the decision table
+    (docs/MIGRATION.md):
 
     1. variant is not sg-ns, or a dp x tp mesh is configured -> in_graph
        (the fused block step is the only implementation of those paths);
@@ -908,13 +899,8 @@ def resolve_dispatch_mode(cfg: "Word2VecConfig",
        pipelined_host (the depth-N window hides cheap launches; XLA's
        scatters run faster standalone than in the loop);
     4. otherwise (high launch latency) -> in_graph.
-
-    ``pallas_grid`` is never chosen here: Mosaic refuses that kernel on a
-    TPU (ops/pallas_sgns.py), so it is reachable by name only.
     """
     mode = cfg.dispatch_mode
-    if mode is None and cfg.chunk_dispatch is not None:
-        mode = "pipelined_host" if cfg.chunk_dispatch else "in_graph"
     if mode not in (None, "auto"):
         check(mode in DISPATCH_MODES,
               f"dispatch_mode must be one of {DISPATCH_MODES} or 'auto'; "
@@ -1263,28 +1249,18 @@ class Word2Vec:
             if self._dispatch_mode != "in_graph":
                 check(cfg.sg and not cfg.hs,
                       f"dispatch_mode={self._dispatch_mode} (per-chunk "
-                      "host dispatch / Pallas grid) is the sg-ns perf "
+                      "host dispatch) is the sg-ns perf "
                       "experiment path; the fused device block step "
                       "covers all four variants")
-                # pair_gen is shared by both alternative executions; the
-                # chunk/tail steps serve pipelined_host.
                 (self._pair_gen, self._chunk_step,
                  self._tail_step) = build_chunked_pipeline(
                     cfg.window, cfg.negative, cfg.batch_size, adagrad)
-            if self._dispatch_mode == "pallas_grid":
-                from multiverso_tpu.ops import pallas_interpret
-                from multiverso_tpu.ops.pallas_sgns import \
-                    build_sgns_grid_step
-                self._grid_step = build_sgns_grid_step(
-                    cfg.batch_size, cfg.negative, adagrad,
-                    interpret=pallas_interpret(
-                        self.input_table.store.sharding.device_set))
             self._sharded_mesh = None
             if cfg.mesh_data * cfg.mesh_model > 1:
                 check(self._dispatch_mode == "in_graph",
-                      "pipelined_host/pallas_grid and a dp x tp mesh are "
-                      "mutually exclusive: both alternative executions "
-                      "would serialize the sharded step; pick one")
+                      "pipelined_host and a dp x tp mesh are mutually "
+                      "exclusive: per-chunk host dispatch would "
+                      "serialize the sharded step; pick one")
                 from jax.sharding import Mesh
                 n = cfg.mesh_data * cfg.mesh_model
                 # The dp x tp mesh regroups the SAME devices the tables
@@ -1632,22 +1608,7 @@ class Word2Vec:
                         self._key, sub = jax.random.split(self._key)
                         lr = np.float32(self._current_lr() *
                                         self._push_scale)
-                        if mode == "pallas_grid":
-                            # One launch runs the whole chunk grid
-                            # on-chip; tables are donated through the
-                            # kernel's input_output_aliases.
-                            (centers2d, contexts2d, negs,
-                             n_pairs) = self._pair_gen(
-                                self._neg_table, self._keep_prob, mat,
-                                lens, sub)
-                            (st_in.data, st_out.data, st_gin.data,
-                             st_gout.data, loss) = finish(self._grid_step(
-                                st_in.data, st_out.data, st_gin.data,
-                                st_gout.data, centers2d, contexts2d,
-                                negs, n_pairs, jnp.asarray(lr)))
-                            losses.append(loss)
-                            pair_counts.append(n_pairs)
-                        elif mode == "pipelined_host":
+                        if mode == "pipelined_host":
                             (centers2d, contexts2d, negs,
                              n_pairs) = self._pair_gen(
                                 self._neg_table, self._keep_prob, mat,

@@ -11,14 +11,4 @@ def pallas_interpret(devices) -> bool:
     return any(d.platform != "tpu" for d in devices)
 
 
-from multiverso_tpu.ops.pallas_rows import (gather_rows, scatter_add_rows,
-                                            scatter_add_sorted_rows,
-                                            tiled_scatter_add_rows,
-                                            tiled_scatter_add_sorted_rows,
-                                            tiled_scatter_eligible)
-from multiverso_tpu.ops.pallas_sgns import build_sgns_grid_step
-
-__all__ = ["pallas_interpret", "gather_rows", "scatter_add_rows",
-           "scatter_add_sorted_rows", "tiled_scatter_add_rows",
-           "tiled_scatter_add_sorted_rows", "tiled_scatter_eligible",
-           "build_sgns_grid_step"]
+__all__ = ["pallas_interpret"]

@@ -1,5 +1,5 @@
-"""Pallas TPU kernels for the table hot ops: dynamic row gather and sorted
-row scatter-add.
+"""Pallas TPU kernels for the table hot ops: the row UPDATES that read,
+change and write back whole rows in one launch.
 
 These are the framework's per-row data-plane primitives — the role the
 OpenMP updater loop plays in the reference (``src/updater/updater.cpp:22-29``)
@@ -8,22 +8,20 @@ per-row DMA with scalar-prefetched indices.
 
 Mosaic constrains mapped block shapes to (8k, 128k) tiles, so arbitrary
 single rows cannot be block-mapped; instead the table stays unmapped
-(``pl.ANY`` -> HBM) and each grid step DMAs a sublane-tile group of rows
-(8 for 4-byte dtypes, 16 for 2-byte — ``group_for_dtype``) addressed by
-the prefetched id array. For scatter:
+(``pl.ANY`` -> HBM) and each grid step DMAs a group of rows addressed by
+the prefetched id array, all in flight together, in place via
+``input_output_aliases`` (the table buffer is donated). Three kernels:
 
-* ids must be SORTED ascending (callers argsort — XLA does that well), so
-  duplicates are consecutive *runs*;
-* within a group, run deltas are folded by an unrolled prefix pass and only
-  the LAST row of each run is written back — no lost updates;
-* the final lane of every group ALWAYS flushes its partial sum: a run
-  spanning a group boundary writes rows[7]+acc[7] back, and the next group
-  (grid is sequential, write DMAs awaited) re-reads the updated row and
-  accumulates its own deltas on top, so cross-boundary runs are exact.
+* ``fused_stateful_rows`` — a store's stateful row update (momentum,
+  AdaGrad, FTRL), chosen by ``core/table.fused_rows_selected``;
+* ``adagrad_fold_rows`` — word2vec's AdaGrad row update over sorted ids,
+  duplicates folded inside the kernel;
+* ``add_unique_rows`` — the LMs' expert layer's accumulators, rows of any
+  multiple of 128 columns.
 
-In-place via ``input_output_aliases`` (the table buffer is donated). The
-jitted XLA paths remain the default; these kernels are opt-in and are
-exercised in interpret mode on CPU plus numerically on the real chip.
+Row READS and the stateless updaters' scatter-add are XLA's: the DMA gather
+and the sorted-run scatter-add that lived here until PR 43 lost to it on the
+chip 5x and 4x; the tiled table sweep won at one small table (ROADMAP A10).
 """
 
 from __future__ import annotations
@@ -33,171 +31,8 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-def group_for_dtype(dtype) -> int:
-    """Rows per grid step: the sublane tile is 8 for 4-byte types and 16
-    for 2-byte types (bf16) — sub-tile VMEM scratch would be rejected by
-    Mosaic on real chips."""
-    return 8 if np.dtype(dtype).itemsize >= 4 else 16
-
-
-def _pad_ids_deltas(ids: jax.Array, deltas: jax.Array, group: int
-                    ) -> Tuple[jax.Array, jax.Array, int]:
-    """Pad to a multiple of ``group``. Padding repeats the last id with a
-    zero delta — harmless accumulate, keeps runs contiguous."""
-    n = ids.shape[0]
-    pad = (-n) % group
-    if pad:
-        ids = jnp.concatenate([ids, jnp.broadcast_to(ids[-1], (pad,))])
-        deltas = jnp.concatenate(
-            [deltas, jnp.zeros((pad,) + deltas.shape[1:], deltas.dtype)])
-    return ids, deltas, n
-
-
-# ---------------------------------------------------------------------------
-# gather
-# ---------------------------------------------------------------------------
-def _make_gather_kernel(group: int):
-    def _gather_kernel(ids_ref, table_ref, out_ref, rows, sems):
-        g = pl.program_id(0)
-        for k in range(group):
-            pltpu.make_async_copy(
-                table_ref.at[ids_ref[g * group + k]],
-                rows.at[k], sems.at[k]).start()
-        for k in range(group):
-            pltpu.make_async_copy(
-                table_ref.at[ids_ref[g * group + k]],
-                rows.at[k], sems.at[k]).wait()
-        out_ref[:] = rows[:]
-    return _gather_kernel
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def gather_rows(table: jax.Array, ids: jax.Array,
-                interpret: bool = False) -> jax.Array:
-    """out[i] = table[ids[i]] — group-row DMA batches per grid step."""
-    group = group_for_dtype(table.dtype)
-    n = ids.shape[0]
-    d = table.shape[1]
-    pad = (-n) % group
-    if pad:
-        ids = jnp.concatenate([ids, jnp.zeros(pad, ids.dtype)])
-    n_padded = n + pad
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n_padded // group,),
-        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec((group, d), lambda g, ids_ref: (g, 0)),
-        scratch_shapes=[pltpu.VMEM((group, d), table.dtype),
-                        pltpu.SemaphoreType.DMA((group,))],
-    )
-    out = pl.pallas_call(
-        _make_gather_kernel(group),
-        out_shape=jax.ShapeDtypeStruct((n_padded, d), table.dtype),
-        grid_spec=grid_spec,
-        interpret=interpret,
-    )(ids.astype(jnp.int32), table)
-    return out[:n]
-
-
-# ---------------------------------------------------------------------------
-# scatter-add (ids must be sorted ascending)
-# ---------------------------------------------------------------------------
-def _make_scatter_kernel(group: int, sign: float):
-    def _scatter_kernel(ids_ref, delta_ref, table_in_ref, table_ref, rows,
-                        sems):
-        del table_in_ref  # aliased with table_ref (the output)
-        g = pl.program_id(0)
-        base = g * group
-
-        # Load the group's rows.
-        for k in range(group):
-            pltpu.make_async_copy(table_ref.at[ids_ref[base + k]],
-                                  rows.at[k], sems.at[k]).start()
-        for k in range(group):
-            pltpu.make_async_copy(table_ref.at[ids_ref[base + k]],
-                                  rows.at[k], sems.at[k]).wait()
-
-        # Fold duplicate-id runs: acc[k] = delta[k] (+ acc[k-1] if same id).
-        acc = [None] * group
-        acc[0] = delta_ref[0, :]
-        for k in range(1, group):
-            same = ids_ref[base + k] == ids_ref[base + k - 1]
-            acc[k] = delta_ref[k, :] + jnp.where(same, acc[k - 1],
-                                                 jnp.zeros_like(acc[k - 1]))
-
-        # Write back only the LAST row of each run (run end = id changes
-        # next). Lane group-1 ALWAYS flushes: if its run continues into the
-        # next group, the partial sum lands in HBM before that group's
-        # (sequential) read, so the continuation accumulates on top of it
-        # instead of dropping it.
-        def _flush(k):
-            step = acc[k] if sign > 0 else -acc[k]
-            rows[k, :] = rows[k, :] + step.astype(rows.dtype)
-            pltpu.make_async_copy(rows.at[k],
-                                  table_ref.at[ids_ref[base + k]],
-                                  sems.at[k]).start()
-            pltpu.make_async_copy(rows.at[k],
-                                  table_ref.at[ids_ref[base + k]],
-                                  sems.at[k]).wait()
-
-        for k in range(group - 1):
-            is_run_end = ids_ref[base + k] != ids_ref[base + k + 1]
-
-            @pl.when(is_run_end)
-            def _(k=k):
-                _flush(k)
-
-        _flush(group - 1)
-    return _scatter_kernel
-
-
-@functools.partial(jax.jit, static_argnames=("interpret", "sign"))
-def scatter_add_sorted_rows(table: jax.Array, sorted_ids: jax.Array,
-                            sorted_deltas: jax.Array,
-                            interpret: bool = False,
-                            sign: float = 1.0) -> jax.Array:
-    """table[ids[i]] += sign*deltas[i] for SORTED ids; in-place (donated).
-    ``sign=-1`` gives the SGD updater's ``data -= delta`` (the client
-    pre-scales by lr, ref ``sgd_updater.h:8-27``)."""
-    if sign not in (1.0, -1.0):
-        raise ValueError(f"sign must be +-1.0 (a direction, not a scale); "
-                         f"got {sign}")
-    group = group_for_dtype(table.dtype)
-    sorted_ids, sorted_deltas, _ = _pad_ids_deltas(sorted_ids,
-                                                   sorted_deltas, group)
-    n = sorted_ids.shape[0]
-    d = table.shape[1]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n // group,),
-        in_specs=[pl.BlockSpec((group, d), lambda g, ids_ref: (g, 0)),
-                  pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pl.ANY),
-        scratch_shapes=[pltpu.VMEM((group, d), table.dtype),
-                        pltpu.SemaphoreType.DMA((group,))],
-    )
-    return pl.pallas_call(
-        _make_scatter_kernel(group, sign),
-        out_shape=jax.ShapeDtypeStruct(table.shape, table.dtype),
-        grid_spec=grid_spec,
-        input_output_aliases={2: 0},   # table (after ids, deltas) -> out
-        compiler_params=pltpu.CompilerParams(has_side_effects=True),
-        interpret=interpret,
-    )(sorted_ids.astype(jnp.int32), sorted_deltas, table)
-
-
-def scatter_add_rows(table: jax.Array, ids: jax.Array, deltas: jax.Array,
-                     interpret: bool = False, sign: float = 1.0) -> jax.Array:
-    """Unsorted convenience wrapper: argsort (XLA), then the kernel."""
-    order = jnp.argsort(ids)
-    return scatter_add_sorted_rows(table, jnp.take(ids, order),
-                                   jnp.take(deltas, order, axis=0),
-                                   interpret=interpret, sign=sign)
-
 
 # ---------------------------------------------------------------------------
 # fused stateful gather-update-scatter (ROADMAP perf #2 / ISSUE 12)
@@ -327,7 +162,7 @@ def fused_stateful_rows(table: jax.Array, state: dict, ids: jax.Array,
     state_keys = sorted(state)
     if not state_keys:
         raise ValueError("fused_stateful_rows needs at least one state "
-                         "leaf; stateless updaters use scatter_add_rows")
+                         "leaf; stateless updaters take XLA's scatter-add")
     group = _FUSED_GROUP_ROWS
     per_worker = [k in updater.per_worker_state for k in state_keys]
     n = ids.shape[0]
@@ -377,103 +212,6 @@ def fused_stateful_rows(table: jax.Array, state: dict, ids: jax.Array,
     new_table = outs[0]
     new_state = {key: outs[1 + j] for j, key in enumerate(state_keys)}
     return new_table, new_state
-
-
-# ---------------------------------------------------------------------------
-# tiled scatter-add: whole-table tile sweep (ROADMAP perf #2)
-# ---------------------------------------------------------------------------
-# The per-row-DMA kernel above moves one row per DMA (~1us each) — it can
-# never beat the standalone XLA scatter at bench shape (8K deltas into a
-# 100K x 128 table). This variant instead SWEEPS the table in block-mapped
-# (T, D) tiles: Mosaic double-buffers the big sequential tile DMAs at
-# near-peak HBM bandwidth, the full sorted delta set sits in VMEM, and
-# each grid step applies its tile's delta segment (pre-sliced client-side
-# with two searchsorted calls) via an in-kernel dynamic loop. Duplicates
-# fold naturally (sequential accumulation into the same VMEM row). Cost
-# model: read+write of the table (~0.25ms for 100Kx128 f32 at v5e HBM
-# peak) + O(N*D) VPU adds — independent of how scattered the ids are.
-
-_TILE_ROWS = 256
-_TILED_DELTA_VMEM_LIMIT = 8 << 20    # full delta block must fit in VMEM
-
-
-def _make_tiled_kernel(tile: int, sign: float):
-    def _kernel(starts_ref, ends_ref, ids_ref, deltas_ref, table_in_ref,
-                out_ref):
-        g = pl.program_id(0)
-        out_ref[:] = table_in_ref[:]
-        base = g * tile
-
-        def body(j, carry):
-            r = ids_ref[j] - base
-            row = out_ref[pl.ds(r, 1), :]
-            d = deltas_ref[pl.ds(j, 1), :]
-            step = d if sign > 0 else -d
-            out_ref[pl.ds(r, 1), :] = row + step.astype(row.dtype)
-            return carry
-
-        jax.lax.fori_loop(starts_ref[g], ends_ref[g], body, 0)
-    return _kernel
-
-
-@functools.partial(jax.jit, donate_argnums=0,
-                   static_argnames=("interpret", "sign", "tile"))
-def tiled_scatter_add_sorted_rows(table: jax.Array, sorted_ids: jax.Array,
-                                  sorted_deltas: jax.Array,
-                                  interpret: bool = False,
-                                  sign: float = 1.0,
-                                  tile: int = _TILE_ROWS) -> jax.Array:
-    """table[ids[i]] += sign*deltas[i] for SORTED ids via a tiled table
-    sweep. Requires the delta block to fit VMEM (use
-    ``tiled_scatter_eligible``)."""
-    if sign not in (1.0, -1.0):
-        raise ValueError(f"sign must be +-1.0; got {sign}")
-    rows, d = table.shape
-    # Non-divisible row counts use Pallas's native boundary-block masking
-    # (grid = ceil(rows/tile)) — padding the table here would add two
-    # whole-table HBM copies per call and break donation through the
-    # padded temp, skewing the very bench this kernel is judged by.
-    n_tiles = -(-rows // tile)
-    bounds = jnp.arange(n_tiles + 1, dtype=sorted_ids.dtype) * tile
-    starts = jnp.searchsorted(sorted_ids, bounds[:-1]).astype(jnp.int32)
-    ends = jnp.searchsorted(sorted_ids, bounds[1:]).astype(jnp.int32)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,      # starts, ends, ids
-        grid=(n_tiles,),
-        in_specs=[
-            # Full sorted delta set: one VMEM block, constant across grid.
-            pl.BlockSpec((sorted_deltas.shape[0], d),
-                         lambda g, *refs: (0, 0)),
-            # Table tile for this grid step.
-            pl.BlockSpec((tile, d), lambda g, *refs: (g, 0)),
-        ],
-        out_specs=pl.BlockSpec((tile, d), lambda g, *refs: (g, 0)),
-    )
-    return pl.pallas_call(
-        _make_tiled_kernel(tile, sign),
-        out_shape=jax.ShapeDtypeStruct((rows, d), table.dtype),
-        grid_spec=grid_spec,
-        input_output_aliases={4: 0},   # table (after 3 scalars + deltas)
-        interpret=interpret,
-    )(starts, ends, sorted_ids.astype(jnp.int32), sorted_deltas, table)
-
-
-def tiled_scatter_eligible(n_deltas: int, n_cols: int, dtype) -> bool:
-    """The whole delta block must fit the VMEM budget."""
-    return (n_deltas * n_cols * np.dtype(dtype).itemsize
-            <= _TILED_DELTA_VMEM_LIMIT)
-
-
-@functools.partial(jax.jit, donate_argnums=0,
-                   static_argnames=("interpret", "sign"))
-def tiled_scatter_add_rows(table: jax.Array, ids: jax.Array,
-                           deltas: jax.Array, interpret: bool = False,
-                           sign: float = 1.0) -> jax.Array:
-    """Unsorted convenience wrapper: argsort (XLA), then the tiled sweep."""
-    order = jnp.argsort(ids)
-    return tiled_scatter_add_sorted_rows(
-        table, jnp.take(ids, order), jnp.take(deltas, order, axis=0),
-        interpret=interpret, sign=sign)
 
 
 # ---------------------------------------------------------------------------

@@ -54,8 +54,7 @@ class MatrixTable(WorkerTable):
         store = ServerStore(name, (option.num_row, option.num_col),
                             option.dtype, updater, zoo.mesh,
                             zoo.num_workers(), shard_axis=0,
-                            init_array=initial_rows(option),
-                            use_pallas_rows=option.use_pallas)
+                            init_array=initial_rows(option))
         super().__init__(store)
         self.num_row = option.num_row
         self.num_col = option.num_col

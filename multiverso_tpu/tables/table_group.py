@@ -363,7 +363,7 @@ class TableGroup(_RowGroup):
                 if device:
                     counter("table.group.device_pushes").inc()
                 for plane in self._update_planes:
-                    # One of four names (ServerStore.row_plane).
+                    # One of two names (ServerStore.row_plane).
                     # graftlint: disable=unbounded-metric-name
                     counter(f"table.rows.plane.{plane}").inc()
             with phase("table.add_rows.sync"):

@@ -20,8 +20,7 @@ overhead lives by timing the same chunk workload under six formulations:
 If B-C >> D: the gather side de-optimizes. If B-D >> C: the scatter does.
 If E/F track A: the cost scales with CARRY SIZE -> per-iteration copies
 of the carried tables are the mechanism and the sub-table restructure is
-the fix. (The Pallas grid legs G/H are gone: Mosaic refuses that kernel on
-a TPU, ops/pallas_sgns.py, so there is nothing to time.) Run ON the chip:
+the fix. Run ON the chip:
 
     python scripts/perf_attrib.py [--vocab 50000] [--dim 128]
 

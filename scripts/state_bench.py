@@ -228,19 +228,24 @@ def bench_stateful_sparse(dry: bool) -> dict:
     mv.init(["-mesh_shape=", "-state_sharding=auto"],
             devices=jax.devices()[:1])
     try:
-        t_x = mv.create_table(mv.MatrixTableOption(512, pcols,
-                                                   updater="adagrad",
-                                                   name="px"))
+        import jax.numpy as jnp
+        from multiverso_tpu.core.table import build_row_update
         t_p = mv.create_table(mv.MatrixTableOption(512, pcols,
                                                    updater="adagrad",
-                                                   name="pp",
-                                                   use_pallas=True))
-        assert t_p.store._pallas_cap == "fused_stateful"
+                                                   name="pp"))
+        # the store picks the kernel by itself (fused_rows_selected), so
+        # the reference is the XLA row update of its updater, over copies
+        assert t_p.store.row_plane == "fused_stateful"
+        xla_update = jax.jit(build_row_update(t_p.store.updater, False),
+                             donate_argnums=(0, 1))
+        xla = (jnp.array(t_p.store.data),
+               jax.tree_util.tree_map(jnp.array, t_p.store.state))
         interpreted = pallas_interpret(t_p.store.sharding.device_set)
         ids = rng.integers(0, 512, size=128).astype(np.int32)
         d = rng.normal(size=(128, pcols)).astype(np.float32)
         for _ in range(3):
-            t_x.add_rows(ids, d, opt)
+            xla = xla_update(*xla, jnp.asarray(ids), jnp.asarray(d),
+                             *opt.scalars())
             t_p.add_rows(ids, d, opt)
         # Bitwise under the interpreter (both planes round strictly per
         # primitive, core/updater.exact_elementwise); on a TPU the fused
@@ -248,10 +253,10 @@ def bench_stateful_sparse(dry: bool) -> dict:
         same = np.array_equal if interpreted else \
             (lambda a, b: np.allclose(a, b, rtol=1e-5, atol=1e-6))
         parity = bool(
-            same(t_x.get(), t_p.get())
-            and all(same(np.asarray(t_x.store.state[k]),
+            same(np.asarray(xla[0]), t_p.get())
+            and all(same(np.asarray(xla[1][k]),
                          np.asarray(t_p.store.state[k]))
-                    for k in t_x.store.state))
+                    for k in xla[1]))
         t0 = time.perf_counter()
         for _ in range(5):
             t_p.add_rows(ids, d, opt)

@@ -11,7 +11,7 @@ Usage:
     # catalog of one run
     python scripts/telemetry_report.py /tmp/t
 
-    # diff two runs (e.g. dispatch_mode=pipelined_host vs pallas_grid)
+    # diff two runs (e.g. dispatch_mode=pipelined_host vs in_graph)
     python scripts/telemetry_report.py /tmp/t_new --baseline /tmp/t_old
 
     # merge per-rank Chrome traces into one Perfetto-loadable file

@@ -44,12 +44,10 @@ def test_word2vec_cli_device_pipeline(tmp_path):
     assert out.exists()
 
 
-@pytest.mark.parametrize("mode", ["in_graph", "pipelined_host",
-                                  "pallas_grid"])
+@pytest.mark.parametrize("mode", ["in_graph", "pipelined_host"])
 def test_word2vec_cli_dispatch_modes(tmp_path, mode):
     """-dispatch_mode reaches the model (Round 6 selector): every explicit
-    mode trains end to end through the CLI (pallas_grid interpreted on
-    CPU)."""
+    mode trains end to end through the CLI."""
     from multiverso_tpu.apps.word2vec_main import main
 
     corpus = tmp_path / "corpus.txt"

@@ -31,7 +31,7 @@ def _tiny(mod):
         lm_vocab=64, lm_dim=32, lm_heads=4, lm_layers=1, lm_seq=32,
         lm_steps=2, lm_max_new=4,
         kernel_rows=64, kernel_dim=128, kernel_batch=16,
-        kernel_updaters=("adagrad",), sgns_vocab=64,
+        kernel_updaters=("adagrad",),
         attn_seq=128, attn_head_dims=(64,))
 
 
@@ -62,7 +62,6 @@ def test_kernel_stage_runs_interpreted_on_one_device(capsys):
         mv.shutdown()
     out = capsys.readouterr().out
     assert "'adagrad': 'fused_stateful'" in out
-    assert "pallas_sgns grid step: ran (interpreted)" in out
     assert "paged_decode_attn matches" in out
 
 

@@ -1,4 +1,6 @@
-"""Pallas row-op kernels vs numpy references (interpret mode on CPU)."""
+"""The row plane a store picks by itself, its XLA plane against NumPy on one
+device, and the Pallas row kernels against the XLA plane (interpret mode on
+CPU)."""
 
 import contextlib
 
@@ -8,258 +10,136 @@ import pytest
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
-from multiverso_tpu.ops.pallas_rows import (gather_rows, scatter_add_rows,
-                                            scatter_add_sorted_rows)
+
+# -- which plane serves a store's row updates ---------------------------------
+# (updater, columns, dtype, mesh arguments, one device) -> row_plane. The
+# store decides from what it shows (core/table.fused_rows_selected); no
+# option says otherwise.
+def _overriding_subclass():
+    from multiverso_tpu.core.updater import AdaGradUpdater
+
+    class Louder(AdaGradUpdater):
+        # inherits the name, the state and ``fused_rows = True`` by
+        # attribute look-up; the claim was made for the parent's math
+        def rows_math(self, d_rows, state_rows, delta, opt):
+            return super().rows_math(d_rows, state_rows, 2.0 * delta, opt)
+    return Louder()
 
 
-def test_gather_rows():
-    rng = np.random.default_rng(0)
-    table = rng.normal(size=(64, 128)).astype(np.float32)
-    ids = np.array([3, 0, 63, 3, 17], dtype=np.int32)
-    out = gather_rows(jnp.asarray(table), jnp.asarray(ids), interpret=True)
-    np.testing.assert_allclose(np.asarray(out), table[ids])
+_ONE = dict(args=[], one=True)
+ROW_PLANES = {
+    "default_128": ("default", 128, np.float32, _ONE, "xla"),
+    "sgd_128": ("sgd", 128, np.float32, _ONE, "xla"),
+    "momentum_sgd_128": ("momentum_sgd", 128, np.float32, _ONE,
+                         "fused_stateful"),
+    "adagrad_128": ("adagrad", 128, np.float32, _ONE, "fused_stateful"),
+    "ftrl_128": ("ftrl", 128, np.float32, _ONE, "fused_stateful"),
+    # the DC-ASGD family does not claim the kernel (Updater.fused_rows)
+    "dcasgd_128": ("dcasgd", 128, np.float32, _ONE, "xla"),
+    "dcasgda_128": ("dcasgda", 128, np.float32, _ONE, "xla"),
+    # Mosaic's one-row DMA slices: float32 at exactly 128 columns only
+    "adagrad_bfloat16": ("adagrad", 128, "bfloat16", _ONE, "xla"),
+    "adagrad_50_columns": ("adagrad", 50, np.float32, _ONE, "xla"),
+    "adagrad_256_columns": ("adagrad", 256, np.float32, _ONE, "xla"),
+    "adagrad_eight_shards": ("adagrad", 128, np.float32,
+                             dict(args=[], one=False), "xla"),
+    "adagrad_sharded_state": (
+        "adagrad", 128, np.float32,
+        dict(args=["-mesh_shape=server:1,worker:2", "-state_sharding=on"],
+             one=False), "xla"),
+    # the guard: a subclass that overrides the math does not inherit the
+    # parent's claim
+    "adagrad_subclass_other_math": (_overriding_subclass, 128, np.float32,
+                                    _ONE, "xla"),
+}
 
 
-def test_scatter_add_sorted_unique():
-    table = np.zeros((16, 128), dtype=np.float32)
-    ids = np.array([1, 4, 9], dtype=np.int32)
-    deltas = np.ones((3, 128), dtype=np.float32)
-    out = scatter_add_sorted_rows(jnp.asarray(table), jnp.asarray(ids),
-                                  jnp.asarray(deltas), interpret=True)
-    expected = table.copy()
-    expected[ids] += 1.0
-    np.testing.assert_allclose(np.asarray(out), expected)
-
-
-def test_scatter_add_duplicates_accumulate():
-    table = np.ones((8, 128), dtype=np.float32)
-    ids = np.array([2, 2, 2, 5], dtype=np.int32)
-    deltas = np.stack([np.full(128, float(i + 1), dtype=np.float32)
-                       for i in range(4)])
-    out = scatter_add_sorted_rows(jnp.asarray(table), jnp.asarray(ids),
-                                  jnp.asarray(deltas), interpret=True)
-    expected = np.ones((8, 128), dtype=np.float32)
-    expected[2] += 1 + 2 + 3
-    expected[5] += 4
-    np.testing.assert_allclose(np.asarray(out), expected)
-
-
-def test_scatter_add_run_crossing_group_boundary():
-    """Regression: a duplicate-id run longer than GROUP(8) spanning a group
-    boundary must not drop the first group's partial sum (advisor round-1
-    finding: 16 deltas of 1.0 yielded +8.0; [1]*10+[3]*6 yielded +2.0)."""
-    table = np.zeros((8, 128), dtype=np.float32)
-    ids = np.full(16, 1, dtype=np.int32)
-    deltas = np.ones((16, 128), dtype=np.float32)
-    out = scatter_add_sorted_rows(jnp.asarray(table), jnp.asarray(ids),
-                                  jnp.asarray(deltas), interpret=True)
-    expected = np.zeros((8, 128), dtype=np.float32)
-    expected[1] = 16.0
-    np.testing.assert_allclose(np.asarray(out), expected)
-
-    ids = np.array([1] * 10 + [3] * 6, dtype=np.int32)
-    out = scatter_add_sorted_rows(jnp.zeros((8, 128), dtype=jnp.float32),
-                                  jnp.asarray(ids), jnp.asarray(deltas),
-                                  interpret=True)
-    expected = np.zeros((8, 128), dtype=np.float32)
-    expected[1] = 10.0
-    expected[3] = 6.0
-    np.testing.assert_allclose(np.asarray(out), expected)
-
-
-def test_scatter_add_long_runs_random():
-    """Runs of random lengths (1..20) across several group boundaries."""
-    rng = np.random.default_rng(7)
-    table = rng.normal(size=(32, 128)).astype(np.float32)
-    ids = np.sort(rng.integers(0, 32, size=67)).astype(np.int32)
-    deltas = rng.normal(size=(67, 128)).astype(np.float32)
-    out = scatter_add_sorted_rows(jnp.asarray(table), jnp.asarray(ids),
-                                  jnp.asarray(deltas), interpret=True)
-    expected = table.copy()
-    np.add.at(expected, ids, deltas)
-    np.testing.assert_allclose(np.asarray(out), expected, rtol=1e-4,
-                               atol=1e-5)
-
-
-def test_scatter_add_unsorted_wrapper():
-    rng = np.random.default_rng(1)
-    table = rng.normal(size=(32, 128)).astype(np.float32)
-    ids = np.array([9, 2, 9, 31, 0, 2], dtype=np.int32)
-    deltas = rng.normal(size=(6, 128)).astype(np.float32)
-    out = scatter_add_rows(jnp.asarray(table), jnp.asarray(ids),
-                           jnp.asarray(deltas), interpret=True)
-    expected = table.copy()
-    np.add.at(expected, ids, deltas)
-    np.testing.assert_allclose(np.asarray(out), expected, rtol=1e-5)
-
-
-def test_pallas_table_path(monkeypatch):
-    """MatrixTable with use_pallas=True routes row ops through the Mosaic
-    kernels (interpret mode on CPU) with identical semantics. Eligibility
-    needs a single shard: restrict the mesh to one device."""
+@pytest.mark.parametrize("case", sorted(ROW_PLANES))
+def test_the_store_picks_its_row_plane_from_what_it_shows(case):
     import multiverso_tpu as mv
-
-    mv.init([], devices=jax.devices()[:1])
-    try:
-        t = mv.create_table(mv.MatrixTableOption(num_row=64, num_col=128,
-                                                 use_pallas=True))
-        assert t.store._pallas_rows
-        rows = [3, 9, 3, 63]
-        deltas = np.stack([np.full(128, float(i + 1), dtype=np.float32)
-                           for i in range(4)])
-        t.add_rows(rows, deltas)
-        expected = np.zeros((64, 128), dtype=np.float32)
-        np.add.at(expected, rows, deltas)
-        np.testing.assert_allclose(t.get_rows([3, 9, 63]),
-                                   expected[[3, 9, 63]], rtol=1e-6)
-        np.testing.assert_allclose(t.get(), expected, rtol=1e-6)
-    finally:
-        mv.shutdown()
-
-
-def test_pallas_flag_ignored_when_ineligible():
-    """Sharded tables (8 devices) silently fall back to the XLA path."""
-    import multiverso_tpu as mv
-
-    mv.init([])
-    try:
-        t = mv.create_table(mv.MatrixTableOption(num_row=64, num_col=128,
-                                                 use_pallas=True))
-        assert not t.store._pallas_rows   # 8 shards -> ineligible
-        t.add_rows([5], np.ones((1, 128), dtype=np.float32))
-        np.testing.assert_allclose(t.get_row(5), np.ones(128))
-    finally:
-        mv.shutdown()
-
-
-# -- round 2: widened eligibility (bf16 tiles, SGD sign) --------------------
-def test_scatter_add_sgd_sign():
-    """Interpret-mode note: bf16 kernels pass here but are REJECTED by
-    Mosaic on real chips (2-byte HBM tiling packs 2 rows/sublane; 1-row DMA
-    slices misalign), so table eligibility stays f32-only."""
-    import jax.numpy as jnp
-    from multiverso_tpu.ops.pallas_rows import (gather_rows,
-                                                group_for_dtype,
-                                                scatter_add_rows)
-
-    assert group_for_dtype(np.float32) == 8
-    assert group_for_dtype(jnp.bfloat16) == 16
-
-    rng = np.random.default_rng(0)
-    for dtype in (np.float32,):
-        table = jnp.asarray(rng.normal(size=(64, 128)), dtype=dtype)
-        ids = jnp.asarray(np.sort(rng.integers(0, 64, size=40))
-                          .astype(np.int32))
-        deltas = jnp.asarray(rng.normal(size=(40, 128)), dtype=dtype)
-        ref = np.array(table, dtype=np.float32)   # writable copy
-        np.add.at(ref, np.asarray(ids), np.asarray(deltas,
-                                                   dtype=np.float32))
-        got = scatter_add_rows(table, ids, deltas, interpret=True)
-        np.testing.assert_allclose(np.asarray(got, dtype=np.float32), ref,
-                                   rtol=2e-2, atol=2e-2)
-        back = gather_rows(got, ids, interpret=True)
-        np.testing.assert_allclose(np.asarray(back, dtype=np.float32),
-                                   ref[np.asarray(ids)], rtol=2e-2,
-                                   atol=2e-2)
-    # SGD sign: data -= delta
-    table = jnp.zeros((16, 128), jnp.float32)
-    ids = jnp.asarray([2, 2, 5], dtype=jnp.int32)
-    deltas = jnp.ones((3, 128), jnp.float32)
-    got = scatter_add_rows(table, ids, deltas, interpret=True, sign=-1.0)
-    assert np.allclose(np.asarray(got)[2], -2.0)
-    assert np.allclose(np.asarray(got)[5], -1.0)
-
-
-def test_table_pallas_eligibility_widened():
-    """SGD tables route through the Pallas row path (single shard,
-    sign-flipped scatter); bf16 stays on XLA; stateful updaters named by
-    the capability registry (adagrad) get the FUSED gather-update-scatter
-    kernel; unregistered stateful updaters (dcasgd) stay on XLA."""
-    import multiverso_tpu as mv
-    from multiverso_tpu.core.options import AddOption
     from multiverso_tpu.core.table import ServerStore
     from multiverso_tpu.core.updater import get_updater
     from multiverso_tpu.core.zoo import Zoo
 
-    mv.init([], devices=jax.devices()[:1])   # single shard for eligibility
+    updater, cols, dtype, place, want = ROW_PLANES[case]
+    dtype = np.dtype(dtype)
+    mv.init(place["args"], devices=jax.devices()[:1] if place["one"]
+            else None)
     try:
-        mesh = Zoo.get().mesh
-        st = ServerStore("p1", (32, 128), np.float32,
-                         get_updater(np.float32, "sgd"), mesh, 1,
-                         use_pallas_rows=True)
-        assert st._pallas_rows and st._pallas_cap == "scatter_sub"
-        st_bf = ServerStore("p2", (32, 128), jnp.bfloat16,
-                            get_updater(np.dtype(jnp.bfloat16), "default"),
-                            mesh, 1, use_pallas_rows=True)
-        assert not st_bf._pallas_rows   # bf16: Mosaic 1-row DMA misaligned
-        st_ada = ServerStore("p3", (32, 128), np.float32,
-                             get_updater(np.float32, "adagrad"), mesh, 1,
-                             use_pallas_rows=True)
-        assert st_ada._pallas_rows and st_ada._pallas_cap == "fused_stateful"
-        st_dc = ServerStore("p4", (32, 128), np.float32,
-                            get_updater(np.float32, "dcasgd"), mesh, 1,
-                            use_pallas_rows=True)
-        assert not st_dc._pallas_rows   # not in the capability registry
-        st_50 = ServerStore("p5", (32, 50), np.float32,
-                            get_updater(np.float32, "default"), mesh, 1,
-                            use_pallas_rows=True)
-        assert not st_50._pallas_rows   # 50 cols: Mosaic lane misaligned
-        # behavior: sgd table applies data -= delta through the kernel
-        ids = jnp.asarray([1, 1, 3], dtype=jnp.int32)
-        st.apply_rows(ids, jnp.ones((3, 128), jnp.float32), AddOption())
-        out = np.asarray(st.read_rows(jnp.asarray([1, 3],
-                                                  dtype=jnp.int32)))
-        assert np.allclose(out[0], -2.0) and np.allclose(out[1], -1.0)
+        made = updater() if callable(updater) else get_updater(dtype,
+                                                               updater)
+        store = ServerStore(case, (64, cols), dtype, made, Zoo.get().mesh, 1)
+        assert store.state_sharded == (case == "adagrad_sharded_state")
+        assert store.row_plane == want
+        # whichever plane: an add lands, and a read finds it
+        opt = mv.AddOption(learning_rate=0.1, rho=0.1, momentum=0.5,
+                           lambda_=0.01)
+        ids = jnp.asarray([5, 9, 5], jnp.int32)
+        store.apply_rows(ids, jnp.ones((3, cols), jnp.float32), opt)
+        got = np.asarray(store.read_rows(jnp.asarray([5, 9, 0], jnp.int32)),
+                         np.float32)
+        assert np.all(got[:2] != 0) and np.all(got[2] == 0)
     finally:
         mv.shutdown()
 
 
-def test_tiled_scatter_matches_numpy_random():
-    """Tiled table-sweep scatter: random duplicated ids vs np.add.at."""
-    from multiverso_tpu.ops.pallas_rows import tiled_scatter_add_rows
-    rng = np.random.default_rng(0)
-    table = rng.normal(size=(1000, 128)).astype(np.float32)
-    ids = rng.integers(0, 1000, size=512).astype(np.int32)
-    deltas = rng.normal(size=(512, 128)).astype(np.float32)
-    want = table.copy()
-    np.add.at(want, ids, deltas)
-    got = tiled_scatter_add_rows(jnp.asarray(table), jnp.asarray(ids),
-                                 jnp.asarray(deltas), interpret=True)
-    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-5)
+# -- the XLA plane of the stateless updaters, one device, 128 columns ---------
+# (what the stateless row kernels served behind an option until PR 43)
+STATELESS_IDS = {
+    "unique": [1, 4, 9],
+    "duplicates": [2, 2, 2, 5],
+    "one_long_run": [1] * 10 + [3] * 6,
+    "unsorted": [9, 2, 9, 31, 0, 2],
+    "long_random": np.random.default_rng(7).integers(0, 32, size=67),
+}
 
 
-def test_tiled_scatter_nonmultiple_rows_and_tile_edges():
-    """Row count not a multiple of the tile + ids clustered at tile
-    boundaries (start/end searchsorted correctness)."""
-    from multiverso_tpu.ops.pallas_rows import tiled_scatter_add_rows
-    rng = np.random.default_rng(1)
-    table = rng.normal(size=(777, 128)).astype(np.float32)
-    # hit first/last rows of tiles plus heavy duplication
-    ids = np.asarray([0, 255, 255, 256, 511, 512, 512, 512, 776, 776],
-                     dtype=np.int32)
+@pytest.mark.parametrize("updater,sign", [("default", 1.0), ("sgd", -1.0)])
+@pytest.mark.parametrize("case", sorted(STATELESS_IDS))
+def test_stateless_store_rows_on_one_device_equal_numpy(case, updater,
+                                                        sign):
+    """``ServerStore.apply_rows`` / ``read_rows``: every occurrence of an id
+    adds (``sgd``: subtracts, the client pre-scales by the rate), in any
+    order; a read answers in the order asked, repeats and all."""
+    import multiverso_tpu as mv
+    from multiverso_tpu.core.table import ServerStore
+    from multiverso_tpu.core.updater import get_updater
+    from multiverso_tpu.core.zoo import Zoo
+
+    rng = np.random.default_rng(3)
+    start = rng.normal(size=(32, 128)).astype(np.float32)
+    ids = np.asarray(STATELESS_IDS[case], np.int32)
     deltas = rng.normal(size=(len(ids), 128)).astype(np.float32)
-    want = table.copy()
-    np.add.at(want, ids, deltas)
-    got = tiled_scatter_add_rows(jnp.asarray(table), jnp.asarray(ids),
-                                 jnp.asarray(deltas), interpret=True)
-    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-5)
+    mv.init([], devices=jax.devices()[:1])
+    try:
+        store = ServerStore("s", (32, 128), np.float32,
+                            get_updater(np.float32, updater),
+                            Zoo.get().mesh, 1, init_array=start)
+        assert store.row_plane == "xla"
+        store.apply_rows(jnp.asarray(ids), jnp.asarray(deltas),
+                         mv.AddOption())
+        want = start.copy()
+        np.add.at(want, ids, sign * deltas)
+        np.testing.assert_allclose(np.asarray(store.read()), want,
+                                   rtol=1e-5, atol=1e-5)
+        ask = np.array([3, 0, 31, 3, 17, 2], np.int32)
+        np.testing.assert_array_equal(
+            np.asarray(store.read_rows(jnp.asarray(ask))),
+            np.asarray(store.read())[ask])
+    finally:
+        mv.shutdown()
 
 
-def test_tiled_scatter_sgd_sign_and_eligibility():
-    from multiverso_tpu.ops.pallas_rows import (tiled_scatter_add_rows,
-                                                tiled_scatter_eligible)
-    rng = np.random.default_rng(2)
-    table = np.zeros((300, 8), dtype=np.float32)
-    ids = np.asarray([3, 3, 299], dtype=np.int32)
-    deltas = np.ones((3, 8), dtype=np.float32)
-    got = tiled_scatter_add_rows(jnp.asarray(table), jnp.asarray(ids),
-                                 jnp.asarray(deltas), interpret=True,
-                                 sign=-1.0)
-    want = np.zeros_like(table)
-    np.add.at(want, ids, -deltas)
-    np.testing.assert_allclose(np.asarray(got), want)
-    assert tiled_scatter_eligible(8192, 128, np.float32)
-    assert not tiled_scatter_eligible(100_000, 128, np.float32)
+def test_the_removed_row_plane_option_is_an_unknown_keyword():
+    """PR 43 took ``use_pallas`` away: the store chooses. It fails as any
+    unknown keyword does, on the option and on the store."""
+    import multiverso_tpu as mv
+    from multiverso_tpu.core.table import ServerStore
+    with pytest.raises(TypeError):
+        mv.MatrixTableOption(num_row=8, num_col=128, use_pallas=True)
+    with pytest.raises(TypeError):
+        ServerStore("s", (8, 128), np.float32, None, None, 1,
+                    use_pallas_rows=True)
 
 
 # ---------------------------------------------------------------------------
@@ -295,9 +175,8 @@ def test_fused_stateful_bitwise_vs_xla(updater):
         assert t_xla.store.row_plane == "xla"
         t_pal = mv.create_table(mv.MatrixTableOption(33, 128,
                                                      updater=updater,
-                                                     name="fp",
-                                                     use_pallas=True))
-        assert t_pal.store._pallas_cap == "fused_stateful"
+                                                     name="fp"))
+        assert t_pal.store.row_plane == "fused_stateful"
         rng = np.random.default_rng(3)
         opt = mv.AddOption(worker_id=0, momentum=0.9, learning_rate=0.05,
                            rho=0.1, lambda_=0.01)
@@ -331,9 +210,8 @@ def test_fused_stateful_duplicates_and_empty():
         assert t_xla.store.row_plane == "xla"
         t_pal = mv.create_table(mv.MatrixTableOption(8, 128,
                                                      updater="adagrad",
-                                                     name="dp",
-                                                     use_pallas=True))
-        assert t_pal.store._pallas_cap == "fused_stateful"
+                                                     name="dp"))
+        assert t_pal.store.row_plane == "fused_stateful"
         opt = mv.AddOption(learning_rate=0.1, rho=0.1)
         # 11 ids over 3 rows: duplicates straddle the 8-lane group
         ids = np.array([2, 2, 2, 6, 6, 1, 1, 1, 1, 2, 6], dtype=np.int32)
@@ -362,9 +240,8 @@ def test_fused_stateful_per_worker_state_indexing():
         assert t_xla.store.row_plane == "xla"
         t_pal = mv.create_table(mv.MatrixTableOption(16, 128,
                                                      updater="adagrad",
-                                                     name="wp",
-                                                     use_pallas=True))
-        assert t_pal.store._pallas_cap == "fused_stateful"
+                                                     name="wp"))
+        assert t_pal.store.row_plane == "fused_stateful"
         rng = np.random.default_rng(5)
         for step in range(4):
             w = step % 2

@@ -278,8 +278,8 @@ def test_a_pallas_plane_member_contributes_its_own_row_functions():
     mv.init([], devices=jax.devices()[:1])
     try:
         shapes = [(64, 128), (32, 128)]
-        solo = _tables(shapes, "adagrad", "ps", use_pallas=True)
-        grouped = _tables(shapes, "adagrad", "pg", use_pallas=True)
+        solo = _tables(shapes, "adagrad", "ps")
+        grouped = _tables(shapes, "adagrad", "pg")
         assert all(t.store.row_plane == "fused_stateful"
                    for t in solo + grouped)
         group = mv.create_table_group(grouped)

@@ -342,10 +342,13 @@ def test_analogy_query(mv_env):
     assert w2v.analogy("a0", "missing", "b0") == []
 
 
-def test_chunked_dispatch_matches_block_step_bitwise(mv_env):
-    """The host-dispatched chunk pipeline (pair_gen + chunk_step* + tail)
-    must reproduce the in-graph compacted block step bitwise: identical key
-    -> identical pair stream, negatives, masks, and update order."""
+@pytest.mark.parametrize("adagrad", [True, False])
+def test_chunked_dispatch_matches_block_step_bitwise(mv_env, adagrad):
+    """The two chunk-loop executions from one key: the host-dispatched
+    chunk pipeline (pair_gen + chunk_step* + tail, pipelined_host's step
+    functions) must reproduce the in-graph compacted block step bitwise:
+    identical key -> identical pair stream, negatives, masks, and update
+    order, under AdaGrad and under plain SGD."""
     import jax
     import jax.numpy as jnp
     from multiverso_tpu.models.word2vec.model import (
@@ -367,12 +370,12 @@ def test_chunked_dispatch_matches_block_step_bitwise(mv_env):
             size=(V, D)).astype(np.float32))] + \
             [jnp.zeros((V, D), jnp.float32) for _ in range(3)]
 
-    block = build_device_block_step(W, K, chunk, adagrad=True,
+    block = build_device_block_step(W, K, chunk, adagrad=adagrad,
                                     compact=True)
     ref = block(*init(), neg_table, keep_prob, sents, lengths, key, lr)
 
     pair_gen, chunk_step, tail_step = build_chunked_pipeline(
-        W, K, chunk, adagrad=True)
+        W, K, chunk, adagrad=adagrad)
     centers2d, contexts2d, negs, n_pairs = pair_gen(
         neg_table, keep_prob, sents, lengths, key)
     n_static = centers2d.shape[0]
@@ -397,74 +400,9 @@ def test_chunked_dispatch_matches_block_step_bitwise(mv_env):
     np.testing.assert_allclose(float(total_loss), float(ref[4]), rtol=1e-6)
 
 
-def test_dispatch_modes_three_way_bitwise(mv_env):
-    """ISSUE 2 acceptance: all three chunk-loop executions — in-graph
-    compacted block step, host-dispatched chunk chain (pipelined_host's
-    step functions), and the Pallas grid-resident kernel (interpret on
-    CPU) — produce bitwise-identical table state from one key."""
-    import jax
-    import jax.numpy as jnp
-    from multiverso_tpu.models.word2vec.model import (
-        build_chunked_pipeline, build_device_block_step,
-        expected_live_chunks)
-    from multiverso_tpu.ops.pallas_sgns import build_sgns_grid_step
-
-    rng = np.random.default_rng(5)
-    V, D, S, L, chunk, W, K = 80, 16, 6, 20, 32, 3, 2
-    neg_table = jnp.asarray(rng.integers(0, V, size=1024).astype(np.int32))
-    keep_prob_host = np.full(V, 0.8, dtype=np.float32)
-    keep_prob = jnp.asarray(keep_prob_host)
-    sents = jnp.asarray(rng.integers(0, V, size=(S, L)).astype(np.int32))
-    lengths = jnp.asarray(rng.integers(2, L + 1, size=S).astype(np.int32))
-    key = jax.random.PRNGKey(13)
-    lr = jnp.float32(0.05)
-
-    def init():
-        return [jnp.asarray(np.random.default_rng(1).normal(
-            size=(V, D)).astype(np.float32))] + \
-            [jnp.zeros((V, D), jnp.float32) for _ in range(3)]
-
-    # mode 1: in-graph compacted block step
-    block = build_device_block_step(W, K, chunk, adagrad=True, compact=True)
-    ref = block(*init(), neg_table, keep_prob, sents, lengths, key, lr)
-
-    # shared pair stream for modes 2 and 3
-    pair_gen, chunk_step, tail_step = build_chunked_pipeline(
-        W, K, chunk, adagrad=True)
-    centers2d, contexts2d, negs, n_pairs = pair_gen(
-        neg_table, keep_prob, sents, lengths, key)
-
-    # mode 2: host-dispatched chunk chain + exact tail
-    est = expected_live_chunks(keep_prob_host, np.asarray(sents),
-                               np.asarray(lengths), W, chunk,
-                               centers2d.shape[0])
-    tables = init()
-    host_loss = jnp.float32(0)
-    for i in range(est):
-        out = chunk_step(*tables, centers2d, contexts2d, negs, n_pairs,
-                         jnp.int32(i), lr)
-        tables = list(out[:4])
-        host_loss = host_loss + out[4]
-    out = tail_step(*tables, centers2d, contexts2d, negs, n_pairs, lr,
-                    start=est)
-    host_tables, host_loss = out[:4], host_loss + out[4]
-
-    # mode 3: Pallas grid (sequential on-chip loop, one dispatch)
-    grid = build_sgns_grid_step(chunk=chunk, negative=K, adagrad=True,
-                                interpret=True)
-    g_out = grid(*init(), centers2d, contexts2d, negs, n_pairs, lr)
-
-    assert int(n_pairs) == int(ref[5]) > 0
-    for a, b, c in zip(ref[:4], host_tables, g_out[:4]):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(c))
-    np.testing.assert_allclose(float(host_loss), float(ref[4]), rtol=1e-6)
-    np.testing.assert_allclose(float(g_out[4]), float(ref[4]), rtol=1e-6)
-
-
 def test_dispatch_mode_auto_decision_table(monkeypatch, mv_env):
     """resolve_dispatch_mode: latency probe + variant/mesh gates +
-    legacy chunk_dispatch mapping + explicit-mode validation."""
+    explicit-mode validation."""
     import dataclasses
     from multiverso_tpu.models.word2vec import model as m
     from multiverso_tpu.utils.log import FatalError
@@ -479,23 +417,25 @@ def test_dispatch_mode_auto_decision_table(monkeypatch, mv_env):
                     dataclasses.replace(cfg, sg=False),
                     dataclasses.replace(cfg, mesh_data=2)):
         assert m.resolve_dispatch_mode(variant) == "in_graph"
-    # legacy bool maps onto the new modes
-    assert m.resolve_dispatch_mode(
-        dataclasses.replace(cfg, chunk_dispatch=True)) == "pipelined_host"
-    assert m.resolve_dispatch_mode(
-        dataclasses.replace(cfg, chunk_dispatch=False)) == "in_graph"
-    # explicit mode wins over the probe; unknown names are rejected
-    assert m.resolve_dispatch_mode(
-        dataclasses.replace(cfg, dispatch_mode="pallas_grid")) == "pallas_grid"
-    with pytest.raises(FatalError):
-        m.resolve_dispatch_mode(
-            dataclasses.replace(cfg, dispatch_mode="bogus"))
+    # explicit mode wins over the probe; unknown names are rejected, the
+    # mode PR 43 removed like any other
+    assert m.resolve_dispatch_mode(dataclasses.replace(
+        cfg, dispatch_mode="pipelined_host")) == "pipelined_host"
+    for unknown in ("bogus", "pallas_grid"):
+        with pytest.raises(FatalError, match="in_graph.*pipelined_host"):
+            m.resolve_dispatch_mode(
+                dataclasses.replace(cfg, dispatch_mode=unknown))
 
 
-@pytest.mark.parametrize("mode", ["pipelined_host", "pallas_grid"])
-def test_device_pipeline_explicit_dispatch_modes_train(mv_env, mode):
-    """End-to-end training under each explicit alternative execution
-    (Pallas grid runs interpreted on CPU) still separates topics."""
+def test_the_removed_dispatch_alias_is_an_unknown_field():
+    """PR 43 took the deprecated bool away: ``dispatch_mode`` says it."""
+    with pytest.raises(TypeError):
+        Word2VecConfig(chunk_dispatch=True)
+
+
+def test_device_pipeline_explicit_dispatch_modes_train(mv_env):
+    """End-to-end training under the explicit alternative execution
+    (pipelined_host) still separates topics."""
     sents = _corpus(300)
     d = Dictionary.build(sents, min_count=1)
     cfg = Word2VecConfig(embedding_size=32, batch_size=512, window=4,
@@ -503,7 +443,7 @@ def test_device_pipeline_explicit_dispatch_modes_train(mv_env, mode):
                          epochs=3, learning_rate=0.1, seed=3,
                          device_pipeline=True, block_sentences=128,
                          pad_sentence_length=16, pipeline=False,
-                         dispatch_mode=mode, dispatch_depth=4)
+                         dispatch_mode="pipelined_host", dispatch_depth=4)
     w2v = Word2Vec(cfg, d)
     stats = w2v.train(sentences=[d.encode(s) for s in sents])
     assert stats["pairs"] > 0
