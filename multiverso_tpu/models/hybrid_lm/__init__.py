@@ -11,9 +11,11 @@ from multiverso_tpu.models.hybrid_lm.config import (ATTENTION, DENSE, EVA,
 from multiverso_tpu.models.hybrid_lm.model import (APPLY_PROGRAM,
                                                    DELTA_PROGRAM, HybridLM,
                                                    dense_param_count,
+                                                   exit_distribution,
                                                    forward_hidden,
                                                    init_buffers, init_params,
-                                                   layer_forward, make_loss,
+                                                   layer_forward,
+                                                   looped_hidden, make_loss,
                                                    pack_batch, param_shapes,
                                                    rmsnorm,
                                                    updated_expert_bias)
@@ -21,6 +23,7 @@ from multiverso_tpu.models.hybrid_lm.model import (APPLY_PROGRAM,
 __all__ = ["HybridLMConfig", "HybridLM", "MAMBA", "EXPERTS", "ATTENTION",
            "LATENT", "DENSE", "EVA", "SPARSE", "LIGHTNING", "SHORTCONV",
            "DELTA_PROGRAM", "APPLY_PROGRAM", "dense_param_count",
-           "forward_hidden", "init_buffers", "init_params", "layer_forward",
-           "make_loss", "pack_batch", "param_shapes", "rmsnorm", "rope",
+           "exit_distribution", "forward_hidden", "looped_hidden",
+           "init_buffers", "init_params", "layer_forward", "make_loss",
+           "pack_batch", "param_shapes", "rmsnorm", "rope",
            "updated_expert_bias"]

@@ -16,10 +16,18 @@ MAMBA, EXPERTS, ATTENTION, LATENT, DENSE, EVA = "M", "E", "*", "L", "D", "V"
 SPARSE, LIGHTNING, SHORTCONV = "S", "N", "C"
 #: A published ``mixer_types`` entry -> the letter of its block.
 MIXER_TYPES = {"minicpm4": SPARSE, "lightning-attn": LIGHTNING}
-#: A published ``layer_types`` entry (``lfm2_moe``) -> the letter of its mixer.
-#: The family's ``full_attention`` is grouped-query attention with an RMSNorm
-#: over each query and key head and a rotary turn over the whole head.
+#: A published ``layer_types`` entry -> the letter of its mixer.
 LAYER_TYPES = {"conv": SHORTCONV, "full_attention": ATTENTION}
+#: What a family that publishes ``layer_types`` hard-sets, by its
+#: ``model_type``. ``lfm2_moe``: ``full_attention`` is grouped-query attention
+#: with an RMSNorm over each query and key head and a rotary turn over the
+#: whole head, the experts' router a sigmoid. ``ouro``: the rotary turn and no
+#: norm a head, a norm on every mixer's OUTPUT (sandwich norm), and the stack
+#: run ``total_ut_steps`` times.
+_LAYER_TYPES_FAMILIES = {
+    "lfm2_moe": {"attn_qk_norm": True, "attn_rope": True,
+                 "scoring_func": "sigmoid"},
+    "ouro": {"attn_rope": True, "post_norm": True}}
 #: The family's switches as published (``minicpm_sala``): each kind of mixer
 #: is implemented so and in no other form.
 _MIXER_SWITCHES = {
@@ -56,14 +64,16 @@ _KEYS_OF_KIND = {
 _OPTIONAL_KEYS = ("moe_shared_expert_intermediate_size", "scoring_func",
                   "hidden_act", "aux_loss_alpha", "num_pred_heads",
                   "norm_add_unit_offset", "scale_emb", "scale_depth",
-                  "dim_model_base", "use_expert_bias", "tie_word_embeddings")
+                  "dim_model_base", "use_expert_bias", "tie_word_embeddings",
+                  "total_ut_steps", "early_exit_threshold")
 #: A sparse block's sizes, as the published ``sparse_config`` group names them.
 _SPARSE_KEYS = ("kernel_size", "kernel_stride", "block_size", "window_size",
                 "init_blocks", "topk", "dense_len")
 #: Keys of this repo, optional in a file.
 _OWN_KEYS = ("learning_rate", "adagrad_step", "init_std", "attn_block",
              "moe_block", "loss_block", "ffn_slab", "row_bucket",
-             "comm_policy", "lightning_chunk", "expert_bias_update_rate")
+             "comm_policy", "lightning_chunk", "expert_bias_update_rate",
+             "exit_entropy_weight")
 
 
 @dataclasses.dataclass
@@ -87,6 +97,21 @@ class HybridLMConfig:
     norm_eps: float = 1e-5
     #: Every RMSNorm scales by ``1 + w`` (``w`` drawn at zero), not by ``w``.
     norm_add_unit_offset: bool = False
+    #: Every block norms its mixer's OUTPUT too before the residual add
+    #: (sandwich norm): ``u + RMSNorm_w2(mixer(RMSNorm_w1(u)))``, a second
+    #: norm leaf a block (``post_norm``).
+    post_norm: bool = False
+    #: The stack and the final norm run this many times over the SAME leaves
+    #: (a looped LM): a pass's normed output is the next pass's input and
+    #: what the head and the exit gate read. Above 1 the loss is every pass's
+    #: cross-entropy weighed by the exit distribution the gate emits, less
+    #: ``exit_entropy_weight`` times its entropy (docs/HYBRID_LM.md "A looped
+    #: stack").
+    total_ut_steps: int = 1
+    exit_entropy_weight: float = 0.0
+    #: Published beside ``total_ut_steps``: the cumulative exit mass at which
+    #: decoding stops looping. Carried; training takes no exit.
+    early_exit_threshold: float = 1.0
     #: Targets a position: head ``h`` of the ``num_pred_heads * vocab_size``
     #: wide output predicts the token ``1 + h`` ahead.
     num_pred_heads: int = 1
@@ -304,6 +329,14 @@ class HybridLMConfig:
         check(not self.attn_rope or self.head_dim % 2 == 0,
               "rotary width must be even")
         check(self.conv_L_cache >= 1, "a convolution has at least one tap")
+        check(self.total_ut_steps >= 1 and self.exit_entropy_weight >= 0.0,
+              "total_ut_steps is at least one, exit_entropy_weight not "
+              "negative")
+        check(self.total_ut_steps == 1 or (
+            not {EXPERTS, SPARSE} & set(self.pattern)
+            and self.num_pred_heads == 1 and not self.dim_model_base),
+            "a looped stack (total_ut_steps > 1) carries no expert or sparse "
+            "block's counts from pass to pass, and has one plain head")
         check(not self.tie_word_embeddings or self.num_pred_heads == 1,
               "a tied head is one vocabulary wide")
         check(self.expert_bias_update_rate >= 0.0
@@ -375,7 +408,8 @@ class HybridLMConfig:
         first ``num_hidden_layers`` of ``hybrid_override_pattern`` where the
         file has one, or two blocks a layer from the first
         ``num_hidden_layers`` of ``mixer_types`` or of ``layer_types`` (an
-        entry this program does not know raises; :meth:`_pattern_of_layers`);
+        entry this program does not know raises, as does a ``model_type``
+        whose switches it does not know: :meth:`_pattern_of_layers`);
         else every layer is two
         blocks, attention (latent where the file has a
         ``kv_lora_rank``, EVA where its ``attention_class`` is ``eva``) and a
@@ -435,9 +469,8 @@ class HybridLMConfig:
             kw["head_dim"] = d.get("head_dim") or \
                 d["hidden_size"] // d["num_attention_heads"]
         if "layer_types" in d:
-            # the family's attention and experts: as LAYER_TYPES says
-            kw.update(attn_qk_norm=True, attn_rope=True,
-                      rope_theta=d["rope_theta"], scoring_func="sigmoid")
+            kw.update(_LAYER_TYPES_FAMILIES[d["model_type"]],
+                      rope_theta=d["rope_theta"])
         kw.update({key: d[key] for key in _OPTIONAL_KEYS if key in d})
         if SPARSE in kinds:
             kw.update({"sparse_" + key: d["sparse_config"][key]
@@ -493,6 +526,11 @@ class HybridLMConfig:
         a dense feed-forward in the first ``num_dense_layers`` layers (in
         every layer of a file that holds no expert) and an expert block in
         each later one."""
+        if d.get("model_type") not in _LAYER_TYPES_FAMILIES:
+            raise ValueError(
+                f"layer_types under model_type {d.get('model_type')!r}: the "
+                f"family's switches are known for "
+                f"{sorted(_LAYER_TYPES_FAMILIES)}")
         names = d["layer_types"][:d["num_hidden_layers"]]
         unknown = sorted(set(names) - set(LAYER_TYPES))
         if unknown or len(names) < d["num_hidden_layers"]:

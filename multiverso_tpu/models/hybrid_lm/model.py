@@ -21,7 +21,12 @@ configuration gives ``expert_bias_update_rate``:
 :func:`updated_expert_bias`). A layer of two
 blocks is two letters. Under muP (``scale_emb``, ``scale_depth``,
 ``dim_model_base``) the embedding, every residual branch and the head's input
-are scaled by constants. Then a final RMSNorm and the head: an untied dense
+are scaled by constants. Where the configuration says so (``post_norm``) a
+block norms its mixer's output too, ``u + RMSNorm_w2(mixer(RMSNorm_w1(u)))``,
+and (``total_ut_steps`` > 1) the stack and the final norm run several times
+over the SAME leaves, a learned exit gate weighing each pass's loss
+(:func:`looped_hidden`, :func:`exit_distribution`). Then a final RMSNorm and
+the head: an untied dense
 leaf, or (``tie_word_embeddings``) the embedding table's own rows, the same
 array that embedded the input; the loss
 is next-token cross-entropy over the vocabulary slice (with
@@ -91,7 +96,8 @@ from multiverso_tpu.telemetry import counter, gauge, span
 from multiverso_tpu.utils.log import check
 
 __all__ = ["HybridLM", "init_params", "init_buffers", "rmsnorm",
-           "forward_hidden", "make_loss", "dense_param_count",
+           "forward_hidden", "looped_hidden", "exit_distribution",
+           "make_loss", "dense_param_count",
            "pack_batch", "updated_expert_bias", "DELTA_PROGRAM",
            "APPLY_PROGRAM"]
 
@@ -103,6 +109,15 @@ APPLY_PROGRAM = "lm_apply"
 
 # -- parameters ---------------------------------------------------------------
 def _layer_shapes(cfg: HybridLMConfig, kind: str) -> Dict[str, tuple]:
+    """A block's leaves; with ``cfg.post_norm`` the norm of its mixer's
+    output beside them."""
+    shapes = _mixer_shapes(cfg, kind)
+    if cfg.post_norm:
+        shapes["post_norm"] = (cfg.hidden_size,)
+    return shapes
+
+
+def _mixer_shapes(cfg: HybridLMConfig, kind: str) -> Dict[str, tuple]:
     d = cfg.hidden_size
     if kind == MAMBA:
         h = cfg.mamba_num_heads
@@ -158,9 +173,12 @@ def _layer_shapes(cfg: HybridLMConfig, kind: str) -> Dict[str, tuple]:
 
 
 def param_shapes(cfg: HybridLMConfig) -> dict:
-    """A tied head has no leaf: it is the embedding table's rows."""
+    """A tied head has no leaf: it is the embedding table's rows. A looped
+    stack has its exit gate, one linear unit with a bias."""
     shapes = {"layers": [_layer_shapes(cfg, k) for k in cfg.pattern],
               "final_norm": (cfg.hidden_size,)}
+    if cfg.total_ut_steps > 1:
+        shapes.update(exit_gate_w=(cfg.hidden_size,), exit_gate_b=(1,))
     if not cfg.tie_word_embeddings:
         shapes["head"] = (cfg.hidden_size,
                           cfg.num_pred_heads * cfg.vocab_size)
@@ -177,14 +195,16 @@ def dense_param_count(cfg: HybridLMConfig) -> int:
 #: branches themselves are (``scale_depth``).
 _OUT_PROJECTIONS = ("out_proj", "wo", "w_down", "s_down", "ffn_down")
 _NORMS = ("norm", "gnorm", "kv_norm", "q_norm", "k_norm", "o_norm",
-          "final_norm")
+          "post_norm", "final_norm")
 
 
 def init_params(cfg: HybridLMConfig) -> dict:
     """Deterministic from ``cfg.seed`` (so ``ps`` and ``local`` start
     bitwise alike): matrices normal ``init_std``, projections back into
     the stream divided by ``sqrt(layers)``, norms and ``D`` one (a norm
-    with the unit offset: zero), a convolution's taps uniform on +-0.5 (the
+    with the unit offset: zero), the exit gate zero (the exit distribution
+    starts at 1/2, 1/4, .., the last pass taking the rest), a convolution's
+    taps uniform on +-0.5 (the
     short convolution's as Mamba-2's), ``A`` in [1, 16], ``dt`` log-uniform in
     ``[time_step_min, time_step_max]`` through the inverse softplus, as
     Mamba-2 draws them; EVA's ``phi`` and ``mu`` normal, clamped to [-1, 1],
@@ -209,7 +229,7 @@ def init_params(cfg: HybridLMConfig) -> dict:
             return (dt + np.log(-np.expm1(-dt))).astype(np.float32)
         if name == "conv_w":
             return rng.uniform(-0.5, 0.5, shape).astype(np.float32)
-        if name == "conv_b":
+        if name in ("conv_b", "exit_gate_w", "exit_gate_b"):
             return np.zeros(shape, np.float32)
         w = rng.standard_normal(shape, dtype=np.float32) * cfg.init_std
         return w / depth if name in _OUT_PROJECTIONS else w
@@ -305,7 +325,9 @@ def layer_forward(kind: str, p: dict, bias, u: jax.Array,
     or None), and third, for an expert block of a configuration that
     weighs one, its balance loss; the mixer's output times
     ``cfg.residual_scale`` where the configuration scales its residual
-    branches. A sparse block's second result is what it chose, ``{"chosen":
+    branches, and normed by the block's ``post_norm`` leaf where it has one
+    (``cfg.post_norm``). A sparse block's second result is what it chose,
+    ``{"chosen":
     [B, K, S, key blocks] bool or None, "pairs": int32 [B]}``
     (:func:`~.attention.sparse_attention`); a Lightning block's ``bias`` is
     its heads' decay. With ``remat`` the block is rematerialised
@@ -334,6 +356,8 @@ def layer_forward(kind: str, p: dict, bias, u: jax.Array,
             y, *chose = mixer(p, n, cfg) if kind == SPARSE \
                 else (mixer(p, n, cfg),)
             y = y[0] if scale == 1.0 else scale * y[0]
+            if cfg.post_norm:
+                y = rmsnorm(y, p["post_norm"], cfg.norm_eps, offset)
             return (seq + y, *chose) if chose else seq + y
 
         with jax.named_scope(scope):
@@ -363,6 +387,9 @@ def layer_forward(kind: str, p: dict, bias, u: jax.Array,
             (cfg.aux_loss_alpha, bsz) if cfg.balanced else None,
             cfg.expert_bias_update_rate > 0, moe_rows_interpret)
         y = y if cfg.residual_scale == 1.0 else cfg.residual_scale * y
+        if cfg.post_norm:
+            y = rmsnorm(y, p["post_norm"], cfg.norm_eps,
+                        cfg.norm_add_unit_offset)
         return (u + y.reshape(u.shape), counts, *more)
 
     with jax.named_scope("lm_experts"):
@@ -402,6 +429,93 @@ def forward_hidden(params: dict, buffers: list, u: jax.Array,
             + ((chose,) if SPARSE in cfg.pattern else ()))
 
 
+def looped_hidden(params: dict, buffers: list, u: jax.Array,
+                  cfg: HybridLMConfig, remat: bool = True,
+                  scan_interpret: Optional[bool] = None) -> jax.Array:
+    """The block stack AND the final norm, ``cfg.total_ut_steps`` times over
+    the same leaves: ``u`` [B, S, hidden] -> [passes, B, S, hidden], pass
+    ``t``'s normed output ``h_t``, which is pass ``t + 1``'s input and what
+    the head and the exit gate read. The passes are ONE rolled ``scan`` whose
+    body is the stack (the leaves loop-invariant): the program holds the
+    blocks once, the backward pass is a scan too, and a leaf's gradient, the
+    sum over its uses, accumulates in that scan's carry. With ``remat`` a
+    pass keeps each block's [B, S, hidden] input, as one walk does."""
+    scan_plane = {} if scan_interpret is None else {
+        "scan_interpret": scan_interpret}
+
+    def one_pass(v, _):
+        for i, kind in enumerate(cfg.pattern):
+            v, _ = layer_forward(kind, params["layers"][i], buffers[i], v,
+                                 cfg, remat, **scan_plane)
+        with jax.named_scope("lm_loop_norm"):
+            v = rmsnorm(v, params["final_norm"], cfg.norm_eps,
+                        cfg.norm_add_unit_offset)
+        return v, v
+
+    with jax.named_scope("lm_loop"):
+        return jax.lax.scan(one_pass, u, None,
+                            length=cfg.total_ut_steps)[1]
+
+
+def exit_distribution(gate_logits: jax.Array) -> jax.Array:
+    """``g`` [passes, ...] -> ``p`` [passes, ...], the probability of leaving
+    after each pass: with ``lambda_t = sigmoid(g_t)``, ``p_1 = lambda_1``,
+    ``p_t = lambda_t prod_{s<t} (1 - lambda_s)`` and the LAST pass takes what
+    is left, ``p_T = prod_{s<T} (1 - lambda_s)`` (``lambda_T`` is unused):
+    the ``p_t`` sum to one."""
+    leave = jax.nn.sigmoid(gate_logits[:-1])
+    reached = jnp.cumprod(1.0 - leave, axis=0)
+    ones = jnp.ones_like(gate_logits[:1])
+    return jnp.concatenate([ones, reached]) * jnp.concatenate([leave, ones])
+
+
+def exit_weighted_cross_entropy(h: jax.Array, head: jax.Array,
+                                targets: jax.Array, weights: jax.Array,
+                                mask: jax.Array, block: int):
+    """``h`` [passes, T, hidden] (normed), ``weights`` [passes, T], ``mask``
+    [T] -> (``sum_i sum_t weights[t, i] l_t(i)``, [passes] ``sum_i mask[i]
+    l_t(i)``) with ``l_t(i) = -log softmax(h_t(i) W_head)[target_i]``: in
+    blocks of ``block`` positions and inside a block one pass after the
+    other (:func:`_position_losses`, a pass's logits recomputed in the
+    backward pass), so that at most ONE [block, vocabulary] block of logits
+    is alive."""
+    passes, t, _ = h.shape
+    blk = min(block, t)
+    pad = (-t) % blk
+    if pad:
+        h = jnp.pad(h, ((0, 0), (0, pad), (0, 0)))
+        weights = jnp.pad(weights, ((0, 0), (0, pad)))
+        targets, mask = jnp.pad(targets, (0, pad)), jnp.pad(mask, (0, pad))
+    nb = (t + pad) // blk
+    one_pass = jax.checkpoint(
+        lambda hb, tb: _position_losses(hb, head, tb, ()))
+
+    def add(total, xs):
+        hb, tb, wb, mb = xs
+        losses = jax.lax.map(lambda x: one_pass(x, tb), hb)    # [passes, blk]
+        return (total[0] + jnp.sum(losses * wb),
+                total[1] + jnp.sum(losses * mb, axis=1)), None
+
+    total, _ = jax.lax.scan(
+        add, (jnp.zeros((), jnp.float32), jnp.zeros(passes, jnp.float32)),
+        (jnp.moveaxis(h.reshape(passes, nb, blk, -1), 1, 0),
+         targets.reshape(nb, blk),
+         jnp.moveaxis(weights.reshape(passes, nb, blk), 1, 0),
+         mask.reshape(nb, blk)))
+    return total
+
+
+def _position_losses(n: jax.Array, head: jax.Array, targets: jax.Array,
+                     heads: tuple) -> jax.Array:
+    """``-log softmax(n W_head)[target]`` a position (and prediction head):
+    ``n`` [blk, hidden] normed, ``targets`` [blk, *heads] -> [blk, *heads],
+    the logits in float32."""
+    logits = (n @ head).astype(jnp.float32).reshape(
+        (n.shape[0],) + heads + (-1,))
+    picked = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
+    return jax.nn.logsumexp(logits, axis=-1) - picked
+
+
 def blocked_cross_entropy(u: jax.Array, norm_w: jax.Array, head: jax.Array,
                           targets: jax.Array, mask: jax.Array, eps: float,
                           block: int, unit_offset: bool = False,
@@ -427,11 +541,7 @@ def blocked_cross_entropy(u: jax.Array, norm_w: jax.Array, head: jax.Array,
     def block_loss(ub, tb, mb):
         n = rmsnorm(ub, norm_w, eps, unit_offset)
         n = n if divisor == 1.0 else n / divisor
-        logits = (n @ head).astype(jnp.float32).reshape(
-            (blk,) + heads + (-1,))
-        picked = jnp.take_along_axis(logits, tb[..., None], axis=-1)[..., 0]
-        return jnp.sum((jax.nn.logsumexp(logits, axis=-1) - picked) * mb,
-                       axis=0)
+        return jnp.sum(_position_losses(n, head, tb, heads) * mb, axis=0)
 
     def add(total, xs):
         return total + block_loss(*xs), None
@@ -458,7 +568,11 @@ def make_loss(cfg: HybridLMConfig, remat: bool = True,
     it and the second result is ``(counts, balance loss)``; with
     ``num_pred_heads`` > 1 (``targets``, ``mask`` [B, S, heads]) each
     head's own loss [heads] comes next in it, and last what the sparse
-    blocks chose (:func:`forward_hidden`)."""
+    blocks chose (:func:`forward_hidden`). A looped stack
+    (``total_ut_steps`` > 1) has the loss of :func:`_make_looped_loss`."""
+    if cfg.total_ut_steps > 1:
+        return _make_looped_loss(cfg, remat, scan_interpret)
+
     def loss_fn(params, rows, buffers, where, targets, mask):
         with jax.named_scope("lm_embed"):
             u = jnp.take(rows, where, axis=0)
@@ -480,6 +594,47 @@ def make_loss(cfg: HybridLMConfig, remat: bool = True,
         aux += tuple(more)
         loss = loss + balance[0] if balance else loss
         return loss, aux if len(aux) > 1 else counts
+
+    return loss_fn
+
+
+def _make_looped_loss(cfg: HybridLMConfig, remat: bool,
+                      scan_interpret: Optional[bool]):
+    """:func:`make_loss` of a looped stack: the mean over the unmasked
+    positions of ``sum_t p_t(i) l_t(i) - beta H(p(i))``, ``l_t`` pass ``t``'s
+    cross-entropy from ``h_t`` (:func:`looped_hidden`: no second norm before
+    the head), ``p`` the exit distribution of the gate's ``g_t(i) = w_g .
+    h_t(i) + b_g`` (float32 at ``highest``, as the routers are), ``H(p) =
+    -sum_t p_t log(max(p_t, 1e-20))`` and ``beta`` =
+    ``cfg.exit_entropy_weight``. Nothing is stopped: the gradient runs
+    through ``p`` into the gate and on into every ``h_t``, and through every
+    ``l_t``. The second result is ``(counts [0, held], {"pass_loss": [passes]
+    each pass's own mean cross-entropy, "exit_mass": [passes] the mean of
+    ``p_t``, "exit_entropy": the mean of ``H``})``."""
+    def loss_fn(params, rows, buffers, where, targets, mask):
+        with jax.named_scope("lm_embed"):
+            u = jnp.take(rows, where, axis=0)
+            u = u if cfg.scale_emb == 1.0 else cfg.scale_emb * u
+        h = looped_hidden(params, buffers, u, cfg, remat, scan_interpret)
+        h = h.reshape(cfg.total_ut_steps, -1, cfg.hidden_size)
+        mask = mask.reshape(-1)
+        count = jnp.maximum(jnp.sum(mask), 1.0)
+        with jax.named_scope("lm_exit_gate"):
+            gate = jnp.einsum(
+                "tnd,d->tn", h.astype(jnp.float32), params["exit_gate_w"],
+                precision=jax.lax.Precision.HIGHEST) + params["exit_gate_b"]
+            p = exit_distribution(gate)
+            entropy = -jnp.sum(p * jnp.log(jnp.maximum(p, 1e-20)), axis=0)
+        with jax.named_scope("lm_head_loss"):
+            weighed, per_pass = exit_weighted_cross_entropy(
+                h, rows.T if cfg.tie_word_embeddings else params["head"],
+                targets.reshape(-1), p * mask, mask, cfg.loss_block)
+        entropy = jnp.sum(entropy * mask) / count
+        loss = weighed / count - cfg.exit_entropy_weight * entropy
+        return loss, (jnp.zeros((0, len(cfg.held)), jnp.int32), {
+            "pass_loss": per_pass / count,
+            "exit_mass": jnp.sum(p * mask, axis=1) / count,
+            "exit_entropy": entropy})
 
     return loss_fn
 
@@ -587,6 +742,10 @@ class HybridLM:
                                      len(cfg.held)), np.int64)
         #: Each prediction head's own loss in the last step.
         self.last_head_losses = np.zeros(cfg.num_pred_heads, np.float32)
+        #: A looped stack's last step: each pass's own mean cross-entropy
+        #: and mean exit mass, [passes].
+        self.last_pass_losses = np.zeros(cfg.total_ut_steps, np.float32)
+        self.last_exit_mass = np.zeros(cfg.total_ut_steps, np.float32)
         #: Per sparse block what the last step's queries chose, on the
         #: device: [B, K, S, key blocks] bool, or None where the sequences
         #: were short enough to be attended densely.
@@ -657,8 +816,10 @@ class HybridLM:
             loss, aux = self._hybrid(ids, self.buffers, where, targets,
                                      mask, rows=distinct)
             # make_loss's order: counts, the balance term, the heads' losses,
-            # the sparse blocks' choices
+            # the sparse blocks' choices; a looped stack's: counts, its
+            # passes' numbers
             counts, *extra = aux if isinstance(aux, tuple) else (aux,)
+            loop = extra.pop() if self.cfg.total_ut_steps > 1 else None
             balance = extra.pop(0) if self.cfg.balanced else None
             heads = extra.pop(0) if self.cfg.num_pred_heads > 1 else None
             every = extra.pop(0) if self.cfg.expert_bias_update_rate \
@@ -667,10 +828,12 @@ class HybridLM:
             # What the host reads comes down in one copy; what the queries
             # chose stays on the device.
             self.last_sparse_chosen = [c["chosen"] for c in chose]
-            loss, counts, balance, heads, every, pairs = jax.device_get(
-                (loss, counts, balance, heads, every,
-                 [c["pairs"] for c in chose]))
+            loss, counts, balance, heads, every, pairs, loop = \
+                jax.device_get((loss, counts, balance, heads, every,
+                                [c["pairs"] for c in chose], loop))
             loss = float(loss)
+            if loop is not None:
+                self._gauge_loop(loop)
             if balance is not None:
                 gauge("lm.moe.balance_loss").set(float(balance))
             if heads is not None:
@@ -686,6 +849,20 @@ class HybridLM:
                     [int(np.asarray(p, np.int64).sum()) for p in pairs])
         return loss
 
+    def _gauge_loop(self, loop: dict) -> None:
+        """A looped step's own numbers, from the step's one copy."""
+        self.last_pass_losses = loop["pass_loss"]
+        self.last_exit_mass = loop["exit_mass"]
+        gauge("lm.loop.exit_entropy").set(float(loop["exit_entropy"]))
+        for t in range(self.cfg.total_ut_steps):
+            # One pair a pass of the configuration: bounded.
+            # graftlint: disable=unbounded-metric-name
+            gauge(f"lm.loop.pass_loss.t{t + 1}").set(
+                float(loop["pass_loss"][t]))
+            # graftlint: disable=unbounded-metric-name
+            gauge(f"lm.loop.exit_mass.t{t + 1}").set(
+                float(loop["exit_mass"][t]))
+
     def _count(self, tokens: np.ndarray, distinct: int,
                sparse_pairs: list = ()) -> None:
         counter("lm.tokens").inc(int(tokens.size))
@@ -693,22 +870,28 @@ class HybridLM:
         counter("lm.rows_pulled").inc(int(distinct))
         if self.cfg.tie_word_embeddings:
             counter("lm.head.tied").inc()
-        # Causal query-key pairs, summed over the attention blocks; an EVA
+        # Causal query-key pairs, summed over the attention blocks' RUNS (a
+        # looped stack runs each ``total_ut_steps`` times); an EVA
         # block's are those inside its windows, and beside them every
         # (query, summary of a chunk of an earlier window).
         seqs, length = tokens.shape
         cfg = self.cfg
-        pairs = cfg.attention_blocks() * seqs * length * (length + 1) // 2
+        passes = cfg.total_ut_steps
+        if passes > 1:
+            counter("lm.loop.passes").inc(passes)
+            counter("lm.loop.block_runs").inc(passes * len(cfg.pattern))
+        pairs = passes * cfg.attention_blocks() * seqs * length \
+            * (length + 1) // 2
         if cfg.eva_blocks():
             window, chunk = cfg.window_size, cfg.eva_chunk_size
             whole, rest = divmod(length, window)
-            pairs += cfg.eva_blocks() * seqs * (
+            pairs += passes * cfg.eva_blocks() * seqs * (
                 whole * window * (window + 1) // 2 + rest * (rest + 1) // 2)
             counter("lm.eva.summary_pairs").inc(
-                cfg.eva_blocks() * seqs * (window // chunk) * (
+                passes * cfg.eva_blocks() * seqs * (window // chunk) * (
                     window * whole * (whole - 1) // 2 + rest * whole))
             counter("lm.eva.chunks").inc(
-                cfg.eva_blocks() * seqs * (-(-length // chunk))
+                passes * cfg.eva_blocks() * seqs * (-(-length // chunk))
                 * (length > window))
         counter("lm.attn.pairs").inc(pairs)
         if sparse_pairs:

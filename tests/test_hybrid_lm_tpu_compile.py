@@ -107,6 +107,7 @@ def test_mixer_compiles_for_v5e_at_published_widths(one_chip, cfg, kind,
 DSV2, EVABYTE = "deepseek-v2-lite-ep4", "evabyte-6.5b-pp8"
 SALA = "minicpm-sala-9b-pp8"
 LFM2 = "lfm2-8b-a1b-ep4"
+OURO = "ouro-2.6b-pp8"
 
 
 # Two-block layers, forward + backward: (configuration, letter, its index in
@@ -363,6 +364,52 @@ def test_lfm2_step_compiles_for_v5e_inside_a_chips_memory(one_chip):
     assert reserved < 15.75 * 2 ** 30 - 0.5e9, (reserved, stats)
     assert {"lm_embed", "lm_head_loss", "lm_shortconv", "lm_attention",
             "lm_dense_ffn", "lm_experts"} <= _scopes_of(compiled)
+
+
+def test_ouro_step_compiles_for_v5e_inside_a_chips_memory(one_chip):
+    """``ouro_train``'s whole loss-and-gradient at the cell's size (409 M
+    dense parameters, one sequence of 8,192 tokens, the six layers run four
+    times in ONE rolled loop, four passes' logits over 49,152 ids): what has
+    to stay under the 15.75 GiB a v5e chip reports is parameters + gradients
+    + accumulators + the table's rows and accumulator + the program's
+    temporaries (7.8 GB here, of which the 12 x 4 saved block inputs are
+    3.2). The blocks lie inside the loop's scope, the gate and the head
+    outside it, and the program holds the stack once: it is a ``while``, not
+    four copies."""
+    from multiverso_tpu.models.hybrid_lm import dense_param_count, make_loss
+    cfg = HybridLMConfig.from_file(os.path.join(
+        ROOT, "benchmark", "configs", OURO + ".json"))
+    assert cfg.total_ut_steps == 4 and cfg.pattern == "*D" * 6
+
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        spec, param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple))
+    compiled = jax.jit(jax.value_and_grad(
+        make_loss(cfg), argnums=(0, 1), has_aux=True)).lower(
+            params, spec((5 * cfg.row_bucket, cfg.hidden_size)),
+            [None] * len(cfg.pattern), spec((1, SEQ), jnp.int32),
+            spec((1, SEQ), jnp.int32), spec((1, SEQ))).compile()
+    stats = compiled.memory_analysis()
+    plane = 4 * dense_param_count(cfg)
+    table = 2 * 4 * cfg.vocab_size * cfg.hidden_size
+    assert plane == 4 * 408_997_889
+    assert stats.argument_size_in_bytes > plane
+    assert stats.output_size_in_bytes > plane
+    assert 48 * SEQ * cfg.hidden_size * 4 < stats.temp_size_in_bytes < 8.5e9
+    reserved = (stats.argument_size_in_bytes + stats.output_size_in_bytes
+                + stats.temp_size_in_bytes + plane + table)
+    assert reserved < 15.75 * 2 ** 30 - 0.5e9, (reserved, stats)
+    text = compiled.as_text()
+    paths = parse_scopes(text)[1].values()
+    assert {"lm_embed", "lm_loop", "lm_loop_norm", "lm_exit_gate",
+            "lm_head_loss", "lm_attention", "lm_dense_ffn"} <= {
+                name for path in paths for name in scope_names(path)}
+    assert all("lm_loop" in scope_names(path) for path in paths
+               if "lm_attention" in scope_names(path))
+    # rolled: the twelve blocks once in the forward and once in the backward
+    assert " while(" in text
 
 
 def test_nemotron_step_on_the_row_kernel_keeps_its_program_small(one_chip,
