@@ -7,8 +7,14 @@ key blocks it sees (the merge of ``parallel/sequence.py``), and saves the
 output and the log-normaliser; the backward walks the same block pairs
 again, recomputing each block's probabilities from the saved log-normaliser.
 Blocks a query block does not see are never visited.
-``ops/pallas_attention.flash_block_attn`` has no backward; this is plain
-``jax.numpy`` under ``jax.custom_vjp``, which XLA compiles for the device.
+The walk has two planes under ONE ``jax.custom_vjp`` that saves ``(q, k, v,
+out, lse)``: plain ``jax.numpy``, which XLA compiles for the device and which
+serves every shape; and, for the plain causal call of float32 arrays on one
+device whose heads fill whole lane tiles (:func:`~multiverso_tpu.ops.
+pallas_causal_attention.attention_kernel_selected`), the Pallas forward and
+backward of ``ops/pallas_causal_attention.py``, which keep a pair of tiles'
+scores and the gradient accumulators in VMEM. Both run under the scope
+``lm_attn_pairs``.
 The values may be narrower or wider than the queries and keys, and the
 caller may give the softmax scale: latent attention (:func:`latent_attention_
 mixer`) has 192-wide rotary-carrying keys against 128-wide values.
@@ -30,12 +36,14 @@ window's queries.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
 from multiverso_tpu.models.hybrid_lm import rope
 from multiverso_tpu.models.hybrid_lm.norm import rmsnorm
+from multiverso_tpu.ops import pallas_causal_attention
 
 __all__ = ["causal_gqa", "attention_mixer", "latent_attention_mixer",
            "eva_attention", "eva_summaries", "eva_mixer", "in_blocks",
@@ -167,20 +175,46 @@ def _backward(q, k, v, remote, chosen, out, lse, dout, scale, blk, span):
             None)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def _blocked_attention(q, k, v, remote, chosen, scale, blk, span):
-    return _forward(q, k, v, remote, chosen, scale, blk, span)[0]
+def _fused(q, k, v, remote, chosen, blk, interpret) -> bool:
+    """Whether the call is the kernels' (:mod:`multiverso_tpu.ops.
+    pallas_causal_attention`): ``interpret`` is None unless the caller knows
+    the arrays to live on one device."""
+    selected = pallas_causal_attention.attention_kernel_selected
+    return interpret is not None and selected(
+        q.shape[1] * blk, blk, *q.shape[3:], v.shape[-1], q.dtype, k.dtype,
+        v.dtype, remote=remote, chosen=chosen)
 
 
-def _vjp_fwd(q, k, v, remote, chosen, scale, blk, span):
-    out, lse = _forward(q, k, v, remote, chosen, scale, blk, span)
+def _walk(q, k, v, remote, chosen, scale, blk, span, interpret):
+    """(out, lse) on the plane the call's arrays choose; ``lse`` is that
+    plane's own, for its backward."""
+    with jax.named_scope("lm_attn_pairs"):
+        if _fused(q, k, v, remote, chosen, blk, interpret):
+            return pallas_causal_attention.forward(q, k, v, scale, blk, span,
+                                                   interpret)
+        return _forward(q, k, v, remote, chosen, scale, blk, span)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _blocked_attention(q, k, v, remote, chosen, scale, blk, span,
+                       interpret=None):
+    return _walk(q, k, v, remote, chosen, scale, blk, span, interpret)[0]
+
+
+def _vjp_fwd(q, k, v, remote, chosen, scale, blk, span, interpret):
+    out, lse = _walk(q, k, v, remote, chosen, scale, blk, span, interpret)
     return out, (q, k, v, remote, chosen, out, lse)
 
 
-def _vjp_bwd(scale, blk, span, saved, dout):
+def _vjp_bwd(scale, blk, span, interpret, saved, dout):
     q, k, v, remote, chosen, out, lse = saved
-    return _backward(q, k, v, remote, chosen, out, lse, dout, scale, blk,
-                     span)
+    with jax.named_scope("lm_attn_pairs"):
+        if _fused(q, k, v, remote, chosen, blk, interpret):
+            return pallas_causal_attention.backward(
+                q, k, v, out, lse, dout, scale, blk, span, interpret) + (
+                    None, None)
+        return _backward(q, k, v, remote, chosen, out, lse, dout, scale,
+                         blk, span)
 
 
 _blocked_attention.defvjp(_vjp_fwd, _vjp_bwd)
@@ -195,17 +229,23 @@ def in_blocks(x, blk):
 
 
 def causal_gqa(q: jax.Array, k: jax.Array, v: jax.Array, block: int,
-               scale: float = None) -> jax.Array:
+               scale: float = None,
+               interpret: Optional[bool] = None) -> jax.Array:
     """``q`` [B, S, K, G, D] (G query heads share each of K key-value
     heads), ``k`` [B, S, K, D], ``v`` [B, S, K, Dv] -> [B, S, K, G, Dv];
     softmax over the keys at or before each query, scale ``D ** -0.5``
     unless given. Any S: padded keys lie after every real query, padded
-    queries are cut away."""
+    queries are cut away. ``interpret``: None unless the caller knows the
+    arrays to live on one device, then :func:`multiverso_tpu.ops.
+    pallas_interpret` of it: shapes the kernels take (:func:`~multiverso_tpu.
+    ops.pallas_causal_attention.attention_kernel_selected`) then run as
+    those, a pair of tiles' scores in VMEM."""
     bsz, s, kh, g, d = q.shape
     blk = min(block, s)
     out = _blocked_attention(
         in_blocks(q, blk), in_blocks(k, blk), in_blocks(v, blk), None, None,
-        float(d) ** -0.5 if scale is None else float(scale), blk, None)
+        float(d) ** -0.5 if scale is None else float(scale), blk, None,
+        interpret)
     return out.reshape(bsz, -1, kh, g, v.shape[-1])[:, :s]
 
 
@@ -395,14 +435,16 @@ def sparse_mixer(p: dict, n: jax.Array, cfg):
     return o @ p["wo"], chosen, pairs
 
 
-def attention_mixer(p: dict, n: jax.Array, cfg) -> jax.Array:
+def attention_mixer(p: dict, n: jax.Array, cfg,
+                    attn_interpret: Optional[bool] = None) -> jax.Array:
     """Causal grouped-query attention, no bias. As the configuration says:
     with ``attn_qk_norm`` an RMSNorm over each query and key head (one weight
     vector of ``head_dim`` each, shared by the heads); with ``attn_rope`` both
     then turned over the whole head in the half layout (``rotate_half``),
     plain ``rope_theta``, positions from the start of the packed sequence.
     With neither the block has no positions (the published ``nemotron_h`` code
-    applies none) and is the function it was before the switches."""
+    applies none) and is the function it was before the switches.
+    ``attn_interpret``: :func:`causal_gqa`'s ``interpret``."""
     bsz, s, _ = n.shape
     kh, hd = cfg.num_key_value_heads, cfg.head_dim
     g = cfg.num_attention_heads // kh
@@ -420,17 +462,20 @@ def attention_mixer(p: dict, n: jax.Array, cfg) -> jax.Array:
     q = heads("wq", "q_norm", (bsz, s, kh, g, hd))
     k = heads("wk", "k_norm", (bsz, s, kh, hd))
     v = (n @ p["wv"]).reshape(bsz, s, kh, cfg.head_dim)
-    o = causal_gqa(q, k, v, cfg.attn_block)
+    o = causal_gqa(q, k, v, cfg.attn_block, interpret=attn_interpret)
     return o.reshape(bsz, s, cfg.q_dim) @ p["wo"]
 
 
-def latent_attention_mixer(p: dict, n: jax.Array, cfg) -> jax.Array:
+def latent_attention_mixer(p: dict, n: jax.Array, cfg,
+                           attn_interpret: Optional[bool] = None
+                           ) -> jax.Array:
     """Multi-head latent attention in its training form: keys and values
     are expanded from a ``kv_lora_rank``-wide latent (RMSNorm'd), queries
     come straight from the input (no query latent). A head's query and key
     are ``[nope | rope]``; the rotary key is ONE vector a token, shared by
     all heads, and positions count from the start of the packed sequence.
-    The softmax scale carries YaRN's ``mscale ** 2`` (:mod:`.rope`)."""
+    The softmax scale carries YaRN's ``mscale ** 2`` (:mod:`.rope`).
+    ``attn_interpret``: :func:`causal_gqa`'s ``interpret``."""
     bsz, s, _ = n.shape
     h, nope, rot = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
                     cfg.qk_rope_head_dim)
@@ -445,5 +490,6 @@ def latent_attention_mixer(p: dict, n: jax.Array, cfg) -> jax.Array:
     k = jnp.concatenate(
         [kv[..., :nope], jnp.broadcast_to(k_rot, (bsz, s, h, rot))], axis=-1)
     o = causal_gqa(q[:, :, :, None, :], k, kv[..., nope:], cfg.attn_block,
-                   rope.softmax_scale(nope + rot, cfg.rope_scaling))
+                   rope.softmax_scale(nope + rot, cfg.rope_scaling),
+                   attn_interpret)
     return o.reshape(bsz, s, h * cfg.v_head_dim) @ p["wo"]

@@ -86,6 +86,8 @@ from multiverso_tpu.models.hybrid_lm.mamba2 import mamba2_mixer
 from multiverso_tpu.models.hybrid_lm.norm import rmsnorm
 from multiverso_tpu.models.hybrid_lm.shortconv import shortconv_mixer
 from multiverso_tpu.ops import pallas_interpret
+from multiverso_tpu.ops.pallas_causal_attention import \
+    attention_kernel_selected
 from multiverso_tpu.ops.pallas_ssd import scan_kernel_selected
 from multiverso_tpu.parallel.comm_policy import reduce_axis_size
 from multiverso_tpu.parallel.expert import (held_topk_moe,
@@ -317,10 +319,30 @@ def scan_kernel_blocks(cfg: HybridLMConfig) -> int:
     return sum(taken[kind] for kind in cfg.pattern if kind in _SCANS)
 
 
+def attn_kernel_blocks(cfg: HybridLMConfig, length: int) -> int:
+    """How many of the pattern's blocks attend sequences of ``length`` through
+    the attention kernels (:func:`~multiverso_tpu.ops.pallas_causal_attention.
+    attention_kernel_selected`): a ``*`` block's grouped heads, an ``L``
+    block's 192-wide keys against its values. EVA's remote keys and the
+    sparse block's mask stay on the ``jax.numpy`` walk at any length."""
+    blk = min(cfg.attn_block, length)
+    length = -(-length // blk) * blk
+    taken = {
+        ATTENTION: attention_kernel_selected(
+            length, blk, cfg.num_key_value_heads,
+            cfg.num_attention_heads // cfg.num_key_value_heads,
+            cfg.head_dim, cfg.head_dim, np.float32),
+        LATENT: attention_kernel_selected(
+            length, blk, cfg.num_attention_heads, 1,
+            cfg.qk_nope_head_dim + cfg.qk_rope_head_dim, cfg.v_head_dim,
+            np.float32)}
+    return sum(taken.get(kind, False) for kind in cfg.pattern)
+
+
 def layer_forward(kind: str, p: dict, bias, u: jax.Array,
                   cfg: HybridLMConfig, remat: bool = False,
                   moe_rows_interpret: Optional[bool] = None,
-                  scan_interpret: Optional[bool] = None):
+                  mixer_interpret: Optional[bool] = None):
     """One block: (``u + mixer(RMSNorm_w(u))``, assignments per held expert
     or None), and third, for an expert block of a configuration that
     weighs one, its balance loss; the mixer's output times
@@ -339,17 +361,20 @@ def layer_forward(kind: str, p: dict, bias, u: jax.Array,
     than ``cfg.ffn_slab`` into slabs of that many positions.
     ``moe_rows_interpret`` is an expert block's ``rows_interpret``
     (:func:`~multiverso_tpu.parallel.expert.held_topk_moe`) and
-    ``scan_interpret`` a Mamba-2 or Lightning block's scan's ``interpret``
-    (:func:`~.mamba2.ssd_chunked`): None unless the caller knows ``u`` to
-    live on one device."""
+    ``mixer_interpret`` a sequence mixer's kernels' ``interpret``, a Mamba-2
+    or Lightning block's scan's (:func:`~.mamba2.ssd_chunked`) and a ``*`` or
+    ``L`` block's attention's (:func:`~.attention.causal_gqa`): None unless
+    the caller knows ``u`` to live on one device."""
     keep = jax.checkpoint if remat else (lambda fn: fn)
     if kind in _SEQUENCE_MIXERS:
         mixer, scope = _SEQUENCE_MIXERS[kind]
         offset, scale = cfg.norm_add_unit_offset, cfg.residual_scale
         if kind == LIGHTNING:
             mixer = functools.partial(mixer, slopes=bias)
-        if kind in _SCANS and scan_interpret is not None:
-            mixer = functools.partial(mixer, scan_interpret=scan_interpret)
+        if mixer_interpret is not None and kind in _SCANS:
+            mixer = functools.partial(mixer, scan_interpret=mixer_interpret)
+        elif mixer_interpret is not None and kind in (ATTENTION, LATENT):
+            mixer = functools.partial(mixer, attn_interpret=mixer_interpret)
 
         def one_sequence(seq):
             n = rmsnorm(seq[None], p["norm"], cfg.norm_eps, offset)
@@ -399,7 +424,7 @@ def layer_forward(kind: str, p: dict, bias, u: jax.Array,
 def forward_hidden(params: dict, buffers: list, u: jax.Array,
                    cfg: HybridLMConfig, remat: bool = True,
                    moe_rows_interpret: Optional[bool] = None,
-                   scan_interpret: Optional[bool] = None):
+                   mixer_interpret: Optional[bool] = None):
     """The block stack over ``u`` [B, S, hidden] -> (hidden states before
     the final norm, [expert blocks, held] assignment counts), then, where
     the configuration weighs one, the summed balance loss, then, where it
@@ -410,11 +435,11 @@ def forward_hidden(params: dict, buffers: list, u: jax.Array,
     # Handed on only where set: a wrapper ``(kind, p, bias, u, cfg, remat)``
     # around ``layer_forward`` (the benchmark's controls) sees what it knows.
     rows_plane = () if moe_rows_interpret is None else (moe_rows_interpret,)
-    scan_plane = {} if scan_interpret is None else {
-        "scan_interpret": scan_interpret}
+    mixer_plane = {} if mixer_interpret is None else {
+        "mixer_interpret": mixer_interpret}
     for i, kind in enumerate(cfg.pattern):
         u, c, *b = layer_forward(kind, params["layers"][i], buffers[i], u,
-                                 cfg, remat, *rows_plane, **scan_plane)
+                                 cfg, remat, *rows_plane, **mixer_plane)
         if isinstance(c, dict):
             chose.append(c)
         elif c is not None:
@@ -431,7 +456,7 @@ def forward_hidden(params: dict, buffers: list, u: jax.Array,
 
 def looped_hidden(params: dict, buffers: list, u: jax.Array,
                   cfg: HybridLMConfig, remat: bool = True,
-                  scan_interpret: Optional[bool] = None) -> jax.Array:
+                  mixer_interpret: Optional[bool] = None) -> jax.Array:
     """The block stack AND the final norm, ``cfg.total_ut_steps`` times over
     the same leaves: ``u`` [B, S, hidden] -> [passes, B, S, hidden], pass
     ``t``'s normed output ``h_t``, which is pass ``t + 1``'s input and what
@@ -440,13 +465,13 @@ def looped_hidden(params: dict, buffers: list, u: jax.Array,
     blocks once, the backward pass is a scan too, and a leaf's gradient, the
     sum over its uses, accumulates in that scan's carry. With ``remat`` a
     pass keeps each block's [B, S, hidden] input, as one walk does."""
-    scan_plane = {} if scan_interpret is None else {
-        "scan_interpret": scan_interpret}
+    mixer_plane = {} if mixer_interpret is None else {
+        "mixer_interpret": mixer_interpret}
 
     def one_pass(v, _):
         for i, kind in enumerate(cfg.pattern):
             v, _ = layer_forward(kind, params["layers"][i], buffers[i], v,
-                                 cfg, remat, **scan_plane)
+                                 cfg, remat, **mixer_plane)
         with jax.named_scope("lm_loop_norm"):
             v = rmsnorm(v, params["final_norm"], cfg.norm_eps,
                         cfg.norm_add_unit_offset)
@@ -557,7 +582,7 @@ def blocked_cross_entropy(u: jax.Array, norm_w: jax.Array, head: jax.Array,
 
 def make_loss(cfg: HybridLMConfig, remat: bool = True,
               moe_rows_interpret: Optional[bool] = None,
-              scan_interpret: Optional[bool] = None):
+              mixer_interpret: Optional[bool] = None):
     """``(params, rows [n, hidden], buffers, where [B, S], targets [B, S],
     mask [B, S]) -> (loss, counts)``: ``rows[where]`` is the embedded
     input (``rows`` the pulled rows of the step's distinct ids). Under a tied
@@ -571,14 +596,14 @@ def make_loss(cfg: HybridLMConfig, remat: bool = True,
     blocks chose (:func:`forward_hidden`). A looped stack
     (``total_ut_steps`` > 1) has the loss of :func:`_make_looped_loss`."""
     if cfg.total_ut_steps > 1:
-        return _make_looped_loss(cfg, remat, scan_interpret)
+        return _make_looped_loss(cfg, remat, mixer_interpret)
 
     def loss_fn(params, rows, buffers, where, targets, mask):
         with jax.named_scope("lm_embed"):
             u = jnp.take(rows, where, axis=0)
             u = u if cfg.scale_emb == 1.0 else cfg.scale_emb * u
         u, counts, *more = forward_hidden(params, buffers, u, cfg, remat,
-                                          moe_rows_interpret, scan_interpret)
+                                          moe_rows_interpret, mixer_interpret)
         balance = [more.pop(0)] if cfg.balanced else []
         with jax.named_scope("lm_head_loss"):
             head = rows.T if cfg.tie_word_embeddings else params["head"]
@@ -599,7 +624,7 @@ def make_loss(cfg: HybridLMConfig, remat: bool = True,
 
 
 def _make_looped_loss(cfg: HybridLMConfig, remat: bool,
-                      scan_interpret: Optional[bool]):
+                      mixer_interpret: Optional[bool]):
     """:func:`make_loss` of a looped stack: the mean over the unmasked
     positions of ``sum_t p_t(i) l_t(i) - beta H(p(i))``, ``l_t`` pass ``t``'s
     cross-entropy from ``h_t`` (:func:`looped_hidden`: no second norm before
@@ -615,7 +640,7 @@ def _make_looped_loss(cfg: HybridLMConfig, remat: bool,
         with jax.named_scope("lm_embed"):
             u = jnp.take(rows, where, axis=0)
             u = u if cfg.scale_emb == 1.0 else cfg.scale_emb * u
-        h = looped_hidden(params, buffers, u, cfg, remat, scan_interpret)
+        h = looped_hidden(params, buffers, u, cfg, remat, mixer_interpret)
         h = h.reshape(cfg.total_ut_steps, -1, cfg.hidden_size)
         mask = mask.reshape(-1)
         count = jnp.maximum(jnp.sum(mask), 1.0)
@@ -707,13 +732,17 @@ class HybridLM:
             one_device
             and token_rows_kernel_selected(cfg.hidden_size, np.float32)
         ) else None
-        # The scan of the Mamba-2 and Lightning blocks likewise: the kernels
-        # that keep a chunk's decay planes in VMEM, for the blocks whose
-        # shapes they take. Counter ``lm.scan.plane.<plane>``.
-        self.scan_interpret = pallas_interpret(devices) if (
-            one_device and scan_kernel_blocks(cfg)) else None
+        # The sequence mixers likewise, for the blocks whose shapes the
+        # kernels take: the scan of the Mamba-2 and Lightning blocks (a
+        # chunk's decay planes in VMEM; counter ``lm.scan.plane.<plane>``) and
+        # the causal attention of the ``*`` and ``L`` blocks (a pair of
+        # tiles' scores and the gradient accumulators in VMEM; a call decides
+        # from its own length; counter ``lm.attn.plane.<plane>``).
+        self.mixer_interpret = pallas_interpret(devices) if one_device and (
+            scan_kernel_blocks(cfg)
+            or attn_kernel_blocks(cfg, cfg.attn_block)) else None
         loss_fn = make_loss(cfg, moe_rows_interpret=self.moe_rows_interpret,
-                            scan_interpret=self.scan_interpret)
+                            mixer_interpret=self.mixer_interpret)
         barrier = jax.lax.optimization_barrier
 
         def lm_delta_step(params, rows, buffers, where, targets, mask):
@@ -912,9 +941,16 @@ class HybridLM:
         counter("lm.moe.rows.plane.xla" if self.moe_rows_interpret is None
                 else "lm.moe.rows.plane.fused").inc(len(cfg.expert_layers()))
         scans = sum(kind in _SCANS for kind in cfg.pattern)
-        fused = scan_kernel_blocks(cfg) * (self.scan_interpret is not None)
+        fused = scan_kernel_blocks(cfg) * (self.mixer_interpret is not None)
         counter("lm.scan.plane.fused").inc(fused)
         counter("lm.scan.plane.xla").inc(scans - fused)
+        # attention block runs a step, by the plane their walk took
+        walks = passes * sum(kind in (ATTENTION, LATENT, EVA, SPARSE)
+                             for kind in cfg.pattern)
+        fused = passes * attn_kernel_blocks(cfg, length) * (
+            self.mixer_interpret is not None)
+        counter("lm.attn.plane.fused").inc(fused)
+        counter("lm.attn.plane.xla").inc(walks - fused)
         for layer, per_expert in zip(self.cfg.expert_layers(),
                                      self.last_counts):
             # One pair per expert layer of the pattern: bounded.

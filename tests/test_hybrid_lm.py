@@ -460,12 +460,12 @@ def test_step_counts_the_plane_its_scan_blocks_run_on(plane, monkeypatch):
         if plane == "fused" else {}
     cfg = small(pattern="M*M", **wide)
     model = HybridLM(cfg, mode="local")
-    assert model.scan_interpret == (True if plane == "fused" else None)
+    assert model.mixer_interpret == (True if plane == "fused" else None)
     monkeypatch.setattr(model_module, "scan_kernel_selected",
                         lambda *shape: False)
     twin = HybridLM(cfg, mode="local")
     monkeypatch.undo()
-    assert twin.scan_interpret is None
+    assert twin.mixer_interpret is None
     reg = get_registry()
     names = [f"lm.scan.plane.{p}" for p in ("fused", "xla")]
     batches = [batch(cfg, seed=11), batch(cfg, seed=12)]

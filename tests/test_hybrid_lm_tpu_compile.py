@@ -163,7 +163,7 @@ def test_two_block_layer_compiles_for_v5e_at_published_widths(
 # The scan of a Mamba-2 block (64 heads of 64 in 8 groups, one sequence of
 # 8,192) and of a Lightning block (16 heads of 128, a group a head, one of
 # 16,384), forward and backward, as HybridLM runs it on one chip
-# (``scan_interpret=False``: the kernels of ``ops/pallas_ssd.py``) and as it
+# (``mixer_interpret=False``: the kernels of ``ops/pallas_ssd.py``) and as it
 # runs anywhere else (the ``jax.numpy`` body, where the check below has to
 # find what it is there to miss).
 @pytest.mark.parametrize("plane", ["fused", "xla"])
@@ -188,7 +188,7 @@ def test_scan_block_compiles_for_v5e_with_its_decay_planes_in_vmem(
     u = spec((1, length, cfg.hidden_size))
     bias = init_buffers(cfg)[layer]
     bias = None if bias is None else spec(bias.shape)
-    scan = {"scan_interpret": False} if plane == "fused" else {}
+    scan = {"mixer_interpret": False} if plane == "fused" else {}
 
     def loss(p, bias, u):
         return jnp.sum(layer_forward(kind, p, bias, u, cfg, True, **scan)[0])
@@ -217,6 +217,86 @@ def test_scan_block_compiles_for_v5e_with_its_decay_planes_in_vmem(
     assert text.count('custom_call_target="tpu_custom_call"') == 3
     assert all("lm_ssd" in scope_names(path) for path in kernels)
     assert compiled.memory_analysis().temp_size_in_bytes < 2.2e9
+
+
+# The causal attention's forward and backward kernels alone at the four
+# causal cells' calls (a sequence at a time under ``lax.map``; sequences a
+# step, positions, key-value heads, group, key width, value width), or the
+# rule's refusal of the shape.
+@pytest.mark.parametrize("seqs,length,kv_heads,group,width,value_width,taken", [
+    (1, SEQ, 16, 1, 128, 128, True), (2, SEQ, 16, 1, 192, 128, True),
+    (2, SEQ, 2, 16, 128, 128, True), (4, SEQ, 8, 4, 64, 64, False)],
+    ids=["ouro_train", "dsv2lite_train", "nemotron_train", "lfm2_train"])
+def test_attention_kernels_compile_for_v5e_at_the_cells_shapes(
+        one_chip, seqs, length, kv_heads, group, width, value_width, taken):
+    from multiverso_tpu.ops.pallas_causal_attention import (
+        attention_kernel_selected, backward, forward)
+    blk = 512
+    assert attention_kernel_selected(length, blk, kv_heads, group, width,
+                                     value_width, np.float32) == taken
+    if not taken:
+        return
+
+    def spec(*shape):
+        return jax.ShapeDtypeStruct((1, length // blk, blk) + shape,
+                                    jnp.float32, sharding=one_chip)
+
+    q, k, v = (spec(kv_heads, group, width), spec(kv_heads, width),
+               spec(kv_heads, value_width))
+    plane = dict(scale=width ** -0.5, blk=blk, span=None, interpret=False)
+    walk = forward.lower(q, k, v, **plane).compile()
+    assert walk.as_text().count("tpu_custom_call") == 1
+    out, lse = (jax.ShapeDtypeStruct(t.shape, t.dtype, sharding=one_chip)
+                for t in jax.eval_shape(
+                    lambda *a: forward(*a, **plane), q, k, v))
+    pull = backward.lower(q, k, v, out, lse, out, **plane).compile()
+    assert pull.as_text().count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("config,kind,layer", [(OURO, "*", 0),
+                                               (DSV2, "L", 0)])
+def test_attention_block_compiles_for_v5e_with_its_pairs_in_vmem(
+        one_chip, config, kind, layer):
+    """An attention block as HybridLM runs it on one chip
+    (``mixer_interpret=False``): the remat's forward and the backward are the
+    only ``pallas_call``s, both under ``lm_attn_pairs``, and under that scope
+    nothing rewrites a slice of a carry (the ``jax.numpy`` walk's ``dq``)."""
+    cfg = HybridLMConfig.from_file(os.path.join(
+        ROOT, "benchmark", "configs", config + ".json"))
+    assert cfg.pattern[layer] == kind
+
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    p = {k: spec(s) for k, s in param_shapes(cfg)["layers"][layer].items()}
+    u = spec((1, SEQ, cfg.hidden_size))
+
+    def scoped(plane):
+        def loss(p, u):
+            return jnp.sum(layer_forward(kind, p, None, u, cfg, True,
+                                         **plane)[0])
+        text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            p, u).compile().as_text()
+        paths = parse_scopes(text)[1]
+        return text, {name: path for name, path in paths.items()
+                      if "lm_attn_pairs" in scope_names(path)}
+
+    def rewrites(pairs):
+        return [name for name, path in pairs.items()
+                if "dynamic-update-slice" in name
+                or "dynamic_update_slice" in path]
+
+    text, pairs = scoped({"mixer_interpret": False})
+    kernels = [path for path in parse_scopes(text)[1].values()
+               if "pallas_call" in path]
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert kernels and all("lm_attn_pairs" in scope_names(path)
+                           for path in kernels)
+    assert not rewrites(pairs), rewrites(pairs)
+    # the check finds on the walk what it is there to miss
+    text, pairs = scoped({})
+    assert "tpu_custom_call" not in text
+    assert rewrites(pairs)
 
 
 @pytest.mark.parametrize("tokens,planes", [(32768, 16), (16384, 21),
