@@ -5,12 +5,11 @@ Per head (``h`` is ``head_dim x state``)::
     h_t = exp(dt_t A) h_{t-1} + dt_t x_t B_t^T        y_t = h_t C_t + D x_t
 
 The program never steps through tokens. A sequence is cut into chunks of
-``chunk`` tokens; inside a chunk the outputs are one masked product
-``(C B^T o L) x`` with ``L[t, s] = exp(sum_{s < r <= t} dt_r A)``; across
-chunks a scan carries the ``head_dim x state`` state, each chunk adding
-what its own tokens leave behind. The reference
-(``benchmark/reference/nemotron3-nano-30b-a3b-ep16.py``) runs the
-recurrence as written.
+``chunk`` tokens; inside a chunk the outputs are one masked product ``(C B^T
+o L) x`` with ``L[t, s] = exp(sum_{s < r <= t} dt_r A)``; across chunks a
+scan carries the ``head_dim x state`` state, each chunk adding what its own
+tokens leave behind. The reference (``benchmark/reference/nemotron3-nano-30b-
+a3b-ep16.py``) runs the recurrence as written.
 """
 
 from __future__ import annotations
@@ -20,10 +19,11 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from multiverso_tpu.ops import pallas_mamba
 from multiverso_tpu.ops.pallas_ssd import scan_kernel_selected, ssd_scan
 
 __all__ = ["mamba2_mixer", "ssd_chunked", "causal_conv1d",
-           "gated_group_rmsnorm"]
+           "gated_group_rmsnorm", "passes_kernel_selected"]
 
 
 def causal_conv1d(x: jax.Array, w: jax.Array, b: jax.Array = None
@@ -162,26 +162,51 @@ def _ssd_xla(x, dt, a, b, c, chunk: int, group: int) -> jax.Array:
     return y.reshape(bsz, nc * chunk, h, p)[:, :s]
 
 
+def passes_kernel_selected(cfg, *dtypes) -> bool:
+    """Whether a Mamba-2 block of ``cfg`` takes its convolution and gated
+    norm through :mod:`multiverso_tpu.ops.pallas_mamba`, as far as its widths
+    and ``dtypes`` say."""
+    return pallas_mamba.mamba_passes_selected(
+        cfg.d_inner, cfg.n_groups * cfg.ssm_state_size,
+        cfg.d_inner // cfg.n_groups, cfg.conv_kernel, *dtypes)
+
+
 def mamba2_mixer(p: dict, n: jax.Array, cfg,
                  scan_interpret: Optional[bool] = None) -> jax.Array:
     """``n`` [B, S, hidden] (already normed) -> the mixer's output.
-    ``scan_interpret``: :func:`ssd_chunked`'s ``interpret``."""
+    ``scan_interpret``: :func:`ssd_chunked`'s ``interpret``, and the
+    ``interpret`` of the passes on either side of the scan: where it is not
+    None, widths :func:`passes_kernel_selected` accepts run the convolution,
+    ``silu`` and the cut into ``x | B | C``, and the gated norm, as the fused
+    passes of :mod:`multiverso_tpu.ops.pallas_mamba`, which read ``in_proj``'s
+    output where it lies; every other call the functions above."""
     bsz, s, _ = n.shape
     h, hp = cfg.mamba_num_heads, cfg.mamba_head_dim
     g, st = cfg.n_groups, cfg.ssm_state_size
     d_inner = cfg.d_inner
     zxbcdt = n @ p["in_proj"]
-    z = zxbcdt[..., :d_inner]
-    xbc = zxbcdt[..., d_inner:d_inner + cfg.conv_dim]
+    fused = scan_interpret is not None and passes_kernel_selected(
+        cfg, zxbcdt.dtype, p["conv_w"].dtype, p["conv_b"].dtype,
+        p["gnorm"].dtype)
+    if fused:
+        x, b, c = pallas_mamba.conv_silu_split(
+            zxbcdt, p["conv_w"], p["conv_b"], d_inner,
+            (d_inner, g * st, g * st), scan_interpret)
+    else:
+        z = zxbcdt[..., :d_inner]
+        xbc = zxbcdt[..., d_inner:d_inner + cfg.conv_dim]
+        xbc = jax.nn.silu(causal_conv1d(xbc, p["conv_w"], p["conv_b"]))
+        x = xbc[..., :d_inner]
+        b = xbc[..., d_inner:d_inner + g * st]
+        c = xbc[..., d_inner + g * st:]
     dt = zxbcdt[..., d_inner + cfg.conv_dim:]
-    xbc = jax.nn.silu(causal_conv1d(xbc, p["conv_w"], p["conv_b"]))
-    x = xbc[..., :d_inner].reshape(bsz, s, h, hp)
-    b = xbc[..., d_inner:d_inner + g * st].reshape(bsz, s, g, st)
-    c = xbc[..., d_inner + g * st:].reshape(bsz, s, g, st)
     dt = jax.nn.softplus(dt + p["dt_bias"])
     a = -jnp.exp(p["A_log"])
-    y = ssd_chunked(x, dt, a, b, c, cfg.chunk_size,
-                    interpret=scan_interpret, skip=p["D"])
-    y = gated_group_rmsnorm(y.reshape(bsz, s, d_inner), z, p["gnorm"], g,
-                            cfg.norm_eps)
+    y = ssd_chunked(x.reshape(bsz, s, h, hp), dt, a,
+                    b.reshape(bsz, s, g, st), c.reshape(bsz, s, g, st),
+                    cfg.chunk_size, interpret=scan_interpret, skip=p["D"])
+    y = y.reshape(bsz, s, d_inner)
+    y = pallas_mamba.gated_group_norm(
+        y, zxbcdt, p["gnorm"], g, cfg.norm_eps, scan_interpret) if fused \
+        else gated_group_rmsnorm(y, z, p["gnorm"], g, cfg.norm_eps)
     return y @ p["out_proj"]

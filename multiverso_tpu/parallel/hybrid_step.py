@@ -37,26 +37,26 @@ class HybridStep:
     ``delta_fn(dense[0], rows, *batch) -> (dense deltas, row deltas, *aux)``
     and ``apply_fn(*dense, merged deltas, *apply_args) -> new dense`` are the
     model's own NAMED functions (the profiler shows ``jit_<name>``), jitted
-    here. ``dense`` names the model's attributes the apply replaces, the
-    differentiated tree first: read at every call, because checkpoints and
-    benchmarks swap them. ``pull(ids, device)`` returns ``group``'s rows as
-    the delta program takes them; ``push(ids, row deltas)`` is the model's
-    closure over ITS ``_push_rows``, looked up when called (the benchmark's
-    control patches that method on the class). Whether the dense deltas
-    are merged is decided here, once, from the mesh: ``dense_sync`` is the
-    tree's one merge program under a ``dp_mesh`` whose ``dp_axis`` is larger
-    than 1, and None otherwise (counters ``hybrid.dense_merge.programs`` /
-    ``.elided``, one or the other a step). Spans: ``<prefix>.pull``,
-    ``.compute`` (``.dispatch`` and ``.sync`` inside it), ``.push``.
+    here, the first with ``delta_options`` as its compiler's options where
+    the model gives any. ``dense`` names the model's attributes the apply
+    replaces, the differentiated tree first: read at every call (checkpoints
+    and benchmarks swap them). ``pull(ids, device)`` returns ``group``'s rows
+    as the delta program takes them; ``push(ids, row deltas)`` closes over the
+    model's ``_push_rows``, looked up when called (a control patches it).
+    Whether the dense deltas are merged is decided here, once, from the mesh:
+    ``dense_sync`` is the tree's one merge program under a ``dp_mesh`` whose
+    ``dp_axis`` is larger than 1, and None otherwise (counters ``hybrid.
+    dense_merge.programs`` / ``.elided``, one or the other a step). Spans:
+    ``<prefix>.pull``, ``.compute`` (``.dispatch``, ``.sync``), ``.push``.
     """
 
     def __init__(self, model, delta_fn: Callable, apply_fn: Callable,
                  dense: Sequence[str], group, pull: Callable, push: Callable,
                  prefix: str, grad_bytes: int, apply_args: tuple = (),
-                 dp_mesh=None, dp_axis=None):
-        self.delta = jax.jit(delta_fn)  # graftlint: disable=missing-donation
-        self.apply = jax.jit(apply_fn,
-                             donate_argnums=tuple(range(len(dense))))
+                 dp_mesh=None, dp_axis=None, delta_options=None):
+        self.delta = jax.jit(  # graftlint: disable=missing-donation
+            delta_fn, compiler_options=delta_options)
+        self.apply = jax.jit(apply_fn, donate_argnums=tuple(range(len(dense))))
         # Launched once a step between the delta and apply programs, the
         # merged tree taking the unmerged one's buffers; None where the
         # mesh has no axis to reduce over, and then nothing is launched.

@@ -463,6 +463,9 @@ def test_step_counts_the_plane_its_scan_blocks_run_on(plane, monkeypatch):
     assert model.mixer_interpret == (True if plane == "fused" else None)
     monkeypatch.setattr(model_module, "scan_kernel_selected",
                         lambda *shape: False)
+    # (at these widths the passes round the scan are kernels too: below)
+    monkeypatch.setattr(model_module.mamba2, "passes_kernel_selected",
+                        lambda *shape: False)
     twin = HybridLM(cfg, mode="local")
     monkeypatch.undo()
     assert twin.mixer_interpret is None
@@ -486,6 +489,80 @@ def test_step_counts_the_plane_its_scan_blocks_run_on(plane, monkeypatch):
     assert ("pallas_call" in text) == (plane == "fused")
     for scope in ("lm_mamba2", "lm_ssd"):
         assert scope in text, scope
+
+
+# hidden 64; 2 heads of 64 in ONE group, state 128: ``x``, ``B``, ``C`` and
+# the norm's group one lane tile each, the chunk of 8 keeps the scan on its
+# ``jax.numpy`` body
+LANE_TILES = dict(mamba_head_dim=64, ssm_state_size=128)
+
+
+@pytest.mark.parametrize("plane", ["xla", "fused", "two_devices"])
+def test_step_counts_the_plane_its_mamba_blocks_passes_run_on(plane):
+    """``lm.mamba.plane.<fused/xla>``, one a step and Mamba-2 block: the
+    fused passes round the scan wherever ``d_inner``, ``groups x state`` and
+    the norm's group are whole 128-lane tiles, on the leaves' one device
+    (here under the interpreter); XLA's at the tiny widths of every other
+    test here, and under a mesh axis of two."""
+    cfg = small(pattern="M*M", **({} if plane == "xla" else LANE_TILES))
+    mesh = {}
+    if plane == "two_devices":
+        mesh = dict(dp_mesh=jax.sharding.Mesh(
+            np.asarray(jax.devices()[:2]), ("dp",)), dp_axis="dp")
+    model = HybridLM(cfg, mode="local", **mesh)
+    assert model.mixer_interpret == (True if plane == "fused" else None)
+    # the compiler is asked to share the blocks' code only where the passes
+    # are COMPILED kernels (a chip: ``mixer_interpret`` False)
+    assert model._delta_options() is None
+    model.mixer_interpret = False
+    assert (model._delta_options() is None) == (plane == "xla")
+    model.mixer_interpret = True if plane == "fused" else None
+    if plane == "two_devices":
+        return
+    reg = get_registry()
+    names = [f"lm.{layer}.plane.{p}" for layer in ("mamba", "scan")
+             for p in ("fused", "xla")]
+    batches = [batch(cfg, seed=11), batch(cfg, seed=12)]
+    before = [reg.counter(n).value for n in names]
+    assert all(np.isfinite(model.step(b)) for b in batches)
+    moved = [reg.counter(n).value - b for n, b in zip(names, before)]
+    steps = 2 * len(batches)
+    assert moved == ([steps, 0] if plane == "fused" else [0, steps]) \
+        + [0, steps]
+    ids, _, where, targets, mask = pack_batch(batches[0], cfg.row_bucket)
+    text = model._hybrid.delta.lower(
+        model.params, jnp.zeros((len(ids), cfg.hidden_size)), model.buffers,
+        where, targets, mask).as_text(debug_info=True)
+    assert ("pallas_call" in text) == (plane == "fused")
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["kept", "remat"])
+def test_mamba_block_on_the_fused_passes_is_the_block_on_xlas(remat):
+    """A Mamba-2 block through ``layer_forward``: its loss and every
+    gradient with ``mixer_interpret=True`` (the passes' kernels under the
+    interpreter) against ``mixer_interpret=None``, at the tiny widths
+    rounded up to whole lane tiles, a sequence at a time, rematerialised
+    and not."""
+    cfg = small(pattern="M", **LANE_TILES)
+    p = init_params(cfg)["layers"][0]
+    rng = np.random.default_rng(8)
+    p = dict(p, conv_b=jnp.asarray(rng.normal(size=p["conv_b"].shape) * 0.3,
+                                   jnp.float32))
+    u = jnp.asarray(rng.standard_normal((2, 40, cfg.hidden_size)),
+                    jnp.float32)
+
+    def run(interpret):
+        def loss(p, u):
+            return jnp.sum(jnp.sin(layer_forward(
+                "M", p, None, u, cfg, remat, mixer_interpret=interpret)[0]))
+        return jax.value_and_grad(loss, (0, 1))(p, u)
+
+    (loss, (dp, du)), (want, (wp, wu)) = run(True), run(None)
+    assert abs(float(loss) - float(want)) < 1e-5 * abs(float(want))
+    assert rel(du, wu) < 1e-5
+    for name in sorted(wp):
+        assert float(jnp.abs(wp[name]).max()) > 0, name
+        assert rel(dp[name], wp[name]) < 1e-5, name
 
 
 def test_step_spans_counters_and_program_names():
