@@ -4,7 +4,7 @@ docs/HYBRID_LM.md."""
 
 from multiverso_tpu.models.hybrid_lm import rope
 from multiverso_tpu.models.hybrid_lm.config import (ATTENTION, DENSE, EVA,
-                                                    EXPERTS, LATENT,
+                                                    EXPERTS, KDA, LATENT,
                                                     LIGHTNING, MAMBA,
                                                     SHORTCONV, SPARSE,
                                                     HybridLMConfig)
@@ -21,7 +21,7 @@ from multiverso_tpu.models.hybrid_lm.model import (APPLY_PROGRAM,
                                                    updated_expert_bias)
 
 __all__ = ["HybridLMConfig", "HybridLM", "MAMBA", "EXPERTS", "ATTENTION",
-           "LATENT", "DENSE", "EVA", "SPARSE", "LIGHTNING", "SHORTCONV",
+           "LATENT", "DENSE", "EVA", "SPARSE", "LIGHTNING", "SHORTCONV", "KDA",
            "DELTA_PROGRAM", "APPLY_PROGRAM", "dense_param_count",
            "exit_distribution", "forward_hidden", "looped_hidden",
            "init_buffers", "init_params", "layer_forward", "make_loss",

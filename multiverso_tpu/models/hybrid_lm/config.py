@@ -9,11 +9,11 @@ import math
 from typing import Any, Dict, Optional, Tuple
 
 __all__ = ["HybridLMConfig", "MAMBA", "EXPERTS", "ATTENTION", "LATENT",
-           "DENSE", "EVA", "SPARSE", "LIGHTNING", "SHORTCONV", "MIXER_TYPES",
-           "LAYER_TYPES"]
+           "DENSE", "EVA", "SPARSE", "LIGHTNING", "SHORTCONV", "KDA",
+           "MIXER_TYPES", "LAYER_TYPES"]
 
 MAMBA, EXPERTS, ATTENTION, LATENT, DENSE, EVA = "M", "E", "*", "L", "D", "V"
-SPARSE, LIGHTNING, SHORTCONV = "S", "N", "C"
+SPARSE, LIGHTNING, SHORTCONV, KDA = "S", "N", "C", "K"
 #: A published ``mixer_types`` entry -> the letter of its block.
 MIXER_TYPES = {"minicpm4": SPARSE, "lightning-attn": LIGHTNING}
 #: A published ``layer_types`` entry -> the letter of its mixer.
@@ -36,6 +36,20 @@ _MIXER_SWITCHES = {
     "lightning-attn": {"lightning_use_rope": True, "qk_norm": True,
                        "use_output_norm": True, "use_output_gate": True,
                        "lightning_scale": "1/sqrt(d)"}}
+
+#: What a family that publishes ``layer_group_size`` (groups of linear-
+#: attention layers closed by a latent-attention one) is implemented with, and
+#: in no other form: a file that says otherwise is refused by the key's name.
+_GROUP_SWITCHES = {
+    "kda_safe_gate": True, "no_kda_lora": True, "use_kda_lora": False,
+    "linear_silu": True, "use_qk_norm": True, "use_mla_nope": False,
+    "group_norm_size": 1, "num_kv_heads_for_linear_attn": 0,
+    "gated_attention_proj_granularity_type": "head_wise",
+    "q_lora_rank": None, "use_nGPT": False,
+    "scale_router_input": False, "value_norm": False, "up_proj_norm": False}
+#: Per-layer clamps of that family's expert activations: zero is no clamp.
+_SWIGLU_LIMITS = ("expert_swiglu_limit_list",
+                  "share_expert_swiglu_limit_list")
 
 #: Keys a file gives under the published ``config.json``'s own names: those
 #: every file has, those a kind of block needs (a file has the keys of the
@@ -60,12 +74,15 @@ _KEYS_OF_KIND = {
     LIGHTNING: ("lightning_nh", "lightning_nkv", "lightning_head_dim",
                 "rope_theta"),
     SHORTCONV: ("conv_L_cache",),
+    KDA: ("num_attention_heads:kda_num_heads", "head_dim:kda_head_dim",
+          "short_conv_kernel_size", "kda_lower_bound"),
 }
 _OPTIONAL_KEYS = ("moe_shared_expert_intermediate_size", "scoring_func",
                   "hidden_act", "aux_loss_alpha", "num_pred_heads",
                   "norm_add_unit_offset", "scale_emb", "scale_depth",
                   "dim_model_base", "use_expert_bias", "tie_word_embeddings",
-                  "total_ut_steps", "early_exit_threshold")
+                  "total_ut_steps", "early_exit_threshold", "n_group",
+                  "topk_group")
 #: A sparse block's sizes, as the published ``sparse_config`` group names them.
 _SPARSE_KEYS = ("kernel_size", "kernel_stride", "block_size", "window_size",
                 "init_blocks", "topk", "dense_len")
@@ -73,7 +90,7 @@ _SPARSE_KEYS = ("kernel_size", "kernel_stride", "block_size", "window_size",
 _OWN_KEYS = ("learning_rate", "adagrad_step", "init_std", "attn_block",
              "moe_block", "loss_block", "ffn_slab", "row_bucket",
              "comm_policy", "lightning_chunk", "expert_bias_update_rate",
-             "exit_entropy_weight")
+             "exit_entropy_weight", "kda_chunk")
 
 
 @dataclasses.dataclass
@@ -92,7 +109,8 @@ class HybridLMConfig:
     #: Lightning linear attention (a fixed decay a head). A layer of two
     #: blocks (attention, then a feed-forward) is two letters. ``C`` a gated
     #: short convolution (two gates around a depthwise causal convolution of
-    #: ``conv_L_cache`` taps).
+    #: ``conv_L_cache`` taps). ``K`` Kimi Delta Attention (a delta-rule state
+    #: with a decay a channel: :mod:`.kda`).
     pattern: str = "MEM*E"
     norm_eps: float = 1e-5
     #: Every RMSNorm scales by ``1 + w`` (``w`` drawn at zero), not by ``w``.
@@ -137,6 +155,16 @@ class HybridLMConfig:
     attn_rope: bool = False
     # -- gated short convolution: taps of the depthwise causal convolution ----
     conv_L_cache: int = 3
+    # -- Kimi Delta Attention: ``kda_num_heads`` heads of ``kda_head_dim`` keys
+    # and values, a convolution of ``short_conv_kernel_size`` taps before
+    # each of q, k, v, the log decay a channel in ``(kda_lower_bound, 0)`` ---
+    kda_num_heads: int = 2
+    kda_head_dim: int = 16
+    short_conv_kernel_size: int = 4
+    kda_lower_bound: float = -5.0
+    #: Positions a chunk of the delta rule: a power of two (whole sub-chunks
+    #: of 16, or one shorter).
+    kda_chunk: int = 64
     # -- latent attention: keys and values expanded from one latent -----------
     kv_lora_rank: int = 32
     qk_nope_head_dim: int = 16
@@ -206,6 +234,11 @@ class HybridLMConfig:
     norm_topk_prob: bool = True
     #: ``sigmoid`` (with a selection bias) or ``softmax`` (without).
     scoring_func: str = "sigmoid"
+    #: Group-limited choice (sigmoid router): the experts in ``n_group``
+    #: contiguous groups, a token's experts taken from its ``topk_group``
+    #: best groups only. 1: no groups.
+    n_group: int = 1
+    topk_group: int = 1
     #: A sigmoid router's selection bias: seeded small, or (False) zero.
     use_expert_bias: bool = True
     #: Rate ``u`` of the selection bias's own update after every step, outside
@@ -324,7 +357,7 @@ class HybridLMConfig:
     def validate(self) -> None:
         from multiverso_tpu.utils.log import check
         check(set(self.pattern) <= {MAMBA, EXPERTS, ATTENTION, LATENT, DENSE,
-                                    EVA, SPARSE, LIGHTNING, SHORTCONV}
+                                    EVA, SPARSE, LIGHTNING, SHORTCONV, KDA}
               and self.pattern, f"bad layer pattern {self.pattern!r}")
         check(not self.attn_rope or self.head_dim % 2 == 0,
               "rotary width must be even")
@@ -354,6 +387,20 @@ class HybridLMConfig:
             f"held experts {self.held} not among {self.router_experts}")
         check(self.num_experts_per_tok <= self.router_experts,
               "more experts a token than experts")
+        check(1 <= self.topk_group <= self.n_group
+              and self.router_experts % self.n_group == 0
+              and (self.n_group == 1 or (
+                  self.scoring_func == "sigmoid"
+                  and self.num_experts_per_tok
+                  <= self.topk_group * (self.router_experts // self.n_group)
+                  and self.router_experts // self.n_group >= 2)),
+              "group-limited routing: a sigmoid router's experts in n_group "
+              "equal groups of two or more, topk_group of them holding the "
+              "experts a token takes")
+        check(self.kda_lower_bound < 0 and self.short_conv_kernel_size >= 1
+              and self.kda_chunk >= 1
+              and self.kda_chunk & (self.kda_chunk - 1) == 0,
+              "a KDA gate's bound is negative, its chunk a power of two")
         check(self.scoring_func in ("sigmoid", "softmax"),
               f"unknown scoring_func {self.scoring_func!r}")
         check(self.hidden_act in ("relu2", "silu"),
@@ -409,7 +456,11 @@ class HybridLMConfig:
         file has one, or two blocks a layer from the first
         ``num_hidden_layers`` of ``mixer_types`` or of ``layer_types`` (an
         entry this program does not know raises, as does a ``model_type``
-        whose switches it does not know: :meth:`_pattern_of_layers`);
+        whose switches it does not know: :meth:`_pattern_of_layers`), or
+        two blocks a layer from ``layer_group_size`` (KDA layers in groups
+        closed by a latent-attention one, :meth:`_pattern_of_groups`; such a
+        file names its router's scores ``score_function`` and its selection
+        bias ``moe_router_enable_expert_bias``);
         else every layer is two
         blocks, attention (latent where the file has a
         ``kv_lora_rank``, EVA where its ``attention_class`` is ``eva``) and a
@@ -432,6 +483,8 @@ class HybridLMConfig:
             pattern = cls._pattern_of_mixers(d)
         elif "layer_types" in d:
             pattern = cls._pattern_of_layers(d, n_held)
+        elif "layer_group_size" in d:
+            pattern = cls._pattern_of_groups(d, n_held)
         else:
             if d.get("q_lora_rank") is not None:
                 raise ValueError("a query latent (q_lora_rank) is not "
@@ -483,6 +536,9 @@ class HybridLMConfig:
                 lightning_published_nh=published.get("lightning_nh",
                                                      d["lightning_nh"]),
                 lightning_heads=tuple(d.get("held_lightning_heads", ())))
+        if "layer_group_size" in d:
+            kw.update(scoring_func=d["score_function"], use_expert_bias=d[
+                "moe_router_enable_expert_bias"])
         kw.setdefault("moe_shared_expert_intermediate_size",
                       d.get("n_shared_experts", 0)
                       * d.get("moe_intermediate_size", 0))
@@ -543,6 +599,32 @@ class HybridLMConfig:
         dense = d["num_dense_layers"] if n_held else len(names)
         return "".join(LAYER_TYPES[name] + (DENSE if i < dense else EXPERTS)
                        for i, name in enumerate(names))
+
+    @staticmethod
+    def _pattern_of_groups(d: Dict[str, Any], n_held: int) -> str:
+        """Two blocks a layer for a file with ``layer_group_size``: layer
+        ``i`` (0-based) mixes by latent attention where ``(i + 1) %
+        layer_group_size == 0`` and by Kimi Delta Attention everywhere else;
+        then a dense feed-forward in the first ``first_k_dense_replace``
+        layers (in every layer of a file that holds no expert) and an expert
+        block in each later one. The family's switches have to read as
+        :data:`_GROUP_SWITCHES` has them, and the activation clamps of the
+        layers kept (:data:`_SWIGLU_LIMITS`) be zero: the form of a non-zero
+        one is not published with the file."""
+        for key, value in _GROUP_SWITCHES.items():
+            if d.get(key, value) != value:
+                raise ValueError(f"layer_group_size with {key} = "
+                                 f"{d[key]!r} is not implemented")
+        layers = d["num_hidden_layers"]
+        for key in _SWIGLU_LIMITS:
+            if any(d.get(key, ())[:layers]):
+                raise ValueError(
+                    f"{key} is not zero in the first {layers} layers: the "
+                    f"clamp's form is not implemented")
+        dense = d.get("first_k_dense_replace", 0) if n_held else layers
+        return "".join(
+            (LATENT if (i + 1) % d["layer_group_size"] == 0 else KDA)
+            + (DENSE if i < dense else EXPERTS) for i in range(layers))
 
     @classmethod
     def from_file(cls, path: str, **overrides) -> "HybridLMConfig":

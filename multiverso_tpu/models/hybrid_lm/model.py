@@ -11,12 +11,14 @@ inside a window and pooled chunk summaries of every earlier one
 (:mod:`.attention`, :mod:`.rope`), ``S`` block-sparse attention whose
 queries choose their key blocks themselves (:func:`.attention.sparse_mixer`),
 ``N`` Lightning linear attention with a fixed decay a head
-(:mod:`.lightning`), ``D`` a dense gated feed-forward, ``E``
+(:mod:`.lightning`), ``K`` Kimi Delta Attention, a delta-rule state with a
+decay a channel (:mod:`.kda`), ``D`` a dense gated feed-forward, ``E``
 this chip's share
 of a top-k expert layer, with shared experts where the configuration has any
 (:func:`~multiverso_tpu.parallel.expert.held_topk_moe`: sigmoid or softmax
-router, ``relu2`` or gated experts, by the configuration's published keys; a
-sigmoid router's selection bias is a buffer, moved after every step where the
+router, the sigmoid one group-limited where the configuration has
+``n_group``, ``relu2`` or gated experts, by the configuration's published
+keys; a sigmoid router's selection bias is a buffer, moved after every step where the
 configuration gives ``expert_bias_update_rate``:
 :func:`updated_expert_bias`). A layer of two
 blocks is two letters. Under muP (``scale_emb``, ``scale_depth``,
@@ -76,10 +78,11 @@ from multiverso_tpu.models.hybrid_lm.attention import (
     in_blocks, attention_mixer, eva_mixer, latent_attention_mixer,
     sparse_mixer)
 from multiverso_tpu.models.hybrid_lm.config import (ATTENTION, DENSE, EVA,
-                                                    EXPERTS, LATENT,
+                                                    EXPERTS, KDA, LATENT,
                                                     LIGHTNING, MAMBA,
                                                     SHORTCONV, SPARSE,
                                                     HybridLMConfig)
+from multiverso_tpu.models.hybrid_lm.kda import kda_mixer
 from multiverso_tpu.models.hybrid_lm.lightning import (lightning_mixer,
                                                        lightning_slopes)
 from multiverso_tpu.models.hybrid_lm import mamba2
@@ -159,6 +162,14 @@ def _mixer_shapes(cfg: HybridLMConfig, kind: str) -> Dict[str, tuple]:
         return {"norm": (d,), "wq": (d, w), "wk": (d, w), "wv": (d, w),
                 "q_norm": hd, "k_norm": hd, "o_norm": (w,), "wg": (d, w),
                 "wo": (w, d)}
+    if kind == KDA:
+        h, hd = cfg.kda_num_heads, cfg.kda_head_dim
+        taps = (h * hd, cfg.short_conv_kernel_size)
+        return {"norm": (d,), "wq": (d, h * hd), "wk": (d, h * hd),
+                "wv": (d, h * hd), "conv_q": taps, "conv_k": taps,
+                "conv_v": taps, "wa": (d, h * hd), "A_log": (h,),
+                "dt_bias": (h * hd,), "wbeta": (d, h), "wg": (d, h),
+                "o_norm": (hd,), "wo": (h * hd, d)}
     if kind == DENSE:
         f = cfg.intermediate_size
         return {"norm": (d,), "ffn_gate": (d, f), "ffn_up": (d, f),
@@ -207,9 +218,10 @@ def init_params(cfg: HybridLMConfig) -> dict:
     with the unit offset: zero), the exit gate zero (the exit distribution
     starts at 1/2, 1/4, .., the last pass taking the rest), a convolution's
     taps uniform on +-0.5 (the
-    short convolution's as Mamba-2's), ``A`` in [1, 16], ``dt`` log-uniform in
-    ``[time_step_min, time_step_max]`` through the inverse softplus, as
-    Mamba-2 draws them; EVA's ``phi`` and ``mu`` normal, clamped to [-1, 1],
+    short convolution's and KDA's as Mamba-2's), ``A`` in [1, 16], ``dt``
+    log-uniform in ``[time_step_min, time_step_max]`` through the inverse
+    softplus, as Mamba-2 draws them (a KDA block's ``A_log`` and ``dt_bias``
+    likewise, a bias a channel); EVA's ``phi`` and ``mu`` normal, clamped to [-1, 1],
     times ``head_dim ** -0.5``."""
     rng = np.random.default_rng(cfg.seed)
     depth = 1.0 if cfg.scale_depth else math.sqrt(len(cfg.pattern))
@@ -229,7 +241,7 @@ def init_params(cfg: HybridLMConfig) -> dict:
                                     math.log(cfg.time_step_max), shape))
             dt = np.maximum(dt, cfg.time_step_floor)
             return (dt + np.log(-np.expm1(-dt))).astype(np.float32)
-        if name == "conv_w":
+        if name in ("conv_w", "conv_q", "conv_k", "conv_v"):
             return rng.uniform(-0.5, 0.5, shape).astype(np.float32)
         if name in ("conv_b", "exit_gate_w", "exit_gate_b"):
             return np.zeros(shape, np.float32)
@@ -296,6 +308,7 @@ _SEQUENCE_MIXERS = {
     EVA: (eva_mixer, "lm_eva"),
     SPARSE: (sparse_mixer, "lm_attention"),
     LIGHTNING: (lightning_mixer, "lm_lightning"),
+    KDA: (kda_mixer, "lm_kda"),
     DENSE: (dense_ffn_mixer, "lm_dense_ffn"),
 }
 
@@ -410,7 +423,8 @@ def layer_forward(kind: str, p: dict, bias, u: jax.Array,
             cfg.routed_scaling_factor, cfg.norm_topk_prob, cfg.moe_block,
             "s_up" in p, cfg.scoring_func, p.get("w_gate"), p.get("s_gate"),
             (cfg.aux_loss_alpha, bsz) if cfg.balanced else None,
-            cfg.expert_bias_update_rate > 0, moe_rows_interpret)
+            cfg.expert_bias_update_rate > 0, moe_rows_interpret, cfg.n_group,
+            cfg.topk_group)
         y = y if cfg.residual_scale == 1.0 else cfg.residual_scale * y
         if cfg.post_norm:
             y = rmsnorm(y, p["post_norm"], cfg.norm_eps,
@@ -938,6 +952,13 @@ class HybridLM:
             counter("lm.lightning.chunks").inc(
                 cfg.pattern.count(LIGHTNING) * seqs
                 * (-(-length // cfg.lightning_chunk)))
+        if KDA in cfg.pattern:
+            # the delta rule has one plane so far, jax.numpy's
+            counter("lm.kda.chunks").inc(
+                cfg.pattern.count(KDA) * seqs * (-(-length // cfg.kda_chunk)))
+            counter("lm.kda.plane.xla").inc(cfg.pattern.count(KDA))
+        if cfg.n_group > 1:
+            counter("lm.moe.group_limited").inc(len(cfg.expert_layers()))
         counter("lm.moe.rows.plane.xla" if self.moe_rows_interpret is None
                 else "lm.moe.rows.plane.fused").inc(len(cfg.expert_layers()))
         scans = sum(kind in _SCANS for kind in cfg.pattern)

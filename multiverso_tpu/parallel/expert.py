@@ -141,18 +141,40 @@ def reference_top1_moe(params: MoEParams, x: jax.Array,
 
 
 # -- the held share of a sigmoid top-k expert layer ---------------------------
+def kept_groups(biased: jax.Array, n_group: int, topk_group: int
+                ) -> jax.Array:
+    """``biased`` [T, E] scores for choosing -> the same with every expert
+    outside the token's ``topk_group`` best groups at ``-inf``: the experts
+    lie in ``n_group`` contiguous groups of ``E / n_group``, a group's score
+    is the sum of its two largest entries, and the groups of largest score
+    are kept (of equal ones the lower-numbered, as ``top_k`` orders them)."""
+    t, e = biased.shape
+    by_group = biased.reshape(t, n_group, e // n_group)
+    group_score = jnp.sum(jax.lax.top_k(by_group, 2)[0], axis=-1)
+    _, best = jax.lax.top_k(group_score, topk_group)
+    kept = jnp.any(best[..., None] == jnp.arange(n_group), axis=1)
+    return jnp.where(kept[..., None], by_group, -jnp.inf).reshape(t, e)
+
+
 def sigmoid_topk_route(n: jax.Array, router: jax.Array, bias: jax.Array,
-                       top_k: int, scaling: float, normalize: bool = True
+                       top_k: int, scaling: float, normalize: bool = True,
+                       n_group: int = 1, topk_group: int = 1
                        ) -> Tuple[jax.Array, jax.Array]:
     """``n`` [T, D] -> (experts [T, k], weights [T, k]): scores
     ``sigmoid(n W_r)`` in float32 at ``highest`` (as the published code
     computes them, so that routing does not turn on a product's rounding),
-    the ``k`` largest of ``score + bias`` chosen, their own scores
-    normalised over the chosen (``+ 1e-20``) and scaled."""
+    the ``k`` largest of ``score + bias`` chosen (with ``n_group`` > 1 among
+    the experts of the token's ``topk_group`` best groups only:
+    :func:`kept_groups`), their own scores normalised over the chosen (``+
+    1e-20``) and scaled."""
     scores = jax.nn.sigmoid(jnp.dot(
         n.astype(jnp.float32), router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
-    _, chosen = jax.lax.top_k(scores + bias, top_k)
+    if n_group > 1:
+        _, chosen = jax.lax.top_k(
+            kept_groups(scores + bias, n_group, topk_group), top_k)
+    else:
+        _, chosen = jax.lax.top_k(scores + bias, top_k)
     w = jnp.take_along_axis(scores, chosen, axis=-1)
     if normalize:
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
@@ -419,14 +441,16 @@ def held_topk_moe(n: jax.Array, router: jax.Array, bias: jax.Array,
                   s_gate: Optional[jax.Array] = None,
                   balance: Optional[Tuple[float, int]] = None,
                   count_all: bool = False,
-                  rows_interpret: Optional[bool] = None):
+                  rows_interpret: Optional[bool] = None,
+                  n_group: int = 1, topk_group: int = 1):
     """One chip's share of a top-k expert layer: ``n`` [T, D] ->
     (y [T, D], assignments per held expert [len(held)]). ``router`` is
     [D, E] over ALL experts, ``w_up`` / ``w_down`` hold the experts
     ``held`` names, in that order. ``shared=False`` leaves the shared
     expert to another share (a deployment computes it once).
 
-    ``scoring``: ``sigmoid`` (the ``k`` largest of ``score + bias``) or
+    ``scoring``: ``sigmoid`` (the ``k`` largest of ``score + bias``, with
+    ``n_group`` > 1 group-limited: :func:`sigmoid_topk_route`) or
     ``softmax`` (greedy, ``bias`` unused). With ``w_gate`` / ``s_gate`` the
     experts are gated, ``(silu(n W_gate) * n W_up) W_down``; without, they
     are ``relu(n W_up)^2 W_down``. ``balance = (alpha, sequences)`` (softmax
@@ -442,8 +466,10 @@ def held_topk_moe(n: jax.Array, router: jax.Array, bias: jax.Array,
         chosen, weights, probs = softmax_topk_route(n, router, top_k,
                                                     scaling, normalize)
     else:
-        chosen, weights = sigmoid_topk_route(n, router, bias, top_k, scaling,
-                                             normalize)
+        with jax.named_scope("lm_route"):
+            chosen, weights = sigmoid_topk_route(
+                n, router, bias, top_k, scaling, normalize, n_group,
+                topk_group)
     tokens, gates, block_expert, in_use, counts = group_held_assignments(
         chosen, weights, held, router.shape[1], block)
     if w_gate is None:
