@@ -48,18 +48,19 @@ ling-3.0-flash-ep32.py``) runs the recurrence as written.
 
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 
 from multiverso_tpu.models.hybrid_lm.mamba2 import causal_conv1d
 from multiverso_tpu.models.hybrid_lm.norm import rmsnorm
+from multiverso_tpu.ops.pallas_kda import (SUB_CHUNK, kda_kernel_selected,
+                                           kda_scan)
 
 __all__ = ["kda_mixer", "kda_chunked", "kda_gate", "l2_normalised",
            "SUB_CHUNK", "GROUP_ELEMENTS"]
 
-#: Positions a sub-chunk at most: 16 steps at the gate's bound of -5 are
-#: ``e^80``, inside float32.
-SUB_CHUNK = 16
 _L2_EPS = 1e-6
 
 
@@ -165,7 +166,8 @@ def _across_chunks(u0, w, b, q_in, k_out, decay):
 
 
 def kda_chunked(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
-                beta: jax.Array, chunk: int) -> jax.Array:
+                beta: jax.Array, chunk: int,
+                interpret: Optional[bool] = None) -> jax.Array:
     """``q``, ``k``, ``g`` [B, S, H, Dk] (``g`` the log decay, not positive),
     ``v`` [B, S, H, Dv], ``beta`` [B, S, H] -> ``o`` [B, S, H, Dv], the state
     zero at each sequence's start. Any ``S``: the tail is padded with
@@ -173,13 +175,28 @@ def kda_chunked(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
     ``chunk`` is a power of two (whole sub-chunks of :data:`SUB_CHUNK`, or one
     shorter sub-chunk). What needs no state is computed for all chunks at once
     (:func:`kda_mixer` bounds the working set by the heads it hands in); the
-    walk over the chunks is five products a trip."""
+    walk over the chunks is five products a trip. ``interpret``: None unless
+    the caller knows the arrays to live on ONE device, and then
+    :func:`~multiverso_tpu.ops.pallas_interpret` of it: shapes the kernels
+    take (:func:`~multiverso_tpu.ops.pallas_kda.kda_kernel_selected`) then
+    walk the chunks with the state and a chunk's planes in VMEM
+    (:func:`~multiverso_tpu.ops.pallas_kda.kda_scan`)."""
     bsz, s, h, _ = q.shape
     sub = min(SUB_CHUNK, chunk)
     if chunk & (chunk - 1):
         raise ValueError(f"a KDA chunk of {chunk} is not a power of two")
     pad = (-s) % chunk
     nc = (s + pad) // chunk
+    if interpret is not None and kda_kernel_selected(
+            chunk, q.shape[3], v.shape[3], h, q.dtype, k.dtype, v.dtype,
+            g.dtype, beta.dtype):
+        def whole(x):                                # -> [B, nc C, H D]
+            return jnp.pad(x.reshape(bsz, s, -1), ((0, 0), (0, pad), (0, 0)))
+
+        o = kda_scan(*(whole(x) for x in (q, k, v, g)), jnp.swapaxes(
+            whole(beta).reshape(bsz, nc, chunk, h), 2, 3)[:, :, :, None],
+            interpret)
+        return o[:, :s].reshape(bsz, s, h, -1)
 
     def chunks(x):                                   # -> [nc, B, H, C, *]
         if pad:
@@ -211,8 +228,10 @@ def _heads_a_group(positions: int, heads: int, head_dim: int) -> int:
                if heads % g == 0 and g <= most)
 
 
-def kda_mixer(p: dict, n: jax.Array, cfg) -> jax.Array:
-    """``n`` [B, S, hidden] (already normed) -> the mixer's output. ``q``,
+def kda_mixer(p: dict, n: jax.Array, cfg,
+              scan_interpret: Optional[bool] = None) -> jax.Array:
+    """``n`` [B, S, hidden] (already normed) -> the mixer's output
+    (``scan_interpret``: :func:`kda_chunked`'s ``interpret``). ``q``,
     ``k``, ``v``: a projection, a depthwise causal convolution of
     ``short_conv_kernel_size`` taps without bias, ``silu``; ``q`` and ``k``
     L2-normed a head, ``q`` times ``d_k ** -0.5``; NO rotary turn. The log
@@ -230,7 +249,7 @@ def kda_mixer(p: dict, n: jax.Array, cfg) -> jax.Array:
     h, d = cfg.kda_num_heads, cfg.kda_head_dim
     per = _heads_a_group(n.shape[1], h, d)
     if per == h:
-        return _heads_mixer(p, n, cfg, h)
+        return _heads_mixer(p, n, cfg, h, scan_interpret)
     groups = h // per
     by_group = {k: jnp.moveaxis(
         p[k].reshape(p[k].shape[0], groups, -1), 1, 0) for k in _BY_COLUMNS}
@@ -238,11 +257,13 @@ def kda_mixer(p: dict, n: jax.Array, cfg) -> jax.Array:
                      for k in _BY_ROWS})
     by_group["o_norm"] = jnp.broadcast_to(p["o_norm"], (groups, d))
     return jnp.sum(jax.lax.map(
-        jax.checkpoint(lambda pg: _heads_mixer(pg, n, cfg, per)), by_group),
-        axis=0)
+        jax.checkpoint(lambda pg: _heads_mixer(pg, n, cfg, per,
+                                               scan_interpret)),
+        by_group), axis=0)
 
 
-def _heads_mixer(p: dict, n: jax.Array, cfg, h: int) -> jax.Array:
+def _heads_mixer(p: dict, n: jax.Array, cfg, h: int,
+                 interpret: Optional[bool] = None) -> jax.Array:
     """:func:`kda_mixer` for ``h`` heads: ``p`` holds their columns of the
     input projections, their taps, rates and biases, and their rows of
     ``W_o``; the result is their share of the mixer's output."""
@@ -260,7 +281,7 @@ def _heads_mixer(p: dict, n: jax.Array, cfg, h: int) -> jax.Array:
                  p["dt_bias"].reshape(h, d), cfg.kda_lower_bound)
     beta = jax.nn.sigmoid(n @ p["wbeta"])
     with jax.named_scope("lm_kda_scan"):
-        o = kda_chunked(q, k, v, g, beta, cfg.kda_chunk)
+        o = kda_chunked(q, k, v, g, beta, cfg.kda_chunk, interpret)
     y = rmsnorm(o, p["o_norm"], cfg.norm_eps) \
         * jax.nn.sigmoid(n @ p["wg"])[..., None]
     return y.reshape(bsz, s, h * d) @ p["wo"]

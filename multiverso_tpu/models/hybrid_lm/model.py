@@ -82,7 +82,7 @@ from multiverso_tpu.models.hybrid_lm.config import (ATTENTION, DENSE, EVA,
                                                     LIGHTNING, MAMBA,
                                                     SHORTCONV, SPARSE,
                                                     HybridLMConfig)
-from multiverso_tpu.models.hybrid_lm.kda import kda_mixer
+from multiverso_tpu.models.hybrid_lm import kda
 from multiverso_tpu.models.hybrid_lm.lightning import (lightning_mixer,
                                                        lightning_slopes)
 from multiverso_tpu.models.hybrid_lm import mamba2
@@ -308,7 +308,7 @@ _SEQUENCE_MIXERS = {
     EVA: (eva_mixer, "lm_eva"),
     SPARSE: (sparse_mixer, "lm_attention"),
     LIGHTNING: (lightning_mixer, "lm_lightning"),
-    KDA: (kda_mixer, "lm_kda"),
+    KDA: (kda.kda_mixer, "lm_kda"),
     DENSE: (dense_ffn_mixer, "lm_dense_ffn"),
 }
 
@@ -375,16 +375,16 @@ def layer_forward(kind: str, p: dict, bias, u: jax.Array,
     ``moe_rows_interpret`` is an expert block's ``rows_interpret``
     (:func:`~multiverso_tpu.parallel.expert.held_topk_moe`) and
     ``mixer_interpret`` a sequence mixer's kernels' ``interpret`` (a scan's,
-    :func:`~.mamba2.ssd_chunked`; the passes' round it, :func:`~.mamba2.
-    mamba2_mixer`; a ``*`` or ``L`` block's attention's, :func:`~.attention.
-    causal_gqa`): None unless the caller knows ``u`` to live on one device."""
+    :func:`~.mamba2.ssd_chunked`, or the passes' round it; the delta rule's,
+    :func:`~.kda.kda_chunked`; a ``*`` or ``L`` block's attention's, :func:`~.
+    attention.causal_gqa`): None unless ``u`` is known to be on one device."""
     keep = jax.checkpoint if remat else (lambda fn: fn)
     if kind in _SEQUENCE_MIXERS:
         mixer, scope = _SEQUENCE_MIXERS[kind]
         offset, scale = cfg.norm_add_unit_offset, cfg.residual_scale
         if kind == LIGHTNING:
             mixer = functools.partial(mixer, slopes=bias)
-        if mixer_interpret is not None and kind in _SCANS:
+        if mixer_interpret is not None and kind in _SCANS + (KDA,):
             mixer = functools.partial(mixer, scan_interpret=mixer_interpret)
         elif mixer_interpret is not None and kind in (ATTENTION, LATENT):
             mixer = functools.partial(mixer, attn_interpret=mixer_interpret)
@@ -746,14 +746,14 @@ class HybridLM:
             one_device
             and token_rows_kernel_selected(cfg.hidden_size, np.float32)
         ) else None
-        # The sequence mixers likewise, for the blocks whose shapes the
-        # kernels take: the Mamba-2 and Lightning blocks' scan (``lm.scan.
-        # plane.<plane>``), a Mamba-2 block's passes round it (``in_proj``'s
-        # output read where it lies; ``lm.mamba.plane.<plane>``), the causal
-        # attention of ``*`` and ``L`` (a call decides from its own length;
-        # ``lm.attn.plane.<plane>``): one counter each, a step and block.
+        # The sequence mixers likewise, where the kernels take a block's
+        # shapes: the Mamba-2 and Lightning blocks' scan (``lm.scan.plane.*``),
+        # a Mamba-2 block's passes round it (``lm.mamba.plane.*``), a KDA
+        # block's delta rule (``lm.kda.plane.*``), the causal attention of
+        # ``*`` and ``L`` (by a call's length; ``lm.attn.plane.*``): a counter.
         self.mixer_interpret = pallas_interpret(devices) if one_device and (
             scan_kernel_blocks(cfg) or passes_kernel_blocks(cfg)
+            or kda_kernel_blocks(cfg)
             or attn_kernel_blocks(cfg, cfg.attn_block)) else None
         loss_fn = make_loss(cfg, moe_rows_interpret=self.moe_rows_interpret,
                             mixer_interpret=self.mixer_interpret)
@@ -953,10 +953,11 @@ class HybridLM:
                 cfg.pattern.count(LIGHTNING) * seqs
                 * (-(-length // cfg.lightning_chunk)))
         if KDA in cfg.pattern:
-            # the delta rule has one plane so far, jax.numpy's
             counter("lm.kda.chunks").inc(
                 cfg.pattern.count(KDA) * seqs * (-(-length // cfg.kda_chunk)))
-            counter("lm.kda.plane.xla").inc(cfg.pattern.count(KDA))
+            fused = kda_kernel_blocks(cfg) * (self.mixer_interpret is not None)
+            counter("lm.kda.plane.fused").inc(fused)
+            counter("lm.kda.plane.xla").inc(cfg.pattern.count(KDA) - fused)
         if cfg.n_group > 1:
             counter("lm.moe.group_limited").inc(len(cfg.expert_layers()))
         counter("lm.moe.rows.plane.xla" if self.moe_rows_interpret is None
@@ -1011,6 +1012,15 @@ class HybridLM:
 
 # (Below the step programs' call chain: a Mosaic kernel's payload carries
 # the file and line of every frame above it, ROADMAP A6(4).)
+def kda_kernel_blocks(cfg: HybridLMConfig) -> int:
+    """How many of the pattern's KDA blocks have shapes the delta rule's
+    kernels take (:func:`~multiverso_tpu.ops.pallas_kda.kda_kernel_selected`:
+    a head's keys and values each one lane tile wide)."""
+    return cfg.pattern.count(KDA) * kda.kda_kernel_selected(
+        cfg.kda_chunk, cfg.kda_head_dim, cfg.kda_head_dim, cfg.kda_num_heads,
+        np.float32)
+
+
 def passes_kernel_blocks(cfg: HybridLMConfig) -> int:
     """How many of the pattern's Mamba-2 blocks have widths the fused passes
     round the scan take (:func:`~.mamba2.passes_kernel_selected`: the

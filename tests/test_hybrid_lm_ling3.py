@@ -539,15 +539,17 @@ def test_the_loss_falls():
 def test_step_counters_scopes_and_program_names():
     cfg = small()
     model = HybridLM(cfg, mode="local")
-    names = ("lm.kda.chunks", "lm.kda.plane.xla", "lm.moe.group_limited",
-             "lm.tokens", "lm.attn.pairs")
+    names = ("lm.kda.chunks", "lm.kda.plane.xla", "lm.kda.plane.fused",
+             "lm.moe.group_limited", "lm.tokens", "lm.attn.pairs")
     reg = get_registry()
     before = {n: reg.counter(n).value for n in names}
     tokens = batch(cfg, seed=6)
     model.step(tokens)
     got = {n: reg.counter(n).value - before[n] for n in names}
-    # two KDA blocks, two sequences of 40 in chunks of 32; one latent block
+    # two KDA blocks (heads of 16: jax.numpy's plane), two sequences of 40 in
+    # chunks of 32; one latent block
     assert got == {"lm.kda.chunks": 2 * 2 * 2, "lm.kda.plane.xla": 2,
+                   "lm.kda.plane.fused": 0,
                    "lm.moe.group_limited": 2, "lm.tokens": 80,
                    "lm.attn.pairs": 2 * 40 * 41 // 2}
     ids, _, where, targets, mask = pack_batch(tokens, cfg.row_bucket)
@@ -559,6 +561,74 @@ def test_step_counters_scopes_and_program_names():
                   "lm_dense_ffn", "lm_head_loss"):
         assert scope in text, scope
     assert DELTA_PROGRAM == "lm_delta_step"
+
+
+def test_heads_of_a_lane_tile_take_the_kernels_and_step_as_the_body(
+        monkeypatch):
+    """KDA heads of 128 on the leaves' one device: the delta rule walks on
+    the kernels (here under the Pallas interpreter), counted ``fused``, and
+    the model steps as one kept on ``jax.numpy``'s plane does."""
+    cfg = small(pattern="KD", kda_num_heads=2, kda_head_dim=128,
+                expert_bias_update_rate=0.0)
+    tokens = batch(cfg, seed=8)
+    names = ("lm.kda.plane.xla", "lm.kda.plane.fused")
+    reg = get_registry()
+
+    def stepped():
+        model = HybridLM(cfg, mode="local")
+        before = {n: reg.counter(n).value for n in names}
+        losses = [model.step(tokens) for _ in range(2)]
+        return model, losses, tuple(reg.counter(n).value - before[n]
+                                    for n in names)
+
+    fused, losses, counted = stepped()
+    assert fused.mixer_interpret is True and counted == (0, 2)
+    monkeypatch.setattr(kda, "kda_kernel_selected", lambda *a: False)
+    body, want, counted = stepped()
+    assert body.mixer_interpret is None and counted == (2, 0)
+    np.testing.assert_allclose(losses, want, rtol=1e-5)
+    for got, leaf in zip(jax.tree_util.tree_leaves(fused.params),
+                         jax.tree_util.tree_leaves(body.params)):
+        assert rel(got, leaf) < 1e-5
+
+
+@pytest.mark.parametrize("config", ["lfm2-8b-a1b-ep4",
+                                    "nemotron3-nano-30b-a3b-ep16",
+                                    "minicpm-sala-9b-pp8"])
+def test_without_a_kda_block_a_model_traces_as_it_did(config, monkeypatch):
+    """A configuration with no ``K`` block: the kernels' rule adds nothing to
+    what its leaves' device already decided, and its loss-and-gradient is,
+    letter for letter, the jaxpr of a program that knows no delta-rule
+    kernel."""
+    from multiverso_tpu.models.hybrid_lm import model as model_module
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           config + ".json")) as f:
+        d = json.load(f)
+    d.update(d["tiny"])
+    cfg = HybridLMConfig.from_dict(d)
+    assert "K" not in cfg.pattern
+    assert model_module.kda_kernel_blocks(cfg) == 0
+    tokens = batch(cfg, seed=9)
+    ids, _, where, targets, mask = pack_batch(tokens, cfg.row_bucket)
+
+    def traced():
+        model = HybridLM(cfg, mode="local")
+        return model.mixer_interpret, str(jax.make_jaxpr(jax.value_and_grad(
+            make_loss(cfg, moe_rows_interpret=model.moe_rows_interpret,
+                      mixer_interpret=model.mixer_interpret),
+            argnums=(0, 1), has_aux=True))(
+                model.params, jnp.zeros((len(ids), cfg.hidden_size)),
+                model.buffers, where, targets, mask))
+
+    now = traced()
+
+    def no_kernel(*a, **kw):
+        raise AssertionError("a delta-rule kernel in a model without one")
+
+    monkeypatch.setattr(model_module, "kda_kernel_blocks", lambda cfg: 0)
+    monkeypatch.setattr(kda, "kda_scan", no_kernel)
+    monkeypatch.setattr(kda, "kda_kernel_selected", no_kernel)
+    assert traced() == now
 
 
 def test_a_model_without_groups_counts_no_group_limited_block():
