@@ -9,9 +9,18 @@ regression). See SURVEY.md for the structural map of the reference this
 framework re-implements TPU-first.
 """
 
-import os as _os
+import sys as _sys
+import time as _time
 
-import jax as _jax
+# The start-up timeline's two stamps of this line (telemetry/startup.py):
+# the clock, and whether the host application had brought the backend up.
+_T_IMPORT0 = _time.time()
+_bridge = _sys.modules.get("jax._src.xla_bridge")
+_BACKEND_READY = _bridge is not None and _bridge.backends_are_initialized()
+
+import os as _os  # noqa: E402
+
+import jax as _jax  # noqa: E402
 
 
 def _configure_jax() -> None:
@@ -56,6 +65,8 @@ from multiverso_tpu.core.options import (AddOption, ArrayTableOption,
                                          GetOption, KVTableOption,
                                          MatrixTableOption)
 
+from multiverso_tpu.telemetry import startup as _startup
+
 __version__ = "0.1.0"
 
 __all__ = [
@@ -69,3 +80,5 @@ __all__ = [
     "AddOption", "GetOption", "ArrayTableOption", "MatrixTableOption",
     "KVTableOption",
 ]
+
+_startup.imported(_T_IMPORT0, _BACKEND_READY)

@@ -37,7 +37,7 @@ import numpy as np
 from multiverso_tpu.core.options import AddOption, GetOption
 from multiverso_tpu.core.updater import Updater, combine_duplicate_rows
 from multiverso_tpu.parallel import mesh as mesh_lib
-from multiverso_tpu.telemetry import gauge, span
+from multiverso_tpu.telemetry import gauge, span, startup
 from multiverso_tpu.utils.configure import get_flag
 from multiverso_tpu.utils.log import check
 from multiverso_tpu.utils.locks import make_lock, set_lock_order
@@ -227,6 +227,9 @@ class ServerStore:
                                                     mesh_axis=axes)
             with span("table.device_put", table=name, leaf=key):
                 self.state[key] = jax.device_put(leaf, leaf_sharding)
+        # When the transfers those calls started had LANDED is the start-up
+        # timeline's to stamp, from its own thread: nothing here waits.
+        startup.watch_transfers([self.data, *self.state.values()])
 
         self._row_plane = "fused_stateful" if fused_rows_selected(
             updater, self.padded_shape, self.dtype, num_servers == 1,

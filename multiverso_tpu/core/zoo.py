@@ -152,8 +152,11 @@ class Zoo:
         fleet router/local/drain roles) must never get here, so the
         children it starts can take the chips."""
         if self._mesh is None and self.started:
-            self._mesh = mesh_lib.build_mesh(devices=self._devices)
-            mesh_lib.log_backend(self._mesh.devices.flat)
+            # The backend's bring-up where the program is first to ask
+            # (near 0 where the host application already had).
+            with span("startup.backend"):
+                self._mesh = mesh_lib.build_mesh(devices=self._devices)
+                mesh_lib.log_backend(self._mesh.devices.flat)
         return self._mesh
 
     def stop(self, finalize_net: bool = True) -> None:

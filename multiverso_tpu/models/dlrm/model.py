@@ -189,6 +189,10 @@ class DLRMModel:
 
     def __init__(self, cfg: DLRMConfig, mode: str = "ps", dp_mesh=None,
                  dp_axis: Optional[str] = None):
+        with span("recsys.build", mode=mode):
+            self._build(cfg, mode, dp_mesh, dp_axis)
+
+    def _build(self, cfg: DLRMConfig, mode: str, dp_mesh, dp_axis) -> None:
         check(mode in ("ps", "local"), f"bad DLRM mode {mode!r}")
         self.cfg, self.mode = cfg, mode
         self.dense_params = init_dense_params(cfg)

@@ -721,6 +721,11 @@ class HybridLM:
                  dp_axis: Optional[str] = None,
                  params: Optional[dict] = None,
                  buffers: Optional[list] = None):
+        with span("lm.build", mode=mode):
+            self._build(cfg, mode, dp_mesh, dp_axis, params, buffers)
+
+    def _build(self, cfg: HybridLMConfig, mode: str, dp_mesh, dp_axis,
+               params, buffers) -> None:
         check(mode in ("ps", "local"), f"bad HybridLM mode {mode!r}")
         cfg.validate()
         self.cfg, self.mode = cfg, mode

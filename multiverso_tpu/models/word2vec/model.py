@@ -40,7 +40,7 @@ from multiverso_tpu.models.word2vec.dictionary import (Dictionary,
                                                        HuffmanEncoder,
                                                        Sampler)
 from multiverso_tpu.telemetry import (counter, gauge, register_program,
-                                      span)
+                                      span, startup)
 from multiverso_tpu.utils.dashboard import monitor
 from multiverso_tpu.utils.log import check, log
 
@@ -1154,6 +1154,10 @@ class Word2Vec:
     ``train()``'s ``stats["row_layout"]`` names the layout."""
 
     def __init__(self, cfg: Word2VecConfig, dictionary: Dictionary):
+        with span("w2v.build"):
+            self._build(cfg, dictionary)
+
+    def _build(self, cfg: Word2VecConfig, dictionary: Dictionary) -> None:
         check(len(dictionary) >= 2, "vocabulary too small")
         self.cfg = cfg
         self.dict = dictionary
@@ -1445,6 +1449,12 @@ class Word2Vec:
     def train(self, sentences: Optional[Iterable[Sequence[int]]] = None,
               corpus_path: Optional[str] = None,
               epochs: Optional[int] = None) -> dict:
+        stats = self._train(sentences, corpus_path, epochs)
+        if not startup.ready:
+            startup.mark_ready(("w2v.device_block", "w2v.group"))
+        return stats
+
+    def _train(self, sentences, corpus_path, epochs) -> dict:
         from multiverso_tpu.utils.async_buffer import ASyncBuffer
 
         epochs = epochs if epochs is not None else self.cfg.epochs

@@ -26,7 +26,8 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
 from multiverso_tpu.parallel import comm_policy as cp
-from multiverso_tpu.telemetry import counter, register_program, span
+from multiverso_tpu.telemetry import (counter, register_program, span,
+                                      startup)
 
 __all__ = ["HybridStep"]
 
@@ -121,6 +122,9 @@ class HybridStep:
                     row_deltas = np.asarray(row_deltas)
         with span(prefix + ".push", **attrs):
             self._push(ids, row_deltas)
+        if not startup.ready:
+            startup.mark_ready((prefix + ".pull", prefix + ".compute",
+                                prefix + ".push"))
         return aux
 
     def _place(self, dense, rows) -> list:

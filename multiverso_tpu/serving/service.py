@@ -32,7 +32,7 @@ from multiverso_tpu.parallel.net import (pack_serve_payload, recv_message,
                                          send_message, unpack_trace_ctx)
 from multiverso_tpu.serving.batcher import DynamicBatcher, ShedError
 from multiverso_tpu.telemetry import (activate, child_of, counter, emit_span,
-                                      gauge, histogram, phase)
+                                      gauge, histogram, phase, span, startup)
 from multiverso_tpu.utils.locks import make_lock
 from multiverso_tpu.utils.log import check, log
 
@@ -139,34 +139,35 @@ class ServingService:
                   f"runner id {runner_id} already registered")
             self._runners[runner_id] = runner       # reserves the id
         batcher = None
-        try:
-            if continuous and hasattr(runner, "params_ref"):
-                from multiverso_tpu.serving.continuous import \
-                    ContinuousBatcher
-                try:
-                    batcher = ContinuousBatcher(
+        with span("serve.register_runner", runner=runner_id):
+            try:
+                if continuous and hasattr(runner, "params_ref"):
+                    from multiverso_tpu.serving.continuous import \
+                        ContinuousBatcher
+                    try:
+                        batcher = ContinuousBatcher(
+                            runner, buckets, max_batch=max_batch,
+                            max_queue=max_queue, paged=paged,
+                            kv_dtype=kv_dtype, page=kv_page,
+                            pool_pages=kv_pages or None,
+                            prefix_entries=prefix_entries)
+                    except Exception as e:  # noqa: BLE001 - an unsupported
+                        # checkpoint layout (MoE / pipeline attention_lm)
+                        # must DEGRADE to drain batching, not crash serving
+                        # bring-up (ROADMAP 5b).
+                        log.warning(
+                            "-serve_continuous: runner %s does not support "
+                            "continuous decode (%s); degrading to drain "
+                            "batching", getattr(runner, "name", "?"), e)
+                if batcher is None:
+                    batcher = DynamicBatcher(
                         runner, buckets, max_batch=max_batch,
-                        max_queue=max_queue, paged=paged,
-                        kv_dtype=kv_dtype, page=kv_page,
-                        pool_pages=kv_pages or None,
-                        prefix_entries=prefix_entries)
-                except Exception as e:  # noqa: BLE001 - an unsupported
-                    # checkpoint layout (MoE / pipeline attention_lm)
-                    # must DEGRADE to drain batching, not crash serving
-                    # bring-up (ROADMAP 5b).
-                    log.warning(
-                        "-serve_continuous: runner %s does not support "
-                        "continuous decode (%s); degrading to drain "
-                        "batching", getattr(runner, "name", "?"), e)
-            if batcher is None:
-                batcher = DynamicBatcher(
-                    runner, buckets, max_batch=max_batch,
-                    max_wait_ms=max_wait_ms, max_queue=max_queue,
-                    pipeline_depth=pipeline_depth)
-        except BaseException:
-            with self._lock:        # un-reserve on a failed build
-                self._runners.pop(runner_id, None)
-            raise
+                        max_wait_ms=max_wait_ms, max_queue=max_queue,
+                        pipeline_depth=pipeline_depth)
+            except BaseException:
+                with self._lock:        # un-reserve on a failed build
+                    self._runners.pop(runner_id, None)
+                raise
         with self._lock:
             self._batchers[runner_id] = batcher
 
@@ -197,18 +198,22 @@ class ServingService:
             pairs = [(self._runners[rid], b)
                      for rid, b in self._batchers.items()]
         warmed = 0
-        for runner, b in pairs:
-            if hasattr(b, "warmup"):
-                # Continuous decode owns its own executables (prefill +
-                # step per bucket) — warm those, not the drain decode.
-                warmed += b.warmup()
-                continue
-            dtype = getattr(runner, "payload_dtype", np.int32)
-            pad_id = getattr(runner, "pad_id", 0)
-            for bucket in b.ladder.buckets:
-                mat = np.full((b.max_batch, bucket), pad_id, dtype=dtype)
-                runner.run(mat, np.zeros(b.max_batch, dtype=np.int32))
-                warmed += 1
+        with span("serve.warmup"):
+            for runner, b in pairs:
+                if hasattr(b, "warmup"):
+                    # Continuous decode owns its own executables (prefill
+                    # + step per bucket) — warm those, not the drain decode.
+                    warmed += b.warmup()
+                    continue
+                dtype = getattr(runner, "payload_dtype", np.int32)
+                pad_id = getattr(runner, "pad_id", 0)
+                for bucket in b.ladder.buckets:
+                    mat = np.full((b.max_batch, bucket), pad_id,
+                                  dtype=dtype)
+                    runner.run(mat, np.zeros(b.max_batch, dtype=np.int32))
+                    warmed += 1
+        if not startup.ready:
+            startup.mark_ready(("serve.warmup",))
         return warmed
 
     # -- connection handling -------------------------------------------------

@@ -11,6 +11,10 @@ are histogram-backed through this package) plus cross-actor tracing:
 * :func:`register_program` / :func:`program_scopes` — a device program's
   ``jax.named_scope`` parts read back from its compiled text, for the
   reader of a device trace (``device_scopes.py``);
+* ``startup.mark_ready`` / ``startup.report`` — the start-up timeline:
+  the process's start to its first completed unit by its parts, and one
+  ``compile.program`` record for each program jax compiled or fetched
+  (``startup.py``);
 * :func:`start_exporter` / ``-telemetry_dir`` — periodic JSON snapshot +
   trace export, with a multi-worker merge tool (``export.py``,
   ``scripts/telemetry_report.py``).

@@ -110,6 +110,11 @@ def metrics_snapshot(buckets: bool = True, seq: int = 0) -> Dict:
     except Exception:  # noqa: BLE001 - additive section, same contract
         pass
     try:
+        from multiverso_tpu.telemetry import startup
+        snap["startup"] = startup.report()
+    except Exception:  # noqa: BLE001 - additive section, same contract
+        pass
+    try:
         from multiverso_tpu.telemetry.profile import profile_state
         prof = profile_state()
         if prof is not None and prof.get("samples"):
@@ -456,6 +461,7 @@ def reset_telemetry() -> None:
     from multiverso_tpu.telemetry.profile import reset_profile
     from multiverso_tpu.telemetry.roofline import reset_roofline
     from multiverso_tpu.telemetry.sketch import reset_sketches
+    from multiverso_tpu.telemetry.startup import reset as reset_startup
     stop_alert_engine()
     reset_flight()
     stop_exporter()
@@ -464,6 +470,7 @@ def reset_telemetry() -> None:
     reset_profile()
     reset_critical_path()
     reset_roofline()
+    reset_startup()
     get_registry().reset()
     buf = get_trace_buffer()
     buf.clear()

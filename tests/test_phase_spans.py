@@ -232,7 +232,7 @@ def test_table_call_phase_is_observed_once_a_call(mv_env, name):
 
 
 @pytest.mark.parametrize("name", ["table.host_init", "table.device_put",
-                                  "zoo.start"])
+                                  "zoo.start", "startup.backend"])
 def test_start_up_phase_is_observed(mv_env, name):
     from multiverso_tpu.core.options import MatrixTableOption
     assert _count("zoo.start") == 1
@@ -241,6 +241,12 @@ def test_start_up_phase_is_observed(mv_env, name):
                                       updater="adagrad"))
     if name == "zoo.start":
         assert _count(name) == before       # bring-up happens once
+    elif name == "startup.backend":
+        # the mesh is built on first use, under its span, and once (the
+        # rest of the timeline's names: tests/test_startup_timeline.py)
+        assert (before, _count(name)) == (0, 1)
+        mv.create_table(MatrixTableOption(num_row=16, num_col=4))
+        assert _count(name) == 1
     else:
         # the table and its one state leaf (adagrad's g2)
         assert _count(name) - before == 2
