@@ -57,9 +57,12 @@ from multiverso_tpu.models.hybrid_lm.mamba2 import causal_conv1d
 from multiverso_tpu.models.hybrid_lm.norm import rmsnorm
 from multiverso_tpu.ops.pallas_kda import (SUB_CHUNK, kda_kernel_selected,
                                            kda_scan)
+from multiverso_tpu.ops.pallas_kda_passes import (delta_rule_inputs,
+                                                  gated_head_norm,
+                                                  kda_passes_selected)
 
 __all__ = ["kda_mixer", "kda_chunked", "kda_gate", "l2_normalised",
-           "SUB_CHUNK", "GROUP_ELEMENTS"]
+           "passes_kernel_selected", "SUB_CHUNK", "GROUP_ELEMENTS"]
 
 _L2_EPS = 1e-6
 
@@ -218,6 +221,8 @@ def kda_chunked(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
 GROUP_ELEMENTS = 1 << 23
 _BY_COLUMNS = ("wq", "wk", "wv", "wa", "wbeta", "wg")
 _BY_ROWS = ("conv_q", "conv_k", "conv_v", "A_log", "dt_bias", "wo")
+#: The input projections whose outputs the fused passes read side by side.
+_JOINED = ("wq", "wk", "wv", "wa")
 
 
 def _heads_a_group(positions: int, heads: int, head_dim: int) -> int:
@@ -228,10 +233,19 @@ def _heads_a_group(positions: int, heads: int, head_dim: int) -> int:
                if heads % g == 0 and g <= most)
 
 
+def passes_kernel_selected(cfg, *dtypes) -> bool:
+    """Whether a KDA block of ``cfg`` takes the passes on either side of its
+    delta rule through :mod:`multiverso_tpu.ops.pallas_kda_passes`, as far as
+    its widths and ``dtypes`` say."""
+    return kda_passes_selected(cfg.kda_head_dim, cfg.kda_num_heads,
+                               cfg.short_conv_kernel_size, *dtypes)
+
+
 def kda_mixer(p: dict, n: jax.Array, cfg,
               scan_interpret: Optional[bool] = None) -> jax.Array:
     """``n`` [B, S, hidden] (already normed) -> the mixer's output
-    (``scan_interpret``: :func:`kda_chunked`'s ``interpret``). ``q``,
+    (``scan_interpret``: :func:`kda_chunked`'s ``interpret``, and the
+    ``interpret`` of the passes on either side of it). ``q``,
     ``k``, ``v``: a projection, a depthwise causal convolution of
     ``short_conv_kernel_size`` taps without bias, ``silu``; ``q`` and ``k``
     L2-normed a head, ``q`` times ``d_k ** -0.5``; NO rotary turn. The log
@@ -245,10 +259,22 @@ def kda_mixer(p: dict, n: jax.Array, cfg,
     would pass :data:`GROUP_ELEMENTS` takes them a group at a time, each
     group rematerialised in the backward pass, and sums the groups' outputs
     (4.6 GB of temporaries a block at 8,192 positions otherwise: PERF.md 6,
-    PR 48)."""
+    PR 48).
+
+    Where ``scan_interpret`` is not None, widths and leaves
+    :func:`passes_kernel_selected` accepts join ``W_q | W_k | W_v | W_a``
+    into ONE product (``w_in``, the group's columns side by side) whose
+    output the fused passes of :mod:`multiverso_tpu.ops.pallas_kda_passes`
+    read in place; every other call runs :func:`_heads_mixer`'s ``jax.numpy``
+    lines."""
     h, d = cfg.kda_num_heads, cfg.kda_head_dim
     per = _heads_a_group(n.shape[1], h, d)
+    fused = scan_interpret is not None and passes_kernel_selected(
+        cfg, n.dtype, *(p[k].dtype for k in _BY_COLUMNS + _BY_ROWS
+                        + ("o_norm",)))
     if per == h:
+        if fused:
+            p = dict(p, w_in=jnp.concatenate([p[k] for k in _JOINED], axis=1))
         return _heads_mixer(p, n, cfg, h, scan_interpret)
     groups = h // per
     by_group = {k: jnp.moveaxis(
@@ -256,6 +282,9 @@ def kda_mixer(p: dict, n: jax.Array, cfg,
     by_group.update({k: p[k].reshape((groups, -1) + p[k].shape[1:])
                      for k in _BY_ROWS})
     by_group["o_norm"] = jnp.broadcast_to(p["o_norm"], (groups, d))
+    if fused:
+        by_group["w_in"] = jnp.concatenate(
+            [by_group.pop(k) for k in _JOINED], axis=-1)
     return jnp.sum(jax.lax.map(
         jax.checkpoint(lambda pg: _heads_mixer(pg, n, cfg, per,
                                                scan_interpret)),
@@ -265,23 +294,37 @@ def kda_mixer(p: dict, n: jax.Array, cfg,
 def _heads_mixer(p: dict, n: jax.Array, cfg, h: int,
                  interpret: Optional[bool] = None) -> jax.Array:
     """:func:`kda_mixer` for ``h`` heads: ``p`` holds their columns of the
-    input projections, their taps, rates and biases, and their rows of
-    ``W_o``; the result is their share of the mixer's output."""
+    input projections (with ``w_in``, the four joined ones' side by side:
+    the fused passes' plane), their taps, rates and biases, and their rows
+    of ``W_o``; the result is their share of the mixer's output."""
     bsz, s, _ = n.shape
     d = cfg.kda_head_dim
-
-    def heads(w, taps):
-        return jax.nn.silu(causal_conv1d(n @ p[w], p[taps])).reshape(
-            bsz, s, h, d)
-
-    q = l2_normalised(heads("wq", "conv_q")) * d ** -0.5
-    k = l2_normalised(heads("wk", "conv_k"))
-    v = heads("wv", "conv_v")
-    g = kda_gate((n @ p["wa"]).reshape(bsz, s, h, d), p["A_log"],
-                 p["dt_bias"].reshape(h, d), cfg.kda_lower_bound)
+    fused = "w_in" in p
     beta = jax.nn.sigmoid(n @ p["wbeta"])
+    if fused:
+        u = n @ p["w_in"]
+        with jax.named_scope("lm_kda_passes"):
+            q, k, v, g = (x.reshape(bsz, s, h, d) for x in delta_rule_inputs(
+                u, (p["conv_q"], p["conv_k"], p["conv_v"]), p["A_log"],
+                p["dt_bias"], d, cfg.kda_lower_bound, interpret))
+    else:
+        projected = {w: n @ p[w] for w in _JOINED}
+        with jax.named_scope("lm_kda_passes"):
+            def heads(w, taps):
+                return jax.nn.silu(causal_conv1d(projected[w], p[taps])
+                                   ).reshape(bsz, s, h, d)
+
+            q = l2_normalised(heads("wq", "conv_q")) * d ** -0.5
+            k = l2_normalised(heads("wk", "conv_k"))
+            v = heads("wv", "conv_v")
+            g = kda_gate(projected["wa"].reshape(bsz, s, h, d), p["A_log"],
+                         p["dt_bias"].reshape(h, d), cfg.kda_lower_bound)
     with jax.named_scope("lm_kda_scan"):
         o = kda_chunked(q, k, v, g, beta, cfg.kda_chunk, interpret)
-    y = rmsnorm(o, p["o_norm"], cfg.norm_eps) \
-        * jax.nn.sigmoid(n @ p["wg"])[..., None]
-    return y.reshape(bsz, s, h * d) @ p["wo"]
+    gate = n @ p["wg"]
+    with jax.named_scope("lm_kda_passes"):
+        y = gated_head_norm(o.reshape(bsz, s, h * d), gate, p["o_norm"],
+                            cfg.norm_eps, interpret) if fused \
+            else (rmsnorm(o, p["o_norm"], cfg.norm_eps)
+                  * jax.nn.sigmoid(gate)[..., None]).reshape(bsz, s, h * d)
+    return y @ p["wo"]

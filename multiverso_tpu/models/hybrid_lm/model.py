@@ -758,7 +758,7 @@ class HybridLM:
         # ``*`` and ``L`` (by a call's length; ``lm.attn.plane.*``): a counter.
         self.mixer_interpret = pallas_interpret(devices) if one_device and (
             scan_kernel_blocks(cfg) or passes_kernel_blocks(cfg)
-            or kda_kernel_blocks(cfg)
+            or kda_kernel_blocks(cfg) or kda_passes_blocks(cfg)
             or attn_kernel_blocks(cfg, cfg.attn_block)) else None
         loss_fn = make_loss(cfg, moe_rows_interpret=self.moe_rows_interpret,
                             mixer_interpret=self.mixer_interpret)
@@ -963,6 +963,11 @@ class HybridLM:
             fused = kda_kernel_blocks(cfg) * (self.mixer_interpret is not None)
             counter("lm.kda.plane.fused").inc(fused)
             counter("lm.kda.plane.xla").inc(cfg.pattern.count(KDA) - fused)
+            # and the passes on either side of it
+            fused = kda_passes_blocks(cfg) * (self.mixer_interpret is not None)
+            counter("lm.kda.passes.plane.fused").inc(fused)
+            counter("lm.kda.passes.plane.xla").inc(
+                cfg.pattern.count(KDA) - fused)
         if cfg.n_group > 1:
             counter("lm.moe.group_limited").inc(len(cfg.expert_layers()))
         counter("lm.moe.rows.plane.xla" if self.moe_rows_interpret is None
@@ -1024,6 +1029,15 @@ def kda_kernel_blocks(cfg: HybridLMConfig) -> int:
     return cfg.pattern.count(KDA) * kda.kda_kernel_selected(
         cfg.kda_chunk, cfg.kda_head_dim, cfg.kda_head_dim, cfg.kda_num_heads,
         np.float32)
+
+
+def kda_passes_blocks(cfg: HybridLMConfig) -> int:
+    """How many of the pattern's KDA blocks have widths the fused passes
+    round the delta rule take (:func:`~.kda.passes_kernel_selected`: the
+    convolutions, ``silu``, the L2 norms and the gate reading ONE joined
+    projection's output; the output norm and gate)."""
+    return cfg.pattern.count(KDA) * kda.passes_kernel_selected(
+        cfg, np.float32)
 
 
 def passes_kernel_blocks(cfg: HybridLMConfig) -> int:

@@ -540,6 +540,7 @@ def test_step_counters_scopes_and_program_names():
     cfg = small()
     model = HybridLM(cfg, mode="local")
     names = ("lm.kda.chunks", "lm.kda.plane.xla", "lm.kda.plane.fused",
+             "lm.kda.passes.plane.xla", "lm.kda.passes.plane.fused",
              "lm.moe.group_limited", "lm.tokens", "lm.attn.pairs")
     reg = get_registry()
     before = {n: reg.counter(n).value for n in names}
@@ -549,7 +550,8 @@ def test_step_counters_scopes_and_program_names():
     # two KDA blocks (heads of 16: jax.numpy's plane), two sequences of 40 in
     # chunks of 32; one latent block
     assert got == {"lm.kda.chunks": 2 * 2 * 2, "lm.kda.plane.xla": 2,
-                   "lm.kda.plane.fused": 0,
+                   "lm.kda.plane.fused": 0, "lm.kda.passes.plane.xla": 2,
+                   "lm.kda.passes.plane.fused": 0,
                    "lm.moe.group_limited": 2, "lm.tokens": 80,
                    "lm.attn.pairs": 2 * 40 * 41 // 2}
     ids, _, where, targets, mask = pack_batch(tokens, cfg.row_bucket)
@@ -557,7 +559,8 @@ def test_step_counters_scopes_and_program_names():
                                       has_aux=True)).lower(
         model.params, jnp.zeros((len(ids), cfg.hidden_size)), model.buffers,
         where, targets, mask).as_text(debug_info=True)
-    for scope in ("lm_kda", "lm_kda_scan", "lm_mla", "lm_experts", "lm_route",
+    for scope in ("lm_kda", "lm_kda_scan", "lm_kda_passes", "lm_mla",
+                  "lm_experts", "lm_route",
                   "lm_dense_ffn", "lm_head_loss"):
         assert scope in text, scope
     assert DELTA_PROGRAM == "lm_delta_step"
@@ -566,12 +569,14 @@ def test_step_counters_scopes_and_program_names():
 def test_heads_of_a_lane_tile_take_the_kernels_and_step_as_the_body(
         monkeypatch):
     """KDA heads of 128 on the leaves' one device: the delta rule walks on
-    the kernels (here under the Pallas interpreter), counted ``fused``, and
-    the model steps as one kept on ``jax.numpy``'s plane does."""
+    the kernels and the passes on either side of it are the fused ones (here
+    under the Pallas interpreter), each counted ``fused``, and the model
+    steps as one kept on ``jax.numpy``'s planes does."""
     cfg = small(pattern="KD", kda_num_heads=2, kda_head_dim=128,
                 expert_bias_update_rate=0.0)
     tokens = batch(cfg, seed=8)
-    names = ("lm.kda.plane.xla", "lm.kda.plane.fused")
+    names = ("lm.kda.plane.xla", "lm.kda.plane.fused",
+             "lm.kda.passes.plane.xla", "lm.kda.passes.plane.fused")
     reg = get_registry()
 
     def stepped():
@@ -582,11 +587,16 @@ def test_heads_of_a_lane_tile_take_the_kernels_and_step_as_the_body(
                                     for n in names)
 
     fused, losses, counted = stepped()
-    assert fused.mixer_interpret is True and counted == (0, 2)
+    assert fused.mixer_interpret is True and counted == (0, 2, 0, 2)
+    # the delta rule on jax.numpy, the passes still the kernels'
     monkeypatch.setattr(kda, "kda_kernel_selected", lambda *a: False)
+    passes, between, counted = stepped()
+    assert passes.mixer_interpret is True and counted == (2, 0, 0, 2)
+    monkeypatch.setattr(kda, "kda_passes_selected", lambda *a: False)
     body, want, counted = stepped()
-    assert body.mixer_interpret is None and counted == (2, 0)
+    assert body.mixer_interpret is None and counted == (2, 0, 2, 0)
     np.testing.assert_allclose(losses, want, rtol=1e-5)
+    np.testing.assert_allclose(between, want, rtol=1e-5)
     for got, leaf in zip(jax.tree_util.tree_leaves(fused.params),
                          jax.tree_util.tree_leaves(body.params)):
         assert rel(got, leaf) < 1e-5
@@ -608,6 +618,7 @@ def test_without_a_kda_block_a_model_traces_as_it_did(config, monkeypatch):
     cfg = HybridLMConfig.from_dict(d)
     assert "K" not in cfg.pattern
     assert model_module.kda_kernel_blocks(cfg) == 0
+    assert model_module.kda_passes_blocks(cfg) == 0
     tokens = batch(cfg, seed=9)
     ids, _, where, targets, mask = pack_batch(tokens, cfg.row_bucket)
 
@@ -626,8 +637,11 @@ def test_without_a_kda_block_a_model_traces_as_it_did(config, monkeypatch):
         raise AssertionError("a delta-rule kernel in a model without one")
 
     monkeypatch.setattr(model_module, "kda_kernel_blocks", lambda cfg: 0)
+    monkeypatch.setattr(model_module, "kda_passes_blocks", lambda cfg: 0)
     monkeypatch.setattr(kda, "kda_scan", no_kernel)
     monkeypatch.setattr(kda, "kda_kernel_selected", no_kernel)
+    monkeypatch.setattr(kda, "kda_passes_selected", no_kernel)
+    monkeypatch.setattr(kda, "delta_rule_inputs", no_kernel)
     assert traced() == now
 
 

@@ -334,16 +334,24 @@ def test_mamba_block_compiles_for_v5e_with_its_passes_fused(one_chip, cfg,
 @pytest.mark.parametrize("plane", ["fused", "xla"])
 def test_kda_block_compiles_for_v5e_with_its_state_in_vmem(one_chip, plane):
     """A KDA block at the published shapes (8,192 positions, groups of 8
-    heads of 128, chunks of 64), forward and backward. On the kernels every
-    ``pallas_call`` lies under ``lm_kda_scan`` (the head group's
-    rematerialised forward, which writes the chunks' starting states, and the
-    backward; nothing reads the first forward's output under a loss that is
-    a sum) and the walk over the 128 chunks is no loop of the program's any
-    more; on ``jax.numpy`` it is two (forward and backward)."""
+    heads of 128, chunks of 64), forward and backward. On the kernels the
+    delta rule's two ``pallas_call``s lie under ``lm_kda_scan`` (the head
+    group's rematerialised forward, which writes the chunks' starting states,
+    and the backward; nothing reads the first forward's output under a loss
+    that is a sum) and the walk over the 128 chunks is no loop of the
+    program's any more; the passes on either side of it
+    (``ops/pallas_kda_passes.py``) lie under ``lm_kda_passes`` and outside
+    ``lm_kda_scan``: four parts of the joined projection's output and the
+    output norm, rematerialised forward and backward each, ``u``'s gradient
+    handed on from part to part and never joined by a copy. On ``jax.numpy``
+    the walk is two loops (forward and backward) and no kernel is called."""
     import re
+
+    from multiverso_tpu.models.hybrid_lm.model import kda_passes_blocks
     cfg = HybridLMConfig.from_file(os.path.join(
         ROOT, "benchmark", "configs", LING3 + ".json"))
     assert cfg.pattern[0] == "K" and SEQ // cfg.kda_chunk == 128
+    assert kda_passes_blocks(cfg) == cfg.pattern.count("K") == 5
 
     def spec(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -361,16 +369,31 @@ def test_kda_block_compiles_for_v5e_with_its_state_in_vmem(one_chip, plane):
              for path in [re.search(r'op_name="([^"]*)"', line).group(1)]
              if "lm_kda_scan" in scope_names(path)]
     calls = _kernel_calls(text)
+    stats = compiled.memory_analysis()
+    print(f"KDA block, {plane}: temporaries "
+          f"{stats.temp_size_in_bytes / 1e9:.3f} GB, code "
+          f"{stats.generated_code_size_in_bytes / 1e6:.1f} MB")
+    assert "lm_kda_passes" in _scopes_of(compiled)
     if plane == "xla":
         assert len(walks) == 2 and not calls
-        assert compiled.memory_analysis().temp_size_in_bytes < 2.0e9
+        assert stats.temp_size_in_bytes < 2.0e9
         return
     assert not walks
-    assert len(calls) == 2 and all(
-        "lm_kda_scan" in scope_names(path) for path in calls)
-    assert sorted(path.split("/")[-2] for path in calls) == [
+    scan_calls = [path for path in calls
+                  if "lm_kda_scan" in scope_names(path)]
+    assert sorted(path.split("/")[-2] for path in scan_calls) == [
         "jit(_backward)", "jit(_forward)"]
-    assert compiled.memory_analysis().temp_size_in_bytes < 1.3e9
+    passes = [path for path in calls if path not in scan_calls]
+    assert all("lm_kda_passes" in scope_names(path) for path in passes)
+    # q, k, v and the gate; the output norm
+    for name, count in (("_inputs_forward", 4), ("_inputs_backward", 4),
+                        ("_out_forward", 1), ("_out_backward", 1)):
+        assert sum(f"jit({name})" in path for path in passes) == count, name
+    assert len(passes) == 10
+    # what still moves 128 MB or more: the joined weights' gradient cut into
+    # its four leaves, once a block; nothing a position long
+    assert not [m for m in _moved_whole(text, 128e6) if str(SEQ) in m[1]]
+    assert stats.temp_size_in_bytes < 1.3e9
 
 
 @pytest.mark.parametrize("seqs,length,kv_heads,group,width,value_width,taken", [
@@ -676,13 +699,22 @@ def test_ling3_step_compiles_for_v5e_inside_a_chips_memory(one_chip):
             spec((2, SEQ), jnp.int32), spec((2, SEQ), jnp.int32),
             spec((2, SEQ))).compile()
     _experts_on_the_row_kernel_among_others(compiled, {"lm_mla", "lm_kda"})
-    walks = [path for path in _kernel_calls(compiled.as_text())
-             if "lm_kda" in scope_names(path)]
-    assert all("lm_kda_scan" in scope_names(path) for path in walks)
+    in_kda = [path for path in _kernel_calls(compiled.as_text())
+              if "lm_kda" in scope_names(path)]
+    walks = [path for path in in_kda if "lm_kda_scan" in scope_names(path)]
     blocks = cfg.pattern.count("K")
     assert sum("jit(_backward)" in path for path in walks) == blocks
     assert sum("jit(_forward)" in path for path in walks) == 2 * blocks
+    # the passes on either side of the rule (PR 51), forward twice as the rule
+    passes = [path for path in in_kda if path not in walks]
+    assert all("lm_kda_passes" in scope_names(path) for path in passes)
+    for name, count in (("_inputs_forward", 8), ("_inputs_backward", 4),
+                        ("_out_forward", 2), ("_out_backward", 1)):
+        assert sum(f"jit({name})" in path
+                   for path in passes) == count * blocks, name
     stats = compiled.memory_analysis()
+    print(f"ling3 step: temporaries {stats.temp_size_in_bytes / 1e9:.3f} GB, "
+          f"code {stats.generated_code_size_in_bytes / 1e6:.1f} MB")
     plane = 4 * dense_param_count(cfg)
     table = 2 * 4 * cfg.vocab_size * cfg.hidden_size
     assert plane == 4 * 657_397_536
@@ -694,6 +726,10 @@ def test_ling3_step_compiles_for_v5e_inside_a_chips_memory(one_chip):
                 + stats.temp_size_in_bytes + plane + table)
     # the jax.numpy delta rule's program reserved 15.17-15.53 GB (PR 48)
     assert reserved < 15.2e9, (reserved, stats)
+    # the five equal KDA blocks share their code (68.6 MB; 79.0 before the
+    # passes' kernels): a program that stops sharing it reads twice that, and
+    # ``peak_hbm_gb`` counts it (PR 47: +1.5%, past the bound)
+    assert stats.generated_code_size_in_bytes < 90e6, stats
     assert {"lm_embed", "lm_head_loss", "lm_kda", "lm_kda_scan", "lm_mla",
             "lm_dense_ffn", "lm_experts", "lm_route"} <= _scopes_of(compiled)
 
